@@ -940,51 +940,62 @@ def merge_states(a: AggState, b: AggState) -> AggState:
 
 def pack_f64_bits(x: jnp.ndarray) -> jnp.ndarray:
     """IEEE-754 bit pattern of float64 values as two int32 words
-    (..., [hi, lo]), composed ARITHMETICALLY — frexp + integer shifts —
-    because the TPU x64 rewrite has no lowering for a 64-bit
-    bitcast-convert (the reason f64 result rows historically rode a
-    second fetch array; see _tile_program).  int32 words bitcast to
-    bytes fine, so f64 rows can join the one flat result buffer and the
-    whole compact readback ships as a SINGLE device_get.
+    (..., [hi, lo]), composed ARITHMETICALLY because the TPU x64 rewrite
+    has no lowering for a 64-bit bitcast-convert — and `jnp.frexp` /
+    `jnp.signbit` on float64 lower through exactly that bitcast, so
+    neither appears here.  The exponent comes from a branch-free binary
+    search over exact power-of-two scalings, the mantissa from two exact
+    floor splits, the sign from the float32 cast (sign-preserving for
+    every input, and a 32-bit bitcast lowers fine).  Everything but the
+    float64 compares/multiplies is int32.  int32 words bitcast to bytes
+    fine, so f64 rows can join the one flat result buffer and the whole
+    compact readback ships as a SINGLE device_get.
 
     Bit-exact for every NORMAL finite value and signed zero; +/-inf keep
-    their sign; NaNs canonicalize to the quiet NaN (payloads never
-    survive SQL semantics — a NaN output only ever means NULL or
-    propagates as NaN either way).  Subnormals degrade to signed zero on
-    backends that flush denormals in arithmetic (XLA CPU treats a
-    subnormal operand as zero even in comparisons, so no arithmetic
-    re-encode can see one); device kernels flush them identically in the
-    aggregation itself, so this loses nothing the dispatch had."""
+    their sign; NaNs canonicalize to the positive quiet NaN (payloads
+    never survive SQL semantics — a NaN output only ever means NULL or
+    propagates as NaN either way).  Subnormals flush to signed zero:
+    XLA CPU treats a subnormal operand as zero even in comparisons, so
+    no arithmetic re-encode can see one, and device kernels flush them
+    identically in the aggregation itself, so this loses nothing the
+    dispatch had.  On the chip float64 itself is emulated (a float32
+    pair: ~48 mantissa bits, float32 exponent range); the words are then
+    the exact bits of the emulated value."""
     xf = x.astype(jnp.float64)
-    neg = jnp.signbit(xf)
-    ax = jnp.abs(xf)
-    # jnp.frexp mis-decomposes subnormals (observed m=0.5/e=-1074 for
-    # every subnormal on the CPU backend): pre-scale them into the
-    # normal range by an exact power of two and correct the exponent
-    tiny = ax < jnp.float64(2.2250738585072014e-308)  # < DBL_MIN
-    m, e = jnp.frexp(jnp.where(tiny, ax * jnp.float64(2.0**64), ax))
-    e = e - jnp.where(tiny, 64, 0)  # ax = m * 2^e with m in [0.5, 1)
-    # 2^52 <= mi < 2^53 exactly (m has <= 53 significant bits); the
-    # garbage mi produces for inf/NaN inputs is discarded by the wheres
-    mi = (m * jnp.float64(1 << 53)).astype(jnp.int64)
-    be = e.astype(jnp.int64) + 1022  # IEEE biased exponent
-    # subnormals: biased exponent <= 0 stores as 0 with the mantissa
-    # shifted right — exact, true subnormals have the low bits free
-    shift = jnp.clip(1 - be, 0, 54)
-    frac = jnp.where(be > 0, mi - (jnp.int64(1) << 52), mi >> shift)
-    stored_e = jnp.clip(be, 0, 0x7FE)
-    is_zero = ax == 0
-    is_inf = jnp.isinf(xf)
     is_nan = jnp.isnan(xf)
-    frac = jnp.where(is_zero | is_inf, jnp.int64(0), frac)
-    frac = jnp.where(is_nan, jnp.int64(1) << 51, frac)  # canonical qNaN
-    stored_e = jnp.where(is_zero, jnp.int64(0), stored_e)
-    stored_e = jnp.where(is_inf | is_nan, jnp.int64(0x7FF), stored_e)
-    frac_hi = (frac >> 32).astype(jnp.int32)  # 20 bits
-    frac_lo = frac & jnp.int64(0xFFFFFFFF)
-    # wrap the low word into signed int32 range without a 64->32 bitcast
-    lo = (frac_lo - ((frac_lo >> 31) << 32)).astype(jnp.int32)
-    hi = (stored_e.astype(jnp.int32) << 20) | frac_hi
+    neg = jnp.signbit(xf.astype(jnp.float32)) & ~is_nan
+    ax = jnp.abs(xf)
+    is_inf = jnp.isinf(xf)
+    is_zero = ax < jnp.float64(2.2250738585072014e-308)  # < DBL_MIN
+    # normalize ax = m * 2^e with m in [1, 2): every scaling is by an
+    # exact power of two, so m keeps all 53 significant bits.  The
+    # garbage inf/NaN/zero inputs produce is discarded by the wheres.
+    m = ax
+    e = jnp.zeros(ax.shape, jnp.int32)
+    for k in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+        big = m >= jnp.float64(2.0**k)
+        m = jnp.where(big, m * jnp.float64(2.0**-k), m)
+        e = e + jnp.where(big, k, 0)
+    for k in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+        small = m < jnp.float64(2.0 ** (1 - k))
+        m = jnp.where(small, m * jnp.float64(2.0**k), m)
+        e = e - jnp.where(small, k, 0)
+    # 52 fraction bits = 20 (hi word) + 16 + 16 (lo word), split with
+    # exact floors so no conversion ever leaves the int32 range
+    t = m * jnp.float64(1 << 20)  # [2^20, 2^21)
+    t_hi = jnp.floor(t)
+    frac_hi = t_hi.astype(jnp.int32) - (1 << 20)
+    r = (t - t_hi) * jnp.float64(1 << 16)  # [0, 2^16)
+    r_hi = jnp.floor(r)
+    r_lo = (r - r_hi) * jnp.float64(1 << 16)  # exact integer < 2^16
+    lo = (r_hi.astype(jnp.int32) << 16) | r_lo.astype(jnp.int32)
+    stored_e = e + 1023  # IEEE biased exponent, [1, 2046] for normals
+    frac_hi = jnp.where(is_zero | is_inf, 0, frac_hi)
+    frac_hi = jnp.where(is_nan, 1 << 19, frac_hi)  # canonical qNaN
+    lo = jnp.where(is_zero | is_inf | is_nan, 0, lo)
+    stored_e = jnp.where(is_zero, 0, stored_e)
+    stored_e = jnp.where(is_inf | is_nan, 0x7FF, stored_e)
+    hi = (stored_e << 20) | frac_hi
     # sign bit via addition: hi is < 2^31 here, so adding INT32_MIN sets
     # exactly bit 31 in two's complement
     hi = hi + jnp.where(neg, jnp.int32(-(2**31)), jnp.int32(0))

@@ -43,40 +43,103 @@ class RangeSpec:
         return -(-self.range_ // self.step)  # ceil
 
 
+# Row width of the blocked prefix scan below: one pass per doubling
+# inside a row (log2 = 10 passes over the plane), then the same scan over
+# the n/1024 row totals.
+_SCAN_COLS = 1024
+
+
+def _shift_right(x: jnp.ndarray, k: int, fill) -> jnp.ndarray:
+    """x shifted k places along its LAST axis, `fill` shifted in."""
+    pad = jnp.full(x.shape[:-1] + (k,), fill, x.dtype)
+    return jnp.concatenate([pad, x[..., :-k]], axis=-1)
+
+
+def prefix_scan(combine, xs: tuple, identity: tuple) -> tuple:
+    """Inclusive prefix scan of the 1-D arrays `xs` (scanned together as
+    one element tuple) under the associative `combine(left, right)`,
+    with `identity` the per-array left-identity values.
+
+    Two-level Hillis-Steele: the plane is viewed as [n/1024, 1024], each
+    doubling step combines a row with itself shifted along the minor
+    axis, and the row totals are scanned the same way (recursively) and
+    folded back in.  Only whole-array shifts and elementwise ops — the
+    forms whose compile time for the chip does not grow with the plane:
+    `jnp.cumsum` on float64 and a 1-D `lax.associative_scan` (strided
+    slices of a long 1-D array) both take minutes to compile at 2^20
+    rows, this takes seconds at 2^24."""
+    n = xs[0].shape[0]
+    if n <= _SCAN_COLS:
+        k = 1
+        while k < n:
+            shifted = tuple(_shift_right(x, k, i) for x, i in zip(xs, identity))
+            xs = combine(shifted, xs)
+            k *= 2
+        return xs
+    rows = -(-n // _SCAN_COLS)
+    padded = rows * _SCAN_COLS
+    ys = tuple(
+        jnp.concatenate([x, jnp.full((padded - n,), i, x.dtype)])
+        .reshape(rows, _SCAN_COLS) if padded != n
+        else x.reshape(rows, _SCAN_COLS)
+        for x, i in zip(xs, identity)
+    )
+    k = 1
+    while k < _SCAN_COLS:
+        shifted = tuple(_shift_right(y, k, i) for y, i in zip(ys, identity))
+        ys = combine(shifted, ys)
+        k *= 2
+    totals = prefix_scan(combine, tuple(y[:, -1] for y in ys), identity)
+    before = tuple(
+        _shift_right(t, 1, i)[:, None] for t, i in zip(totals, identity)
+    )
+    ys = combine(before, ys)
+    return tuple(y.reshape(padded)[:n] for y in ys)
+
+
+def _running_max(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running maximum of a non-negative-or-(-1) index array."""
+    (out,) = prefix_scan(
+        lambda a, b: (jnp.maximum(a[0], b[0]),), (x,), (-1,)
+    )
+    return out
+
+
+def _sum_since_start(starts: jnp.ndarray, adds: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running sum of `adds` that restarts at every row where
+    `starts` is set (a segmented scan: the sum a row sees never leaves
+    its own series, so its magnitude — and its rounding error — is the
+    series', not the whole plane's)."""
+
+    def combine(a, b):
+        (fa, va), (fb, vb) = a, b
+        return fa | fb, jnp.where(fb, vb, va + vb)
+
+    return prefix_scan(combine, (starts, adds), (False, 0.0))[1]
+
+
 def strip_counter_resets_segmented(
     series: jnp.ndarray, values: jnp.ndarray, valid: jnp.ndarray
 ) -> jnp.ndarray:
     """`strip_counter_resets` for PADDED tile planes: invalid rows (pad
     rows, dedup losers, rows outside the fetch range) may sit BETWEEN a
     series' samples, so "previous sample" means the previous VALID row of
-    the same series, found with a cummax over valid row indices.  The
-    accumulation mirrors `strip_counter_resets` operation-for-operation
-    (global cumsum of reset adds, then per-series baseline subtraction):
-    invalid rows contribute exact 0.0 terms to the cumsum, so on the same
-    logical sample sequence the output is BIT-identical to running the
-    dense kernel on the compacted array.  Only valid rows' outputs are
-    meaningful."""
+    the same series, found with a running max over valid row indices.
+    The accumulation is `strip_counter_resets`' (a running sum of reset
+    adds restarting at each series' first valid row); invalid rows
+    contribute exact 0.0 terms, so on the same logical sample sequence
+    the two agree to the last ulp or two (the scan tree's shape follows
+    the row positions).  Only valid rows' outputs are meaningful."""
     n = series.shape[0]
-    idx = jnp.arange(n)
-    last_valid = jax.lax.associative_scan(
-        jnp.maximum, jnp.where(valid, idx, -1)
-    )
-    prev_idx = jnp.concatenate([jnp.full((1,), -1), last_valid[:-1]])
+    idx = jnp.arange(n, dtype=jnp.int32)
+    last_valid = _running_max(jnp.where(valid, idx, -1))
+    prev_idx = jnp.concatenate([jnp.full((1,), -1, jnp.int32), last_valid[:-1]])
     safe_prev = jnp.clip(prev_idx, 0, None)
     pv = jnp.take(values, safe_prev)
     ps = jnp.take(series, safe_prev)
     same = valid & (prev_idx >= 0) & (ps == series)
     reset_add = jnp.where(same & (values < pv), pv, 0.0)
-    cum = jnp.cumsum(reset_add)
-    is_first = valid & ~same
-    marked = jnp.where(is_first, idx, -1)
-    last_first_idx = jax.lax.associative_scan(jnp.maximum, marked)
-    baseline = jnp.where(
-        last_first_idx >= 0,
-        jnp.take(cum - reset_add, jnp.clip(last_first_idx, 0, None)),
-        0.0,
-    )
-    return values + (cum - baseline)
+    return values + _sum_since_start(valid & ~same, reset_add)
 
 
 def strip_counter_resets(series: jnp.ndarray, values: jnp.ndarray, valid: jnp.ndarray):
@@ -89,21 +152,9 @@ def strip_counter_resets(series: jnp.ndarray, values: jnp.ndarray, valid: jnp.nd
     prev_valid = jnp.concatenate([jnp.zeros(1, dtype=bool), valid[:-1]])
     same = (series == prev_s) & prev_valid & valid
     reset_add = jnp.where(same & (values < prev_v), prev_v, 0.0)
-    cum = jnp.cumsum(reset_add)
-    # Subtract each series' cumsum baseline (value of cum just before its
-    # first element) so accumulation restarts per series.
-    is_first = ~same & valid
-    # Propagate the most recent series-start baseline forward (series are
-    # contiguous in the sorted layout), then subtract it.
-    idx = jnp.arange(series.shape[0])
-    marked = jnp.where(is_first, idx, -1)
-    last_first_idx = jax.lax.associative_scan(jnp.maximum, marked)
-    baseline = jnp.where(
-        last_first_idx >= 0,
-        jnp.take(cum - reset_add, jnp.clip(last_first_idx, 0, None)),
-        0.0,
-    )
-    return values + (cum - baseline)
+    # accumulation restarts at each series' first row (series are
+    # contiguous in the sorted layout)
+    return values + _sum_since_start(valid & ~same, reset_add)
 
 
 @dataclass
