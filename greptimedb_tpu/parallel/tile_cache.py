@@ -936,10 +936,13 @@ class TileCacheManager:
         h = hashlib.sha1("|".join(file_ids).encode()).hexdigest()[:16]
         return os.path.join(self.persist_dir, f"region_{region_id}", h)
 
-    def _try_load_persisted(self, entry: _SuperTiles) -> bool:
+    def _try_load_persisted(
+        self, entry: _SuperTiles, dictionary: TableDictionary
+    ) -> bool:
         """Attach a persisted consolidation to a fresh entry: order,
         sorted host planes, file offsets and mmap'd column buffers.
-        Returns True when the store matched this exact file-set."""
+        Returns True when the store matched this exact file-set AND its
+        tag codes can still be brought to the current dictionary."""
         d = self._fileset_dir(entry.region_id, entry.file_ids)
         if d is None or not os.path.exists(os.path.join(d, "meta.json")):
             return False
@@ -949,6 +952,20 @@ class TileCacheManager:
             with open(os.path.join(d, "meta.json")) as f:
                 meta = json.load(f)
             if tuple(meta["file_ids"]) != entry.file_ids:
+                return False
+            stored_epochs = [
+                *meta.get("host_epochs", {}).values(),
+                *meta.get("epochs", {}).values(),
+            ]
+            if not all(dictionary.can_repair_from(e) for e in stored_epochs):
+                # written by an earlier process before the dictionary
+                # last grew (another region of the table added tag values
+                # that sort in between): the codes are stale and the
+                # permutation that would repair them died with that
+                # process.  Drop the store; the rebuild persists afresh.
+                import shutil
+
+                shutil.rmtree(d, ignore_errors=True)
                 return False
             entry.order = np.load(os.path.join(d, "order.npy"), mmap_mode="r")
             entry.file_row_offsets = np.load(os.path.join(d, "offsets.npy"))
@@ -1550,7 +1567,7 @@ class TileCacheManager:
                     num_rows=total, pad=padded_size(max(total, 1)),
                 )
                 with _timed("super.load_persisted"):
-                    self._try_load_persisted(entry)
+                    self._try_load_persisted(entry, dictionary)
             missing = [c for c in need if c not in entry.cols]
             if not missing and entry.valid is not None:
                 metrics.TILE_CACHE_HITS.inc()

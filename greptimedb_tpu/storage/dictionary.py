@@ -72,10 +72,13 @@ class TableDictionary:
         self.epoch = 0
         # perm history: _perms[i] maps codes at epoch i -> epoch i+1
         self._perms: dict[str, list[np.ndarray]] = {}
+        # the history is process-local (only values + epoch persist): codes
+        # written at an epoch before this one cannot be repaired forward
+        self._history_floor = 0
         if path and os.path.exists(path):
             with open(path) as f:
                 d = json.load(f)
-            self.epoch = int(d.get("epoch", 0))
+            self.epoch = self._history_floor = int(d.get("epoch", 0))
             for name, cd in d.get("columns", {}).items():
                 self._cols[name] = _ColumnDict(cd["values"], cd.get("has_null", False))
 
@@ -219,6 +222,12 @@ class TableDictionary:
             return bisect.bisect_right(cd.values, value)
 
     # ---- cache repair ------------------------------------------------------
+    def can_repair_from(self, epoch: int) -> bool:
+        """False for codes written before this process loaded the
+        dictionary: their permutations were never recorded here, so
+        `perm_since` would answer "identity" for codes that have moved."""
+        return epoch >= self._history_floor
+
     def perm_since(self, name: str, epoch: int) -> np.ndarray | None:
         """Composed permutation mapping codes assigned at `epoch` to current
         codes; None = identity (nothing changed for this column)."""

@@ -172,6 +172,58 @@ def test_persisted_tiles_skip_reconsolidation(tmp_path):
     db2.close()
 
 
+def test_persisted_tiles_with_stale_tag_codes_rebuild(tmp_path):
+    """A hash-partitioned table: each region's consolidation is persisted
+    at the dictionary epoch it was built at, and later regions add hosts
+    that sort in between ("h10" < "h2"), shifting the earlier codes.  The
+    permutation history lives in the process, so a SECOND Database cannot
+    repair those codes forward: it must drop the stale store and rebuild
+    from Parquet — not merge different hosts into one group."""
+    import os as _os
+    import time as _time
+
+    import numpy as np
+
+    home = str(tmp_path / "db")
+    db = Database(data_home=home)
+    db.config.query.disabled_passes = ("cold_host_serve", "host_fast_path")
+    db.sql(
+        "CREATE TABLE p (host STRING, ts TIMESTAMP TIME INDEX, v DOUBLE,"
+        " PRIMARY KEY (host)) PARTITION BY HASH (host) PARTITIONS 4"
+    )
+    n_hosts, ticks = 40, 64
+    hosts = np.tile(np.array([f"h{i}" for i in range(n_hosts)]), ticks)
+    ts = np.repeat(np.arange(ticks, dtype=np.int64) * 1000, n_hosts)
+    db.insert_rows("p", pa.table({
+        "host": pa.array(hosts),
+        "ts": pa.array(ts, pa.timestamp("ms")),
+        "v": pa.array(np.random.default_rng(5).uniform(0, 100, len(ts))),
+    }))
+    db.sql("ADMIN flush_table('p')")
+    q = "SELECT host, max(v) AS m, count(*) AS c FROM p GROUP BY host"
+    want = db.sql_one(q).to_pydict()
+    assert len(want["host"]) == n_hosts
+    pdir = _os.path.join(home, "tile_cache")
+    deadline = _time.time() + 30
+    while _time.time() < deadline:
+        n_meta = sum(
+            f == "meta.json" for _r, _d, files in _os.walk(pdir) for f in files
+        )
+        if n_meta == 4:
+            break
+        _time.sleep(0.2)
+    assert n_meta == 4, "persist writer did not commit every region"
+    db.close()
+
+    db2 = Database(data_home=home)
+    db2.config.query.disabled_passes = ("cold_host_serve", "host_fast_path")
+    lowered0 = metrics.TILE_LOWERED_TOTAL.get()
+    got = db2.sql_one(q).to_pydict()
+    assert metrics.TILE_LOWERED_TOTAL.get() > lowered0
+    assert got == want
+    db2.close()
+
+
 def test_window_tile_engages_and_matches(db, monkeypatch):
     """Windowed query over deep retention gathers a compact window tile
     (kernel scans the window, not the retention) — results must equal the
