@@ -1,47 +1,78 @@
 """ctypes bindings for the native runtime library.
 
-Auto-builds `libgreptime_native.so` with g++ on first import if missing
-(and a toolchain exists); every entry point has a pure-Python fallback so
-the package works without the native lib — but the hot paths (WAL recovery
-scan, line-protocol tokenize, crc32) run native when available.
+Builds `libgreptime_native.<source hash>.so` with g++ from the committed
+`src/greptime_native.cpp` whenever no library for THAT source exists — the
+binary is git-ignored, so a file left by an older checkout must never win
+over the source.  Every entry point has a pure-Python fallback so the
+package works without a toolchain — but the hot paths (WAL recovery scan,
+line-protocol tokenize, crc32) run native when available; `available()`
+says which (chip_smoke.py prints it).
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 
 _DIR = os.path.dirname(__file__)
-_LIB_PATH = os.path.join(_DIR, "libgreptime_native.so")
+_SRC = os.path.join(_DIR, "src", "greptime_native.cpp")
 _lib = None
+_load_failed = False
 
 
-def _try_build() -> bool:
-    src = os.path.join(_DIR, "src", "greptime_native.cpp")
-    if not os.path.exists(src):
-        return False
+def _lib_path() -> str | None:
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    except OSError:
+        return None
+    return os.path.join(_DIR, f"libgreptime_native.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    # build beside the target and rename: concurrent builders (test
+    # workers) each install a complete file
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", _LIB_PATH, src],
+            ["g++", "-O3", "-fPIC", "-shared", "-std=c++17", "-o", tmp, _SRC],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return True
-    except Exception:
+        os.replace(tmp, lib_path)
+    except (OSError, subprocess.SubprocessError):
+        try:
+            os.remove(tmp)
+        except OSError:
+            pass
         return False
+    for stale in glob.glob(os.path.join(_DIR, "libgreptime_native*.so")):
+        if stale != lib_path:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return True
 
 
 def load() -> ctypes.CDLL | None:
-    global _lib
+    global _lib, _load_failed
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH) and not _try_build():
+    if _load_failed:
+        return None
+    lib_path = _lib_path()
+    if lib_path is None or (not os.path.exists(lib_path) and not _build(lib_path)):
+        _load_failed = True
         return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(lib_path)
     except OSError:
+        _load_failed = True
         return None
     lib.gt_crc32.restype = ctypes.c_uint32
     lib.gt_crc32.argtypes = [ctypes.c_char_p, ctypes.c_size_t, ctypes.c_uint32]
@@ -59,23 +90,6 @@ def load() -> ctypes.CDLL | None:
         ctypes.POINTER(ctypes.c_int64),
         ctypes.c_int64,
     ]
-    for name in (
-        "gt_snappy_uncompressed_length",
-        "gt_snappy_decompress",
-        "gt_snappy_compress",
-        "gt_snappy_max_compressed_length",
-        "gt_lp_parse_homogeneous",
-    ):
-        if not hasattr(lib, name):
-            # Stale .so missing newer entry points: rebuild once.
-            _lib = None
-            try:
-                os.remove(_LIB_PATH)
-            except OSError:
-                return None
-            if not _try_build():
-                return None
-            return load()
     lib.gt_snappy_uncompressed_length.restype = ctypes.c_int64
     lib.gt_snappy_uncompressed_length.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.gt_snappy_decompress.restype = ctypes.c_int64

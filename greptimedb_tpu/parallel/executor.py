@@ -37,7 +37,6 @@ import pyarrow as pa
 import pyarrow.compute as pc
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.jax_compat import shard_map as _shard_map
 
 from ..ops.aggregate import (
     BLOCK_ROWS,
@@ -519,7 +518,7 @@ def _compiled_step(mesh: Mesh, plan: DistGroupByPlan):
         nulls = {k: v[0] for k, v in nulls.items()}
         return _device_step(plan, cols, valid[0], nulls)
 
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=P(REGION_AXIS, None),
@@ -758,9 +757,9 @@ def distributed_groupby(
         for col, aggs in per_col_aggs.items()
         if col in states
     }
-    # ONE batched device->host fetch of every finalized row (the per-array
-    # np.asarray conversions below each paid a link round-trip on the
-    # remote harness), metered as transfer time so readback stays
+    # ONE batched device->host fetch of every finalized row (per-array
+    # np.asarray conversions would each be their own device->host
+    # crossing), metered as transfer time so readback stays
     # attributable on the mesh path too
     from ..utils import metrics as _metrics
 

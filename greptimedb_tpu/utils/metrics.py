@@ -312,7 +312,7 @@ TPU_PRECOMPILES = REGISTRY.counter(
 )
 
 # Device-side result finalization + readback accounting (the O(rows_out)
-# fetch contract): BYTES are the honest unit on a remote-device link —
+# fetch contract): BYTES are the honest unit —
 # greptime_tile_readback_ms conflates compute with transfer because
 # device_get blocks on the async dispatch, so tests and the bench assert
 # on bytes.  Dispatch/fetch counters back the one-dispatch-one-fetch

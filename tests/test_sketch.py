@@ -138,9 +138,7 @@ def test_device_mesh_sketch_merge():
             counts = jax.lax.psum(counts, "regions")
             return regs, counts
 
-        from greptimedb_tpu.utils.jax_compat import shard_map
-
-        return shard_map(
+        return jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(P("regions"), P("regions"), P("regions")),

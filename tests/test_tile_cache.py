@@ -822,8 +822,8 @@ def test_host_fast_path_includes_memtable(db):
 
 def test_cold_host_serve_then_device_build(db):
     """A cold grouped aggregate answers from the host consolidation with
-    ZERO device plane uploads (on the remote-TPU harness uploads dominate
-    cold latency); the next touch builds the HBM tiles so warm reps keep
+    ZERO device plane uploads (uploads dominate a first touch); the
+    next touch builds the HBM tiles so warm reps keep
     the one-dispatch path.  Results match the CPU path in both phases.
     Pinned to the LEGACY ladder (tile.fused_build=false) — under the fused
     planner the second touch joins a background build instead
