@@ -87,6 +87,7 @@ def check(cond, msg: str):
 COMPILE = {
     "compiles": 0, "compile_s": 0.0, "compile_s_max": 0.0,
     "cache_requests": 0, "cache_hits": 0, "cache_misses": 0,
+    "slowest": [],  # [seconds, jitted function name], the five longest
 }
 
 
@@ -101,20 +102,25 @@ def _watch_compiles():
         elif name == "/jax/compilation_cache/cache_misses":
             COMPILE["cache_misses"] += 1
 
-    def on_duration(name, secs, **_kw):
+    def on_duration(name, secs, **kw):
         if name == "/jax/core/compile/backend_compile_duration":
             COMPILE["compiles"] += 1
             COMPILE["compile_s"] += secs
             COMPILE["compile_s_max"] = max(COMPILE["compile_s_max"], secs)
+            COMPILE["slowest"] = sorted(
+                COMPILE["slowest"] + [[round(secs, 2), str(kw.get("fun_name"))]],
+                reverse=True,
+            )[:5]
 
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
 
 
-def _compile_snapshot() -> dict:
+def _compile_snapshot(slowest: bool = False) -> dict:
     return {
         k: (round(v, 3) if isinstance(v, float) else v)
         for k, v in COMPILE.items()
+        if slowest or k != "slowest"
     }
 
 
@@ -804,7 +810,7 @@ def main(args) -> dict:
         smoke_one_chip(ds, home)
     else:
         smoke_four_chips(ds, home, args.chips)
-    emit({"event": "compile", "after": "all", **_compile_snapshot()})
+    emit({"event": "compile", "after": "all", **_compile_snapshot(slowest=True)})
     return device
 
 
@@ -812,7 +818,7 @@ if __name__ == "__main__":
     cli = parse_args()
     try:
         result = {"ok": True, "device": main(cli)}
-    except BaseException:  # noqa: BLE001 — any failure is the exit code
+    except Exception:  # noqa: BLE001 — the boundary: any failure is the exit code
         traceback.print_exc()
         sys.stdout.flush()
         sys.stderr.flush()

@@ -1015,11 +1015,26 @@ def unpack_f64_bits(hilo) -> "object":
     return bits.view(np.float64)
 
 
+def mesh_min(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """Elementwise minimum across a mesh axis, as all_gather + a local
+    fold instead of `lax.pmin`: for 64-bit types (f64 accumulators, int64
+    timestamps) the TPU compiler lowers only SUM all-reduces
+    ("UNIMPLEMENTED: Supported lowering only of Sum all reduce").  Order
+    statistics are exact under any order, so the result is pmin's."""
+    return jnp.min(jax.lax.all_gather(x, axis_name), axis=0)
+
+
+def mesh_max(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
+    """`mesh_min`'s twin for the maximum."""
+    return jnp.max(jax.lax.all_gather(x, axis_name), axis=0)
+
+
 def psum_states(state: AggState, axis_name: str) -> AggState:
     """Merge partials across a mesh axis with XLA collectives over ICI.
 
-    This is the TPU-native MergeScan: sums/counts ride psum, min/max ride
-    pmin/pmax, LAST does an argmax-style two-field reduction.
+    This is the TPU-native MergeScan: sums/counts ride psum, min/max an
+    all_gather + fold (mesh_min/mesh_max), LAST does an argmax-style
+    two-field reduction.
     """
     out = AggState()
     if state.sums is not None:
@@ -1027,15 +1042,15 @@ def psum_states(state: AggState, axis_name: str) -> AggState:
     if state.counts is not None:
         out.counts = jax.lax.psum(state.counts, axis_name)
     if state.mins is not None:
-        out.mins = jax.lax.pmin(state.mins, axis_name)
+        out.mins = mesh_min(state.mins, axis_name)
     if state.maxs is not None:
-        out.maxs = jax.lax.pmax(state.maxs, axis_name)
+        out.maxs = mesh_max(state.maxs, axis_name)
     if state.last_ts is not None:
-        max_ts = jax.lax.pmax(state.last_ts, axis_name)
+        max_ts = mesh_max(state.last_ts, axis_name)
         mine = state.last_ts == max_ts
         small = jnp.asarray(jnp.finfo(state.last_val.dtype).min, state.last_val.dtype)
         out.last_ts = max_ts
-        out.last_val = jax.lax.pmax(jnp.where(mine, state.last_val, small), axis_name)
+        out.last_val = mesh_max(jnp.where(mine, state.last_val, small), axis_name)
     return out
 
 

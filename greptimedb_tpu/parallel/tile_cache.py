@@ -2695,24 +2695,29 @@ class TileCacheManager:
 
     def ensure_perm(self, entry: _SuperTiles, ts_name: str):
         """Lazily build the ts-ascending permutation for time-major plans
-        (padding rows sort last via an int64-max key).  Cached on the
-        entry; ~one device sort per (region, file-set).  Build + budget
-        accounting run under the lock so a concurrent eviction can't leave
-        phantom bytes in the counter (bytes are only charged while the
-        entry is still cached) and the argsort never runs twice."""
+        (padding rows last).  Cached on the entry; one sort per (region,
+        file-set).  The sort runs on the HOST over the consolidation's
+        own sorted ts plane: the plane is a concatenation of per-series
+        ascending runs, which numpy's stable sort merges in about a
+        second, where a device `argsort` of the same plane costs a
+        minute or more of XLA compile before it runs at all (measured:
+        74 s on a v5e host at 2^25 rows, int64).  Stable on both sides,
+        so the permutation is the one the device sort gave.  Build +
+        budget accounting run under the lock so a concurrent eviction
+        can't leave phantom bytes in the counter (bytes are only charged
+        while the entry is still cached) and the sort never runs twice."""
         with self._lock:
             if entry.perm is None:
-                # argsort over the full column + its int64 workspace
-                self._reserve_locked(entry.pad * 24, {entry.region_id})
-                ts_chunks = entry.cols[ts_name]
-                valid_chunks = entry.valid
-                if len(self.devices) > 1:
-                    ts_chunks = [jax.device_put(x, self.devices[0]) for x in ts_chunks]
-                    valid_chunks = [jax.device_put(x, self.devices[0]) for x in valid_chunks]
-                ts = jnp.concatenate(ts_chunks)
-                valid = jnp.concatenate(valid_chunks)
-                key = jnp.where(valid, ts, jnp.iinfo(jnp.int64).max)
-                entry.perm = jnp.argsort(key).astype(jnp.int32)
+                self._reserve_locked(entry.pad * 4, {entry.region_id})
+                ts_host = entry.sorted_host.get(ts_name)
+                if ts_host is None:  # no host plane kept: read the device's
+                    ts_host = np.concatenate(
+                        [np.asarray(c) for c in entry.cols[ts_name]]
+                    )
+                n = entry.num_rows  # valid rows are exactly the first n
+                perm = np.arange(entry.pad, dtype=np.int32)
+                perm[:n] = np.argsort(np.asarray(ts_host[:n]), kind="stable")
+                entry.perm = jax.device_put(perm, self.devices[0])
                 entry.nbytes += entry.pad * 4
                 if self._super.get(entry.region_id) is entry:
                     self._used += entry.pad * 4
@@ -3392,9 +3397,10 @@ def _mega_program(member_keys: tuple):
 # collectives over ICI instead of the host-side N:1 device_put loop.
 #
 # Accumulation-order contract (the dense/hash parity bar from the
-# agg-strategy work): counts merge with psum and min/max with pmin/pmax —
-# integer adds and order statistics are bit-exact under ANY reduction
-# order — while float sums and LAST states, whose merge is order-
+# agg-strategy work): counts merge with psum and min/max with an
+# all_gather + fold (ops/aggregate.mesh_min/mesh_max: the chip lowers no
+# 64-bit all-reduce but SUM) — integer adds and order statistics are
+# bit-exact under ANY reduction order — while float sums and LAST states, whose merge is order-
 # sensitive, all_gather the per-source partials and fold them in GLOBAL
 # SOURCE ORDER, exactly the single-chip loop's left fold.  The merged
 # states are therefore bit-identical for any mesh size (1 device == 8
@@ -3512,7 +3518,7 @@ def _mesh_merge_program(plan, nullable_cols, mesh, n_local, positions):
     state dict (plus the union key table for hash), replicated."""
     from jax.sharding import PartitionSpec as P
 
-    from ..ops.aggregate import HASH_EMPTY, hash_group_slots
+    from ..ops.aggregate import HASH_EMPTY, hash_group_slots, mesh_max, mesh_min
     from .mesh import REGION_AXIS
 
     is_hash = plan.agg_strategy == "hash"
@@ -3629,12 +3635,12 @@ def _mesh_merge_program(plan, nullable_cols, mesh, n_local, positions):
                 local = sts[0].mins
                 for st in sts[1:]:
                     local = jnp.minimum(local, st.mins)
-                kwargs["mins"] = jax.lax.pmin(local, REGION_AXIS)
+                kwargs["mins"] = mesh_min(local, REGION_AXIS)
             if sts[0].maxs is not None:
                 local = sts[0].maxs
                 for st in sts[1:]:
                     local = jnp.maximum(local, st.maxs)
-                kwargs["maxs"] = jax.lax.pmax(local, REGION_AXIS)
+                kwargs["maxs"] = mesh_max(local, REGION_AXIS)
             if sts[0].sums is not None:
                 g = gathered(sts, lambda st: st.sums)
                 d0, s0 = real[0]
@@ -3659,25 +3665,14 @@ def _mesh_merge_program(plan, nullable_cols, mesh, n_local, positions):
 
     # the outputs ARE replicated — collectives plus a fold every device
     # computes identically — but the static replication checker cannot
-    # prove it through the gather-indexed fold; disable the check under
-    # whichever keyword this jax spells it
-    kw = {}
-    for name in ("check_rep", "check_vma"):
-        try:
-            import inspect
-
-            if name in inspect.signature(_shard_map).parameters:
-                kw = {name: False}
-                break
-        except (TypeError, ValueError):  # pragma: no cover — exotic jax
-            break
+    # prove it through the gather-indexed fold
     return jax.jit(
         jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=(P(REGION_AXIS), P()),
             out_specs=P(),
-            **kw,
+            check_vma=False,
         )
     )
 
