@@ -137,7 +137,10 @@ class Dataset:
         self.ticks = hours * 3600 // SCRAPE_S
         self.end = T0 + hours * 3600_000
         self.host_names = np.array([f"host_{i}" for i in range(hosts)])
-        self.host1 = f"host_{703 % hosts}"
+        self.host1_index = 703 % hosts  # bench.py's HOST1
+        self.host1 = f"host_{self.host1_index}"
+        # the PromQL metric table holds the last 2 h
+        self.tql_ticks = min(self.ticks, 2 * 3600 // SCRAPE_S)
         self.usage_user = np.empty((self.ticks, hosts), np.float64)
 
     @property
@@ -217,7 +220,7 @@ def load(db, ds: Dataset, home: str, regions: int) -> dict:
     if regions == 1:
         # the single-field metric table the PromQL engine needs: the last
         # 2 h of usage_user (bench.py _tql_phase builds the same one)
-        n_tql = min(ds.ticks, 2 * 3600 // SCRAPE_S)
+        n_tql = ds.tql_ticks
         tql_rows = n_tql * ds.hosts
         if not reuse:
             db.sql(
@@ -267,8 +270,7 @@ def load(db, ds: Dataset, home: str, regions: int) -> dict:
 def fold_single_groupby(ds: Dataset):
     """max(usage_user) per minute, one host, the last hour."""
     n = min(ds.ticks, 3600 // SCRAPE_S)
-    h = int(np.nonzero(ds.host_names == ds.host1)[0][0])
-    col = ds.usage_user[ds.ticks - n:, h]
+    col = ds.usage_user[ds.ticks - n:, ds.host1_index]
     per_min = 60 // SCRAPE_S
     lo = ds.end - n * SCRAPE_S * 1000
     mx = col.reshape(n // per_min, per_min).max(axis=1)
@@ -286,8 +288,7 @@ def fold_double_groupby(ds: Dataset):
 
 
 def fold_high_cpu(ds: Dataset):
-    h = int(np.nonzero(ds.host_names == ds.host1)[0][0])
-    col = ds.usage_user[:, h]
+    col = ds.usage_user[:, ds.host1_index]
     hot = col[col > 90.0]
     return [(int(hot.size), float(hot.max()))]
 
@@ -319,9 +320,8 @@ def fold_rate(ds: Dataset, start: int, end: int, step: int, range_ms: int,
     extrapolatedRate per (series, step) — written against the regular
     [tick, host] grid instead of flat sorted samples.  Returns
     {host index: [(ts_ms, value)...]} for the defined steps."""
-    n_tql = min(ds.ticks, 2 * 3600 // SCRAPE_S)
-    first_tick = ds.ticks - n_tql
-    tick_ts = T0 + (first_tick + np.arange(n_tql, dtype=np.int64)) * (
+    first_tick = ds.ticks - ds.tql_ticks
+    tick_ts = T0 + (first_tick + np.arange(ds.tql_ticks, dtype=np.int64)) * (
         SCRAPE_S * 1000
     )
     fetched = (tick_ts >= start - range_ms) & (tick_ts <= end)
@@ -547,6 +547,14 @@ def run_request(name: str, call, parse, want, rtol, db, engaged: tuple,
     }
 
 
+def _matrix_rows(result: list) -> list:
+    """Prometheus matrix JSON -> (hostname, ts ms, value) rows in order."""
+    return [
+        (s["metric"]["hostname"], int(ts) * 1000, float(v))
+        for s in result for ts, v in s["values"]
+    ]
+
+
 def _device_bytes(db) -> dict:
     """Device bytes resident, three views: the tile cache's own plane
     accounting, the runtime's memory_stats per device (0 on the CPU
@@ -632,15 +640,12 @@ def smoke_one_chip(ds: Dataset, home: str):
             emit({"event": "compile", "after": name, **_compile_snapshot()})
 
         # PromQL over the last 2 h: rate over every series, increase over one
-        n_tql = min(ds.ticks, 2 * 3600 // SCRAPE_S)
-        start_s = (ds.end - n_tql * SCRAPE_S * 1000) // 1000 + 600
+        start_s = (ds.end - ds.tql_ticks * SCRAPE_S * 1000) // 1000 + 600
         end_s = ds.end // 1000 - 60
-        order = ds.host_order()
-        host_1 = int(np.nonzero(ds.host_names == "host_1")[0][0])
         for name, query, hosts, per_second in (
-            ("rate", "rate(tql_cpu[5m])", order, True),
+            ("rate", "rate(tql_cpu[5m])", ds.host_order(), True),
             ("increase-1", 'increase(tql_cpu{hostname="host_1"}[5m])',
-             np.array([host_1]), False),
+             np.array([1]), False),
         ):
             fold = fold_rate(
                 ds, start_s * 1000, end_s * 1000, 60_000, 300_000, hosts,
@@ -650,16 +655,9 @@ def smoke_one_chip(ds: Dataset, home: str):
                 (str(ds.host_names[h]), ts, v)
                 for h in hosts for ts, v in fold[int(h)]
             ]
-
-            def parse(result):
-                return [
-                    (s["metric"]["hostname"], int(ts) * 1000, float(v))
-                    for s in result for ts, v in s["values"]
-                ]
-
             emit(run_request(
                 name, lambda: client.query_range(query, start_s, end_s, 60),
-                parse, want, RTOL_F64, db,
+                _matrix_rows, want, RTOL_F64, db,
                 ("TPU_DEVICE_DISPATCHES", "TQL_TILE_DISPATCHES"),
                 cold_may_host_serve=True,
             ))

@@ -972,11 +972,12 @@ def pack_f64_bits(x: jnp.ndarray) -> jnp.ndarray:
     # garbage inf/NaN/zero inputs produce is discarded by the wheres.
     m = ax
     e = jnp.zeros(ax.shape, jnp.int32)
-    for k in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+    steps = (512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
+    for k in steps:
         big = m >= jnp.float64(2.0**k)
         m = jnp.where(big, m * jnp.float64(2.0**-k), m)
         e = e + jnp.where(big, k, 0)
-    for k in (512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+    for k in steps:
         small = m < jnp.float64(2.0 ** (1 - k))
         m = jnp.where(small, m * jnp.float64(2.0**k), m)
         e = e - jnp.where(small, k, 0)

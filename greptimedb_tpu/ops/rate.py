@@ -68,27 +68,24 @@ def prefix_scan(combine, xs: tuple, identity: tuple) -> tuple:
     `jnp.cumsum` on float64 and a 1-D `lax.associative_scan` (strided
     slices of a long 1-D array) both take minutes to compile at 2^20
     rows, this takes seconds at 2^24."""
+
+    def scan_last_axis(ys):
+        k = 1
+        while k < ys[0].shape[-1]:
+            shifted = tuple(_shift_right(y, k, i) for y, i in zip(ys, identity))
+            ys = combine(shifted, ys)
+            k *= 2
+        return ys
+
     n = xs[0].shape[0]
     if n <= _SCAN_COLS:
-        k = 1
-        while k < n:
-            shifted = tuple(_shift_right(x, k, i) for x, i in zip(xs, identity))
-            xs = combine(shifted, xs)
-            k *= 2
-        return xs
+        return scan_last_axis(xs)
     rows = -(-n // _SCAN_COLS)
     padded = rows * _SCAN_COLS
-    ys = tuple(
-        jnp.concatenate([x, jnp.full((padded - n,), i, x.dtype)])
-        .reshape(rows, _SCAN_COLS) if padded != n
-        else x.reshape(rows, _SCAN_COLS)
+    ys = scan_last_axis(tuple(
+        jnp.pad(x, (0, padded - n), constant_values=i).reshape(rows, _SCAN_COLS)
         for x, i in zip(xs, identity)
-    )
-    k = 1
-    while k < _SCAN_COLS:
-        shifted = tuple(_shift_right(y, k, i) for y, i in zip(ys, identity))
-        ys = combine(shifted, ys)
-        k *= 2
+    ))
     totals = prefix_scan(combine, tuple(y[:, -1] for y in ys), identity)
     before = tuple(
         _shift_right(t, 1, i)[:, None] for t, i in zip(totals, identity)

@@ -2709,14 +2709,11 @@ class TileCacheManager:
         with self._lock:
             if entry.perm is None:
                 self._reserve_locked(entry.pad * 4, {entry.region_id})
-                ts_host = entry.sorted_host.get(ts_name)
-                if ts_host is None:  # no host plane kept: read the device's
-                    ts_host = np.concatenate(
-                        [np.asarray(c) for c in entry.cols[ts_name]]
-                    )
                 n = entry.num_rows  # valid rows are exactly the first n
                 perm = np.arange(entry.pad, dtype=np.int32)
-                perm[:n] = np.argsort(np.asarray(ts_host[:n]), kind="stable")
+                perm[:n] = np.argsort(
+                    np.asarray(entry.sorted_host[ts_name][:n]), kind="stable"
+                )
                 entry.perm = jax.device_put(perm, self.devices[0])
                 entry.nbytes += entry.pad * 4
                 if self._super.get(entry.region_id) is entry:
