@@ -15,6 +15,10 @@ One process, default `device.*` settings, the normal entry points:
   * every answer is compared with an independent numpy fold over the
     seed-generated arrays: group keys, row count and row order exact,
     values to the tolerances in `RTOL_*` below;
+  * the values `uniform(0, 100)` never draws — 0.0, -0.0, 100.0, negatives,
+    far exponents, NULL — go through the compact f64 readback in a small
+    table of their own, and with +/-inf and NaN through `pack_f64_bits`
+    directly, compared exactly;
   * the device is proved, not assumed: the device path may not fall back,
     route to the CPU or degrade, every warm repetition must show a device
     dispatch, and the device supervisor must end HEALTHY with nothing
@@ -365,12 +369,92 @@ def fold_rate(ds: Dataset, start: int, end: int, step: int, range_ms: int,
     }
 
 
+# ---- the values the generator never draws ----------------------------------
+# A device-finalized f64 result (lastpoint, ORDER BY/LIMIT/HAVING) comes
+# back through ops/aggregate.pack_f64_bits, which has a branch each for
+# zero, sign, inf and NaN/NULL; `uniform(0, 100)` reaches none of them
+# (TSBS's own clamped walk sits on 0 and 100 all the time).  Table `edge`:
+# one series per case, three rows each in ts order.  Every value is exact
+# in float32, so the chip's emulated f64 holds it exactly and the answers
+# are compared exactly (rtol 0), not to a tolerance.  No inf in the table:
+# the tile path's min/max/last clamp a stored inf to the largest finite
+# f64 where the CPU executor returns inf (PERF.md, open questions); inf
+# and NaN go through the pack directly in `check_pack_on_device`.
+
+EDGE = {  # hostname -> (v rows, w rows); v's last row is the lastpoint
+    "e0": ([5.0, 3.0, 0.0], [0.0, 0.0, 0.0]),
+    "e1": ([1.0, 2.0, -0.0], [-0.0, -0.0, -0.0]),
+    "e2": ([0.0, 50.0, 100.0], [100.0, 100.0, 100.0]),
+    "e3": ([None, None, None], [-37.25, 37.25, 0.0]),
+    "e4": ([12.5, 0.0, -37.25], [-1.5, -2.5, -3.0]),
+    "e5": ([1.0, 2.0, -(2.0**-100)], [None, 0.0, None]),
+    "e6": ([-1.0, -2.0, 2.0**100], [None, None, None]),
+    "e7": ([-8.0, -0.5, 0.5], [0.5, 0.25, 0.125]),
+}
+
+
+def load_edge(db):
+    import pyarrow as pa
+
+    db.sql(
+        "CREATE TABLE IF NOT EXISTS edge (hostname STRING, ts TIMESTAMP(3) "
+        "TIME INDEX, v DOUBLE, w DOUBLE, PRIMARY KEY (hostname)) "
+        "WITH (append_mode = 'true')"
+    )
+    if db.sql_one("SELECT count(*) AS n FROM edge")["n"][0].as_py():
+        return  # a reused data home holds it already
+    names = sorted(EDGE)
+    db.insert_rows("edge", pa.table({
+        "hostname": pa.array([h for h in names for _ in range(3)]),
+        "ts": pa.array(
+            [T0 + i * SCRAPE_S * 1000 for _ in names for i in range(3)],
+            pa.timestamp("ms"),
+        ),
+        "v": pa.array([x for h in names for x in EDGE[h][0]], pa.float64()),
+        "w": pa.array([x for h in names for x in EDGE[h][1]], pa.float64()),
+    }))
+    db.sql("ADMIN flush_table('edge')")
+
+
+def fold_edge_lastpoint(_ds):
+    return [(h, EDGE[h][0][-1]) for h in sorted(EDGE)]
+
+
+def fold_edge_orderby_limit(_ds):
+    """max, min and sum of `w` per series (NULLs skipped, NULL if none)."""
+    out = []
+    for h in sorted(EDGE):
+        w = [x for x in EDGE[h][1] if x is not None]
+        out.append((h, max(w), min(w), sum(w)) if w else (h, None, None, None))
+    return out
+
+
+def check_pack_on_device():
+    """`pack_f64_bits` on the device against the host's own bits."""
+    import jax
+    import jax.numpy as jnp
+
+    from greptimedb_tpu.ops.aggregate import pack_f64_bits, unpack_f64_bits
+
+    vals = np.array([
+        0.0, -0.0, 100.0, -100.0, -37.25, 0.5, 1.0, 2.0**100, -(2.0**-100),
+        np.inf, -np.inf, np.nan,
+    ])  # each exact in float32, so exact in the chip's emulated f64
+    got = unpack_f64_bits(np.asarray(jax.jit(pack_f64_bits)(jnp.asarray(vals))))
+    nan = np.isnan(vals)
+    check(np.isnan(got[nan]).all(), f"pack_f64_bits: NaN came back as {got[nan]}")
+    same = got[~nan].view(np.uint64) == vals[~nan].view(np.uint64)
+    check(same.all(), f"pack_f64_bits: {vals[~nan][~same]} came back as "
+                      f"{got[~nan][~same]}")
+    emit({"event": "pack_f64_bits", "values": len(vals), "bit_exact": True})
+
+
 # ---- comparison ------------------------------------------------------------
 
 
 def compare_rows(name: str, got: list, want: list, rtol: float):
     """Row count, row order and every key column exact; float columns to
-    `rtol` (ints — count(*) — exact)."""
+    `rtol` (0 = equal; ints — count(*) — and NULLs exact)."""
     check(len(got) == len(want), f"{name}: {len(got)} rows, want {len(want)}")
     for i, (g, w) in enumerate(zip(got, want)):
         check(len(g) == len(w), f"{name}: row {i} has {len(g)} columns")
@@ -478,7 +562,7 @@ class Counters:
     WATCHED = MUST_NOT_MOVE + (
         "TPU_DEVICE_DISPATCHES", "TILE_LOWERED_TOTAL", "TQL_TILE_DISPATCHES",
         "TQL_TILE_COLD_SERVES", "TPU_READBACK_BYTES", "TILE_MESH_DISPATCHES",
-        "TILE_MESH_DEGRADED",
+        "TILE_MESH_DEGRADED", "TPU_DEVICE_FINALIZE",
     )
 
     def __init__(self):
@@ -638,6 +722,31 @@ def smoke_one_chip(ds: Dataset, home: str):
                 cold_may_host_serve=False,
             ))
             emit({"event": "compile", "after": name, **_compile_snapshot()})
+
+        # the values the generator never draws, through the compact f64
+        # readback
+        check_pack_on_device()
+        load_edge(db)
+        for name, sql, fold in (
+            (
+                "edge-lastpoint",
+                "SELECT hostname, last_value(v) AS lv FROM edge GROUP BY hostname",
+                fold_edge_lastpoint,
+            ),
+            (
+                "edge-orderby-limit",
+                "SELECT hostname, max(w) AS mx, min(w) AS mn, sum(w) AS s "
+                "FROM edge GROUP BY hostname ORDER BY hostname LIMIT 8",
+                fold_edge_orderby_limit,
+            ),
+        ):
+            emit(run_request(
+                name, lambda: client.sql(sql), lambda rows: rows, fold(ds),
+                0.0, db,
+                ("TPU_DEVICE_DISPATCHES", "TILE_LOWERED_TOTAL",
+                 "TPU_DEVICE_FINALIZE"),
+                cold_may_host_serve=False,
+            ))
 
         # PromQL over the last 2 h: rate over every series, increase over one
         start_s = (ds.end - ds.tql_ticks * SCRAPE_S * 1000) // 1000 + 600

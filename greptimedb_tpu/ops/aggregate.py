@@ -960,13 +960,16 @@ def pack_f64_bits(x: jnp.ndarray) -> jnp.ndarray:
     identically in the aggregation itself, so this loses nothing the
     dispatch had.  On the chip float64 itself is emulated (a float32
     pair: ~48 mantissa bits, float32 exponent range); the words are then
-    the exact bits of the emulated value."""
+    the exact bits of the emulated value.  There every constant below
+    outside float32's range folds to inf or 0: the search steps it cannot
+    represent then never fire (no value there needs them), and zero is
+    recognised by `== 0` and by what the search could not normalize, never
+    by a comparison with a constant only float64 holds."""
     xf = x.astype(jnp.float64)
     is_nan = jnp.isnan(xf)
     neg = jnp.signbit(xf.astype(jnp.float32)) & ~is_nan
     ax = jnp.abs(xf)
     is_inf = jnp.isinf(xf)
-    is_zero = ax < jnp.float64(2.2250738585072014e-308)  # < DBL_MIN
     # normalize ax = m * 2^e with m in [1, 2): every scaling is by an
     # exact power of two, so m keeps all 53 significant bits.  The
     # garbage inf/NaN/zero inputs produce is discarded by the wheres.
@@ -981,6 +984,9 @@ def pack_f64_bits(x: jnp.ndarray) -> jnp.ndarray:
         small = m < jnp.float64(2.0 ** (1 - k))
         m = jnp.where(small, m * jnp.float64(2.0**k), m)
         e = e - jnp.where(small, k, 0)
+    # zero, and whatever lies under the platform's smallest normal (the
+    # subnormal flush above): the search leaves those outside [1, 2)
+    is_zero = (ax == 0) | ~((m >= 1) & (m < 2))
     # 52 fraction bits = 20 (hi word) + 16 + 16 (lo word), split with
     # exact floors so no conversion ever leaves the int32 range
     t = m * jnp.float64(1 << 20)  # [2^20, 2^21)

@@ -44,10 +44,14 @@ def test_rehearsal_passes_and_reports_the_cpu(tmp_path):
     queries = {e["query"]: e for e in events if "query" in e}
     assert set(queries) == {
         "single-groupby-1-1-1", "double-groupby-1", "high-cpu-1", "lastpoint",
-        "groupby-orderby-limit", "rate", "increase-1",
+        "groupby-orderby-limit", "edge-lastpoint", "edge-orderby-limit",
+        "rate", "increase-1",
     }
     for e in queries.values():
         assert len(e["warm_ms"]) == 3 and all(d >= 1 for d in e["dispatches"])
+    # the values the generator never draws were compared exactly
+    assert queries["edge-lastpoint"]["rtol"] == 0.0
+    assert {"event": "pack_f64_bits", "values": 12, "bit_exact": True} in events
 
 
 def test_device_path_that_cannot_engage_fails(tmp_path):

@@ -317,6 +317,42 @@ def test_pack_f64_bits_round_trip():
     assert list(sub) == [0.0, 0.0] and list(np.signbit(sub)) == [False, True]
 
 
+def test_pack_f64_bits_float32_exponent_range():
+    """On the chip float64 is a float32 pair with float32's exponent range:
+    every constant of the pack beyond it folds to inf or 0.  With x64 off
+    the same folding happens here, so the special cases are held to it —
+    exact 0.0 once came back as -inf because zero was told by `< DBL_MIN`,
+    which folds to `< 0`."""
+    import warnings
+
+    import jax
+    import jax.numpy as jnp
+
+    from greptimedb_tpu.ops.aggregate import pack_f64_bits, unpack_f64_bits
+
+    rng = np.random.default_rng(11)
+    vals = np.concatenate([
+        np.array([
+            0.0, -0.0, 100.0, -100.0, -37.25, 0.5, 1.0, np.inf, -np.inf,
+            np.nan, 1.17549435e-38, 3.4028235e38, -3.4028235e38, 1e-30,
+        ]),
+        rng.standard_normal(500) * 10.0 ** rng.integers(-37, 38, 500),
+    ]).astype(np.float32)
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.simplefilter("ignore")  # float64 truncates: the point
+        out = unpack_f64_bits(np.asarray(pack_f64_bits(jnp.asarray(vals))))
+    want = vals.astype(np.float64)
+    normal = ~np.isnan(vals) & (
+        (np.abs(vals) >= 1.17549435e-38) | (vals == 0)
+    )
+    assert (out[normal].view(np.uint64) == want[normal].view(np.uint64)).all()
+    assert np.isnan(out[np.isnan(vals)]).all()
+    # under float32's smallest normal: signed zero, never garbage
+    tiny = ~normal & ~np.isnan(vals)
+    assert (out[tiny] == 0).all()
+    assert (np.signbit(out[tiny]) == np.signbit(vals[tiny])).all()
+
+
 def test_compact_readback_is_single_buffer(db):
     """The compact (device-finalize) result — lastpoint included — ships
     as ONE flat buffer: a single device_get of a single array (each extra
