@@ -1,0 +1,8 @@
+"""The share of the traced slice in which no operation ran on the device."""
+
+
+def read(run: dict):
+    trace = run["trace"]
+    if not trace or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
