@@ -58,6 +58,7 @@ from .metric.engine import (
 )
 from .storage.engine import TimeSeriesEngine
 from .storage.sst import ScanPredicate
+from .utils import tracing
 from .utils.config import Config
 from .utils.errors import (
     DatabaseNotFoundError,
@@ -331,7 +332,8 @@ class Database:
         ctx = {"database": self.current_database}
         for ic in interceptors:
             text = ic.pre_parsing(text, ctx)
-        stmts = parse_sql(text)
+        with tracing.stage("query.parse"):
+            stmts = parse_sql(text)
         # plan-cacheable only when the text is exactly one SELECT (the cache
         # key is the full text; see _execute).  ALIGN TO NOW plans are
         # rejected at plan level (plan_uncacheable) wherever they nest.
@@ -925,7 +927,6 @@ class Database:
         import time as _time
 
         from .utils import metrics as _metrics
-        from .utils import tracing
         from .utils.memory import batch_nbytes
 
         table = pa.Table.from_batches([batch])
@@ -1457,8 +1458,6 @@ class Database:
                 self._plan_cache.move_to_end(key)
             else:
                 hit = None
-        from .utils import tracing
-
         if hit is not None:
             plan, schema = hit[1], hit[2]
             tracing.set_attribute("plan_cache", "hit")

@@ -185,6 +185,11 @@ def hash_group_slots(
 BLOCK_ROWS = 4096
 BLOCK_SPAN = 16
 _FAST_MIN_ROWS = 1 << 16
+# The two lowerings a `lax.cond` picks between at run time trace under these
+# scopes, so a profiler trace's device ops say which branch ran (metadata
+# only: the compiled program is the same).
+_blocked = jax.named_scope("blocked")
+_scatter = jax.named_scope("scatter")
 
 
 def windowed_slot_sum(ps, base, segs: int, span: int):
@@ -463,7 +468,8 @@ def limb_segment_sums(
 
     counts01 = tuple(count01) if count01 is not None else tuple([None] * C)
     return jax.lax.cond(
-        ok_block, fast, slow, (gb, mb, tuple(limb_cols), counts01)
+        ok_block, _blocked(fast), _scatter(slow),
+        (gb, mb, tuple(limb_cols), counts01),
     )
 
 
@@ -582,7 +588,10 @@ def segment_aggregate(
             v, g, m, t = args
             return _segment_scatter(v, g, num_groups, aggs, m, t, acc_dtype)
 
-        return jax.lax.cond(ok_block, fast_last, slow_last, (values, g32, mask, ts))
+        return jax.lax.cond(
+            ok_block, _blocked(fast_last), _scatter(slow_last),
+            (values, g32, mask, ts),
+        )
 
     def fast(args):
         v, g, m = args
@@ -592,7 +601,9 @@ def segment_aggregate(
         v, g, m = args
         return _segment_scatter(v, g, num_groups, aggs, m, None, acc_dtype)
 
-    return jax.lax.cond(ok_block, fast, slow, (values, g32, mask))
+    return jax.lax.cond(
+        ok_block, _blocked(fast), _scatter(slow), (values, g32, mask)
+    )
 
 
 def _segment_scatter(
@@ -786,7 +797,9 @@ def segment_aggregate_multi(
             for v, m in zip(vs, ms)
         ])
 
-    return jax.lax.cond(ok_block, fast, slow, (tuple(values), tuple(masks)))
+    return jax.lax.cond(
+        ok_block, _blocked(fast), _scatter(slow), (tuple(values), tuple(masks))
+    )
 
 
 def _segment_blocked_last(

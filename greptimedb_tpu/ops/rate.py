@@ -190,6 +190,7 @@ def range_windows(
     )
 
 
+@jax.named_scope("range_windows")
 def range_windows_dyn(
     series: jnp.ndarray,
     ts: jnp.ndarray,

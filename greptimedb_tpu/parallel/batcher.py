@@ -583,13 +583,12 @@ class QueryBatcher:
             leaves = []
             for _, p in pendings:
                 leaves.extend(p.leaves)
-            t0 = time.perf_counter()
-            with tracing.span("tile.batch_readback", members=len(pendings)):
+            with tracing.span("tile.batch_readback", members=len(pendings)) as rb:
                 with rtt_sim.round_trip():
                     fetched = device_health.supervised_call(
                         "readback", lambda: jax.device_get(leaves)
                     )
-            transfer_ms = (time.perf_counter() - t0) * 1000.0
+            transfer_ms = rb.duration() * 1000.0
         except BaseException:  # noqa: BLE001 — pack failure solos everyone
             for m, _ in pendings:
                 m.solo = True

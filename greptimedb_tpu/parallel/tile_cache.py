@@ -163,7 +163,9 @@ def fused_build_scope():
     prev = getattr(_FUSED_BUILD, "depth", 0)
     _FUSED_BUILD.depth = prev + 1
     try:
-        yield
+        # a ghost's stages move no counter, as its dispatches move none
+        with tracing.counters_muted():
+            yield
     finally:
         _FUSED_BUILD.depth = prev
 
@@ -2936,6 +2938,14 @@ def _value_to_numpy(col) -> np.ndarray | None:
     return arr
 
 
+def _record_dispatch(disp, plan, **noted):
+    """A closed `tile.dispatch` span's duration into the flight recorder:
+    one clock for the trace, the counter, `device_dispatches` and EXPLAIN
+    ANALYZE."""
+    flight_recorder.stage_add("dispatch", disp.duration() * 1000.0)
+    flight_recorder.note(strategy=plan.agg_strategy, **noted)
+
+
 # ---- the single-dispatch program -------------------------------------------
 
 
@@ -3065,12 +3075,15 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
     # working set.  A single unrolled program over 4 chunks x 10 columns
     # both overcommitted HBM (concurrent column scheduling) and took
     # minutes to compile.
-    partial_jit = jax.jit(
-        functools.partial(
-            compute_partial_states, plan, count_cols=nullable_cols
-        ),
-        static_argnames=(),
-    )
+    # Each piece traces under a `jax.named_scope`, so the device ops of a
+    # profiler trace say which piece they belong to (metadata only).
+    def _partial_states(*args, **kwargs):
+        with jax.named_scope("partial"):
+            return compute_partial_states(
+                plan, *args, count_cols=nullable_cols, **kwargs
+            )
+
+    partial_jit = jax.jit(_partial_states)
 
     def _partial(cols, valid, nulls, dyn, perm, limbs, hash_table=None):
         if is_hash:
@@ -3079,9 +3092,11 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
             )
         return partial_jit(cols, valid, nulls, dyn, perm, limbs=limbs)
 
-    merge_jit = jax.jit(
-        lambda a, b: {k: merge_states(a[k], b[k]) for k in a}
-    )
+    def _merge(a, b):
+        with jax.named_scope("merge"):
+            return {k: merge_states(a[k], b[k]) for k in a}
+
+    merge_jit = jax.jit(_merge)
 
     def _device_select(merged, outs, presence, hv):
         """Device finalization: HAVING mask (ops/aggregate.having_mask)
@@ -3229,7 +3244,7 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
             return buf, accs64, table_keys
         return buf, accs64
 
-    final_jit = jax.jit(_final)
+    final_jit = jax.jit(jax.named_scope("finalize")(_final))
 
     def run_all(sources, dyn, sync=False):
         # per-source partials compute WHERE THE CHUNK LIVES (jit follows
@@ -3373,11 +3388,13 @@ def _mega_program(member_keys: tuple):
                     states = partial_jit(
                         cols, valid, nulls, pdyn, perm, limbs=limbs
                     )
-                merged = (
-                    states
-                    if merged is None
-                    else {k: merge_states(merged[k], states[k]) for k in merged}
-                )
+                if merged is None:
+                    merged = states
+                else:
+                    with jax.named_scope("merge"):
+                        merged = {
+                            k: merge_states(merged[k], states[k]) for k in merged
+                        }
             outs.append(final_jit(merged, hv, table_keys))
         return tuple(outs)
 
@@ -5016,10 +5033,11 @@ class TileExecutor:
                     # in-window (and dedup-surviving) rows into a compact
                     # tile — the kernel then scans the window, not the
                     # retention (reference prunes SSTs/row-groups by time)
-                    wsrc = self.cache.ensure_window_tile(
-                        s, window, use_ts, self._plan_cols(plan),
-                        set(limb_need), dedup, ctx.dictionary.epoch,
-                    )
+                    with tracing.stage("tile.window", region=s.region_id):
+                        wsrc = self.cache.ensure_window_tile(
+                            s, window, use_ts, self._plan_cols(plan),
+                            set(limb_need), dedup, ctx.dictionary.epoch,
+                        )
                     if wsrc is not None:
                         passes.note(
                             "window_tile", True,
@@ -5233,20 +5251,13 @@ class TileExecutor:
                         strategy=attempt_plan.agg_strategy,
                         acc=attempt_plan.acc_dtype,
                         mesh_devices=0,
-                    ):
-                        t_disp = time.perf_counter()
+                    ) as disp:
                         with rtt_sim.round_trip(enabled=not _in_fused_build()):
                             packed = device_health.supervised_call(
                                 "dispatch",
                                 lambda: program(tuple(device_sources), dyn),
                             )
-                        flight_recorder.stage_add(
-                            "dispatch",
-                            (time.perf_counter() - t_disp) * 1000.0,
-                        )
-                        flight_recorder.note(
-                            strategy=attempt_plan.agg_strategy
-                        )
+                    _record_dispatch(disp, attempt_plan)
                 table = self._finalize(
                     packed, int_layout, acc32_layout, acc64_layout, int_dtype,
                     attempt_plan, lowering, schema, ctx, dyn_host, fspec,
@@ -5277,18 +5288,14 @@ class TileExecutor:
                     strategy=attempt_plan.agg_strategy,
                     acc=attempt_plan.acc_dtype,
                     retry=True,
-                ):
-                    t_disp = time.perf_counter()
+                ) as disp:
                     with rtt_sim.round_trip(enabled=not _in_fused_build()):
                         packed = device_health.supervised_call(
                             "dispatch",
                             lambda: program(tuple(device_sources), dyn),
                         )
-                    flight_recorder.stage_add(
-                        "dispatch", (time.perf_counter() - t_disp) * 1000.0
-                    )
-                    flight_recorder.note(strategy=attempt_plan.agg_strategy)
-                    flight_recorder.flag("retry")
+                _record_dispatch(disp, attempt_plan)
+                flight_recorder.flag("retry")
                 table = self._finalize(
                     packed, int_layout, acc32_layout, acc64_layout, int_dtype,
                     attempt_plan, lowering, schema, ctx, dyn_host, fspec,
@@ -5583,14 +5590,10 @@ class TileExecutor:
                     strategy=attempt_plan.agg_strategy,
                     acc=attempt_plan.acc_dtype,
                     streamed=True,
-                ):
-                    t_disp = time.perf_counter()
+                ) as disp:
                     packed = program(make_sources(), dyn, sync=True)
-                    flight_recorder.stage_add(
-                        "dispatch", (time.perf_counter() - t_disp) * 1000.0
-                    )
-                    flight_recorder.note(strategy=attempt_plan.agg_strategy)
-                    flight_recorder.flag("streamed")
+                _record_dispatch(disp, attempt_plan)
+                flight_recorder.flag("streamed")
             except QueryTimeoutError:
                 raise  # the deadline owns the query
             except Exception as e:  # noqa: BLE001 — fall to all-at-once
@@ -5665,8 +5668,7 @@ class TileExecutor:
                 acc=attempt_plan.acc_dtype,
                 mesh_devices=mesh_n,
                 shard_axis=REGION_AXIS,
-            ):
-                t_disp = time.perf_counter()
+            ) as disp:
                 # supervised with the mesh's device slots as the blast
                 # radius; shape-ineligibility is a benign verdict, not a
                 # device error, so it never feeds the breaker
@@ -5679,12 +5681,7 @@ class TileExecutor:
                     devices=tuple(range(mesh_n)),
                     countable=lambda e: not isinstance(e, _MeshIneligible),
                 )
-                flight_recorder.stage_add(
-                    "dispatch", (time.perf_counter() - t_disp) * 1000.0
-                )
-                flight_recorder.note(
-                    strategy=attempt_plan.agg_strategy, mesh_devices=mesh_n
-                )
+            _record_dispatch(disp, attempt_plan, mesh_devices=mesh_n)
             metrics.TILE_MESH_DISPATCHES.inc()
             passes.note(
                 "mesh_dispatch", True,
@@ -7175,48 +7172,52 @@ class TileExecutor:
         # The span carries both figures: on an async dispatch the transfer
         # time here INCLUDES waiting out the device compute, which is what
         # makes readback the honest place to look for slow dispatches.
-        with tracing.span("tile.readback") as rb_span:
-            t0 = time.perf_counter()
-            fetched = self._fetch_result(packed)
-            # compact (device-finalize) results are ONE flat buffer — the
-            # f64 rows ride it as packed bit pairs; full-buffer results
-            # keep the (buf, accs64) pair
-            buf = fetched[0]
-            accs64 = fetched[1] if len(fetched) > 1 else None
-            # hash strategy ships the slot->gid key table as a third part
-            table_keys = fetched[2] if len(fetched) > 2 else None
-            ms = (time.perf_counter() - t0) * 1000.0
-            if not _in_fused_build():
-                # the builder's priming fetch stays out of the per-query
-                # readback accounting (bench + EXPLAIN read deltas)
-                metrics.TILE_READBACK_MS.observe(ms)
-                metrics.TPU_READBACK_MS.observe(ms)
-                metrics.TPU_READBACK_TRANSFER_MS.observe(ms)
-                metrics.TPU_READBACK_BYTES.inc(sum(p.nbytes for p in fetched))
-                metrics.TPU_DEVICE_FETCHES.inc()
-            self._rb_local.transfer_ms = ms
-            rb_span.attributes["transfer_ms"] = round(ms, 3)
-            rb_span.attributes["bytes"] = sum(p.nbytes for p in fetched)
-            rb_span.attributes["device_finalize"] = bool(
-                getattr(lowering, "post_done", None)
-            )
-            flight_recorder.stage_add("readback_transfer", ms)
-            flight_recorder.add_bytes(
-                down=int(sum(p.nbytes for p in fetched))
-            )
-            t_dec = time.perf_counter()
-            try:
-                return self._decode_result(
-                    buf, accs64, int_layout, acc32_layout, acc64_layout,
-                    int_dtype, plan, lowering, ctx, dyn_host, spec,
-                    table_keys=table_keys,
+        # `tile.readback` holds the fetch and, as its child stage,
+        # `tile.decode`: its self time is the fetch.  The two stages' clocks
+        # are the only ones here; histograms, the flight recorder, EXPLAIN
+        # ANALYZE and the stage counters all read them.
+        dec = tracing.stage("tile.decode")
+        fetched = None
+        try:
+            with tracing.span("tile.readback") as rb_span:
+                fetched = self._fetch_result(packed)
+                # compact (device-finalize) results are ONE flat buffer —
+                # the f64 rows ride it as packed bit pairs; full-buffer
+                # results keep the (buf, accs64) pair
+                buf = fetched[0]
+                accs64 = fetched[1] if len(fetched) > 1 else None
+                # hash strategy ships the slot->gid key table as a third part
+                table_keys = fetched[2] if len(fetched) > 2 else None
+                nbytes = int(sum(p.nbytes for p in fetched))
+                rb_span.attributes["bytes"] = nbytes
+                rb_span.attributes["device_finalize"] = bool(
+                    getattr(lowering, "post_done", None)
                 )
-            finally:
-                dec_ms = (time.perf_counter() - t_dec) * 1000.0
+                with dec:
+                    return self._decode_result(
+                        buf, accs64, int_layout, acc32_layout, acc64_layout,
+                        int_dtype, plan, lowering, ctx, dyn_host, spec,
+                        table_keys=table_keys,
+                    )
+        finally:
+            if fetched is not None:
+                dec_ms = (dec.duration_s or 0.0) * 1000.0
+                ms = rb_span.duration() * 1000.0 - dec_ms
+                if not _in_fused_build():
+                    # the builder's priming fetch stays out of the per-query
+                    # readback accounting (bench + EXPLAIN read deltas)
+                    metrics.TPU_READBACK_MS.observe(ms)
+                    metrics.TPU_READBACK_TRANSFER_MS.observe(ms)
+                    metrics.TPU_READBACK_BYTES.inc(nbytes)
+                    metrics.TPU_DEVICE_FETCHES.inc()
                 metrics.TPU_READBACK_DECODE_MS.observe(dec_ms)
+                self._rb_local.transfer_ms = ms
                 self._rb_local.decode_ms = dec_ms
+                rb_span.attributes["transfer_ms"] = round(ms, 3)
                 rb_span.attributes["decode_ms"] = round(dec_ms, 3)
+                flight_recorder.stage_add("readback_transfer", ms)
                 flight_recorder.stage_add("readback_decode", dec_ms)
+                flight_recorder.add_bytes(down=nbytes)
 
     def _fused_dispatch(self, cds):
         """Dispatch N captured members as ONE fused XLA invocation and
@@ -7274,21 +7275,19 @@ class TileExecutor:
             )
         traces0 = _MEGA_STATS["traces"]
         metrics.TPU_DEVICE_DISPATCHES.inc()
-        with tracing.span("tile.fused_dispatch", members=len(cds)):
-            t_disp = time.perf_counter()
+        with tracing.span("tile.fused_dispatch", members=len(cds)) as disp:
             with rtt_sim.round_trip():
                 packed_all = device_health.supervised_call(
                     "dispatch", lambda: fused(tuple(inputs))
                 )
-            dispatch_ms = (time.perf_counter() - t_disp) * 1000.0
+        dispatch_ms = disp.duration() * 1000.0
         leaves = [a for packed in packed_all for a in packed]
-        t_rb = time.perf_counter()
-        with tracing.span("tile.batch_readback", members=len(cds)):
+        with tracing.span("tile.batch_readback", members=len(cds)) as rb:
             with rtt_sim.round_trip():
                 fetched = device_health.supervised_call(
                     "readback", lambda: jax.device_get(leaves)
                 )
-        transfer_ms = (time.perf_counter() - t_rb) * 1000.0
+        transfer_ms = rb.duration() * 1000.0
         tables = [None] * len(cds)
         off = 0
         for pos, i in enumerate(order):
@@ -7327,15 +7326,16 @@ class TileExecutor:
         accs64 = fetched[1] if len(fetched) > 1 else None
         table_keys = fetched[2] if len(fetched) > 2 else None
         metrics.TPU_READBACK_BYTES.inc(sum(p.nbytes for p in fetched))
-        t_dec = time.perf_counter()
+        dec = tracing.stage("tile.decode")
         try:
-            return self._decode_result(
-                buf, accs64, int_layout, acc32_layout, acc64_layout,
-                int_dtype, plan, lowering, ctx, dyn_host, spec,
-                table_keys=table_keys,
-            )
+            with dec:
+                return self._decode_result(
+                    buf, accs64, int_layout, acc32_layout, acc64_layout,
+                    int_dtype, plan, lowering, ctx, dyn_host, spec,
+                    table_keys=table_keys,
+                )
         finally:
-            dec_ms = (time.perf_counter() - t_dec) * 1000.0
+            dec_ms = dec.duration_s * 1000.0
             metrics.TPU_READBACK_DECODE_MS.observe(dec_ms)
             self._rb_local.decode_ms = dec_ms
 

@@ -23,6 +23,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ...datatypes.schema import SemanticType
+from ...utils import tracing
 from ...utils.errors import PlanError, UnsupportedError
 from ..logical_plan import TableScan
 from .parser import (
@@ -115,7 +116,8 @@ class PromqlEngine:
 
     # ---- public API (mirrors the HTTP /api/v1 surface) --------------------
     def query_range(self, promql: str, start_ms: int, end_ms: int, step_ms: int) -> pa.Table:
-        ast = parse_promql(promql)
+        with tracing.stage("query.parse"):
+            ast = parse_promql(promql)
         out = self._eval(ast, start_ms, end_ms, step_ms)
         if isinstance(out, Scalar):
             steps = np.arange(start_ms, end_ms + 1, step_ms, dtype=np.int64)

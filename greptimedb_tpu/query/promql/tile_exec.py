@@ -720,14 +720,11 @@ class TqlTileExecutor:
 
         ghost = _in_fused_build()
         mesh_n = self.cache.mesh_devices()
-        import time as _time
-
         with tracing.span(
             "tile.dispatch", strategy="tql", func=func,
             series=s_pad, steps=w, regions=len(sources),
             mesh_devices=mesh_n,
-        ):
-            t_disp = _time.perf_counter()
+        ) as disp:
             if mesh_n > 0 and len(sources) > 1:
                 mat, pres = self._mesh_dispatch(
                     csig, sources, region_sigs, dyn, sources_meta, ghost
@@ -740,15 +737,13 @@ class TqlTileExecutor:
                 if not ghost:
                     metrics.TPU_DEVICE_DISPATCHES.inc()
                 mat, pres = fn(tuple(sources), dyn)
-            flight_recorder.stage_add(
-                "dispatch", (_time.perf_counter() - t_disp) * 1000.0
-            )
-            flight_recorder.note(
-                strategy="tql", mesh_devices=mesh_n, build_mode="warm"
-            )
-            np_mat, np_pres, pregathered = self._readback(
-                mat, pres, ghost, cfg, compact_ok=agg_op is None
-            )
+        flight_recorder.stage_add("dispatch", disp.duration() * 1000.0)
+        flight_recorder.note(
+            strategy="tql", mesh_devices=mesh_n, build_mode="warm"
+        )
+        np_mat, np_pres, pregathered = self._readback(
+            mat, pres, ghost, cfg, compact_ok=agg_op is None
+        )
         if not ghost:
             metrics.TQL_TILE_DISPATCHES.inc()
         passes.note(
@@ -804,38 +799,31 @@ class TqlTileExecutor:
         [series_out, steps] result, never the padded series space.
         Fused by-label results are already compact [groups, steps] and
         always take the one-round-trip form."""
-        import time as _time
-
-        t0 = _time.perf_counter()
         threshold = int(getattr(cfg.tql, "compact_readback_kb", 1024)) << 10
         pregathered = None
-        if compact_ok and mat.size * 8 > threshold:
-            np_pres = [np.asarray(p) for p in jax.device_get(pres)]
-            pregathered = _legacy_order(np_pres)
-            if pregathered:
-                sel = jnp.asarray(np.asarray(pregathered, np.int32))
-                np_mat = np.asarray(jax.device_get(jnp.take(mat, sel, axis=0)))
+        with tracing.span("tile.readback") as rb:
+            if compact_ok and mat.size * 8 > threshold:
+                np_pres = [np.asarray(p) for p in jax.device_get(pres)]
+                pregathered = _legacy_order(np_pres)
+                if pregathered:
+                    sel = jnp.asarray(np.asarray(pregathered, np.int32))
+                    np_mat = np.asarray(jax.device_get(jnp.take(mat, sel, axis=0)))
+                else:
+                    np_mat = np.zeros((0, mat.shape[1]))
             else:
-                np_mat = np.zeros((0, mat.shape[1]))
-        else:
-            np_mat, np_pres = jax.device_get((mat, pres))
-            np_mat = np.asarray(np_mat)
-            np_pres = [np.asarray(p) for p in np_pres]
-        ms = (_time.perf_counter() - t0) * 1000.0
+                np_mat, np_pres = jax.device_get((mat, pres))
+                np_mat = np.asarray(np_mat)
+                np_pres = [np.asarray(p) for p in np_pres]
+            nbytes = int(np_mat.nbytes + sum(p.nbytes for p in np_pres))
+            rb.attributes["bytes"] = nbytes
+            rb.attributes["compact"] = pregathered is not None
+        ms = rb.duration() * 1000.0
         flight_recorder.stage_add("readback_transfer", ms)
-        flight_recorder.add_bytes(
-            down=int(np_mat.nbytes + sum(p.nbytes for p in np_pres))
-        )
+        flight_recorder.add_bytes(down=nbytes)
         if not ghost:
             metrics.TPU_DEVICE_FETCHES.inc()
             metrics.TPU_READBACK_MS.observe(ms)
-            metrics.TPU_READBACK_BYTES.inc(
-                int(np_mat.nbytes + sum(p.nbytes for p in np_pres))
-            )
-            tracing.add_event(
-                "tile.readback", bytes=int(np_mat.nbytes), ms=round(ms, 2),
-                compact=pregathered is not None,
-            )
+            metrics.TPU_READBACK_BYTES.inc(nbytes)
         return np_mat, np_pres, pregathered
 
     # ---- host assembly -----------------------------------------------------

@@ -19,7 +19,8 @@ import numpy as np
 import pyarrow as pa
 
 from ..utils.errors import GreptimeError, StatusCode
-from ..utils.metrics import REGISTRY
+from ..utils.metrics import HTTP_REQUEST_S, REGISTRY
+from ..utils.tracing import stage
 from .influx import parse_line_protocol, write_points
 
 
@@ -65,12 +66,23 @@ class _Handler(BaseHTTPRequestHandler):
         pass  # quiet; metrics cover it
 
     def _send(self, code: int, payload, content_type="application/json"):
-        body = payload if isinstance(payload, bytes) else json.dumps(payload).encode()
-        self.send_response(code)
-        self.send_header("Content-Type", content_type)
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        """Render (`http.render`, unless the caller already holds bytes),
+        then write (`http.write`): two stages, so that rendering a large
+        answer is not timed as socket time.  A callable `payload` builds
+        the document inside the render stage."""
+        if isinstance(payload, bytes):
+            body = payload
+        else:
+            with stage("http.render"):
+                if callable(payload):
+                    payload = payload()
+                body = json.dumps(payload).encode()
+        with stage("http.write", bytes=len(body)):
+            self.send_response(code)
+            self.send_header("Content-Type", content_type)
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
 
     def _params(self) -> dict:
         parsed = urllib.parse.urlparse(self.path)
@@ -116,6 +128,15 @@ class _Handler(BaseHTTPRequestHandler):
         self._dispatch()
 
     def _dispatch(self):
+        """One `http.request` stage per request, every route: the root of
+        the stage clocks.  Its self time is reading the body, the hand-off
+        to the kernel thread and whatever no child stage covers; a traced
+        statement adds its `trace_id` (`tracing.set_root_attribute`)."""
+        with stage("http.request", route=self.route) as st:
+            self._route()
+        HTTP_REQUEST_S.inc(st.duration_s)
+
+    def _route(self):
         try:
             self.db.ensure_session()  # per-request session anchor
             route = self.route
@@ -370,19 +391,14 @@ class _Handler(BaseHTTPRequestHandler):
         from ..utils import kernel_executor
         from ..utils.tracing import protocol_scope
 
-        outputs = []
         # protocol tag for the statement's root span (kernel_executor runs
         # the closure under a COPY of this context, so the scope crosses)
         with protocol_scope("http"):
             results = kernel_executor.run(lambda: list(self.db.sql(sql)))
-        for result in results:
-            if isinstance(result, int):
-                outputs.append({"affectedrows": result})
-            elif result is None:
-                outputs.append({"affectedrows": 0})
-            else:
-                outputs.append(_table_to_greptime_json(result))
-        return self._send(200, {"output": outputs, "execution_time_ms": 0})
+        return self._send(200, lambda: {
+            "output": [_table_to_greptime_json(result) for result in results],
+            "execution_time_ms": 0,
+        })
 
     def _handle_logs(self, params):
         """Structured log search (reference /v1/logs, log-query crate DSL)."""
@@ -402,9 +418,9 @@ class _Handler(BaseHTTPRequestHandler):
             # requests on other threads must not see this request's db
             query.database = params["db"]
         table = kernel_executor.run(lambda: execute_log_query(self.db, query))
-        return self._send(
-            200, {"output": [_table_to_greptime_json(table)], "execution_time_ms": 0}
-        )
+        return self._send(200, lambda: {
+            "output": [_table_to_greptime_json(table)], "execution_time_ms": 0,
+        })
 
     def _handle_influx(self, params):
         body_raw = params.get("__body") or b""
@@ -572,13 +588,13 @@ class _Handler(BaseHTTPRequestHandler):
                 engine.query_range,
                 params["query"], int(start * 1000), int(end * 1000), int(step * 1000),
             )
-            return self._send(200, _prom_matrix_json(table))
+            return self._send(200, lambda: _prom_matrix_json(table))
         if endpoint == "query":
             t = float(params.get("time", 0))
             table = kernel_executor.run(
                 engine.query_instant, params["query"], int(t * 1000)
             )
-            return self._send(200, _prom_vector_json(table))
+            return self._send(200, lambda: _prom_vector_json(table))
         if endpoint == "labels":
             labels = set()
             for meta in self.db.catalog.tables(self.db.current_database):

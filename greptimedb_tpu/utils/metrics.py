@@ -234,7 +234,6 @@ TILE_CACHE_MISSES = REGISTRY.counter("greptime_tile_cache_misses_total", "HBM ti
 TILE_CACHE_EVICTIONS = REGISTRY.counter("greptime_tile_cache_evictions_total", "HBM tile cache evictions")
 TILE_QUERY_ELAPSED = REGISTRY.histogram("greptime_query_tile_elapsed", "Tile-path query seconds")
 TILE_LOWERED_TOTAL = REGISTRY.counter("greptime_query_tile_lowered_total", "Queries served from the HBM tile cache")
-TILE_READBACK_MS = REGISTRY.histogram("greptime_tile_readback_ms", "Device->host result fetch milliseconds per tile query")
 TILE_LIMB_RERUNS = REGISTRY.counter("greptime_tile_limb_reruns_total", "Tile queries rerun in exact f64 after the limb error-bound verdict failed")
 AGG_STRATEGY_TOTAL = REGISTRY.counter(
     "greptime_agg_strategy_total",
@@ -791,3 +790,58 @@ TILE_HEALTH_INVALIDATIONS = REGISTRY.counter(
     "generation change (quarantine or heal): entries rebuild on the "
     "surviving device set",
 )
+
+# ---- stage clocks (utils/tracing.py `stage`) --------------------------------
+# SELF seconds per stage of the request path: a stage's duration minus what
+# its counted descendants covered, so the counters of one request sum to
+# HTTP_REQUEST_S's move.  One module-level Counter each (benchmark readers
+# find counters by these names); STAGE_SELF_S below is the only registry,
+# and a stage whose name is not in it is transparent.
+
+
+def _stage_self_s(stage: str, what: str) -> Counter:
+    return REGISTRY.counter(
+        f"greptime_stage_self_seconds_{stage.replace('.', '_')}_total",
+        f"Self seconds of the `{stage}` stage: {what}",
+    )
+
+
+STAGE_SELF_S_HTTP_REQUEST = _stage_self_s(
+    "http.request", "body read, kernel-thread hand-off, whatever no child stage covers")
+STAGE_SELF_S_HTTP_RENDER = _stage_self_s("http.render", "result to response bytes")
+STAGE_SELF_S_HTTP_WRITE = _stage_self_s("http.write", "status line, headers, socket write")
+STAGE_SELF_S_QUERY_PARSE = _stage_self_s("query.parse", "SQL / PromQL text to statements")
+STAGE_SELF_S_QUERY_PLAN = _stage_self_s("query.plan", "statement to logical plan")
+STAGE_SELF_S_QUERY_TPU = _stage_self_s(
+    "query.tpu", "device-path host work outside the tile stages")
+STAGE_SELF_S_QUERY_CPU = _stage_self_s("query.cpu", "CPU engine, fallback included")
+STAGE_SELF_S_TILE_BUILD = _stage_self_s("tile.build", "super-tile resolution")
+STAGE_SELF_S_TILE_COMPILE = _stage_self_s("tile.compile", "tile-program cache lookup")
+STAGE_SELF_S_TILE_WINDOW = _stage_self_s(
+    "tile.window", "window-tile probe or build: the in-window rows found on the host")
+STAGE_SELF_S_TILE_DISPATCH = _stage_self_s("tile.dispatch", "compiled program invocation")
+STAGE_SELF_S_TILE_READBACK = _stage_self_s(
+    "tile.readback", "device->host fetch, waiting out the device included")
+STAGE_SELF_S_TILE_DECODE = _stage_self_s("tile.decode", "fetched buffers to Arrow rows")
+HTTP_REQUEST_S = REGISTRY.counter(
+    "greptime_http_request_seconds_total",
+    "Inclusive seconds of every HTTP request (the `http.request` stage)",
+)
+STAGE_SELF_S: dict[str, Counter] = {
+    "http.request": STAGE_SELF_S_HTTP_REQUEST,
+    "http.render": STAGE_SELF_S_HTTP_RENDER,
+    "http.write": STAGE_SELF_S_HTTP_WRITE,
+    "query.parse": STAGE_SELF_S_QUERY_PARSE,
+    "query.plan": STAGE_SELF_S_QUERY_PLAN,
+    "query.tpu": STAGE_SELF_S_QUERY_TPU,
+    "query.cpu": STAGE_SELF_S_QUERY_CPU,
+    "query.cpu_fallback": STAGE_SELF_S_QUERY_CPU,
+    "tile.build": STAGE_SELF_S_TILE_BUILD,
+    "tile.compile": STAGE_SELF_S_TILE_COMPILE,
+    "tile.window": STAGE_SELF_S_TILE_WINDOW,
+    "tile.dispatch": STAGE_SELF_S_TILE_DISPATCH,
+    "tile.fused_dispatch": STAGE_SELF_S_TILE_DISPATCH,
+    "tile.readback": STAGE_SELF_S_TILE_READBACK,
+    "tile.batch_readback": STAGE_SELF_S_TILE_READBACK,
+    "tile.decode": STAGE_SELF_S_TILE_DECODE,
+}

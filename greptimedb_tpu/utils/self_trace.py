@@ -174,6 +174,7 @@ def statement_trace(owner, kind: str, query_text: str, database: str = "",
             # follows the tail decision — no root-less orphan rows for
             # sampled-out traces
             tracing.register_collector(root.trace_id, collector)
+            tracing.set_root_attribute(trace_id=root.trace_id)
             yield root
     except BaseException as exc:
         err = exc

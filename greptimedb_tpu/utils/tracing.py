@@ -20,6 +20,14 @@ known — slow/erroring statements are force-kept, fast ones head-sample
 (utils/self_trace.py owns the policy; this module only carries spans).
 Spans with no collector in scope export straight to the ring, exactly the
 pre-collector behavior.
+
+Two layers of one primitive.  `stage(name)` is the MEASUREMENT: one
+monotonic clock pair, a `jax.profiler.TraceAnnotation` held for the stage's
+life (inert outside a profiler session; inside one the stage sits on the
+host plane, on the device trace's clock) and the stage's SELF time added to
+its counter in `metrics.STAGE_SELF_S` (duration minus what its counted
+descendants covered; a name with no counter is transparent).  `span(name)`
+is a stage plus the trace identity: ids, parent, collector, export.
 """
 
 from __future__ import annotations
@@ -31,6 +39,8 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+
+from . import metrics
 
 # Span/trace ids need uniqueness, not unpredictability: a process-local
 # PRNG (seeded from the OS once) is ~50x cheaper than secrets.token_hex's
@@ -65,8 +75,14 @@ class Span:
     status_message: str = ""
     service: str = ""
     collector: object | None = field(default=None, repr=False)
+    # the stage that measured this span (None for a hand-made Span)
+    stage: object | None = field(default=None, repr=False)
 
     def duration(self) -> float:
+        """Seconds on the monotonic clock; `start`/`end` are wall time for
+        the OTLP row (`end` = `start` + this)."""
+        if self.stage is not None:
+            return self.stage.elapsed()
         return (self.end or time.time()) - self.start
 
     def add_event(self, name: str, **attrs):
@@ -262,9 +278,141 @@ def _noop() -> _NoopSpan:
     return _NoopSpan(name="", trace_id="", span_id="", parent_id=None)
 
 
+# The innermost open stage of this context: the frame self time is charged
+# against.  `kernel_executor.run` copies the context, so the stack crosses
+# the hop to the `gt-kernel` thread as `_current` does (the frame is the
+# same object on both sides, and the caller blocks while the callee adds).
+_frame: contextvars.ContextVar["stage | None"] = contextvars.ContextVar(
+    "stage_frame", default=None
+)
+# Scope in which stages move no counter: the background fused builder's
+# priming run (the convention `TPU_DEVICE_DISPATCHES` keeps for ghosts).
+_muted: contextvars.ContextVar[bool] = contextvars.ContextVar(
+    "stage_muted", default=False
+)
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+@contextlib.contextmanager
+def counters_muted():
+    """Scope in which `stage()` still measures and annotates but adds to
+    no counter."""
+    token = _muted.set(True)
+    try:
+        yield
+    finally:
+        _muted.reset(token)
+
+
+def _plain(attrs: dict) -> dict:
+    """What an annotation can carry: the profiler writes `name#k=v,k=v#`,
+    so only numbers and short strings free of its delimiters (a statement's
+    text is neither).  Outside a profiler session nothing is carried."""
+    if not attrs or not _annotation.is_enabled():
+        return {}
+    return {
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float))
+        or (isinstance(v, str) and len(v) <= 64 and not set(v) & {"#", ",", "="})
+    }
+
+
+class stage:
+    """One measured stage: `with stage("tile.decode") as st: ...`.
+
+    Reads `time.perf_counter()` at entry and exit (`duration_s` afterwards,
+    `elapsed()` at any time), holds a `jax.profiler.TraceAnnotation` of the
+    same name (inert outside a profiler session) and, at exit, adds its
+    SELF time to its counter in `metrics.STAGE_SELF_S`: `duration_s` minus
+    `child_s`, the seconds its counted descendants covered.  It then adds
+    its whole duration to the enclosing stage's `child_s`; a stage whose
+    name has no counter is transparent and passes its own `child_s` up, so
+    its time stays with the nearest counted ancestor.  Under `suppressed()`
+    only the clock is read."""
+
+    __slots__ = (
+        "name", "attrs", "duration_s", "child_s", "_t0", "_parent", "_token",
+        "_ann",
+    )
+
+    def __init__(self, name: str, **attrs):
+        self.name, self.attrs = name, attrs
+        self.duration_s: float | None = None
+        self.child_s = 0.0
+        self._ann = None
+
+    def __enter__(self):
+        if not _suppress.get():
+            global _annotation
+            if _annotation is None:
+                from jax.profiler import TraceAnnotation
+
+                _annotation = TraceAnnotation
+            SEEN_SPAN_NAMES.add(self.name)
+            self._parent = _frame.get()
+            self._token = _frame.set(self)
+            self._ann = _annotation(self.name, **_plain(self.attrs))
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.duration_s = d = time.perf_counter() - self._t0
+        if self._ann is None:
+            return False
+        self._ann.__exit__(*exc)
+        _frame.reset(self._token)
+        counter = None if _muted.get() else metrics.STAGE_SELF_S.get(self.name)
+        if counter is not None:
+            counter.inc(d - self.child_s)
+        if self._parent is not None:
+            self._parent.child_s += d if counter is not None else self.child_s
+        return False
+
+    def elapsed(self) -> float:
+        if self.duration_s is not None:
+            return self.duration_s
+        return time.perf_counter() - self._t0
+
+    def set(self, **attrs):
+        """Attributes learned inside the stage (the annotation takes them
+        until it closes)."""
+        self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**_plain(attrs))
+
+
+def set_root_attribute(**attrs):
+    """Attributes for the outermost open stage of this context: a traced
+    statement hands its `trace_id` to the `http.request` it runs in."""
+    f = _frame.get()
+    while f is not None and f._parent is not None:
+        f = f._parent
+    if f is not None:
+        f.set(**attrs)
+
+
+@contextlib.contextmanager
+def _open_span(s: Span):
+    """Run `s` as the current span inside its stage, then record it."""
+    s.stage = st = stage(s.name, **s.attributes)
+    token = _current.set(s)
+    st.__enter__()
+    try:
+        yield s
+    except BaseException as exc:
+        s.record_exception(exc)
+        raise
+    finally:
+        st.__exit__(None, None, None)
+        s.end = s.start + st.duration_s
+        _current.reset(token)
+        _record(s)
+
+
 @contextlib.contextmanager
 def span(name: str, parent=_UNSET, service: str | None = None, collector=_UNSET, **attributes):
-    """One traced stage.
+    """One traced stage: `stage(name)` plus ids, parent, collector, export.
 
     `parent` defaults to the ambient contextvar span; pass it explicitly to
     parent a span created on a worker thread (thread pools do not inherit
@@ -288,17 +436,8 @@ def span(name: str, parent=_UNSET, service: str | None = None, collector=_UNSET,
         service=service or (p.service if p and p.service else _service.get()),
         collector=inherited if collector is _UNSET else collector,
     )
-    SEEN_SPAN_NAMES.add(name)
-    token = _current.set(s)
-    try:
+    with _open_span(s):
         yield s
-    except BaseException as exc:
-        s.record_exception(exc)
-        raise
-    finally:
-        s.end = time.time()
-        _current.reset(token)
-        _record(s)
 
 
 def _record(s: Span):
@@ -377,14 +516,5 @@ def extract_context(headers: dict[str, str], name: str = "remote", service: str 
         service=service or _service.get(),
         collector=_collectors.get(trace_id),
     )
-    SEEN_SPAN_NAMES.add(name)
-    token = _current.set(s)
-    try:
+    with _open_span(s):
         yield s
-    except BaseException as exc:
-        s.record_exception(exc)
-        raise
-    finally:
-        s.end = time.time()
-        _current.reset(token)
-        _record(s)
