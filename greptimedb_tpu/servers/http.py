@@ -17,30 +17,17 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 import pyarrow as pa
+import pyarrow.compute as pc
 
 from ..utils.errors import GreptimeError, StatusCode
-from ..utils.metrics import HTTP_REQUEST_S, REGISTRY
+from ..utils.metrics import (
+    HTTP_RENDER_COLUMNAR_CELLS,
+    HTTP_RENDER_FALLBACK_CELLS,
+    HTTP_REQUEST_S,
+    REGISTRY,
+)
 from ..utils.tracing import stage
 from .influx import parse_line_protocol, write_points
-
-
-def _table_to_greptime_json(table: pa.Table | None) -> dict:
-    """Render in the reference's /v1/sql response shape
-    (servers/src/http/handler.rs GreptimedbV1 output)."""
-    if table is None:
-        return {"affectedrows": 0}
-    if isinstance(table, int):
-        return {"affectedrows": table}
-    schema = {
-        "column_schemas": [
-            {"name": f.name, "data_type": str(f.type)} for f in table.schema
-        ]
-    }
-    rows = []
-    cols = [table[c].to_pylist() for c in table.column_names]
-    for i in range(table.num_rows):
-        rows.append([_json_value(col[i]) for col in cols])
-    return {"records": {"schema": schema, "rows": rows}}
 
 
 def _json_value(v):
@@ -57,6 +44,127 @@ def _json_value(v):
     return v
 
 
+# ---- /v1/sql and /v1/logs records, rendered by column ----------------------
+# A column first becomes an array of values JSON has, by Arrow kernels
+# chosen from its type (`_json_ready`).  An answer of some size is then
+# assembled as text in Arrow too, a large_string array of JSON texts per
+# column and one join per row, so no Python object exists per cell between
+# the table and the response bytes; a small one, where the dozen Arrow
+# calls of that cost more than its cells, goes to `json` as lists.  What
+# the kernels do not cover goes cell by cell through `_json_value`, a
+# column at a time.
+
+_TEXT = pa.large_string()
+_EMPTY, _QUOTE, _COMMA, _ROW_SEP, _POINT_ZERO = (
+    pa.scalar(t, _TEXT) for t in ("", '"', ",", "],[", ".0")
+)
+# towards zero, as `int()` cut the old float of milliseconds
+_TO_MS = {"s": (pc.multiply_checked, 1000), "us": (pc.divide, 1000), "ns": (pc.divide, 1_000_000)}
+# a character `json.dumps` would escape, or DEL: anything but printable
+# ASCII without `"` and `\`
+_MUST_ESCAPE = r'[^ !#-\[\]-~]'
+# rows from which assembling the text in Arrow is the cheaper way: its
+# calls cost what some 90 rows of three columns cost as lists
+_TEXT_MIN_ROWS = 64
+_compact_json = json.JSONEncoder(separators=(",", ":")).encode
+
+
+def _json_ready(col: pa.Array) -> pa.Array | None:
+    """`col` with a value JSON has in every cell (a null cell stays null);
+    None for a type the kernels do not cover."""
+    t = col.type
+    if pa.types.is_dictionary(t):
+        t = t.value_type
+        col = col.cast(t)
+    if pa.types.is_timestamp(t):
+        # the stored integer, in milliseconds: no datetime, no host zone
+        ms = col.cast(pa.int64())
+        if t.unit != "ms":
+            scale, by = _TO_MS[t.unit]
+            ms = scale(ms, by)
+        return ms
+    if pa.types.is_floating(t):
+        x = col if t == pa.float64() else col.cast(pa.float64())
+        values = x.to_numpy(zero_copy_only=False)  # a null reads NaN
+        finite = np.isfinite(values)
+        return x if finite.all() else pa.array(values, mask=~finite)
+    if (pa.types.is_integer(t) or pa.types.is_boolean(t) or pa.types.is_null(t)
+            or pa.types.is_string(t) or pa.types.is_large_string(t)):
+        return col
+    return None
+
+
+def _json_text(ready: pa.Array) -> pa.Array:
+    """Every cell of a `_json_ready` column as JSON text."""
+    t = ready.type
+    text = ready.cast(_TEXT)
+    if pa.types.is_floating(t):
+        # Arrow writes the shortest digits that round-trip, and a whole
+        # number without its fraction: 100.0 has to stay a float
+        whole = pc.invert(pc.match_substring_regex(text, "[.e]"))
+        if pc.any(whole).as_py():
+            dotted = pc.binary_join_element_wise(text, _POINT_ZERO, _EMPTY)
+            text = pc.if_else(whole, dotted, text)
+    elif pa.types.is_string(t) or pa.types.is_large_string(t):
+        if pc.any(pc.match_substring_regex(text, _MUST_ESCAPE)).as_py():
+            escape = json.encoder.encode_basestring_ascii
+            cells = [v if v is None else escape(v) for v in text.to_pylist()]
+            return pa.array(cells, _TEXT)
+        text = pc.binary_join_element_wise(_QUOTE, text, _QUOTE, _EMPTY)
+    return text
+
+
+def _rows_json(batch: pa.RecordBatch) -> bytes:
+    """`batch`'s rows as `[a,b],[c,d]`: the `rows` array without its own
+    brackets."""
+    if not batch.num_columns:
+        return b",".join([b"[]"] * batch.num_rows)
+    as_text = batch.num_rows >= _TEXT_MIN_ROWS
+    columns = []
+    for col in batch.columns:
+        ready = _json_ready(col)
+        if ready is None:
+            HTTP_RENDER_FALLBACK_CELLS.inc(len(col))
+            cells = [_json_value(v) for v in col.to_pylist()]
+            columns.append(pa.array(map(_compact_json, cells), _TEXT) if as_text else cells)
+        else:
+            HTTP_RENDER_COLUMNAR_CELLS.inc(len(col))
+            columns.append(_json_text(ready) if as_text else ready.to_pylist())
+    if not as_text:
+        return _compact_json(list(zip(*columns)))[1:-1].encode()
+    rows = pc.binary_join_element_wise(
+        *columns, _COMMA, null_handling="replace", null_replacement="null"
+    )
+    whole = pa.LargeListArray.from_arrays(pa.array([0, len(rows)], pa.int64()), rows)
+    return b"[%b]" % pc.binary_join(whole, _ROW_SEP)[0].as_buffer().to_pybytes()
+
+
+def _result_json(result: pa.Table | int | None) -> bytes:
+    """One statement's result in the reference's /v1/sql response shape
+    (servers/src/http/handler.rs GreptimedbV1 output)."""
+    if result is None:
+        return b'{"affectedrows":0}'
+    if isinstance(result, int):
+        return b'{"affectedrows":%d}' % result
+    schema = _compact_json({
+        "column_schemas": [
+            {"name": f.name, "data_type": str(f.type)} for f in result.schema
+        ]
+    })
+    rows = b",".join(
+        _rows_json(batch)
+        for batch in result.combine_chunks().to_batches() if batch.num_rows
+    )
+    return b'{"records":{"schema":%b,"rows":[%b]}}' % (schema.encode(), rows)
+
+
+def _results_json(results) -> bytes:
+    """The whole /v1/sql (and /v1/logs) response document."""
+    return b'{"output":[%b],"execution_time_ms":0}' % b",".join(
+        _result_json(result) for result in results
+    )
+
+
 class _Handler(BaseHTTPRequestHandler):
     server_version = "greptimedb-tpu/0.1"
     db = None  # set by HttpServer
@@ -69,14 +177,14 @@ class _Handler(BaseHTTPRequestHandler):
         """Render (`http.render`, unless the caller already holds bytes),
         then write (`http.write`): two stages, so that rendering a large
         answer is not timed as socket time.  A callable `payload` builds
-        the document inside the render stage."""
+        the document, or its bytes, inside the render stage."""
         if isinstance(payload, bytes):
             body = payload
         else:
             with stage("http.render"):
                 if callable(payload):
                     payload = payload()
-                body = json.dumps(payload).encode()
+                body = payload if isinstance(payload, bytes) else json.dumps(payload).encode()
         with stage("http.write", bytes=len(body)):
             self.send_response(code)
             self.send_header("Content-Type", content_type)
@@ -395,10 +503,7 @@ class _Handler(BaseHTTPRequestHandler):
         # the closure under a COPY of this context, so the scope crosses)
         with protocol_scope("http"):
             results = kernel_executor.run(lambda: list(self.db.sql(sql)))
-        return self._send(200, lambda: {
-            "output": [_table_to_greptime_json(result) for result in results],
-            "execution_time_ms": 0,
-        })
+        return self._send(200, lambda: _results_json(results))
 
     def _handle_logs(self, params):
         """Structured log search (reference /v1/logs, log-query crate DSL)."""
@@ -418,9 +523,7 @@ class _Handler(BaseHTTPRequestHandler):
             # requests on other threads must not see this request's db
             query.database = params["db"]
         table = kernel_executor.run(lambda: execute_log_query(self.db, query))
-        return self._send(200, lambda: {
-            "output": [_table_to_greptime_json(table)], "execution_time_ms": 0,
-        })
+        return self._send(200, lambda: _results_json([table]))
 
     def _handle_influx(self, params):
         body_raw = params.get("__body") or b""

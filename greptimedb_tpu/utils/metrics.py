@@ -827,6 +827,16 @@ HTTP_REQUEST_S = REGISTRY.counter(
     "greptime_http_request_seconds_total",
     "Inclusive seconds of every HTTP request (the `http.request` stage)",
 )
+# how often `servers/http.py` renders a records answer by column, and how
+# often a column's type sends it through the per-cell `_json_value` instead
+HTTP_RENDER_COLUMNAR_CELLS = REGISTRY.counter(
+    "greptime_http_render_columnar_cells_total",
+    "Cells of /v1/sql and /v1/logs answers rendered to JSON a column at a time, by its Arrow type",
+)
+HTTP_RENDER_FALLBACK_CELLS = REGISTRY.counter(
+    "greptime_http_render_fallback_cells_total",
+    "Cells of /v1/sql and /v1/logs answers rendered one by one (a column type the kernels do not cover)",
+)
 STAGE_SELF_S: dict[str, Counter] = {
     "http.request": STAGE_SELF_S_HTTP_REQUEST,
     "http.render": STAGE_SELF_S_HTTP_RENDER,
