@@ -139,14 +139,15 @@ def main(seed: int):
     timed("segmented f64 sum (prefix_scan)", jax.jit(R._sum_since_start),
           d_ts == 0, d_val)
 
-    def windows(sid, ts, v, valid, start, nsteps):
+    def windows(sid, ts, v, valid, start, nsteps, raw=None):
         return R.range_windows_dyn(
             sid, ts, v, valid, start=start, step=STEP_MS, range_=RANGE_MS,
             n_steps=W_PAD, k=K, num_series=S_PAD, n_steps_actual=nsteps,
+            raw_values=raw,
         )
 
-    fields = ("count", "first_ts", "last_ts", "first_val", "last_val",
-              "sum", "min", "max")
+    fields = ("count", "first_ts", "last_ts", "first_val", "first_raw",
+              "last_val", "sum", "min", "max")
 
     def all_stats(*a):
         st = windows(*a)
@@ -154,9 +155,10 @@ def main(seed: int):
 
     def rate_program(sid, ts, v, valid, start, nsteps):
         """What the tile program runs for `rate`: the compiler drops the
-        sum/min/max stats nothing reads (40 scatters, not 64)."""
+        sum/min/max stats nothing reads (40 64-bit scatters and 8 int32 ones for
+        `first_raw`, not 72)."""
         st = windows(sid, ts, R.strip_counter_resets_segmented(sid, v, valid),
-                     valid, start, nsteps)
+                     valid, start, nsteps, raw=v)
         vals, defined = R.extrapolated_rate_dyn(
             st, start, STEP_MS, RANGE_MS, W_PAD, "rate"
         )
@@ -164,8 +166,8 @@ def main(seed: int):
 
     args = (d_sid, d_ts, d_val, d_valid, start, nsteps)
     timed("rate program: strip + range_windows_dyn + extrapolated_rate_dyn",
-          jax.jit(rate_program), *args, reps=2, scatters=40)
-    stats = timed("range_windows_dyn k=8, all eight stats", jax.jit(all_stats),
+          jax.jit(rate_program), *args, reps=2, scatters=48)
+    stats = timed("range_windows_dyn k=8, eight stats (no raw plane)", jax.jit(all_stats),
                   *args, reps=2, scatters=64)
 
     segs = S_PAD * W_PAD + 1

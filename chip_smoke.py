@@ -344,6 +344,7 @@ def fold_rate(ds: Dataset, start: int, end: int, step: int, range_ms: int,
     i_last_c = np.clip(i_last, 0, len(tts) - 1)
     first_ts, last_ts = tts[i_first_c], tts[i_last_c]
     fv, lv = adj[:, i_first_c], adj[:, i_last_c]
+    raw_first = v[:, i_first_c]  # the clamp's zero point: the raw sample
     si = (last_ts - first_ts).astype(np.float64)
     avg_between = si / np.maximum(count - 1, 1)
     d_start = (first_ts - (steps - range_ms)).astype(np.float64)
@@ -353,10 +354,9 @@ def fold_rate(ds: Dataset, start: int, end: int, step: int, range_ms: int,
     ext_e = np.where(d_end < thr, d_end, avg_between / 2.0)
     result = lv - fv
     with np.errstate(all="ignore"):
-        zero_dur = np.where(
-            result > 0, si * (fv / np.where(result == 0, 1.0, result)), np.inf
-        )
-        ext_s = np.minimum(ext_s, np.where(zero_dur < 0, ext_s, zero_dur))
+        clamps = (result > 0) & (raw_first >= 0)
+        zero_dur = si * (raw_first / np.where(clamps, result, 1.0))
+        ext_s = np.where(clamps, np.minimum(ext_s, zero_dur), ext_s)
         out = result * ((si + ext_s + ext_e) / np.where(si == 0, 1.0, si))
     if per_second:
         out = out / (range_ms / 1000.0)

@@ -1143,11 +1143,13 @@ class Database:
         from .query.promql.engine import PromqlEngine
 
         engine = PromqlEngine(self)
+        # seconds to the NEAREST millisecond: 1767225600.123 * 1000 is
+        # ...122.9999 in binary and would truncate one millisecond low
         return engine.query_range(
             stmt.query,
-            start_ms=int(stmt.start * 1000),
-            end_ms=int(stmt.end * 1000),
-            step_ms=int(stmt.step * 1000),
+            start_ms=round(stmt.start * 1000),
+            end_ms=round(stmt.end * 1000),
+            step_ms=round(stmt.step * 1000),
         )
 
     # ---- providers for the query engine ------------------------------------

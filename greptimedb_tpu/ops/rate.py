@@ -115,6 +115,7 @@ def _sum_since_start(starts: jnp.ndarray, adds: jnp.ndarray) -> jnp.ndarray:
     return prefix_scan(combine, (starts, adds), (False, 0.0))[1]
 
 
+@jax.named_scope("reset_strip")
 def strip_counter_resets_segmented(
     series: jnp.ndarray, values: jnp.ndarray, valid: jnp.ndarray
 ) -> jnp.ndarray:
@@ -139,6 +140,7 @@ def strip_counter_resets_segmented(
     return values + _sum_since_start(valid & ~same, reset_add)
 
 
+@jax.named_scope("reset_strip")
 def strip_counter_resets(series: jnp.ndarray, values: jnp.ndarray, valid: jnp.ndarray):
     """Per-series monotonic re-accumulation: after a counter reset
     (v[i] < v[i-1]), add the pre-reset level so adjusted values never
@@ -162,6 +164,9 @@ class WindowStats:
     first_ts: jnp.ndarray
     last_ts: jnp.ndarray
     first_val: jnp.ndarray
+    # the sample at `first_ts` as stored, before any reset adjustment:
+    # Prometheus' zero-point clamp reads the raw first sample
+    first_raw: jnp.ndarray
     last_val: jnp.ndarray
     sum: jnp.ndarray
     min: jnp.ndarray
@@ -176,17 +181,20 @@ def range_windows(
     spec: RangeSpec,
     num_series: int,
     acc_dtype=jnp.float64,
+    raw_values: jnp.ndarray | None = None,
 ) -> WindowStats:
     """Assign each sample to its <=K containing windows and reduce.
 
     Window w covers (t_w - range, t_w] with t_w = start + w*step —
     Prometheus range selector semantics (left-open, right-closed).
+    `raw_values`: the samples as stored, where `values` went through
+    `strip_counter_resets` (see `range_windows_dyn`).
     """
     return range_windows_dyn(
         series, ts, values, valid,
         start=spec.start, step=spec.step, range_=spec.range_,
         n_steps=spec.num_steps, k=spec.windows_per_sample,
-        num_series=num_series, acc_dtype=acc_dtype,
+        num_series=num_series, acc_dtype=acc_dtype, raw_values=raw_values,
     )
 
 
@@ -204,6 +212,7 @@ def range_windows_dyn(
     num_series: int,
     acc_dtype=jnp.float64,
     n_steps_actual=None,
+    raw_values: jnp.ndarray | None = None,
 ) -> WindowStats:
     """`range_windows` with the evaluation grid split into STATIC shape
     parameters (`n_steps`, `k` — the [S*W] layout and the per-sample
@@ -213,12 +222,19 @@ def range_windows_dyn(
     sliding its window re-hits the compile cache instead of re-tracing.
     `n_steps_actual` (dynamic, defaults to `n_steps`) masks the padded
     windows past the real grid; arithmetic on the surviving windows is
-    identical to the static form, so results are bit-identical."""
+    identical to the static form, so results are bit-identical.
+    `raw_values` are the samples as stored where `values` are reset-
+    adjusted (`rate` / `increase`): `first_raw` is then the raw sample at
+    `first_ts`, found through one more segment reduction per unrolled
+    window, over ROW INDICES (int32: an eighth of what a reduction over
+    the chip's emulated 64-bit values costs) and one gather; without them
+    `values` are the raw samples and `first_raw` is `first_val`."""
     num_groups = num_series * n_steps
     if n_steps_actual is None:
         n_steps_actual = n_steps
     segs = num_groups + 1
     v = values.astype(acc_dtype)
+    raw = None if raw_values is None else raw_values.astype(acc_dtype)
 
     tsmax = jnp.iinfo(jnp.int64).max
     tsmin = jnp.iinfo(jnp.int64).min
@@ -259,10 +275,11 @@ def range_windows_dyn(
     sum_, min_, max_ = sum_[:num_groups], min_[:num_groups], max_[:num_groups]
 
     # Second pass: values at the first/last timestamps (two-field argmin/max).
-    first_val = jnp.zeros(num_groups + 1, acc_dtype)
-    last_val = jnp.zeros(num_groups + 1, acc_dtype)
     fv = jnp.full(num_groups + 1, small, acc_dtype)
     lv = jnp.full(num_groups + 1, small, acc_dtype)
+    n_rows = ts.shape[0]
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    first_row = jnp.full(num_groups + 1, n_rows, jnp.int32)
     for j in range(k):
         w = w0 + j
         t_w = start + w.astype(jnp.int64) * step
@@ -277,14 +294,29 @@ def range_windows_dyn(
         lv = jnp.maximum(
             lv, jax.ops.segment_max(jnp.where(at_last, v, small), gid, num_segments=num_groups + 1)
         )
+        if raw is not None:
+            first_row = jnp.minimum(
+                first_row,
+                jax.ops.segment_min(
+                    jnp.where(at_first, rows, n_rows), gid, num_segments=num_groups + 1
+                ),
+            )
     first_val = fv[:num_groups]
     last_val = lv[:num_groups]
+    if raw is None:
+        first_raw = first_val
+    else:
+        first_row = first_row[:num_groups]
+        first_raw = jnp.where(
+            first_row < n_rows, jnp.take(raw, jnp.minimum(first_row, n_rows - 1)), small
+        )
 
     return WindowStats(
         count=count,
         first_ts=first_ts,
         last_ts=last_ts,
         first_val=first_val,
+        first_raw=first_raw,
         last_val=last_val,
         sum=sum_,
         min=min_,
@@ -302,13 +334,16 @@ def extrapolated_rate(
     Port of the semantics in reference
     promql/src/functions/extrapolate_rate.rs (is_counter = rate/increase,
     is_rate divides by range seconds).  For counters the caller must have
-    applied `strip_counter_resets` so last-first already includes resets.
+    applied `strip_counter_resets` so last-first already includes resets,
+    and handed `range_windows` the raw samples beside the adjusted ones:
+    the zero-point clamp reads `stats.first_raw`.
     """
     return extrapolated_rate_dyn(
         stats, spec.start, spec.step, spec.range_, spec.num_steps, kind
     )
 
 
+@jax.named_scope("extrapolate")
 def extrapolated_rate_dyn(
     stats: WindowStats,
     start,
@@ -338,13 +373,14 @@ def extrapolated_rate_dyn(
 
     result = (stats.last_val - stats.first_val).astype(jnp.float64)
     if kind in ("rate", "increase"):
-        # Counter: cannot extrapolate below zero at the window start.
-        zero_dur = jnp.where(
-            result > 0,
-            sampled_interval * (stats.first_val / jnp.where(result == 0, 1.0, result)),
-            jnp.asarray(float("inf"), jnp.float64),
-        )
-        extend_start = jnp.minimum(extend_start, jnp.where(zero_dur < 0, extend_start, zero_dur))
+        # Counter: cannot extrapolate below zero at the window start.  As
+        # Prometheus does, the zero point comes from the RAW first sample
+        # of the window (not the reset-adjusted one), and only where the
+        # counter went up and that sample is not negative.
+        first_raw = stats.first_raw.astype(jnp.float64)
+        clamps = (result > 0) & (first_raw >= 0)
+        zero_dur = sampled_interval * (first_raw / jnp.where(clamps, result, 1.0))
+        extend_start = jnp.where(clamps, jnp.minimum(extend_start, zero_dur), extend_start)
     extrapolate_to = sampled_interval + extend_start + extend_end
     safe_si = jnp.where(sampled_interval == 0, 1.0, sampled_interval)
     value = result * (extrapolate_to / safe_si)
@@ -370,6 +406,7 @@ def merge_disjoint_stats(a: WindowStats, b: WindowStats) -> WindowStats:
         first_ts=pick(a.first_ts, b.first_ts),
         last_ts=pick(a.last_ts, b.last_ts),
         first_val=pick(a.first_val, b.first_val),
+        first_raw=pick(a.first_raw, b.first_raw),
         last_val=pick(a.last_val, b.last_val),
         sum=pick(a.sum, b.sum),
         min=pick(a.min, b.min),

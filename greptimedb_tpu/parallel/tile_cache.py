@@ -6231,8 +6231,12 @@ class TileExecutor:
             # runs the consolidated HOST build (decode + encode + sort +
             # persist — what cold-serve and the selective fast path read);
             # device planes ride the per-family background builds, which
-            # upload only what queries actually touch.  The build gate
-            # coalesces with a racing query-triggered family build.
+            # upload only what queries actually touch — except where the
+            # table has ONE field (a Prometheus metric table): its only
+            # family of planes is what any first query touches, so they
+            # upload here and that query dispatches instead of being
+            # served cold.  The build gate coalesces with a racing
+            # query-triggered family build.
             nonnull = [
                 c for c in value_cols
                 if schema.has_column(c) and not schema.column(c).nullable
@@ -6248,7 +6252,7 @@ class TileExecutor:
             with self.cache.build_gate(ctx.table_key) as leader:
                 if leader:
                     out = self.cache.fused_union_build(
-                        ctx, schema, [manifest], device=False,
+                        ctx, schema, [manifest], device=len(value_cols) == 1,
                     )
                 else:
                     out = {"regions_built": 0, "coalesced": True, "ms": 0.0}

@@ -124,7 +124,8 @@ class PromqlEngine:
             return pa.table(
                 {"ts": pa.array(steps, pa.timestamp("ms")), "value": out.row(len(steps)).copy()}
             )
-        return _matrix_to_table(out.drop_empty())
+        with tracing.stage("tql.assemble"):
+            return _matrix_to_table(out.drop_empty())
 
     def query_instant(self, promql: str, time_ms: int) -> pa.Table:
         return self.query_range(promql, time_ms, time_ms, max(1, 1000))
@@ -330,9 +331,14 @@ class PromqlEngine:
         t = jnp.asarray(ts)
         v = jnp.asarray(values)
         valid = jnp.ones(len(values), dtype=bool)
-        if func in ("rate", "increase"):
-            v = strip_counter_resets(s, v, valid)
-        stats = range_windows(s, t, v, valid, spec, num_series=num_series)
+        # counters: adjusted samples for the increase, raw ones for the
+        # zero point of Prometheus' clamp (as tile_exec._region_stats)
+        raw = v if func in ("rate", "increase") else None
+        if raw is not None:
+            v = strip_counter_resets(s, raw, valid)
+        stats = range_windows(
+            s, t, v, valid, spec, num_series=num_series, raw_values=raw
+        )
         if func in _RATE_FUNCS:
             vals, defined = extrapolated_rate(stats, spec, func)
         elif func == "__last_ts":  # timestamp(): the last sample's time in seconds

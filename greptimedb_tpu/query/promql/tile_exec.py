@@ -54,6 +54,7 @@ stats merge is pure selection (ops/rate.merge_disjoint_stats).
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import re
 import threading
@@ -157,13 +158,16 @@ def _region_stats(src, dyn, rsig, csig):
     # the offset modifier shift
     ts_ms = ts_nat * unit_ns // 1_000_000 + dyn["offset"]
 
-    if func in ("rate", "increase"):
-        vf = strip_counter_resets_segmented(sid, vf, in_fetch)
+    # counters: the reset-adjusted samples carry the increase, the raw
+    # ones the zero point of Prometheus' clamp (WindowStats.first_raw)
+    raw = vf if func in ("rate", "increase") else None
+    if raw is not None:
+        vf = strip_counter_resets_segmented(sid, raw, in_fetch)
     stats = range_windows_dyn(
         sid, ts_ms, vf, in_fetch,
         start=dyn["start"], step=dyn["step"], range_=dyn["range"],
         n_steps=w_pad, k=k, num_series=s_pad,
-        n_steps_actual=dyn["nsteps"],
+        n_steps_actual=dyn["nsteps"], raw_values=raw,
     )
     # scan-presence per series (a scanned series with no windowed sample
     # still occupies a matrix row in the legacy path — `absent()` and
@@ -194,33 +198,34 @@ def _finalize(stats: WindowStats, dyn, csig):
     mat = vals.reshape(s_pad, w_pad)
     if agg is None:
         return mat
-    op = agg
-    # the sid -> gid map is derivable from (radices, keep_idx) — built
-    # here at TRACE time so it constant-folds into the compiled program
-    # and never costs the warm path a per-query numpy pass
-    gidmap = _gid_map(radices, list(keep_idx))
-    gid = jnp.asarray(gidmap)
-    g_pad = 1
-    for i in keep_idx:
-        g_pad *= radices[i]
-    present = ~jnp.isnan(mat)
-    zeroed = jnp.where(present, mat, 0.0)
-    sums = jax.ops.segment_sum(zeroed, gid, num_segments=g_pad)
-    counts = jax.ops.segment_sum(
-        present.astype(jnp.float64), gid, num_segments=g_pad
-    )
-    if op == "sum":
-        out = jnp.where(counts > 0, sums, jnp.nan)
-    elif op in ("avg", "mean"):
-        out = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), jnp.nan)
-    elif op == "count":
-        out = jnp.where(counts > 0, counts, jnp.nan)
-    else:  # min / max
-        fill = jnp.inf if op == "min" else -jnp.inf
-        filled = jnp.where(present, mat, fill)
-        seg = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-        ext = seg(filled, gid, num_segments=g_pad)
-        out = jnp.where(counts > 0, ext, jnp.nan)
+    with jax.named_scope("by_fold"):
+        op = agg
+        # the sid -> gid map is derivable from (radices, keep_idx) — built
+        # here at TRACE time so it constant-folds into the compiled program
+        # and never costs the warm path a per-query numpy pass
+        gidmap = _gid_map(radices, list(keep_idx))
+        gid = jnp.asarray(gidmap)
+        g_pad = 1
+        for i in keep_idx:
+            g_pad *= radices[i]
+        present = ~jnp.isnan(mat)
+        zeroed = jnp.where(present, mat, 0.0)
+        sums = jax.ops.segment_sum(zeroed, gid, num_segments=g_pad)
+        counts = jax.ops.segment_sum(
+            present.astype(jnp.float64), gid, num_segments=g_pad
+        )
+        if op == "sum":
+            out = jnp.where(counts > 0, sums, jnp.nan)
+        elif op in ("avg", "mean"):
+            out = jnp.where(counts > 0, sums / jnp.maximum(counts, 1), jnp.nan)
+        elif op == "count":
+            out = jnp.where(counts > 0, counts, jnp.nan)
+        else:  # min / max
+            fill = jnp.inf if op == "min" else -jnp.inf
+            filled = jnp.where(present, mat, fill)
+            seg = jax.ops.segment_min if op == "min" else jax.ops.segment_max
+            ext = seg(filled, gid, num_segments=g_pad)
+            out = jnp.where(counts > 0, ext, jnp.nan)
     return out
 
 
@@ -252,9 +257,9 @@ def _partial_program(sig):
     def build():
         def fn(src, dyn):
             st, p = _region_stats(src, dyn, rsig, csig)
-            return (
-                st.count, st.first_ts, st.last_ts, st.first_val,
-                st.last_val, st.sum, st.min, st.max,
+            # field order, which `_merge_program` rebuilds from
+            return tuple(
+                getattr(st, f.name) for f in dataclasses.fields(st)
             ), p
 
         return jax.jit(fn)
@@ -305,10 +310,10 @@ class TqlTileExecutor:
         if not passes.enabled("tql_tile", getattr(cfg, "query", None)):
             passes.note("tql_tile", False, "pass disabled: legacy scan path")
             return None
+        from ...parallel.tile_cache import _in_fused_build
+
         try:
             _fault_fire("tql.tile", table=sel.metric, func=func)
-            from ...parallel.tile_cache import _in_fused_build
-
             cache = self.cache
             # db-qualified key, matching the SQL tile path's
             # ctx.table_key so device_dispatches.table_name filters see
@@ -322,12 +327,22 @@ class TqlTileExecutor:
                     if cache is not None else None
                 ),
             ):
-                return self._attempt(
-                    func, sel, range_ms, start, end, step, agg
-                )
+                # self time = the host work around the dispatch: catalog,
+                # matcher masks, grid, plane residency (the dispatch, the
+                # fetch and the assembly are counted stages inside it)
+                with tracing.stage("tql.plan", func=func) as plan:
+                    try:
+                        return self._attempt(
+                            func, sel, range_ms, start, end, step, agg
+                        )
+                    except _Ineligible as ie:
+                        plan.set(ineligible=str(ie))
+                        raise
         except QueryTimeoutError:
             raise  # the deadline owns the query, tile or not
         except _Ineligible as ie:
+            if not _in_fused_build():
+                metrics.TQL_TILE_INELIGIBLE.inc()
             passes.note("tql_tile", False, f"{ie}: legacy scan path")
             return None
         except Exception as exc:  # noqa: BLE001 — degrade, never fail
@@ -754,10 +769,11 @@ class TqlTileExecutor:
             series=s_pad, steps=w, mesh_devices=mesh_n,
             compact_readback=pregathered is not None,
         )
-        return self._assemble(
-            np_mat, np_pres, dictionary, tags, steps, w, agg_op, keep,
-            radices, keep_idx, pregathered,
-        )
+        with tracing.stage("tql.assemble"):
+            return self._assemble(
+                np_mat, np_pres, dictionary, tags, steps, w, agg_op, keep,
+                radices, keep_idx, pregathered,
+            )
 
     def _mesh_dispatch(self, csig, sources, region_sigs, dyn, sources_meta,
                        ghost):

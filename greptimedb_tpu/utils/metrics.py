@@ -367,6 +367,11 @@ TQL_TILE_DEGRADED = REGISTRY.counter(
     "TQL tile-path attempts that failed (fault tql.tile / device error) "
     "and degraded to the legacy upload-per-query path",
 )
+TQL_TILE_INELIGIBLE = REGISTRY.counter(
+    "greptime_tql_tile_ineligible_total",
+    "TQL range-vector evaluations the tile path found ineligible and handed "
+    "to the legacy scan (the reason is on the `tql.plan` stage)",
+)
 TQL_TILE_COLD_SERVES = REGISTRY.counter(
     "greptime_tql_tile_cold_serves_total",
     "Cold TQL queries answered from the legacy scan while their family's "
@@ -823,6 +828,10 @@ STAGE_SELF_S_TILE_DISPATCH = _stage_self_s("tile.dispatch", "compiled program in
 STAGE_SELF_S_TILE_READBACK = _stage_self_s(
     "tile.readback", "device->host fetch, waiting out the device included")
 STAGE_SELF_S_TILE_DECODE = _stage_self_s("tile.decode", "fetched buffers to Arrow rows")
+STAGE_SELF_S_TQL_PLAN = _stage_self_s(
+    "tql.plan", "TQL tile path's host work around the dispatch: catalog, masks, grid, residency")
+STAGE_SELF_S_TQL_ASSEMBLE = _stage_self_s(
+    "tql.assemble", "fetched PromQL matrix to labelled series, then to the Arrow table")
 HTTP_REQUEST_S = REGISTRY.counter(
     "greptime_http_request_seconds_total",
     "Inclusive seconds of every HTTP request (the `http.request` stage)",
@@ -854,4 +863,6 @@ STAGE_SELF_S: dict[str, Counter] = {
     "tile.readback": STAGE_SELF_S_TILE_READBACK,
     "tile.batch_readback": STAGE_SELF_S_TILE_READBACK,
     "tile.decode": STAGE_SELF_S_TILE_DECODE,
+    "tql.plan": STAGE_SELF_S_TQL_PLAN,
+    "tql.assemble": STAGE_SELF_S_TQL_ASSEMBLE,
 }

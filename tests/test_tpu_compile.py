@@ -84,13 +84,15 @@ def test_reset_strip_compiles(one_chip):
 
 def test_rate_windows_compile(one_chip):
     """range_windows_dyn + extrapolated_rate_dyn at the smoke's grid:
-    4000 series -> 4096, 110 steps -> 128, 5 windows per sample -> 8."""
+    4000 series -> 4096, 110 steps -> 128, 5 windows per sample -> 8;
+    the raw plane rides along for the clamp's `first_raw`, as in the tile
+    program (the strip itself compiles in the test above)."""
     from greptimedb_tpu.ops.rate import extrapolated_rate_dyn, range_windows_dyn
 
     def rate(sid, ts, v, valid, start, step, range_):
         stats = range_windows_dyn(
-            sid, ts, v, valid, start=start, step=step, range_=range_,
-            n_steps=128, k=8, num_series=4096,
+            sid, ts, v + 1.0, valid, start=start, step=step, range_=range_,
+            n_steps=128, k=8, num_series=4096, raw_values=v,
         )
         return extrapolated_rate_dyn(stats, start, step, range_, 128, "rate")
 

@@ -225,9 +225,10 @@ def test_tile_matches_numpy_twin():
                 ext_s = d_start if d_start < thr else avg / 2.0
                 ext_e = d_end if d_end < thr else avg / 2.0
                 result = wv[-1] - wv[0]
-                if result > 0 and wv[0] >= 0:
-                    zero_dur = si * (wv[0] / result)
-                    if 0 <= zero_dur < ext_s:
+                raw_first = hv[wmask][0]  # Prometheus clamps with the RAW sample
+                if result > 0 and raw_first >= 0:
+                    zero_dur = si * (raw_first / result)
+                    if zero_dur < ext_s:
                         ext_s = zero_dur
                 twin[(h, int(t1))] = (
                     result * ((si + ext_s + ext_e) / si) / (rng_ms / 1000.0)
@@ -281,6 +282,27 @@ def test_warm_zero_uploads_one_dispatch():
         progs0 = len(tile_exec._PROGRAMS)
         db.sql_one("TQL EVAL (90, 570, '30s') rate(tq[2m])")
         assert len(tile_exec._PROGRAMS) == progs0
+    finally:
+        db.close()
+
+
+def test_prewarm_of_a_one_field_table_makes_the_first_query_warm():
+    """A metric table has one field, so one family of planes: `prewarm`
+    uploads them (a wider table's ride its families' background builds),
+    and the table's FIRST TQL query dispatches instead of being served
+    cold from the legacy scan."""
+    db = _db()
+    try:
+        _load_counter(db, np.random.default_rng(7))
+        db.prewarm(tables=["tq"])
+        entry = next(iter(db.query_engine.tile_cache._super.values()))
+        assert {"host", "ts", "greptime_value"} <= set(entry.cols)
+        d0, c0 = m.TQL_TILE_DISPATCHES.get(), m.TQL_TILE_COLD_SERVES.get()
+        q = "TQL EVAL (60, 540, '30s') rate(tq[2m])"
+        first = db.sql_one(q)
+        assert m.TQL_TILE_DISPATCHES.get() == d0 + 1
+        assert m.TQL_TILE_COLD_SERVES.get() == c0
+        _assert_rows_close(_rows(first), _rows(_legacy(db, q)))
     finally:
         db.close()
 
