@@ -139,11 +139,11 @@ def main(seed: int):
     timed("segmented f64 sum (prefix_scan)", jax.jit(R._sum_since_start),
           d_ts == 0, d_val)
 
-    def windows(sid, ts, v, valid, start, nsteps, raw=None):
+    def windows(sid, ts, v, valid, start, nsteps, raw=None, reduce=R.REDUCTIONS):
         return R.range_windows_dyn(
             sid, ts, v, valid, start=start, step=STEP_MS, range_=RANGE_MS,
             n_steps=W_PAD, k=K, num_series=S_PAD, n_steps_actual=nsteps,
-            raw_values=raw,
+            raw_values=raw, reduce=reduce,
         )
 
     fields = ("count", "first_ts", "last_ts", "first_val", "first_raw",
@@ -154,11 +154,11 @@ def main(seed: int):
         return tuple(getattr(st, f) for f in fields)
 
     def rate_program(sid, ts, v, valid, start, nsteps):
-        """What the tile program runs for `rate`: the compiler drops the
-        sum/min/max stats nothing reads (40 64-bit scatters and 8 int32 ones for
-        `first_raw`, not 72)."""
+        """What the tile program runs for `rate`: every statistic it reads
+        is found by row position (two searches over the carried keys, seven
+        gathers of S*W), no segment reduction at all."""
         st = windows(sid, ts, R.strip_counter_resets_segmented(sid, v, valid),
-                     valid, start, nsteps, raw=v)
+                     valid, start, nsteps, raw=v, reduce=())
         vals, defined = R.extrapolated_rate_dyn(
             st, start, STEP_MS, RANGE_MS, W_PAD, "rate"
         )
@@ -166,9 +166,18 @@ def main(seed: int):
 
     args = (d_sid, d_ts, d_val, d_valid, start, nsteps)
     timed("rate program: strip + range_windows_dyn + extrapolated_rate_dyn",
-          jax.jit(rate_program), *args, reps=2, scatters=48)
-    stats = timed("range_windows_dyn k=8, eight stats (no raw plane)", jax.jit(all_stats),
-                  *args, reps=2, scatters=64)
+          jax.jit(rate_program), *args, reps=3, scatters=0)
+    timed("window rows: carry scan + series bounds + two searches + counts",
+          lambda *a: R._window_rows(*a, n_steps=W_PAD, num_series=S_PAD),
+          d_sid, d_ts, d_valid, start, np.int64(STEP_MS), np.int64(RANGE_MS), nsteps)
+    cells = jax.block_until_ready(
+        (jnp.arange(S_PAD * W_PAD, dtype=jnp.int32) * 7) % (SERIES * SAMPLES)
+    )
+    for name, plane in (("f64", d_val), ("int64", d_ts), ("int32", d_sid)):
+        timed(f"one gather of S*W rows from the {name} plane",
+              jax.jit(lambda p, i: jnp.take(p, i, mode="clip")), plane, cells)
+    stats = timed("range_windows_dyn k=8, all nine stats (no raw plane)", jax.jit(all_stats),
+                  *args, reps=2, scatters=24)
 
     segs = S_PAD * W_PAD + 1
     gid = jax.block_until_ready(jnp.where(

@@ -6,19 +6,28 @@ range-vector matrix, and src/promql/src/functions/extrapolate_rate.rs
 implementing Prometheus' extrapolated rate — itself a port of Prometheus'
 `extrapolatedRate`).
 
-Design: instead of materializing a ragged range-vector matrix (dynamic
-shapes), every sample is assigned to the K eval windows that can contain it
-(K = ceil(range/step), static from the query), and per-(series, window)
-statistics are computed with segment reductions.  Counter resets are removed
-up front by a per-series monotonic re-accumulation so first/last arithmetic
-needs no pairwise pass inside windows.
+Design: no ragged range-vector matrix is materialized (dynamic shapes).
+The samples arrive as flat columns sorted by (series, ts), so the rows of a
+(series, window) cell are one contiguous run of the plane: two binary
+searches find it, and the statistics `rate` / `increase` / `delta` /
+`count_over_time` / `last_over_time` / `timestamp()` read (count, first and
+last timestamp and value) are a difference of row counts and gathers at the
+run's ends — selections, no arithmetic, no scatter.  Only sum / min / max
+(`avg/sum/min/max_over_time`) still assign every sample to the K eval
+windows that can contain it (K = ceil(range/step), static from the query)
+and reduce by segment.  Counter resets are removed up front by a per-series
+monotonic re-accumulation so first/last arithmetic needs no pairwise pass
+inside windows.
 
 Inputs are flat sorted columns (series id, ts, value) — exactly what the
-region scan produces after dedup — padded per `tiles.py`.
+region scan produces after dedup — padded per `tiles.py`; invalid rows may
+sit anywhere among them (`range_windows_dyn` has the precondition).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from dataclasses import dataclass
 
 import jax
@@ -173,6 +182,26 @@ class WindowStats:
     max: jnp.ndarray
 
 
+# The statistics a caller may ask `range_windows*` to reduce by segment;
+# the other six fields of `WindowStats` are selections, found by position.
+REDUCTIONS = ("sum", "min", "max")
+
+
+_READS = {
+    "avg_over_time": ("sum",),
+    "sum_over_time": ("sum",),
+    "min_over_time": ("min",),
+    "max_over_time": ("max",),
+}
+
+
+def reductions_for(func: str) -> tuple[str, ...]:
+    """Which of `REDUCTIONS` the range function `func` reads (`over_time`'s
+    arms): none for the rate family, `count_over_time`, `last_over_time`
+    and `timestamp()`."""
+    return _READS.get(func, ())
+
+
 def range_windows(
     series: jnp.ndarray,
     ts: jnp.ndarray,
@@ -182,20 +211,137 @@ def range_windows(
     num_series: int,
     acc_dtype=jnp.float64,
     raw_values: jnp.ndarray | None = None,
+    reduce: tuple[str, ...] = REDUCTIONS,
 ) -> WindowStats:
-    """Assign each sample to its <=K containing windows and reduce.
+    """Per-(series, window) statistics of the samples on a static grid.
 
     Window w covers (t_w - range, t_w] with t_w = start + w*step —
     Prometheus range selector semantics (left-open, right-closed).
     `raw_values`: the samples as stored, where `values` went through
-    `strip_counter_resets` (see `range_windows_dyn`).
+    `strip_counter_resets`; `reduce`: see `range_windows_dyn`, whose
+    precondition on the rows' order holds here too.
     """
     return range_windows_dyn(
         series, ts, values, valid,
         start=spec.start, step=spec.step, range_=spec.range_,
         n_steps=spec.num_steps, k=spec.windows_per_sample,
         num_series=num_series, acc_dtype=acc_dtype, raw_values=raw_values,
+        reduce=reduce,
     )
+
+
+def _first_greater(keys, lo, hi, target, rounds):
+    """Per element of the (broadcast-alike) `lo` / `hi` / `target`: the
+    first position p of [lo, hi) with keys[p] > target, or hi; `keys` is
+    non-decreasing over each [lo, hi) and no run is longer than
+    2**rounds - 1.  `rounds` gathers of `keys`, one per halving; `rounds`
+    may be traced (the loop then runs as many rounds as the longest run
+    needs, not as many as the plane's length would)."""
+
+    def halve(i, pos):
+        cand = pos + jnp.left_shift(1, jnp.asarray(rounds - 1 - i, jnp.int32))
+        below = jnp.take(keys, cand - 1, mode="clip") <= target
+        return jnp.where((cand <= hi) & below, cand, pos)
+
+    return jax.lax.fori_loop(0, rounds, halve, lo)
+
+
+@functools.partial(jax.jit, static_argnames=("num_series",))
+def series_present(series, valid, num_series: int) -> jnp.ndarray:
+    """[num_series] bools: which series hold a valid row.  By position, as
+    `_window_rows` bounds a series' run (and under `range_windows_dyn`'s
+    precondition): the first row that carries a key >= s carries s itself
+    where s has a sample.  One int32 scan and a search of 4096-wide
+    gathers, where a `segment_max` over the plane is a scatter."""
+    n = series.shape[0]
+    if n == 0:
+        return jnp.zeros(num_series, bool)
+    sid_c = _running_max(jnp.where(valid, series.astype(jnp.int32), -1))
+    s = jnp.arange(num_series, dtype=jnp.int32)
+    first = _first_greater(
+        sid_c, jnp.zeros(num_series, jnp.int32), jnp.full(num_series, n, jnp.int32),
+        s - 1, n.bit_length(),
+    )
+    return (first < n) & (jnp.take(sid_c, first, mode="clip") == s)
+
+
+@functools.partial(jax.jit, static_argnames=("n_steps", "num_series"))
+def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
+                 *, n_steps: int, num_series: int):
+    """Row positions of every (series, window) cell: (count, first_row,
+    last_row), each [num_series, n_steps] int32; the rows are meaningful
+    where count > 0.  See `range_windows_dyn` for the order it needs."""
+    n = ts.shape[0]
+    tsmin = jnp.iinfo(jnp.int64).min
+    rows = jnp.arange(n, dtype=jnp.int32)
+
+    # Over every row, the last VALID row at or before it: its index, its
+    # (series, ts) key, and how many valid rows the plane holds up to
+    # here.  Whatever an invalid row holds (a pad row's zeros, a dedup
+    # loser, a series the matcher masked) is never read: the carried keys
+    # are non-decreasing because the valid rows' are.
+    def carry(a, b):
+        take = b[0] >= 0
+        return (
+            jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1]),
+            jnp.where(take, b[2], a[2]), a[3] + b[3],
+        )
+
+    last_valid, sid_c, ts_c, n_valid = prefix_scan(
+        carry,
+        (
+            jnp.where(valid, rows, -1),
+            jnp.where(valid, series.astype(jnp.int32), -1),
+            jnp.where(valid, ts.astype(jnp.int64), tsmin),
+            valid.astype(jnp.int32),
+        ),
+        (-1, -1, tsmin, 0),
+    )
+
+    # a series' run [lo, hi): from the first row that carries a key >= s to
+    # one past its last valid row (the rows after that, up to the next
+    # series' first valid row, carry the same key and hold no sample: a
+    # masked neighbour or the pad tail must not lengthen the search)
+    s_lo = _first_greater(
+        sid_c, jnp.zeros(num_series + 1, jnp.int32),
+        jnp.full(num_series + 1, n, jnp.int32),
+        jnp.arange(num_series + 1, dtype=jnp.int32) - 1, n.bit_length(),
+    )
+    lo, nxt = s_lo[:-1, None], s_lo[1:, None]
+    hi = jnp.where(nxt > 0, jnp.take(last_valid, nxt - 1, mode="clip") + 1, 0)
+    hi = jnp.maximum(hi, lo)
+    rounds = 32 - jax.lax.clz(jnp.max(hi - lo))
+
+    # a window's rows [a, b) inside the run: a the first row past
+    # t_w - range, b the first past t_w; both are valid rows (the carried
+    # key changes only there) or the run's end.  Every target is the
+    # grid's left edge + d with 0 <= d <= span, so where the span fits 31
+    # bits the search runs on 32-bit keys, a row's distance from that edge
+    # saturated at both ends (exact: a saturated key still compares with
+    # every d as the row's ts does with the target); a 64-bit gather and
+    # compare cost the chip three times as much.
+    w = jnp.arange(n_steps, dtype=jnp.int64)[None, :]
+    edge = start - range_
+    d = jnp.stack([w * step, w * step + range_])
+    run = jnp.broadcast_to(lo, (2, num_series, n_steps))
+    stop = jnp.iinfo(jnp.int32).max
+
+    def narrow():
+        key = jnp.where(last_valid >= 0, jnp.clip(ts_c - edge, -1, stop), -1)
+        return _first_greater(
+            key.astype(jnp.int32), run, hi, d.astype(jnp.int32), rounds
+        )
+
+    def wide():
+        return _first_greater(ts_c, run, hi, edge + d, rounds)
+
+    bounds = jax.lax.cond((n_steps - 1) * step + range_ < stop, narrow, wide)
+    before = jnp.where(
+        bounds > 0, jnp.take(n_valid, bounds - 1, mode="clip"), 0
+    )
+    count = jnp.where(w < n_steps_actual, before[1] - before[0], 0)
+    last_row = jnp.take(last_valid, bounds[1] - 1, mode="clip")
+    return count, bounds[0], last_row
 
 
 @jax.named_scope("range_windows")
@@ -213,41 +359,109 @@ def range_windows_dyn(
     acc_dtype=jnp.float64,
     n_steps_actual=None,
     raw_values: jnp.ndarray | None = None,
+    reduce: tuple[str, ...] = REDUCTIONS,
 ) -> WindowStats:
     """`range_windows` with the evaluation grid split into STATIC shape
     parameters (`n_steps`, `k` — the [S*W] layout and the per-sample
-    window unroll) and DYNAMIC values (`start`/`step`/`range_` may be
-    traced scalars), so one compiled program serves every query in a
-    (padded-series, padded-steps, padded-k) shape bucket — a dashboard
-    sliding its window re-hits the compile cache instead of re-tracing.
-    `n_steps_actual` (dynamic, defaults to `n_steps`) masks the padded
-    windows past the real grid; arithmetic on the surviving windows is
-    identical to the static form, so results are bit-identical.
-    `raw_values` are the samples as stored where `values` are reset-
-    adjusted (`rate` / `increase`): `first_raw` is then the raw sample at
-    `first_ts`, found through one more segment reduction per unrolled
-    window, over ROW INDICES (int32: an eighth of what a reduction over
-    the chip's emulated 64-bit values costs) and one gather; without them
-    `values` are the raw samples and `first_raw` is `first_val`."""
+    window unroll of the reductions) and DYNAMIC values (`start`/`step`/
+    `range_` may be traced scalars), so one compiled program serves every
+    query in a (padded-series, padded-steps, padded-k) shape bucket — a
+    dashboard sliding its window re-hits the compile cache instead of
+    re-tracing.  `n_steps_actual` (dynamic, defaults to `n_steps`) masks
+    the padded windows past the real grid.
+
+    **The order it needs.**  Among the VALID rows, `series` is
+    non-decreasing and `ts` is non-decreasing within a series, so the rows
+    of a cell are one run [a, b) of the plane.  Invalid rows may sit
+    anywhere and hold anything: the kernel carries the last valid row's
+    key over them (`_window_rows`), so a pad row's zeros at the tail or a
+    masked series' rows in the middle break nothing.  Valid rows whose
+    series is outside [0, num_series) are in no cell.  What each caller
+    hands it:
+
+    * `tile_exec._region_stats` (the one-jit program and the mesh
+      partials): the super-tile planes, whose real rows [0, num_rows) the
+      cache sorted by (pk codes..., ts) at consolidation and keeps so
+      through delta merges and dictionary repairs (value-sorted codes, the
+      NULL slot last: a repair is an order-preserving map); `series` is
+      the codes' mixed radix and `ts` a monotone map of the stored one, so
+      ALL real rows are in (series, ts) order, valid or not, and the pad
+      rows [num_rows, pad) (code 0, ts 0, never valid) sit at the tail.
+    * `engine._range_from_samples` (the legacy scan and subqueries): dense
+      samples in `np.lexsort((ts, sid))` order, every row valid, no pad.
+
+    **By position.**  count, first_ts, last_ts, first_val, last_val and
+    first_raw are selections: two searches over the carried keys find
+    [a, b), a difference of valid-row counts is `count`, and five gathers
+    read the first and the last row.  No arithmetic touches a value, so
+    the six are bit-equal to a loop over (series, window).  Of duplicate
+    (series, ts) among valid rows (an append-mode table the dedup plane
+    does not cover; Prometheus data has none) the FIRST in plane order is
+    the window's first sample and the LAST its last, as the reference's
+    `range_manipulate` + `extrapolate_rate` read them.  `raw_values` are
+    the samples as stored where `values` are reset-adjusted (`rate` /
+    `increase`): `first_raw` is the raw sample of the first row; without
+    them `values` are the raw samples and `first_raw` is `first_val`.
+
+    **By segment.**  `reduce` names which of sum / min / max to compute
+    (`reductions_for(func)`; the rest keep their fill values): each still
+    assigns a sample to the <= k windows that can contain it and reduces
+    with one `jax.ops.segment_*` per unrolled window, because a
+    prefix-sum difference rounds differently from a segment sum and
+    min / max want a range-minimum structure.  On the chip such a
+    reduction over 64-bit values lowers to a scatter of ~90 ns a row."""
     num_groups = num_series * n_steps
     if n_steps_actual is None:
         n_steps_actual = n_steps
-    segs = num_groups + 1
-    v = values.astype(acc_dtype)
-    raw = None if raw_values is None else raw_values.astype(acc_dtype)
-
-    tsmax = jnp.iinfo(jnp.int64).max
-    tsmin = jnp.iinfo(jnp.int64).min
     big = jnp.asarray(jnp.finfo(acc_dtype).max, acc_dtype)
     small = jnp.asarray(jnp.finfo(acc_dtype).min, acc_dtype)
 
-    count = jnp.zeros(segs, jnp.int32)
-    first_ts = jnp.full(segs, tsmax, jnp.int64)
-    last_ts = jnp.full(segs, tsmin, jnp.int64)
+    def fill(value, dtype=acc_dtype):
+        return jnp.full(num_groups, value, dtype)
+
+    # what a cell without a sample reads, and a window past the real grid
+    empty = WindowStats(
+        count=fill(0, jnp.int32),
+        first_ts=fill(jnp.iinfo(jnp.int64).max, jnp.int64),
+        last_ts=fill(jnp.iinfo(jnp.int64).min, jnp.int64),
+        first_val=fill(small), first_raw=fill(small), last_val=fill(small),
+        sum=fill(0), min=fill(big), max=fill(small),
+    )
+    if ts.shape[0] == 0:  # a subquery whose every point is NaN
+        return empty
+
+    count, first_row, last_row = (
+        x.reshape(num_groups) for x in _window_rows(
+            series, ts, valid, start, step, range_, n_steps_actual,
+            n_steps=n_steps, num_series=num_series,
+        )
+    )
+    live = count > 0
+
+    def at(plane, row, fills):
+        return jnp.where(live, jnp.take(plane, row, mode="clip"), fills)
+
+    v = values.astype(acc_dtype)
+    ts = ts.astype(jnp.int64)
+    first_val = at(v, first_row, empty.first_val)
+    stats = dataclasses.replace(
+        empty,
+        count=count,
+        first_ts=at(ts, first_row, empty.first_ts),
+        last_ts=at(ts, last_row, empty.last_ts),
+        first_val=first_val,
+        first_raw=first_val if raw_values is None else at(
+            raw_values.astype(acc_dtype), first_row, empty.first_raw
+        ),
+        last_val=at(v, last_row, empty.last_val),
+    )
+    if not reduce:
+        return stats
+
+    segs = num_groups + 1
     sum_ = jnp.zeros(segs, acc_dtype)
     min_ = jnp.full(segs, big, acc_dtype)
     max_ = jnp.full(segs, small, acc_dtype)
-
     # First window index that can contain sample t: smallest w with t_w >= t.
     w0 = jnp.ceil((ts - start) / step).astype(jnp.int32)
     w0 = jnp.maximum(w0, 0)
@@ -256,71 +470,18 @@ def range_windows_dyn(
         t_w = start + w.astype(jnp.int64) * step
         in_win = valid & (w >= 0) & (w < n_steps_actual) & (ts <= t_w) & (ts > t_w - range_)
         gid = jnp.where(in_win, series.astype(jnp.int32) * n_steps + w, num_groups)
-        count = count + jax.ops.segment_sum(in_win.astype(jnp.int32), gid, num_segments=segs)
-        first_ts = jnp.minimum(
-            first_ts, jax.ops.segment_min(jnp.where(in_win, ts, tsmax), gid, num_segments=segs)
-        )
-        last_ts = jnp.maximum(
-            last_ts, jax.ops.segment_max(jnp.where(in_win, ts, tsmin), gid, num_segments=segs)
-        )
-        sum_ = sum_ + jax.ops.segment_sum(jnp.where(in_win, v, 0), gid, num_segments=segs)
-        min_ = jnp.minimum(
-            min_, jax.ops.segment_min(jnp.where(in_win, v, big), gid, num_segments=segs)
-        )
-        max_ = jnp.maximum(
-            max_, jax.ops.segment_max(jnp.where(in_win, v, small), gid, num_segments=segs)
-        )
-
-    count, first_ts, last_ts = count[:num_groups], first_ts[:num_groups], last_ts[:num_groups]
-    sum_, min_, max_ = sum_[:num_groups], min_[:num_groups], max_[:num_groups]
-
-    # Second pass: values at the first/last timestamps (two-field argmin/max).
-    fv = jnp.full(num_groups + 1, small, acc_dtype)
-    lv = jnp.full(num_groups + 1, small, acc_dtype)
-    n_rows = ts.shape[0]
-    rows = jnp.arange(n_rows, dtype=jnp.int32)
-    first_row = jnp.full(num_groups + 1, n_rows, jnp.int32)
-    for j in range(k):
-        w = w0 + j
-        t_w = start + w.astype(jnp.int64) * step
-        in_win = valid & (w >= 0) & (w < n_steps_actual) & (ts <= t_w) & (ts > t_w - range_)
-        gid = jnp.where(in_win, series.astype(jnp.int32) * n_steps + w, num_groups)
-        safe_gid = jnp.clip(gid, 0, num_groups - 1)
-        at_first = in_win & (ts == first_ts[safe_gid])
-        at_last = in_win & (ts == last_ts[safe_gid])
-        fv = jnp.maximum(
-            fv, jax.ops.segment_max(jnp.where(at_first, v, small), gid, num_segments=num_groups + 1)
-        )
-        lv = jnp.maximum(
-            lv, jax.ops.segment_max(jnp.where(at_last, v, small), gid, num_segments=num_groups + 1)
-        )
-        if raw is not None:
-            first_row = jnp.minimum(
-                first_row,
-                jax.ops.segment_min(
-                    jnp.where(at_first, rows, n_rows), gid, num_segments=num_groups + 1
-                ),
+        if "sum" in reduce:
+            sum_ = sum_ + jax.ops.segment_sum(jnp.where(in_win, v, 0), gid, num_segments=segs)
+        if "min" in reduce:
+            min_ = jnp.minimum(
+                min_, jax.ops.segment_min(jnp.where(in_win, v, big), gid, num_segments=segs)
             )
-    first_val = fv[:num_groups]
-    last_val = lv[:num_groups]
-    if raw is None:
-        first_raw = first_val
-    else:
-        first_row = first_row[:num_groups]
-        first_raw = jnp.where(
-            first_row < n_rows, jnp.take(raw, jnp.minimum(first_row, n_rows - 1)), small
-        )
-
-    return WindowStats(
-        count=count,
-        first_ts=first_ts,
-        last_ts=last_ts,
-        first_val=first_val,
-        first_raw=first_raw,
-        last_val=last_val,
-        sum=sum_,
-        min=min_,
-        max=max_,
+        if "max" in reduce:
+            max_ = jnp.maximum(
+                max_, jax.ops.segment_max(jnp.where(in_win, v, small), gid, num_segments=segs)
+            )
+    return dataclasses.replace(
+        stats, sum=sum_[:num_groups], min=min_[:num_groups], max=max_[:num_groups]
     )
 
 

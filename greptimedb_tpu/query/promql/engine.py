@@ -318,6 +318,7 @@ class PromqlEngine:
             extrapolated_rate,
             over_time,
             range_windows,
+            reductions_for,
             strip_counter_resets,
         )
 
@@ -336,8 +337,11 @@ class PromqlEngine:
         raw = v if func in ("rate", "increase") else None
         if raw is not None:
             v = strip_counter_resets(s, raw, valid)
+        # `flat` is in np.lexsort((ts, sid)) order, every row a sample:
+        # the order `range_windows` searches
         stats = range_windows(
-            s, t, v, valid, spec, num_series=num_series, raw_values=raw
+            s, t, v, valid, spec, num_series=num_series, raw_values=raw,
+            reduce=reductions_for(func),
         )
         if func in _RATE_FUNCS:
             vals, defined = extrapolated_rate(stats, spec, func)

@@ -13,7 +13,7 @@ Routing ladder (the `tql_tile` optimizer pass, off-switch `tql.tile`):
 
   warm    every region's super-tile planes (tag codes, ts, value, nulls,
           dedup keep) are device-resident -> ONE compiled dispatch fuses
-          counter-reset stripping + window assignment + extrapolated
+          counter-reset stripping + window statistics + extrapolated
           rate / *_over_time + the by-label sum/avg/min/max/count
           aggregation, and the readback ships the compacted
           [series_out, steps] result (never raw samples);
@@ -69,6 +69,8 @@ from ...ops.rate import (
     merge_disjoint_stats,
     over_time,
     range_windows_dyn,
+    reductions_for,
+    series_present,
     strip_counter_resets_segmented,
 )
 from ...utils import flight_recorder, metrics
@@ -145,9 +147,10 @@ def _region_stats(src, dyn, rsig, csig):
         )
 
     # mixed-radix series id over the pk tag codes (the same code space
-    # the (pk, ts) super-tile sort ordered rows by, so each series'
-    # samples are contiguous and ts-ascending — what the reset scan and
-    # the first/last stats need)
+    # the (pk, ts) super-tile sort ordered rows by, so the rows are in
+    # (series, ts) order — contiguity is what the reset scan needs, the
+    # order what `range_windows_dyn` searches; its docstring has the
+    # precondition and why these planes meet it)
     sid = jnp.zeros(ts_nat.shape, jnp.int32)
     stride = 1
     for c, r in zip(reversed(codes), reversed(radices)):
@@ -168,16 +171,12 @@ def _region_stats(src, dyn, rsig, csig):
         start=dyn["start"], step=dyn["step"], range_=dyn["range"],
         n_steps=w_pad, k=k, num_series=s_pad,
         n_steps_actual=dyn["nsteps"], raw_values=raw,
+        reduce=reductions_for(func),
     )
     # scan-presence per series (a scanned series with no windowed sample
     # still occupies a matrix row in the legacy path — `absent()` and
     # binary ops see it)
-    presence = (
-        jax.ops.segment_max(
-            in_fetch.astype(jnp.int32), sid, num_segments=s_pad
-        )
-        > 0
-    )
+    presence = series_present(sid, in_fetch, s_pad)
     return stats, presence
 
 
@@ -761,6 +760,8 @@ class TqlTileExecutor:
         )
         if not ghost:
             metrics.TQL_TILE_DISPATCHES.inc()
+            if reductions_for(func):
+                metrics.TQL_TILE_SEGMENT_STATS.inc()
         passes.note(
             "tql_tile", True,
             f"warm: {func} over {len(sources)} region(s) served from "

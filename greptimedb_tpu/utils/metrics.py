@@ -372,6 +372,12 @@ TQL_TILE_INELIGIBLE = REGISTRY.counter(
     "TQL range-vector evaluations the tile path found ineligible and handed "
     "to the legacy scan (the reason is on the `tql.plan` stage)",
 )
+TQL_TILE_SEGMENT_STATS = REGISTRY.counter(
+    "greptime_tql_tile_segment_stats_total",
+    "TQL tile dispatches whose program reduces sum / min / max by segment "
+    "over the plane (avg/sum/min/max_over_time); the rate family, count, "
+    "last and timestamp() read rows by position and never move it",
+)
 TQL_TILE_COLD_SERVES = REGISTRY.counter(
     "greptime_tql_tile_cold_serves_total",
     "Cold TQL queries answered from the legacy scan while their family's "
