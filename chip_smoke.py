@@ -132,16 +132,17 @@ def _compile_snapshot(slowest: bool = False) -> dict:
 
 
 class Dataset:
-    """The repo's TSBS cpu-only generator (bench.py): per chunk of ticks,
-    ten `rng.uniform(0, 100)` draws in METRICS order.  Keeps `usage_user`
-    as a [ticks, hosts] array — the ground truth every fold reads."""
+    """A TSBS cpu-only data set (one row per host per 10 s scrape): per
+    chunk of ticks, ten `rng.uniform(0, 100)` draws in METRICS order.  Keeps
+    `usage_user` as a [ticks, hosts] array — the ground truth every fold
+    reads."""
 
     def __init__(self, hosts: int, hours: int, seed: int):
         self.hosts, self.hours, self.seed = hosts, hours, seed
         self.ticks = hours * 3600 // SCRAPE_S
         self.end = T0 + hours * 3600_000
         self.host_names = np.array([f"host_{i}" for i in range(hosts)])
-        self.host1_index = 703 % hosts  # bench.py's HOST1
+        self.host1_index = 703 % hosts  # the host of the one-host shapes
         self.host1 = f"host_{self.host1_index}"
         # the PromQL metric table holds the last 2 h
         self.tql_ticks = min(self.ticks, 2 * 3600 // SCRAPE_S)
@@ -223,7 +224,7 @@ def load(db, ds: Dataset, home: str, regions: int) -> dict:
     tql_rows = 0
     if regions == 1:
         # the single-field metric table the PromQL engine needs: the last
-        # 2 h of usage_user (bench.py _tql_phase builds the same one)
+        # 2 h of usage_user
         n_tql = ds.tql_ticks
         tql_rows = n_tql * ds.hosts
         if not reuse:
@@ -512,7 +513,7 @@ class Client:
 
 
 def sql_requests(ds: Dataset) -> list:
-    """(name, sql, fold, rtol) — the SQL is bench.py's TSBS text."""
+    """(name, sql, fold, rtol) — TSBS cpu-only query shapes as SQL."""
     end = ds.end
     w_all = (T0, end)
     w1 = (end - min(ds.hours, 1) * 3600_000, end)

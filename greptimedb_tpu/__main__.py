@@ -3,8 +3,7 @@
 Role-equivalent of the reference's `greptime` binary (reference
 cmd/src/bin/greptime.rs:26-61): `standalone start` brings up the all-in-one
 server; `sql` executes statements against a data dir; `export`/`import`
-move table data as Parquet (reference cli data export/import); `bench`
-runs the TSBS-style benchmark.
+move table data as Parquet (reference cli data export/import).
 """
 
 from __future__ import annotations
@@ -468,19 +467,6 @@ def cmd_objbench(args):
     return 0
 
 
-def cmd_bench(args):
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(__file__)), "bench.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    mod.main()
-    return 0
-
-
 def main(argv=None):
     parser = argparse.ArgumentParser(prog="greptimedb-tpu")
     sub = parser.add_subparsers(dest="command", required=True)
@@ -574,9 +560,6 @@ def main(argv=None):
     p.add_argument("--num-objects", type=int, default=64)
     p.add_argument("--size-kb", type=int, default=1024)
     p.set_defaults(fn=cmd_objbench)
-
-    p = sub.add_parser("bench", help="run the TSBS-style benchmark")
-    p.set_defaults(fn=cmd_bench)
 
     args = parser.parse_args(argv)
     return args.fn(args)

@@ -73,7 +73,7 @@ from collections import OrderedDict
 
 import jax
 
-from ..utils import device_health, flight_recorder, metrics, rtt_sim, tracing
+from ..utils import device_health, flight_recorder, metrics, tracing
 from ..utils.deadline import check_deadline, current_deadline
 from ..utils.fault_injection import fire as _fault_fire
 
@@ -584,10 +584,9 @@ class QueryBatcher:
             for _, p in pendings:
                 leaves.extend(p.leaves)
             with tracing.span("tile.batch_readback", members=len(pendings)) as rb:
-                with rtt_sim.round_trip():
-                    fetched = device_health.supervised_call(
-                        "readback", lambda: jax.device_get(leaves)
-                    )
+                fetched = device_health.supervised_call(
+                    "readback", lambda: jax.device_get(leaves)
+                )
             transfer_ms = rb.duration() * 1000.0
         except BaseException:  # noqa: BLE001 — pack failure solos everyone
             for m, _ in pendings:

@@ -86,7 +86,7 @@ from ..storage.dictionary import TableDictionary
 from ..storage.region import OP_COL, Region
 from ..storage.sst import FileMeta
 from ..query import analyze, passes
-from ..utils import device_health, flight_recorder, metrics, rtt_sim, tracing
+from ..utils import device_health, flight_recorder, metrics, tracing
 from ..utils.deadline import check_deadline, current_deadline
 from ..utils.errors import QueryTimeoutError
 from ..utils.fault_injection import fire as _fault_fire
@@ -206,13 +206,11 @@ device_health.register_scope_propagator(
 # owns errors there, and the foreground path it primes stays supervised.
 device_health.register_bypass(_in_fused_build)
 
-# GRAFT_TILE_TIMING=1 prints per-phase wall times of the cold path (the
-# bench's second-process cold probe uses it to attribute cold latency)
+# GRAFT_TILE_TIMING=1 prints per-phase wall times of the cold path
 _TIMING = os.environ.get("GRAFT_TILE_TIMING") == "1"
 
 # Per-region wall times (ms) of the most recent region-streamed query
-# (_streamed_execute): the bench's larger_than_hbm probe reads this to
-# record flat per-region latency.  Single-query diagnostic, not a metric.
+# (_streamed_execute).  Single-query diagnostic, not a metric.
 LAST_STREAM_CHUNK_MS: list[float] = []
 
 
@@ -5252,11 +5250,10 @@ class TileExecutor:
                         acc=attempt_plan.acc_dtype,
                         mesh_devices=0,
                     ) as disp:
-                        with rtt_sim.round_trip(enabled=not _in_fused_build()):
-                            packed = device_health.supervised_call(
-                                "dispatch",
-                                lambda: program(tuple(device_sources), dyn),
-                            )
+                        packed = device_health.supervised_call(
+                            "dispatch",
+                            lambda: program(tuple(device_sources), dyn),
+                        )
                     _record_dispatch(disp, attempt_plan)
                 table = self._finalize(
                     packed, int_layout, acc32_layout, acc64_layout, int_dtype,
@@ -5289,11 +5286,10 @@ class TileExecutor:
                     acc=attempt_plan.acc_dtype,
                     retry=True,
                 ) as disp:
-                    with rtt_sim.round_trip(enabled=not _in_fused_build()):
-                        packed = device_health.supervised_call(
-                            "dispatch",
-                            lambda: program(tuple(device_sources), dyn),
-                        )
+                    packed = device_health.supervised_call(
+                        "dispatch",
+                        lambda: program(tuple(device_sources), dyn),
+                    )
                 _record_dispatch(disp, attempt_plan)
                 flight_recorder.flag("retry")
                 table = self._finalize(
@@ -7131,11 +7127,10 @@ class TileExecutor:
             and total >= 2 * chunk
         )
         if streamed:
-            with rtt_sim.round_trip(enabled=not _in_fused_build()):
-                out = device_health.supervised_call(
-                    "readback",
-                    lambda: streamed_device_get(list(packed), chunk),
-                )
+            out = device_health.supervised_call(
+                "readback",
+                lambda: streamed_device_get(list(packed), chunk),
+            )
             metrics.TPU_READBACK_STREAMED.inc()
             passes.note(
                 "streamed_readback", True,
@@ -7144,10 +7139,9 @@ class TileExecutor:
                 bytes=total,
             )
             return tuple(np.asarray(p) for p in out)
-        with rtt_sim.round_trip(enabled=not _in_fused_build()):
-            got = device_health.supervised_call(
-                "readback", lambda: jax.device_get(packed)
-            )
+        got = device_health.supervised_call(
+            "readback", lambda: jax.device_get(packed)
+        )
         return tuple(np.asarray(p) for p in got)
 
     def _finalize(
@@ -7280,17 +7274,15 @@ class TileExecutor:
         traces0 = _MEGA_STATS["traces"]
         metrics.TPU_DEVICE_DISPATCHES.inc()
         with tracing.span("tile.fused_dispatch", members=len(cds)) as disp:
-            with rtt_sim.round_trip():
-                packed_all = device_health.supervised_call(
-                    "dispatch", lambda: fused(tuple(inputs))
-                )
+            packed_all = device_health.supervised_call(
+                "dispatch", lambda: fused(tuple(inputs))
+            )
         dispatch_ms = disp.duration() * 1000.0
         leaves = [a for packed in packed_all for a in packed]
         with tracing.span("tile.batch_readback", members=len(cds)) as rb:
-            with rtt_sim.round_trip():
-                fetched = device_health.supervised_call(
-                    "readback", lambda: jax.device_get(leaves)
-                )
+            fetched = device_health.supervised_call(
+                "readback", lambda: jax.device_get(leaves)
+            )
         transfer_ms = rb.duration() * 1000.0
         tables = [None] * len(cds)
         off = 0

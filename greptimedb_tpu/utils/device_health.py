@@ -1,16 +1,13 @@
 """Per-device health supervision: bounded device calls, wedge detection,
 quarantine + heal.
 
-The bench history proves the failure mode this module closes: a wedged
-native XLA call holds the GIL-adjacent runtime hostage and no raised-error
-ladder (HBM retry, CPU fallback) ever fires, because nothing is *raised* —
-the call simply never returns.  BENCH r02–r05 published rc=124 for exactly
-this reason, and PR 12 bolted a jax-free supervisor onto bench.py to
-survive it.  This is the production twin: every blocking device
-interaction on the query path (upload, compile+dispatch, readback,
-memory_stats probe, mesh collective) runs through `supervised_call`,
-which executes the call on a dedicated per-device worker thread under a
-hard deadline:
+The failure mode this module closes: a wedged native XLA call holds the
+GIL-adjacent runtime hostage and no raised-error ladder (HBM retry, CPU
+fallback) ever fires, because nothing is *raised* — the call simply never
+returns.  Every blocking device interaction on the query path (upload,
+compile+dispatch, readback, memory_stats probe, mesh collective) runs
+through `supervised_call`, which executes the call on a dedicated
+per-device worker thread under a hard deadline:
 
     timeout = min(device.call_timeout_s, statement's remaining budget)
 

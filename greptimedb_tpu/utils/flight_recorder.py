@@ -18,7 +18,6 @@ Surfaces (all read-only views over the ring):
   * `information_schema.device_dispatches` (models/information_schema.py)
   * EXPLAIN ANALYZE's device-stage split (query/tpu_exec.py)
   * the `/debug/tile` HTTP endpoint (servers/http.py)
-  * bench.py's per-query stage-attribution digests
 
 Contract: recording must never fail or slow the recorded query.  Every
 `emit` crosses the `recorder.emit` fault point inside a try/except that
@@ -27,9 +26,8 @@ swallows ANY failure into `greptime_recorder_errors_total`; with
 pays one thread-local read per query.
 
 Ghost (background fused-builder) dispatches are recorded but LABELED
-(`ghost = True`) so per-query views — bench deltas, EXPLAIN ANALYZE —
-exclude the builder's priming run, exactly like the per-query metric
-counters do.
+(`ghost = True`) so per-query views (EXPLAIN ANALYZE) exclude the
+builder's priming run, exactly like the per-query metric counters do.
 """
 
 from __future__ import annotations
@@ -56,17 +54,6 @@ STAGES = (
     "readback_decode",
 )
 
-# Compact per-stage shorthand for the bench record's stage digest (the
-# summary line must stay under the driver's ~2 KB tail capture).
-STAGE_SHORT = {
-    "build": "bu",
-    "upload": "up",
-    "compile": "co",
-    "dispatch": "di",
-    "readback_transfer": "rt",
-    "readback_decode": "rd",
-}
-
 
 @dataclass
 class DispatchRecord:
@@ -89,16 +76,6 @@ class DispatchRecord:
     hbm_budget: int = 0
     flags: tuple = ()  # retry, degraded, streamed, coalesced, hedged...
     regions: tuple = ()  # ((region_id, mode, build_ms, rows), ...)
-
-    def dominant_stage(self) -> tuple[str, float]:
-        """(stage, ms) of the slowest recorded stage — the one-line
-        attribution the bench digest carries."""
-        best, best_ms = "", 0.0
-        for name in STAGES:
-            ms = float(self.stages_ms.get(name, 0.0))
-            if ms > best_ms:
-                best, best_ms = name, ms
-        return best, best_ms
 
     def stage_ms(self, name: str) -> float:
         return float(self.stages_ms.get(name, 0.0))

@@ -133,7 +133,7 @@ def _await_heal(n_devices, max_s=15.0):
 # ---- wedge chaos: zero failed queries, quarantine, heal, bit-parity ---------
 
 @pytest.mark.wedge
-def test_wedge_mid_warm_dispatch_quarantine_and_heal(tmp_path):
+def test_wedge_mid_warm_dispatch_quarantine_and_heal(tmp_path, monkeypatch):
     """A warm dispatch that never returns: the query must still answer
     (abandon -> quarantine -> degrade ladder), the device health machinery
     must record the abandonment, the prober must re-admit the devices once
@@ -145,18 +145,36 @@ def test_wedge_mid_warm_dispatch_quarantine_and_heal(tmp_path):
         want = _ser(db.sql_one(_Q))  # warm reference bytes
         a0 = metrics.DEVICE_HEALTH_ABANDONED.get(kind="dispatch")
         q0 = metrics.DEVICE_HEALTH_QUARANTINES.get()
+        # read before the wedge: the prober may re-admit the devices any
+        # moment after the wedge is released
+        h0 = metrics.DEVICE_HEALTH_HEALS.get()
+        # both deadlines armed for the wedged statement: the call's own
+        # must be the one that fires
+        db.config.query.timeout_s = 600.0
+        abandoned = []
+        real_abandon = dh.SUPERVISOR._abandon
+
+        def spy(worker, kind, indices, timeout):
+            abandoned.append((kind, timeout))
+            return real_abandon(worker, kind, indices, timeout)
+
+        monkeypatch.setattr(dh.SUPERVISOR, "_abandon", spy)
         w = _Wedge("dispatch")
         try:
-            t0 = time.monotonic()
-            got = db.sql_one(_Q)  # the wedged query — must still answer
-            wall = time.monotonic() - t0
+            # the wedged query — must still answer: a statement deadline
+            # that fired would raise QueryTimeoutError here
+            got = db.sql_one(_Q)
         finally:
             w.release()
+            db.config.query.timeout_s = 0.0
         assert _ser(got) == want, "the degraded answer diverged"
         assert w.plan.trips == 1
         assert w.entered.is_set()
-        # bounded: abandon at call_timeout_s, not at the statement deadline
-        assert wall < 10.0
+        # bounded: abandoned at call_timeout_s, not at what the statement
+        # deadline had left
+        assert [t for kind, t in abandoned if kind == "dispatch"] == [
+            db.config.device.call_timeout_s
+        ]
         assert metrics.DEVICE_HEALTH_ABANDONED.get(kind="dispatch") == a0 + 1
         assert metrics.DEVICE_HEALTH_QUARANTINES.get() > q0
         dig = dh.SUPERVISOR.digest()
@@ -165,7 +183,6 @@ def test_wedge_mid_warm_dispatch_quarantine_and_heal(tmp_path):
         assert _ser(db.sql_one(_Q)) == want
         # heal: the prober's ghost dispatches re-admit every device
         n = len(db.query_engine.tile_cache.devices)
-        h0 = metrics.DEVICE_HEALTH_HEALS.get()
         _await_heal(n)
         assert metrics.DEVICE_HEALTH_HEALS.get() > h0
         assert dh.SUPERVISOR.digest()["heals"] >= 1

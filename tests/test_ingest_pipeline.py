@@ -622,6 +622,119 @@ def test_flush_overlap_admits_writes_mid_encode(tmp_path):
     engine.close()
 
 
+# ---- through the Database: pipelined vs legacy ladder --------------------------
+
+
+def test_database_pipelined_vs_legacy_ladder(tmp_path):
+    """Pipelined (group commit + vectorized routing + flush overlap, the
+    defaults) vs legacy ingest through `Database` on a small dataset —
+    bit-identical query results, the greptime_ingest_* stage metrics
+    present, and merged-frame evidence (WAL frames < writes) asserted
+    via counters."""
+    from concurrent.futures import Future
+
+    from greptimedb_tpu.database import Database
+    from greptimedb_tpu.storage.worker import _WriteRequest
+
+    t0, n_hosts = 1_767_225_600_000, 8
+
+    def mk_db(name, pipelined: bool) -> Database:
+        cfg = Config()
+        cfg.storage.compaction_background_enable = False
+        if not pipelined:
+            cfg.storage.ingest_group_commit = False
+            cfg.storage.ingest_flush_workers = 1
+            cfg.storage.ingest_flush_overlap = False
+        db = Database(data_home=str(tmp_path / name), config=cfg)
+        db.sql(
+            "CREATE TABLE cpu (hostname STRING, ts TIMESTAMP(3) TIME INDEX,"
+            " usage_user DOUBLE, usage_system DOUBLE, PRIMARY KEY (hostname))"
+            " PARTITION BY HASH (hostname) PARTITIONS 2"
+        )
+        return db
+
+    def ingest(db, tick_lo, tick_hi, seed):
+        rng = np.random.default_rng(seed)
+        n = (tick_hi - tick_lo) * n_hosts
+        ts = t0 + np.repeat(np.arange(tick_lo, tick_hi, dtype=np.int64), n_hosts) * 10_000
+        db.insert_rows("cpu", pa.table({
+            "hostname": pa.array(
+                np.tile([f"host_{i}" for i in range(n_hosts)], tick_hi - tick_lo)
+            ),
+            "ts": pa.array(ts, pa.timestamp("ms")),
+            "usage_user": pa.array(rng.uniform(0, 100, n)),
+            "usage_system": pa.array(rng.uniform(0, 100, n)),
+        }))
+
+    db_new = mk_db("pipelined", True)
+    db_old = mk_db("legacy", False)
+    try:
+        w0 = m.INGEST_WRITES_TOTAL.get()
+        f0 = m.INGEST_WAL_FRAMES.get()
+        split0 = m.INGEST_SPLIT_MS.total()
+        wal0 = m.INGEST_WAL_MS.total()
+        mem0 = m.INGEST_MEMTABLE_MS.total()
+        enc0 = m.INGEST_FLUSH_ENCODE_MS.total()
+        for db in (db_new, db_old):
+            for lo in range(0, 300, 100):
+                ingest(db, lo, lo + 100, seed=lo)
+            # the multi-row VALUES path (zip transpose + coercion)
+            db.sql(
+                "INSERT INTO cpu VALUES"
+                " ('host_0', 1767225600001, 1.5, 2.5),"
+                " ('host_1', 1767225600002, 3.5, 4.5)"
+            )
+        # a deterministic drained group through the pipelined worker:
+        # five requests commit as ONE merged WAL frame, five entry ids
+        engine = db_new.storage
+        frames1 = m.INGEST_WAL_FRAMES.get()
+        writes1 = m.INGEST_WRITES_TOTAL.get()
+        rid = db_new.catalog.table("cpu", "public").region_ids[0]
+        reqs = [
+            _WriteRequest(rid, pa.record_batch(
+                {"hostname": pa.array([f"gh_{i}"]),
+                 "ts": pa.array([t0 + 10_000_000 + i], pa.timestamp("ms")),
+                 "usage_user": pa.array([1.0]),
+                 "usage_system": pa.array([2.0])},
+            ), Future())
+            for i in range(5)
+        ]
+        engine.workers._worker_for(rid)._handle(reqs)
+        assert [r.future.result(timeout=30) for r in reqs] == [1] * 5
+        assert m.INGEST_WAL_FRAMES.get() - frames1 == 1
+        assert m.INGEST_WRITES_TOTAL.get() - writes1 == 5
+        db_old.sql(
+            "INSERT INTO cpu VALUES"
+            + ", ".join(
+                f"('gh_{i}', {t0 + 10_000_000 + i}, 1.0, 2.0)"
+                for i in range(5)
+            )
+        )
+        # merged-frame evidence overall: fewer frames than write requests
+        writes_d = m.INGEST_WRITES_TOTAL.get() - w0
+        frames_d = m.INGEST_WAL_FRAMES.get() - f0
+        assert writes_d > 0 and frames_d < writes_d, (frames_d, writes_d)
+        # every ingest stage metric observed something
+        assert m.INGEST_SPLIT_MS.total() > split0
+        assert m.INGEST_WAL_MS.total() > wal0
+        assert m.INGEST_MEMTABLE_MS.total() > mem0
+        db_new.storage.flush_all()
+        db_old.storage.flush_all()
+        assert m.INGEST_FLUSH_ENCODE_MS.total() > enc0
+        # bit-identical query results across the two ladders
+        for q in (
+            "SELECT hostname, ts, usage_user, usage_system FROM cpu"
+            " ORDER BY hostname, ts",
+            "SELECT hostname, avg(usage_user), count(usage_system) FROM cpu"
+            " GROUP BY hostname ORDER BY hostname",
+        ):
+            t_new, t_old = db_new.sql_one(q), db_old.sql_one(q)
+            assert t_new.to_pydict() == t_old.to_pydict(), q
+    finally:
+        db_new.close()
+        db_old.close()
+
+
 # ---- config -----------------------------------------------------------------
 
 

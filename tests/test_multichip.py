@@ -410,8 +410,8 @@ def test_wide_multihost_slice_leaves_host_path(tmp_path):
             tbl[f"m{i}"] = pa.array(rng.uniform(0, 100, n_hosts * ticks))
         d.insert_rows("c", pa.table(tbl))
         d.sql("ADMIN flush_table('c')")
-        # the bench prewarms every numeric field after flush (PREWARM=1
-        # default): the gate keys on WARM planes — cold slices keep the
+        # prewarm every numeric field after flush, as the benchmark's
+        # set-up does: the gate keys on WARM planes — cold slices keep the
         # host path because an upload would cost more than the slice
         d.prewarm(tables=["c"])
         sel = ", ".join(f"max(m{i}) AS x{i}" for i in range(10))

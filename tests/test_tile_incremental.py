@@ -229,6 +229,84 @@ def test_delta_flush_is_o_delta_not_o_total(tmp_path):
         db.close()
 
 
+N_HOSTS = 8
+TICKS = 720  # 2 h at 10 s scrape
+T0 = 1_767_225_600_000
+
+
+def _ingest_cpu(db, tick_lo, tick_hi, seed):
+    """Every host at every 10 s tick of [tick_lo, tick_hi); returns rows."""
+    rng = np.random.default_rng(seed)
+    ticks = tick_hi - tick_lo
+    n = ticks * N_HOSTS
+    ts = T0 + np.repeat(np.arange(tick_lo, tick_hi, dtype=np.int64), N_HOSTS) * 10_000
+    db.insert_rows("cpu", pa.table({
+        "hostname": pa.array(np.tile([f"host_{i}" for i in range(N_HOSTS)], ticks)),
+        "ts": pa.array(ts, pa.timestamp("ms")),
+        "usage_user": pa.array(rng.uniform(0, 100, n)),
+        "usage_system": pa.array(rng.uniform(0, 100, n)),
+    }))
+    return n
+
+
+def test_prewarm_delta_query(tmp_path):
+    """One query family through the full engine path on a TSBS-shaped
+    append-mode table: prewarm builds the tiles off the query path, the
+    post-flush delta (~5 % new rows) merges into the SAME entry instead
+    of rebuilding, and the result matches the authoritative CPU path."""
+    db = _mk_db(tmp_path, "prewarm_delta")
+    try:
+        db.sql(
+            "CREATE TABLE cpu (hostname STRING, ts TIMESTAMP(3) TIME INDEX,"
+            " usage_user DOUBLE, usage_system DOUBLE,"
+            " PRIMARY KEY (hostname)) WITH (append_mode = 'true')"
+        )
+        n = _ingest_cpu(db, 0, TICKS, seed=1)
+        db.storage.flush_all()
+
+        # prewarm: the cold consolidation runs OFF the query path
+        pw0 = metrics.PREWARM_BUILDS.get()
+        db.prewarm(tables=["cpu"])
+        assert metrics.PREWARM_BUILDS.get() > pw0
+
+        q = (
+            "SELECT hostname, time_bucket('1m', ts) AS tb,"
+            " avg(usage_user) AS au FROM cpu GROUP BY hostname, tb"
+        )
+        lowered0 = metrics.TILE_LOWERED_TOTAL.get()
+        db.sql_one(q)
+        db.sql_one(q)  # device planes warm (cold-serve answered once)
+        assert metrics.TILE_LOWERED_TOTAL.get() > lowered0, (
+            "query did not take the tile path"
+        )
+
+        # delta flush (~5% new rows) + re-query: must delta-merge, not
+        # rebuild
+        merges0 = metrics.TILE_DELTA_MERGES.get()
+        entry = _entry(db)
+        _ingest_cpu(db, TICKS, TICKS + TICKS // 20, seed=2)
+        db.storage.flush_all()
+        t_delta = db.sql_one(q)
+        assert metrics.TILE_DELTA_MERGES.get() == merges0 + 1, (
+            "post-flush query rebuilt the super-tile instead of delta-merging"
+        )
+        assert _entry(db) is entry
+
+        # correctness vs the authoritative CPU path
+        db.config.query.backend = "cpu"
+        t_cpu = db.sql_one(q)
+        db.config.query.backend = "tpu"
+        k = [("hostname", "ascending"), ("tb", "ascending")]
+        got = t_delta.sort_by(k).to_pydict()
+        want = t_cpu.sort_by(k).to_pydict()
+        assert got["hostname"] == want["hostname"]
+        for x, y in zip(got["au"], want["au"]):
+            assert math.isclose(x, y, rel_tol=1e-9), (x, y)
+        assert n == TICKS * N_HOSTS
+    finally:
+        db.close()
+
+
 def test_window_tiles_survive_disjoint_delta(tmp_path, monkeypatch):
     """A cached window tile whose window cannot contain a delta row stays
     resident (bit-identical data); one the delta intersects is dropped
