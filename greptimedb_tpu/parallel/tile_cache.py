@@ -242,6 +242,53 @@ def _chunk_bounds(pad: int, chunk_rows: int = TILE_CHUNK_ROWS) -> list[tuple[int
     ]
 
 
+def _ascending_run_starts(ts: np.ndarray) -> np.ndarray:
+    """Start offsets of the maximal non-decreasing runs of `ts`.  In
+    (pk, ts) order every series is one run or part of one (two adjacent
+    series whose concatenation still ascends share a run, which a search
+    does not mind), so no tag plane is read."""
+    if len(ts) == 0:
+        return np.zeros(0, np.int64)
+    breaks = np.flatnonzero(ts[1:] < ts[:-1]) + 1
+    return np.concatenate([np.zeros(1, np.int64), breaks])
+
+
+def _run_lower_bounds(
+    ts: np.ndarray, lo: np.ndarray, hi: np.ndarray, value: int
+) -> np.ndarray:
+    """Per run r over rows [lo[r], hi[r]) of ascending `ts`: the first row
+    with ts >= value (hi[r] where none).  One bisection over all runs at
+    once — log2(longest run) rounds of a gather of one row per run — so
+    nothing the size of the plane is read or allocated."""
+    lo, hi = lo.copy(), hi.copy()
+    last = len(ts) - 1
+    while True:
+        live = lo < hi
+        if not live.any():
+            return lo
+        mid = (lo + hi) >> 1
+        # (a closed run's mid is its end, at most one past the plane: clipped)
+        below = live & (np.asarray(ts[np.minimum(mid, last)]) < value)
+        lo[below] = mid[below] + 1
+        above = live & ~below
+        hi[above] = mid[above]
+
+
+def _range_rows(
+    first: np.ndarray, end: np.ndarray, keep: np.ndarray | None = None
+) -> np.ndarray:
+    """Concatenation of arange(first[r], end[r]) over r, as int32 row
+    indices — for disjoint ascending ranges, the ascending list of their
+    rows — less the rows a `keep` plane drops."""
+    lens = end - first
+    total = int(lens.sum())
+    # each range's first row minus the rows laid down before it, repeated
+    # over the range; adding 0..total-1 gives the rows themselves
+    rows = np.repeat((first - (np.cumsum(lens) - lens)).astype(np.int32), lens)
+    rows += np.arange(total, dtype=np.int32)
+    return rows if keep is None else rows[keep[rows]]
+
+
 def _lex_merge_positions(
     old_keys: list[np.ndarray], new_keys: list[np.ndarray]
 ) -> np.ndarray:
@@ -391,6 +438,10 @@ class _SuperTiles:
     # tiny slice on the host, skipping the device link entirely (the role
     # of the reference's inverted index + page pruning point lookups)
     sorted_host: dict[str, np.ndarray] = field(default_factory=dict)
+    # start offsets of the ascending runs of the sorted ts plane: built at
+    # the first window probe (TileCacheManager._ts_runs), dropped wherever
+    # the plane is replaced or grows
+    ts_run_starts: np.ndarray | None = None
     host_epochs: dict[str, int] = field(default_factory=dict)
     file_row_offsets: np.ndarray | None = None
     # the cold-serve router answered from host once: the next grouped
@@ -415,6 +466,9 @@ class _SuperTiles:
     # DedupReader (mito2/src/read/dedup.rs).  keep_host serves the host
     # fast path; valid_dedup replaces `valid` in device dispatches.
     keep_host: np.ndarray | None = None
+    # keep_prefix[i] = kept rows among the first i (built with keep_host):
+    # a window's deduplicated row count is a difference per run
+    keep_prefix: np.ndarray | None = None
     valid_dedup: list | None = None
     tm_valid_dedup: list | None = None
     # consolidated (sorted, padded) host arrays mmap'd from the persisted
@@ -972,6 +1026,7 @@ class TileCacheManager:
                 entry.sorted_host[c] = np.load(
                     os.path.join(d, f"sh_{c}.npy"), mmap_mode="r"
                 )
+            entry.ts_run_starts = None  # run bounds are one plane's, never carried
             for c, epoch in meta.get("host_epochs", {}).items():
                 entry.host_epochs[c] = epoch
             for c in meta["cols"]:
@@ -1628,6 +1683,7 @@ class TileCacheManager:
                     entry.sorted_host[name] = cats[name][entry.order]
                     if name != ts_col:
                         entry.host_epochs[name] = dictionary.epoch
+                entry.ts_run_starts = None  # as in _try_load_persisted
                 entry.file_row_offsets = np.concatenate(
                     [[0], np.cumsum([ht.num_rows for ht in host_tiles])]
                 ).astype(np.int64)
@@ -2077,7 +2133,9 @@ class TileCacheManager:
                 c: dictionary.epoch for c in sort_cols if c != ts_col
             }
             entry.file_row_offsets = new_offsets
+            entry.ts_run_starts = None  # the plane grew: its runs moved
             entry.keep_host = None
+            entry.keep_prefix = None
             entry.valid_dedup = None
             if patch_device:
                 entry.cols = patched_cols
@@ -2322,7 +2380,9 @@ class TileCacheManager:
 
     # window tiles engage when the window covers less than this fraction
     # of the entry's rows (otherwise the full super-tile is cheaper than
-    # building a nearly-as-big copy)
+    # building a nearly-as-big copy).  The cover is COUNTED from the runs
+    # of the sorted ts plane (_window_ranges) before anything is gathered,
+    # so a window over it costs two searches, not a pass over the plane
     _WINDOW_TILE_MAX_COVER = 0.5
     _WINDOW_TILE_MIN_ROWS = 1 << 22  # below this the full scan is cheap
 
@@ -2336,14 +2396,17 @@ class TileCacheManager:
         dedup: bool,
         dict_epoch: int,
     ):
-        """Build (or fetch) the compact device tile for one query window:
-        host-side flatnonzero over the sorted ts (AND the dedup keep
-        plane, so stale versions never even upload), mmap fancy-gather of
-        each needed column, upload in chunk-device order, quantize limb
-        planes from the gathered values.  Returns a list of source
-        tuples (cols, valid, nulls, perm, limbs) or None when the window
-        doesn't qualify.  Rows keep their (pk, ts) order, so the blocked
-        kernel geometry holds on the compacted tile."""
+        """Build (or fetch) the compact device tile for one query window.
+        The window's rows are one contiguous range per ascending run of
+        the sorted ts plane, found by two searches per run; their count
+        (less the rows the dedup keep plane drops, so stale versions never
+        even upload) decides BEFORE anything the size of the plane is
+        allocated whether a tile is built.  If so: the ranges' rows,
+        an mmap fancy-gather of each needed column, upload in chunk-device
+        order, limb planes quantized from the gathered values.  Returns a
+        list of source tuples (cols, valid, nulls, perm, limbs) or None
+        when the window doesn't qualify.  Rows keep their (pk, ts) order,
+        so the blocked kernel geometry holds on the compacted tile."""
         if entry.num_rows < self._WINDOW_TILE_MIN_ROWS:
             return None
         if ts_name not in entry.sorted_host:
@@ -2396,24 +2459,19 @@ class TileCacheManager:
         n = snap["rows"] if snap is not None else -1
         idx = None
         if missing:
-            ts_sorted = entry.sorted_host[ts_name]
-            mask = (np.asarray(ts_sorted) >= window[0]) & (
-                np.asarray(ts_sorted) < window[1]
-            )
-            if dedup:
-                if not self.ensure_dedup_keep(entry):
-                    return None
-                mask &= entry.keep_host
-            idx = np.flatnonzero(mask).astype(np.int32)
-            if snap is not None and len(idx) != snap["rows"]:
+            if dedup and not self.ensure_dedup_keep(entry):
+                return None
+            first, end, n = self._window_ranges(entry, window, ts_name, dedup)
+            metrics.TILE_WINDOW_COUNTED.inc()
+            if snap is not None and n != snap["rows"]:
                 # row set changed under the same epoch (shouldn't happen:
                 # the file set pins sorted_host) — full rebuild, replace
                 snap = None
                 missing = list(cols_needed)
                 missing_limbs = []
-            n = len(idx)
             if n == 0 or n > entry.num_rows * self._WINDOW_TILE_MAX_COVER:
                 return None
+            idx = _range_rows(first, end, entry.keep_host if dedup else None)
         # pad to a 2^22 grid: bounded compile-shape variety, chunks stay
         # BLOCK_ROWS multiples.  Window tiles dispatch at 2^22-row chunks
         # (not the 2^24 super-tile chunk): a 10-column limb program over a
@@ -2581,6 +2639,39 @@ class TileCacheManager:
         metrics.TILE_WINDOW_BUILDS.inc()
         return self._window_sources(wt, need_cols, limb_cols)
 
+    def _ts_runs(self, entry: _SuperTiles, ts_name: str) -> np.ndarray:
+        """The run bounds of the entry's sorted ts plane: one diff over it,
+        once per plane (the first probe falls in a warm-up), charged to
+        the host budget like the plane itself."""
+        with self._lock:
+            if entry.ts_run_starts is None:
+                ts = np.asarray(entry.sorted_host[ts_name][: entry.num_rows])
+                entry.ts_run_starts = _ascending_run_starts(ts)
+                entry.host_nbytes += entry.ts_run_starts.nbytes
+                if self._super.get(entry.region_id) is entry:
+                    self._host_used += entry.ts_run_starts.nbytes
+            return entry.ts_run_starts
+
+    def _window_ranges(
+        self, entry: _SuperTiles, window: tuple[int, int], ts_name: str,
+        dedup: bool,
+    ) -> tuple[np.ndarray, np.ndarray, int]:
+        """Rows of the sorted plane with window[0] <= ts < window[1]: per
+        ascending run the range [first[r], end[r]), and their exact count
+        — with `dedup` the count of rows the keep plane leaves (the caller
+        has built it).  Reads 2 x log2(longest run) rows per run."""
+        with self._lock:  # one plane's ts, length, runs and keep count
+            ts = entry.sorted_host[ts_name]
+            starts = self._ts_runs(entry, ts_name)
+            ends = np.append(starts[1:], entry.num_rows)
+            kept = entry.keep_prefix
+        first = _run_lower_bounds(ts, starts, ends, window[0])
+        # searched from `first`, so an inverted window is empty, not negative
+        end = _run_lower_bounds(ts, first, ends, window[1])
+        if dedup:
+            return first, end, int((kept[end] - kept[first]).sum())
+        return first, end, int((end - first).sum())
+
     @staticmethod
     def _window_sources(wt: dict, need_cols: set[str], limb_cols: set[str]):
         n_chunks = len(wt["valid"])
@@ -2616,13 +2707,16 @@ class TileCacheManager:
                 keep[: n - 1] &= ~same
             bounds = _chunk_bounds(entry.pad, self.chunk_rows)
             entry.keep_host = keep[:n]
+            entry.keep_prefix = np.zeros(n + 1, np.int32)
+            np.cumsum(entry.keep_host, dtype=np.int32, out=entry.keep_prefix[1:])
             entry.valid_dedup = self._up_chunks(keep, bounds, entry.region_id)
             added = entry.pad  # device bools
+            host_added = entry.keep_host.nbytes + entry.keep_prefix.nbytes
             entry.nbytes += added
-            entry.host_nbytes += entry.keep_host.nbytes
+            entry.host_nbytes += host_added
             if self._super.get(entry.region_id) is entry:
                 self._used += added
-                self._host_used += entry.keep_host.nbytes
+                self._host_used += host_added
             return True
 
     def host_column_chunks(self, entry: _SuperTiles, name: str):

@@ -247,6 +247,7 @@ AGG_HASH_OVERFLOW = REGISTRY.counter(
 TILE_PERSIST_HITS = REGISTRY.counter("greptime_tile_persist_hits_total", "Super-tile consolidations loaded from the persisted store (cold-start skip)")
 TILE_PERSIST_WRITES = REGISTRY.counter("greptime_tile_persist_writes_total", "Super-tile consolidations written to the persisted store")
 TILE_WINDOW_BUILDS = REGISTRY.counter("greptime_tile_window_builds_total", "Compact window tiles gathered from sorted encodes")
+TILE_WINDOW_COUNTED = REGISTRY.counter("greptime_tile_window_counted_total", "Window-tile probes whose row count came from the run bounds of the sorted ts plane (less the builds: probes that declined without touching the plane)")
 TILE_HOST_FAST_PATH = REGISTRY.counter("greptime_tile_host_fast_path_total", "Selective queries served from the sorted host encode cache")
 TILE_STREAM_QUERIES = REGISTRY.counter("greptime_tile_stream_total", "Queries whose working set exceeded the HBM budget, executed region-streamed")
 TILE_DELTA_MERGES = REGISTRY.counter(

@@ -334,6 +334,205 @@ def test_window_tile_extends_with_new_columns(db, monkeypatch):
     assert metrics.TILE_WINDOW_BUILDS.get() == builds2
 
 
+def _host_entry(codes, ts):
+    """A super-tile entry of host planes alone, its rows in the (pk, ts)
+    order the consolidation's stable lexsort leaves (equal keys in flush
+    order, so the newest version of a key sits last)."""
+    import numpy as np
+
+    from greptimedb_tpu.ops.tiles import padded_size
+    from greptimedb_tpu.parallel.tile_cache import _SuperTiles
+
+    order = np.lexsort([ts, codes]).astype(np.int32)
+    return _SuperTiles(
+        region_id=7, file_ids=("f0",), num_rows=len(ts),
+        pad=padded_size(len(ts)), order=order,
+        sorted_host={"host": codes[order], "ts": ts[order]},
+    )
+
+
+def _probe_plane(kind, rng):
+    """(series codes, ts) in flush order, made from the seed."""
+    import numpy as np
+
+    if kind == "regular":  # 6 series x 40 ticks, every series its own run
+        return np.repeat(np.arange(6), 40), np.tile(np.arange(40) * 10 + 100, 6)
+    if kind == "duplicate_ts":  # append mode: a timestamp recurs within a series
+        codes = np.sort(rng.integers(0, 5, 600))
+        return codes, rng.integers(0, 40, 600) * 10 + 100
+    if kind == "one_row_runs":  # one row a series, each older than the last
+        return np.arange(50), 1000 - np.arange(50) * 10
+    if kind == "merged_series":
+        # series i ends where i + 1 begins (equal ts at the seam for odd i):
+        # the whole plane ascends and is ONE run
+        starts = np.arange(8) * 100 - (np.arange(8) % 2) * 10
+        return np.repeat(np.arange(8), 10), (starts[:, None] + np.arange(10) * 10).ravel()
+    if kind == "ragged":  # runs of 1, 2 and 900 rows side by side
+        lens = rng.permutation(np.r_[np.ones(20, int), np.full(20, 2), [900, 700, 3]])
+        codes = np.repeat(np.arange(len(lens)), lens)
+        return codes, np.concatenate([np.sort(rng.integers(0, 500, n)) * 10 for n in lens])
+    if kind == "overlapping_files":
+        # a second flush overwrites a third of the keys: under dedup the older
+        # versions are losers, spread over every window's inside, edges and outside
+        codes, ts = np.repeat(np.arange(6), 80), np.tile(np.arange(80) * 10 + 100, 6)
+        again = rng.random(len(ts)) < 0.35
+        return np.r_[codes, codes[again]], np.r_[ts, ts[again]]
+    assert kind == "random"
+    return rng.integers(0, 30, 3000), rng.integers(-200, 200, 3000) * 5
+
+
+def _probe_windows(ts, rng):
+    """Windows that start or end exactly on a sample (lo inclusive, hi
+    exclusive) or beside one, before the first and after the last sample,
+    empty and inverted ones, and the whole plane."""
+    import numpy as np
+
+    lo, hi = int(ts.min()), int(ts.max())
+    out = [(lo - 50, lo), (lo - 50, lo + 1), (hi, hi + 1), (hi + 1, hi + 50),
+           (lo - 5, hi + 5), (lo, hi), (lo + 1, hi + 1)]
+    for _ in range(12):
+        s1, s2 = sorted(int(x) for x in rng.choice(ts, 2))
+        out += [(s1, s2), (s1, s2 + 1), (s1 + 1, s2), (s1 - 1, s2 - 1),
+                (s1, s1), (s1, s1 + 1), (s2, s1)]
+    return out
+
+
+@pytest.mark.parametrize("kind,dedup", [
+    ("regular", False), ("duplicate_ts", False), ("duplicate_ts", True),
+    ("one_row_runs", False), ("merged_series", False), ("merged_series", True),
+    ("ragged", False), ("overlapping_files", True), ("overlapping_files", False),
+    ("random", False), ("random", True),
+])
+def test_window_rows_from_run_bounds_equal_the_mask(kind, dedup):
+    """The rows of a window found by two searches per ascending run of the
+    sorted ts plane are, in count and element for element, those of the
+    mask over the whole plane that `ensure_window_tile` used to build."""
+    import numpy as np
+
+    from greptimedb_tpu.parallel.tile_cache import TileCacheManager, _range_rows
+
+    rng = np.random.default_rng(32)
+    codes, ts = _probe_plane(kind, rng)
+    entry = _host_entry(codes.astype(np.int32), ts.astype(np.int64))
+    cache = TileCacheManager(budget_bytes=1 << 28)
+    ts_sorted = entry.sorted_host["ts"]
+    if kind == "merged_series":
+        assert len(cache._ts_runs(entry, "ts")) == 1
+    if kind == "one_row_runs":
+        assert len(cache._ts_runs(entry, "ts")) == entry.num_rows
+    if dedup:
+        assert cache.ensure_dedup_keep(entry)
+        if kind == "overlapping_files":
+            assert 0 < np.count_nonzero(~entry.keep_host) < len(ts) // 3
+    counted = metrics.TILE_WINDOW_COUNTED.get()
+    for lo, hi in _probe_windows(ts_sorted, rng):
+        mask = (ts_sorted >= lo) & (ts_sorted < hi)
+        if dedup:
+            mask &= entry.keep_host
+        first, end, n = cache._window_ranges(entry, (lo, hi), "ts", dedup)
+        assert n == np.count_nonzero(mask), (lo, hi)
+        rows = _range_rows(first, end, entry.keep_host if dedup else None)
+        assert rows.dtype == np.int32
+        np.testing.assert_array_equal(rows, np.flatnonzero(mask), err_msg=str((lo, hi)))
+    assert metrics.TILE_WINDOW_COUNTED.get() == counted  # a probe's, not a search's
+
+
+@pytest.mark.parametrize("floor", ["below", "above"])
+def test_window_tile_min_rows_floor(floor, monkeypatch):
+    """A plane below `_WINDOW_TILE_MIN_ROWS` is not probed at all; one at
+    the floor is counted, and the tile built from the ranges holds the
+    rows `flatnonzero` of the mask gave, in their order."""
+    import numpy as np
+
+    from greptimedb_tpu.parallel.tile_cache import TileCacheManager
+
+    codes = np.repeat(np.arange(6, dtype=np.int32), 4000)
+    ts = np.tile(np.arange(4000, dtype=np.int64) * 10, 6)
+    entry = _host_entry(codes, ts)
+    monkeypatch.setattr(
+        TileCacheManager, "_WINDOW_TILE_MIN_ROWS",
+        len(ts) + (1 if floor == "below" else 0),
+    )
+    cache = TileCacheManager(budget_bytes=1 << 30)
+    counted, builds = metrics.TILE_WINDOW_COUNTED.get(), metrics.TILE_WINDOW_BUILDS.get()
+    src = cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
+    if floor == "below":
+        assert src is None and not entry.window_tiles and entry.ts_run_starts is None
+        assert metrics.TILE_WINDOW_COUNTED.get() == counted
+        return
+    assert metrics.TILE_WINDOW_COUNTED.get() == counted + 1
+    assert metrics.TILE_WINDOW_BUILDS.get() == builds + 1
+    ts_sorted = entry.sorted_host["ts"]
+    want = ts_sorted[np.flatnonzero((ts_sorted >= 5000) & (ts_sorted < 15000))]
+    wt = entry.window_tiles[(5000, 15000, False)]
+    assert wt["rows"] == len(want) == 6000 and len(src) == len(wt["valid"])
+    got = np.concatenate([np.asarray(c) for c in wt["cols"]["ts"]])
+    np.testing.assert_array_equal(got[: len(want)], want)
+    valid = np.concatenate([np.asarray(c) for c in wt["valid"]])
+    assert valid[: len(want)].all() and not valid[len(want):].any()
+    # the same window again is a lookup: neither counted nor built
+    assert cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
+    assert metrics.TILE_WINDOW_COUNTED.get() == counted + 1
+    assert metrics.TILE_WINDOW_BUILDS.get() == builds + 1
+
+
+def test_window_tile_declines_by_count_without_a_pass_over_the_plane(db, monkeypatch):
+    """A window over most of the entry declines on the count the run
+    bounds give: the probe is counted, nothing is built, the pass notes
+    the decline as before, and `np.flatnonzero` is never reached inside
+    the probe (the run bounds were built by the first probe, as a run's
+    warm-up builds them)."""
+    import numpy as np
+
+    from greptimedb_tpu.parallel.tile_cache import TileCacheManager
+    from greptimedb_tpu.query import passes
+
+    db.config.query.disabled_passes = ("cold_host_serve",)  # device-path mechanics under test
+    db.config.query.fallback_to_cpu = False  # a raise inside the probe must surface
+    monkeypatch.setattr(TileCacheManager, "_WINDOW_TILE_MIN_ROWS", 1 << 14)
+    _mk_cpu_table(db)
+    n = 1 << 16
+    db.insert_rows("cpu", pa.table({
+        "host": pa.array(np.repeat([f"h{i}" for i in range(8)], n // 8)),
+        "region": pa.array(np.repeat("r0", n)),
+        "ts": pa.array(np.tile(np.arange(n // 8, dtype=np.int64) * 1000, 8), pa.timestamp("ms")),
+        "usage_user": pa.array(np.random.default_rng(9).uniform(0, 100, n)),
+        "usage_system": pa.array(np.zeros(n)),
+    }))
+    db.sql("ADMIN flush_table('cpu')")
+    q = ("SELECT host, count(*) AS c, avg(usage_user) AS a FROM cpu"
+         " WHERE ts >= {} AND ts < 8000000 GROUP BY host ORDER BY host")
+    db.sql_one(q.format(100000))  # builds the entry and, in its probe, the run bounds
+    entry = next(iter(db.query_engine.tile_cache._super.values()))
+    assert len(entry.ts_run_starts) == 8
+
+    probe = TileCacheManager.ensure_window_tile
+
+    def probe_without_flatnonzero(self, *args, **kwargs):
+        def reached(*_a, **_k):
+            raise AssertionError("np.flatnonzero reached inside the window probe")
+
+        with monkeypatch.context() as m:
+            m.setattr(np, "flatnonzero", reached)
+            return probe(self, *args, **kwargs)
+
+    monkeypatch.setattr(TileCacheManager, "ensure_window_tile", probe_without_flatnonzero)
+    counted, builds = metrics.TILE_WINDOW_COUNTED.get(), metrics.TILE_WINDOW_BUILDS.get()
+    lowered = _tile_count()
+    with passes.use_trace(passes.PassTrace()) as trace:
+        t1 = db.sql_one(q.format(200000))
+    assert metrics.TILE_WINDOW_COUNTED.get() == counted + 1
+    assert metrics.TILE_WINDOW_BUILDS.get() == builds and not entry.window_tiles
+    assert _tile_count() == lowered + 1  # answered by the full-tile scan on the device
+    notes = [d for d in trace.decisions if d.name == "window_tile"]
+    assert [(d.fired, d.why) for d in notes] == [(
+        False,
+        "window covers most of retention (or tile build declined): "
+        "full-tile scan with device masking",
+    )]
+    assert t1.to_pydict()["c"] == [7800] * 8
+
+
 def test_query_deadline_aborts_cpu_scan(db):
     """query.timeout_s bounds a statement cooperatively: a CPU-path scan
     past its deadline raises QueryTimeoutError instead of grinding (the

@@ -364,6 +364,70 @@ def test_window_tiles_survive_disjoint_delta(tmp_path, monkeypatch):
         db.close()
 
 
+def test_window_probe_counts_the_rows_a_delta_added(tmp_path, monkeypatch):
+    """The run bounds a window probe counts from belong to ONE ts plane: a
+    delta extend that lengthens the plane drops them, and the next probe
+    rebuilds them and counts the new rows too."""
+    from greptimedb_tpu.parallel.tile_cache import TileCacheManager
+
+    monkeypatch.setattr(TileCacheManager, "_WINDOW_TILE_MIN_ROWS", 0)
+    probes = []
+    ranges = TileCacheManager._window_ranges
+
+    def recorded(self, entry, window, ts_name, dedup):
+        got = ranges(self, entry, window, ts_name, dedup)
+        ts = np.asarray(entry.sorted_host[ts_name])
+        mask = (ts >= window[0]) & (ts < window[1])
+        if dedup:
+            mask &= entry.keep_host
+        probes.append((got[2], int(np.count_nonzero(mask)), entry.num_rows))
+        return got
+
+    monkeypatch.setattr(TileCacheManager, "_window_ranges", recorded)
+    db = _mk_db(tmp_path, "wp")
+    db.config.query.disabled_passes = ("cold_host_serve",)  # device-path mechanics under test
+    try:
+        db.sql(
+            "CREATE TABLE t (host STRING, region STRING,"
+            " ts TIMESTAMP(3) TIME INDEX, v DOUBLE, w DOUBLE,"
+            " PRIMARY KEY (host, region))"
+        )
+        rng = np.random.default_rng(32)
+        db.insert_rows("t", _batch(rng, 3000, 0, 3000, null_tags=False,
+                                   null_vals=False))
+        db.sql("ADMIN flush_table('t')")
+        # the window holds every row: the probe counts and declines each time
+        wq = (
+            "SELECT host, time_bucket('60s', ts) AS tb, count(*) AS c"
+            " FROM t WHERE ts >= 0 AND ts < 9000000 GROUP BY host, tb"
+        )
+        counted = metrics.TILE_WINDOW_COUNTED.get()
+        db.sql_one(wq)
+        entry = _entry(db)
+        runs = entry.ts_run_starts
+        assert runs is not None and probes[-1][0] == probes[-1][1] == entry.num_rows
+        db.insert_rows("t", _batch(rng, 150, 4000, 4400, null_tags=False,
+                                   null_vals=False))
+        db.sql("ADMIN flush_table('t')")
+        merges = metrics.TILE_DELTA_MERGES.get()
+        t = db.sql_one(wq)
+        assert _entry(db) is entry and metrics.TILE_DELTA_MERGES.get() == merges + 1
+        assert entry.num_rows > probes[0][2], "the delta added no row"
+        assert entry.ts_run_starts is not runs, "run bounds of the old plane reused"
+        assert probes[-1][0] == probes[-1][1] == entry.num_rows
+        assert sum(t["c"].to_pylist()) == entry.num_rows
+        # (after a delta the family's fused build probes the window too)
+        assert metrics.TILE_WINDOW_COUNTED.get() == counted + len(probes) >= counted + 2
+        assert not entry.window_tiles
+        assert entry.host_nbytes == (
+            entry.order.nbytes + entry.file_row_offsets.nbytes
+            + sum(a.nbytes for a in entry.sorted_host.values())
+            + entry.ts_run_starts.nbytes
+        )
+    finally:
+        db.close()
+
+
 def test_lex_merge_positions_matches_stable_lexsort():
     """Property check of the sorted-run merge against numpy's stable
     lexsort over the concatenation — including heavy duplicate keys,
