@@ -25,8 +25,10 @@ One process, default `device.*` settings, the normal entry points:
     abandoned.  Any failed assertion, phase or comparison exits non-zero.
 
 `--chips 4` runs ONLY the mesh path and what it is compared with: the same
-dataset hash-partitioned into 4 regions, `double-groupby-1` and `high-cpu-1`
-with `tile.mesh_devices = 4` against `mesh_devices = 0`.
+dataset hash-partitioned into 4 regions, `double-groupby-1`, `high-cpu-1`,
+`lastpoint` and `groupby-orderby-limit` with `tile.mesh_devices = 4` (each
+warm repetition a mesh dispatch, none degraded or handed to the single chip)
+against `mesh_devices = 0`.
 
 `--rehearse` relaxes exactly one thing, the platform assertion, so the
 script can be rehearsed on the CPU at a tiny size
@@ -563,7 +565,7 @@ class Counters:
     WATCHED = MUST_NOT_MOVE + (
         "TPU_DEVICE_DISPATCHES", "TILE_LOWERED_TOTAL", "TQL_TILE_DISPATCHES",
         "TQL_TILE_COLD_SERVES", "TPU_READBACK_BYTES", "TILE_MESH_DISPATCHES",
-        "TILE_MESH_DEGRADED", "TPU_DEVICE_FINALIZE",
+        "TILE_MESH_DEGRADED", "TILE_MESH_INELIGIBLE", "TPU_DEVICE_FINALIZE",
     )
 
     def __init__(self):
@@ -807,7 +809,9 @@ def smoke_four_chips(ds: Dataset, home: str, chips: int):
         emit({"event": "loaded", **load(db, ds, home, regions=chips)})
         requests = [
             r for r in sql_requests(ds)
-            if r[0] in ("double-groupby-1", "high-cpu-1")
+            if r[0] in (
+                "double-groupby-1", "high-cpu-1", "lastpoint", "groupby-orderby-limit",
+            )
         ]
         meshed = {}
         for name, sql, fold, rtol in requests:
@@ -829,6 +833,8 @@ def smoke_four_chips(ds: Dataset, home: str, chips: int):
                       f"{name} (warm {rep}): no mesh dispatch")
                 check(d["TILE_MESH_DEGRADED"] == 0,
                       f"{name} (warm {rep}): mesh degraded")
+                check(d["TILE_MESH_INELIGIBLE"] == 0,
+                      f"{name} (warm {rep}): handed to the single chip")
                 for k in Counters.MUST_NOT_MOVE:
                     check(d[k] == 0, f"{name} (warm {rep}): {k} moved")
             meshed[name] = table.to_pydict()

@@ -108,7 +108,7 @@ from .executor import (
     compute_partial_states,
     host_last_winners,
 )
-from .mesh import REGION_AXIS
+from .mesh import REGION_AXIS, region_device_index
 
 
 
@@ -231,6 +231,21 @@ def _timed(phase: str):
                 )
 
     return cm()
+
+
+def _window_tile_bytes(entry) -> int:
+    """Device bytes of an entry's window tiles (a copy of the dict's values:
+    callers outside the cache lock race its builds)."""
+    return sum(wt["nbytes"] for wt in list(entry.window_tiles.values()))
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _permuted_chunks(chunks, perm, bounds):
+    """A region's chunks in `perm`'s row order, chunked again by `bounds`.
+    Jitted so that the gather and its index arithmetic are ONE program to
+    compile a dtype and device."""
+    full = jnp.concatenate(chunks)[perm]
+    return [full[a:b] for a, b in bounds]
 
 
 def _chunk_bounds(pad: int, chunk_rows: int = TILE_CHUNK_ROWS) -> list[tuple[int, int]]:
@@ -739,17 +754,64 @@ class TileCacheManager:
         with self._lock:
             self._region_versions[region_id] = manifest_version
 
+    def device_used(self) -> list[int]:
+        """This cache's bytes on each mesh device (tile.mesh_devices > 1),
+        from where chunk_device places an entry's chunks: a region's
+        first chunk on its own mesh slot, further chunks round robin from
+        there, an entry's bytes taken as even over its chunks.  Off the
+        mesh path one sum, `_used`: chunks round-robin over ALL devices
+        there and `budget` has always been held against their total."""
+        mesh_n = self.mesh_devices()
+        if mesh_n <= 1:
+            return [self._used]
+        used = [0] * mesh_n
+        for rid, entry in list(self._super.items()):
+            slots = self._region_slots(rid, mesh_n)
+            for d in slots:
+                used[d] += entry.nbytes // len(slots)
+        return used
+
+    def _region_slots(self, region_id: int, mesh_n: int) -> list[int]:
+        """The mesh slot of each chunk of a region's entry (chunk_device);
+        of a region with no entry yet, the slot of its first chunk."""
+        if not passes.enabled("chunk_placement", self.config):
+            return [0]
+        base = region_device_index(region_id, mesh_n)
+        entry = self._super.get(region_id)
+        chunks = len(_chunk_bounds(entry.pad if entry else 0, self.chunk_rows))
+        return [(base + i) % mesh_n for i in range(chunks)]
+
+    def _over_locked(
+        self, limit: int, est: int = 0, est_regions: set[int] = frozenset(),
+    ) -> set[int]:
+        """The regions with planes on a device that holds more than
+        `limit` bytes of this cache, `est` bytes about to land beside
+        `est_regions` counted in: empty where the budget holds.
+        `budget` is ONE chip's share (query.tile_cache_mb, sized against a
+        16 GB chip) and under the mesh path every chip is held to it by
+        itself, so four one-region tables that all hash to chip 0 cannot
+        fill it fourfold while a table spread over four chips may hold the
+        share of each."""
+        used = self.device_used()
+        if len(used) == 1:
+            return set(self._super) if used[0] + est > limit else set()
+        for rid in est_regions:
+            for d in set(self._region_slots(rid, len(used))):
+                used[d] += est
+        full = {d for d, n in enumerate(used) if n > limit}
+        return {
+            rid for rid in self._super
+            if full.intersection(self._region_slots(rid, len(used)))
+        }
+
     def _reserve_locked(self, est: int, pinned_regions: set[int]):
-        """Make room for `est` bytes ABOUT to allocate on device: evict as
-        if the budget were already reduced by them.  Every ensure_* path
-        that allocates must reserve first — charging after allocation let
-        transients overshoot HBM at TSBS 3-day scale."""
-        if est and self._used > self.budget - est:
-            saved, self.budget = self.budget, max(self.budget - est, 0)
-            try:
-                self._evict_locked(pinned_regions)
-            finally:
-                self.budget = saved
+        """Make room for `est` bytes ABOUT to allocate on device beside
+        `pinned_regions`' planes: evict as if they were already there.
+        Every ensure_* path that allocates must reserve first — charging
+        after allocation let transients overshoot HBM at TSBS 3-day
+        scale."""
+        if est and self._over_locked(self.budget, est, pinned_regions):
+            self._evict_locked(pinned_regions, est=est)
 
     def release_unneeded(self, entry: _SuperTiles, keep_cols: set[str]):
         """Drop THIS entry's device planes for columns the current query
@@ -820,11 +882,7 @@ class TileCacheManager:
                     entry.perm = None
                 entry.nbytes -= freed
                 self._used -= freed
-            saved, self.budget = self.budget, self.budget // 2
-            try:
-                self._evict_locked(pinned_regions)
-            finally:
-                self.budget = saved
+            self._evict_locked(pinned_regions, self.budget // 2)
 
     def probe_hbm(self, headroom: float = 0.9) -> int:
         """Startup allocation probe (admission.hbm_probe): measure REAL
@@ -958,6 +1016,7 @@ class TileCacheManager:
         beside the tile cache's budget loop; shared by
         information_schema.device_memory and /debug/tile."""
         rows: list[dict] = []
+        used = self.device_used()
         for i, dev in enumerate(self.devices):
             try:
                 stats = device_health.supervised_call(
@@ -967,14 +1026,16 @@ class TileCacheManager:
                 ) or {}
             except Exception:  # noqa: BLE001 — CPU devices have no stats
                 stats = {}
+            # off the mesh path one sum, shown on every row as ever
+            in_use = used[0] if len(used) == 1 else (used[i] if i < len(used) else 0)
             rows.append({
                 "device": i,
                 "device_kind": str(dev),
                 "bytes_in_use": int(stats.get("bytes_in_use", 0)),
                 "bytes_limit": int(stats.get("bytes_limit", 0)),
                 "tile_budget": int(self.budget),
-                "tile_in_use": int(self._used),
-                "tile_headroom": int(self.budget - self._used),
+                "tile_in_use": int(in_use),
+                "tile_headroom": int(self.budget - in_use),
                 "chunk_rows": int(self.chunk_rows),
                 "degrade_rounds": int(self.degrade_rounds),
             })
@@ -1298,8 +1359,6 @@ class TileCacheManager:
             return devs[0]
         mesh_n = self.mesh_devices()
         if mesh_n > 0 and region_id is not None:
-            from .mesh import region_device_index
-
             base = region_device_index(region_id, mesh_n)
             return devs[(base + i) % mesh_n]
         return devs[i % len(devs)]
@@ -1342,7 +1401,20 @@ class TileCacheManager:
         flight_recorder.add_bytes(up=int(buf.nbytes))
         return out
 
-    def _evict_locked(self, pinned_regions: set[int]):
+    def _evict_locked(
+        self, pinned_regions: set[int], limit: int | None = None, est: int = 0,
+    ):
+        """Evict until no device holds more than `limit` (default: the
+        budget) of this cache's bytes, `est` bytes about to land beside
+        `pinned_regions` counted in; only from the regions that lie on a
+        device over it (`_over_locked`)."""
+        if limit is None:
+            limit = self.budget
+
+        def still_over():
+            return self._over_locked(limit, est, pinned_regions)
+
+        over = still_over()
         # Re-derivable planes strip FIRST, and INCREMENTALLY — per limb
         # column, then per window tile — stopping as soon as the budget
         # holds.  Round 4 cleared every limb plane and window tile of an
@@ -1354,7 +1426,7 @@ class TileCacheManager:
         # whole super-tiles cost a Parquet decode — evict in that order.
         for entry in list(self._super.values()):
             for key in list(entry.limb_cols):
-                if self._used <= self.budget:
+                if entry.region_id not in over:
                     break
                 freed = sum(
                     int(l.nbytes) + int(s.nbytes)
@@ -1362,23 +1434,22 @@ class TileCacheManager:
                 )
                 entry.nbytes -= freed
                 self._used -= freed
+                over = still_over()
         for entry in list(self._super.values()):
             for key in list(entry.window_tiles):
-                if self._used <= self.budget:
+                if entry.region_id not in over:
                     break
                 freed = entry.window_tiles.pop(key)["nbytes"]
                 entry.nbytes -= freed
                 self._used -= freed
-        while self._used > self.budget and len(self._super) > len(pinned_regions):
-            for rid in list(self._super):
-                if rid not in pinned_regions:
-                    dropped = self._super.pop(rid)
-                    self._used -= dropped.nbytes
-                    self._host_used -= dropped.host_nbytes
-                    metrics.TILE_CACHE_EVICTIONS.inc()
-                    break
-            else:
-                break
+                over = still_over()
+        while over - pinned_regions:
+            rid = next(r for r in self._super if r in over and r not in pinned_regions)
+            dropped = self._super.pop(rid)
+            self._used -= dropped.nbytes
+            self._host_used -= dropped.host_nbytes
+            metrics.TILE_CACHE_EVICTIONS.inc()
+            over = still_over()
         while self._host_used > self.host_budget and len(self._host) > 0:
             key, entry = next(iter(self._host.items()))
             self._host_used -= entry.nbytes
@@ -2211,7 +2282,10 @@ class TileCacheManager:
         with self._lock:
             for entry in entries:
                 for tag in tag_cols:
-                    if tag not in entry.epochs:
+                    # an entry read back from its persisted file set has
+                    # the stored epochs and no device plane yet: the lazy
+                    # upload repairs that one when it lands
+                    if tag not in entry.epochs or tag not in entry.cols:
                         continue
                     perm = dictionary.perm_since(tag, entry.epochs[tag])
                     if perm is not None:
@@ -2243,6 +2317,7 @@ class TileCacheManager:
         planes carry the last-write-wins keep mask (ensure_dedup_keep
         must have run)."""
         perm = self.ensure_perm(entry, ts_name)
+        home = next(iter(perm.devices()))
         bounds = _chunk_bounds(entry.pad, self.chunk_rows)
         added = 0
         with self._lock:
@@ -2259,13 +2334,14 @@ class TileCacheManager:
             self._reserve_locked(est, {entry.region_id})
 
             def permuted_chunks(chunks):
-                # time-major copies live on device 0: the ts-ascending
-                # gather is a global permutation, which has no chunk-local
-                # form (multi-device stays with the pk-sorted path)
+                # time-major copies live on ONE device, the region's first
+                # (the perm's): the ts-ascending gather is a permutation of
+                # the whole region, which has no chunk-local form.  Under
+                # the mesh path that is the region's own mesh device, so a
+                # one-chunk region's copies never leave its chip
                 if len(self.devices) > 1:
-                    chunks = [jax.device_put(x, self.devices[0]) for x in chunks]
-                full = jnp.concatenate(chunks)[perm]
-                return [full[a:b] for a, b in bounds]
+                    chunks = [jax.device_put(x, home) for x in chunks]
+                return _permuted_chunks(tuple(chunks), perm, tuple(bounds))
 
             if entry.tm_valid is None:
                 entry.tm_valid = permuted_chunks(entry.valid)
@@ -2385,6 +2461,7 @@ class TileCacheManager:
     # so a window over it costs two searches, not a pass over the plane
     _WINDOW_TILE_MAX_COVER = 0.5
     _WINDOW_TILE_MIN_ROWS = 1 << 22  # below this the full scan is cheap
+    _WINDOW_TILE_GRID = 1 << 22  # a tile's rows pad to a multiple of this
 
     def ensure_window_tile(
         self,
@@ -2457,11 +2534,11 @@ class TileCacheManager:
                 missing_limbs = []
 
         n = snap["rows"] if snap is not None else -1
-        idx = None
+        ranges = None
         if missing:
             if dedup and not self.ensure_dedup_keep(entry):
                 return None
-            first, end, n = self._window_ranges(entry, window, ts_name, dedup)
+            *ranges, n = self._window_ranges(entry, window, ts_name, dedup)
             metrics.TILE_WINDOW_COUNTED.inc()
             if snap is not None and n != snap["rows"]:
                 # row set changed under the same epoch (shouldn't happen:
@@ -2471,7 +2548,26 @@ class TileCacheManager:
                 missing_limbs = []
             if n == 0 or n > entry.num_rows * self._WINDOW_TILE_MAX_COVER:
                 return None
-            idx = _range_rows(first, end, entry.keep_host if dedup else None)
+        with tracing.stage("tile.window_build", region=entry.region_id, rows=n):
+            return self._build_window_tile(
+                entry, key, need_cols, limb_cols, dict_epoch,
+                snap, missing, missing_limbs, n, ranges,
+            )
+
+    def _build_window_tile(
+        self, entry: _SuperTiles, key: tuple, need_cols: set[str],
+        limb_cols: set[str], dict_epoch: int, snap: dict | None,
+        missing: list[str], missing_limbs: list[str], n: int,
+        ranges: list | None,
+    ):
+        """The build half of `ensure_window_tile` (stage `tile.window_build`):
+        gather the `n` in-window rows `ranges` bounds (`key[2]`: less the
+        dedup losers) for each `missing` column, upload, quantize, and
+        commit (or extend) the tile."""
+        idx = (
+            _range_rows(*ranges, entry.keep_host if key[2] else None)
+            if ranges is not None else None
+        )
         # pad to a 2^22 grid: bounded compile-shape variety, chunks stay
         # BLOCK_ROWS multiples.  Window tiles dispatch at 2^22-row chunks
         # (not the 2^24 super-tile chunk): a 10-column limb program over a
@@ -2480,7 +2576,7 @@ class TileCacheManager:
         # round-4 driver dg-all OOM.  Equal-size chunks also mean ONE
         # compile shape per tile, and the size is stable across column
         # extensions (cached planes and new planes must chunk identically).
-        grid = 1 << 22
+        grid = self._WINDOW_TILE_GRID
         pad = -(-n // grid) * grid
         bounds = _chunk_bounds(pad, min(self.chunk_rows, grid))
 
@@ -2808,7 +2904,9 @@ class TileCacheManager:
                 perm[:n] = np.argsort(
                     np.asarray(entry.sorted_host[ts_name][:n]), kind="stable"
                 )
-                entry.perm = jax.device_put(perm, self.devices[0])
+                entry.perm = jax.device_put(
+                    perm, self.chunk_device(0, entry.region_id)
+                )
                 entry.nbytes += entry.pad * 4
                 if self._super.get(entry.region_id) is entry:
                     self._used += entry.pad * 4
@@ -3550,21 +3648,15 @@ def _mesh_runs(device_sources) -> list[list]:
 
 
 def _stack_mesh_inputs(mesh, devices, sources, n_local):
-    """Stack one run's sources into global [D, S, ...] arrays sharded
-    over the `regions` axis with zero cross-device movement for sources
-    already resident on their mesh device (chunk placement co-locates
-    them); off-mesh sources hop once.  Devices short of S sources pad
-    with all-invalid dummies (valid=False ⇒ identity states).  Returns
-    (global_data, positions) where positions[k] = (device, local slot)
-    of global source k — the static fold order.
-
-    The per-dispatch jnp.stack DOES copy each device's local planes once
-    (HBM-bandwidth, device-local — no link traffic).  Deliberately NOT
-    cached: a resident stacked copy would permanently double every warm
-    entry's HBM footprint (the budget's scarcest resource), while the
-    transient copy lives only for the dispatch and costs microseconds
-    per GB next to the aggregation pass it feeds.  Revisit if profiles
-    ever show the stack dominating a warm mesh dispatch."""
+    """Assemble one run's sources into `n_local` global pytrees, slot s
+    holding every device's s-th source: each leaf a global array whose
+    leading axis is sharded over the `regions` axis, MADE OF the planes
+    where they lie (make_array_from_single_device_arrays: no copy, no
+    device op, nothing to compile).  Chunk placement co-locates a source
+    with its mesh device; an off-mesh source hops once.  Devices short of
+    S sources pad with all-invalid dummies (valid=False ⇒ identity
+    states).  Returns (slots, positions) where positions[k] = (device,
+    local slot) of global source k — the static fold order."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .mesh import REGION_AXIS
@@ -3573,7 +3665,7 @@ def _stack_mesh_inputs(mesh, devices, sources, n_local):
     dev_index = {d: i for i, d in enumerate(devices)}
     per_dev: list[list] = [[] for _ in range(n_dev)]
     positions: list[tuple[int, int]] = []
-    for k, (cols, valid, nulls, _perm, limbs) in enumerate(sources):
+    for cols, valid, nulls, _perm, limbs in sources:
         d = dev_index.get(
             next(iter(valid.devices())) if hasattr(valid, "devices") else None
         )
@@ -3581,39 +3673,46 @@ def _stack_mesh_inputs(mesh, devices, sources, n_local):
             d = min(range(n_dev), key=lambda i: (len(per_dev[i]), i))
         positions.append((d, len(per_dev[d])))
         per_dev[d].append((cols, valid, nulls, limbs))
-    template = per_dev[positions[0][0]][0] if sources else None
-    stacked = []
-    for d, dev in enumerate(devices):
-        srcs = list(per_dev[d])
-        while len(srcs) < n_local:
-            srcs.append(
-                jax.tree_util.tree_map(
-                    lambda l: jax.device_put(jnp.zeros(l.shape, l.dtype), dev),
-                    template,
-                )
-            )
-        moved = [jax.device_put(s, dev) for s in srcs]
-        stacked.append(
-            jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *moved)
-        )
-    leaves0, treedef = jax.tree_util.tree_flatten(stacked[0])
-    per_dev_leaves = [jax.tree_util.tree_flatten(s)[0] for s in stacked]
+    template = per_dev[positions[0][0]][0]
+    treedef = jax.tree_util.tree_structure(template)
+
+    def local_leaves(d, s):
+        dev = devices[d]
+        if s < len(per_dev[d]):
+            leaves = jax.tree_util.tree_leaves(per_dev[d][s])
+            if all(
+                getattr(l, "devices", None) and l.devices() == {dev}
+                for l in leaves
+            ):
+                return leaves
+            make = lambda: [jax.device_put(l, dev) for l in leaves]  # noqa: E731
+        else:
+            make = lambda: [  # noqa: E731
+                jax.device_put(np.zeros(l.shape, l.dtype), dev)
+                for l in jax.tree_util.tree_leaves(template)
+            ]
+        # the caller's thread is outside the supervisor: the rare hop and
+        # the dummies are uploads like any other
+        return device_health.supervised_call("upload", make, devices=(d,))
+
     sharding = NamedSharding(mesh, P(REGION_AXIS))
-    out_leaves = []
-    for i, leaf0 in enumerate(leaves0):
-        shards = [per_dev_leaves[d][i][None] for d in range(n_dev)]
-        out_leaves.append(
+    slots = []
+    for s in range(n_local):
+        by_dev = [local_leaves(d, s) for d in range(n_dev)]
+        slots.append(jax.tree_util.tree_unflatten(treedef, [
             jax.make_array_from_single_device_arrays(
-                (n_dev,) + tuple(leaf0.shape), sharding, shards
+                (n_dev * leaf.shape[0],) + tuple(leaf.shape[1:]), sharding,
+                [by_dev[d][i] for d in range(n_dev)],
             )
-        )
-    return jax.tree_util.tree_unflatten(treedef, out_leaves), tuple(positions)
+            for i, leaf in enumerate(by_dev[0])
+        ]))
+    return tuple(slots), tuple(positions)
 
 
 @functools.lru_cache(maxsize=64)
-def _mesh_merge_program(plan, nullable_cols, mesh, n_local, positions):
+def _mesh_merge_program(plan, nullable_cols, mesh, positions):
     """jit'd shard_map over the `regions` mesh computing per-source
-    partial AggStates (this device's n_local stacked sources) and merging
+    partial AggStates (this device's slots of `_stack_mesh_inputs`) and merging
     them with collectives — see the module-section comment above for the
     order contract.  Hash plans thread a LOCAL key table per device, then
     merge by keyed scatter before/through the collective: the gathered
@@ -3632,33 +3731,32 @@ def _mesh_merge_program(plan, nullable_cols, mesh, n_local, positions):
     real = positions
 
     def per_device(data, dyn):
-        cols, valid, nulls, limbs = data
         local_states = []
         table = (
             jnp.full((plan.hash_slots,), HASH_EMPTY, jnp.int64)
             if is_hash
             else None
         )
-        for s in range(n_local):
-            src_cols = {k: v[0, s] for k, v in cols.items()}
-            src_nulls = {k: v[0, s] for k, v in nulls.items()}
-            src_limbs = {
-                k: jax.tree_util.tree_map(lambda l: l[0, s], v)
-                for k, v in limbs.items()
-            }
-            if is_hash:
-                st, table = compute_partial_states(
-                    plan, src_cols, valid[0, s], src_nulls, dyn, None,
-                    count_cols=nullable_cols, limbs=src_limbs,
-                    hash_table=table,
-                )
-            else:
-                st = compute_partial_states(
-                    plan, src_cols, valid[0, s], src_nulls, dyn, None,
-                    count_cols=nullable_cols, limbs=src_limbs,
-                )
+        for cols, valid, nulls, limbs in data:
+            # a slot's leaves arrive as this device's own planes: the
+            # shard of the leading axis IS the resident source
+            with jax.named_scope("mesh_partial"):
+                if is_hash:
+                    st, table = compute_partial_states(
+                        plan, cols, valid, nulls, dyn, None,
+                        count_cols=nullable_cols, limbs=limbs,
+                        hash_table=table,
+                    )
+                else:
+                    st = compute_partial_states(
+                        plan, cols, valid, nulls, dyn, None,
+                        count_cols=nullable_cols, limbs=limbs,
+                    )
             local_states.append(st)
+        with jax.named_scope("mesh_merge"):
+            return merge(local_states, table)
 
+    def merge(local_states, table):
         def gathered(sts, get):
             # [D, S, rows]: every device sees every source's partial
             return jax.lax.all_gather(
@@ -3853,22 +3951,28 @@ def _mesh_hash_cross_program(plan):
     return jax.jit(cross)
 
 
-def _mesh_run(plan, nullable_cols, mesh, device_sources, pdyn, hv, program):
-    """Execute one query's sources on the mesh: one shard_map dispatch
-    per shape run, cross-run pairwise merge, then the single-chip
-    program's OWN final_jit on the first mesh device (device-finalize
-    once, post-merge).  Returns the packed result buffers exactly as the
-    single-chip run_all would."""
-    devices = [mesh.devices.reshape(-1)[i] for i in range(mesh.devices.size)]
-    runs = _mesh_runs(device_sources)
+def _mesh_stage(mesh, device_sources) -> list[tuple]:
+    """The host half of a mesh dispatch, on the caller's thread (stage
+    `tile.mesh_stack`): split the sources into shape runs and assemble
+    each run's sharded inputs.  Raises _MeshIneligible."""
+    devices = list(mesh.devices.reshape(-1))
+    return [
+        _stack_mesh_inputs(mesh, devices, run, -(-len(run) // len(devices)))
+        for run in _mesh_runs(device_sources)
+    ]
+
+
+def _mesh_run(plan, nullable_cols, mesh, staged, pdyn, hv, program):
+    """Execute one query's staged sources (`_mesh_stage`) on the mesh: one
+    shard_map dispatch per shape run, cross-run pairwise merge, then the
+    single-chip program's OWN final_jit on the first mesh device
+    (device-finalize once, post-merge).  Returns the packed result
+    buffers exactly as the single-chip run_all would."""
+    devices = list(mesh.devices.reshape(-1))
     merged = None
     table_keys = None
-    for sources in runs:
-        n_local = -(-len(sources) // len(devices))
-        data, positions = _stack_mesh_inputs(mesh, devices, sources, n_local)
-        prog = _mesh_merge_program(
-            plan, nullable_cols, mesh, n_local, positions
-        )
+    for data, positions in staged:
+        prog = _mesh_merge_program(plan, nullable_cols, mesh, positions)
         out = prog(data, pdyn)
         if plan.agg_strategy == "hash":
             states, keys = out
@@ -4808,9 +4912,13 @@ class TileExecutor:
                 else []
             )
             est_dev = 0
+            # ... and by mesh slot: under the mesh path a region lies on
+            # its own chip, and the budget is every chip's own share
+            mesh_n = self.cache.mesh_devices()
+            est_slot: dict[int, int] = {}
             total_rows = 0
             win_rows = 0
-            for _region, metas_i, _mems in region_sources:
+            for region_i, metas_i, _mems in region_sources:
                 rows_i = sum(m.num_rows for m in metas_i)
                 if not rows_i:
                     continue
@@ -4823,6 +4931,11 @@ class TileExecutor:
                 per_row += 8 * len(device_value_cols)
                 per_row += 8 * len(limb_est)
                 est_dev += padded_size(rows_i) * per_row
+                slot = (
+                    region_device_index(region_i.region_id, mesh_n)
+                    if mesh_n > 1 else 0
+                )
+                est_slot[slot] = est_slot.get(slot, 0) + padded_size(rows_i) * per_row
             threshold = getattr(self.config, "tile_stream_threshold", 0.6)
             # A bounded window that the compact window-tile path can serve
             # (cover under ~half the retention) manages its own HBM —
@@ -4838,7 +4951,7 @@ class TileExecutor:
                 and total_rows > 0
                 and win_rows <= 0.55 * total_rows
             )
-            if est_dev > threshold * self.cache.budget and not window_served:
+            if max(est_slot.values(), default=0) > threshold * self.cache.budget and not window_served:
                 # the streamed path releases each region's planes right
                 # after folding its partials: its fetches must stay
                 # eager even under a batch leader's deferred-fetch scope
@@ -5060,6 +5173,9 @@ class TileExecutor:
             # key table; shape-only precompile doesn't model it
             and self.cache._tile_opt("pipelined_build", True)
             and passes.enabled("pipelined_build", self.config)
+            # it compiles the single-chip partial program, which a mesh
+            # dispatch never runs
+            and self.cache.mesh_devices() == 0
         ):
             self._precompile_async(
                 plan, fspec, super_entries[0], dyn_host,
@@ -5159,16 +5275,26 @@ class TileExecutor:
                     )
                     if up is None:
                         return None
+                    # a tag plane uploaded HERE (its device copy released by
+                    # release_unneeded, or never made) comes from the
+                    # persisted codes at their stored epoch, after phase
+                    # B's repair has run: gather it forward now, or this
+                    # dispatch groups by stale codes
+                    self.cache.repair_super([up], ctx.dictionary, all_tag_cols)
                     if up is not s:
                         # entry was evicted + rebuilt mid-query: adopt the
                         # live object (and re-derive its dedup plane)
                         s = up
                         if dedup and not self.cache.ensure_dedup_keep(s):
                             return None
-                if s.nbytes > self.cache.budget // 2:
+                if s.nbytes - _window_tile_bytes(s) > self.cache.budget // 2:
                     # one-entry deployments: make room for THIS query's
                     # planes by dropping the entry's own unused columns
-                    # (whole-entry eviction can't, the entry is pinned)
+                    # (whole-entry eviction can't, the entry is pinned).
+                    # Window tiles do not count: the budget's eviction
+                    # sheds those, and with them counted a region whose
+                    # windows pile up drops and re-uploads its tag planes
+                    # with every other query
                     self.cache.release_unneeded(s, need_cols)
                 if plan.time_major:
                     cols, valid, nulls = self.cache.ensure_time_major(
@@ -5328,7 +5454,7 @@ class TileExecutor:
             # multi-chip first (tile.mesh_devices > 0): the same sources
             # under shard_map with collective merge; ANY failure there
             # degrades to the single-chip dispatch below, never an error
-            packed = self._mesh_attempt(
+            packed, off_mesh = self._mesh_attempt(
                 attempt_plan, nullable_cols, device_sources, dyn, ctx,
                 program,
             )
@@ -5343,6 +5469,7 @@ class TileExecutor:
                         strategy=attempt_plan.agg_strategy,
                         acc=attempt_plan.acc_dtype,
                         mesh_devices=0,
+                        **({"mesh_ineligible": off_mesh} if off_mesh else {}),
                     ) as disp:
                         packed = device_health.supervised_call(
                             "dispatch",
@@ -5727,18 +5854,22 @@ class TileExecutor:
         self, attempt_plan, nullable_cols, device_sources, dyn, ctx, program,
     ):
         """Try the multi-chip shard_map dispatch (tile.mesh_devices > 0).
-        Returns the packed result buffers, or None to run the single-chip
-        dispatch instead — shape ineligible, pass disabled, or ANY
-        failure in the collective program (the degrade contract: a broken
-        mesh must never fail a query the single chip can answer)."""
+        Returns (packed result buffers, None), or (None, why) to run the
+        single-chip dispatch instead — shape ineligible, pass disabled, or
+        ANY failure in the collective program (the degrade contract: a
+        broken mesh must never fail a query the single chip can answer).
+        `why` is None with the mesh path off; else the single-chip
+        `tile.dispatch` carries it, and a counter moves: TILE_MESH_DEGRADED
+        for a failure, TILE_MESH_INELIGIBLE for the rest."""
         mesh_n = self.cache.mesh_devices()
         if mesh_n <= 0:
-            return None
+            return None, None
         if not passes.enabled("mesh_dispatch", self.config):
             passes.note(
                 "mesh_dispatch", False, "pass disabled: single-chip dispatch"
             )
-            return None
+            metrics.TILE_MESH_INELIGIBLE.inc()
+            return None, "mesh_dispatch pass disabled"
         pdyn = {
             k: dyn[k]
             for k in ("filter_values", "bucket_origin", "bucket_interval")
@@ -5759,17 +5890,21 @@ class TileExecutor:
                 mesh_devices=mesh_n,
                 shard_axis=REGION_AXIS,
             ) as disp:
-                # supervised with the mesh's device slots as the blast
-                # radius; shape-ineligibility is a benign verdict, not a
-                # device error, so it never feeds the breaker
+                # shape-ineligibility is a benign verdict, not a device
+                # error: it is raised here, outside the supervisor, so it
+                # never feeds the breaker
+                with tracing.stage(
+                    "tile.mesh_stack", sources=len(device_sources)
+                ):
+                    staged = _mesh_stage(mesh, device_sources)
+                # supervised with the mesh's device slots as the blast radius
                 packed = device_health.supervised_call(
                     "mesh",
                     lambda: _mesh_run(
-                        attempt_plan, nullable_cols, mesh, device_sources,
+                        attempt_plan, nullable_cols, mesh, staged,
                         pdyn, hv, program,
                     ),
                     devices=tuple(range(mesh_n)),
-                    countable=lambda e: not isinstance(e, _MeshIneligible),
                 )
             _record_dispatch(disp, attempt_plan, mesh_devices=mesh_n)
             metrics.TILE_MESH_DISPATCHES.inc()
@@ -5781,14 +5916,15 @@ class TileExecutor:
                 "post-merge",
                 devices=mesh_n, sources=len(device_sources),
             )
-            return packed
+            return packed, None
         except QueryTimeoutError:
             raise  # the deadline owns the query, mesh or not
         except _MeshIneligible as mi:
             passes.note(
                 "mesh_dispatch", False, f"{mi}: single-chip dispatch"
             )
-            return None
+            metrics.TILE_MESH_INELIGIBLE.inc()
+            return None, str(mi)
         except Exception as exc:  # noqa: BLE001 — degrade, never fail
             metrics.TILE_MESH_DEGRADED.inc()
             flight_recorder.flag("mesh_degraded")
@@ -5806,7 +5942,7 @@ class TileExecutor:
                 f"collective failure ({type(exc).__name__}): degraded to "
                 "the single-chip dispatch",
             )
-            return None
+            return None, f"degraded: {type(exc).__name__}"
 
     # -- helpers -------------------------------------------------------------
     @staticmethod

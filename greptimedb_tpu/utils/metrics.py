@@ -354,6 +354,12 @@ TILE_MESH_DEGRADED = REGISTRY.counter(
     "Mesh tile dispatches that failed (collective error / OOM) and "
     "degraded to the single-chip path",
 )
+TILE_MESH_INELIGIBLE = REGISTRY.counter(
+    "greptime_tile_mesh_ineligible_total",
+    "Tile dispatches handed to the single chip while tile.mesh_devices > 0: "
+    "a source shape the mesh program does not express, or the mesh_dispatch "
+    "pass disabled (the reason is the tile.dispatch span's mesh_ineligible)",
+)
 TPU_DEVICE_FETCHES = REGISTRY.counter(
     "greptime_tpu_device_fetches_total",
     "Device->host result fetches (one per lowered query attempt)",
@@ -830,8 +836,12 @@ STAGE_SELF_S_QUERY_CPU = _stage_self_s("query.cpu", "CPU engine, fallback includ
 STAGE_SELF_S_TILE_BUILD = _stage_self_s("tile.build", "super-tile resolution")
 STAGE_SELF_S_TILE_COMPILE = _stage_self_s("tile.compile", "tile-program cache lookup")
 STAGE_SELF_S_TILE_WINDOW = _stage_self_s(
-    "tile.window", "window-tile probe or build: the in-window rows found on the host")
+    "tile.window", "window-tile probe: the in-window rows counted on the host")
+STAGE_SELF_S_TILE_WINDOW_BUILD = _stage_self_s(
+    "tile.window_build", "a window tile's gather of the in-window rows and its upload")
 STAGE_SELF_S_TILE_DISPATCH = _stage_self_s("tile.dispatch", "compiled program invocation")
+STAGE_SELF_S_TILE_MESH_STACK = _stage_self_s(
+    "tile.mesh_stack", "a mesh dispatch's stacking of each device's local planes")
 STAGE_SELF_S_TILE_READBACK = _stage_self_s(
     "tile.readback", "device->host fetch, waiting out the device included")
 STAGE_SELF_S_TILE_DECODE = _stage_self_s("tile.decode", "fetched buffers to Arrow rows")
@@ -865,7 +875,9 @@ STAGE_SELF_S: dict[str, Counter] = {
     "tile.build": STAGE_SELF_S_TILE_BUILD,
     "tile.compile": STAGE_SELF_S_TILE_COMPILE,
     "tile.window": STAGE_SELF_S_TILE_WINDOW,
+    "tile.window_build": STAGE_SELF_S_TILE_WINDOW_BUILD,
     "tile.dispatch": STAGE_SELF_S_TILE_DISPATCH,
+    "tile.mesh_stack": STAGE_SELF_S_TILE_MESH_STACK,
     "tile.fused_dispatch": STAGE_SELF_S_TILE_DISPATCH,
     "tile.readback": STAGE_SELF_S_TILE_READBACK,
     "tile.batch_readback": STAGE_SELF_S_TILE_READBACK,

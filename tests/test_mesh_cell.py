@@ -1,0 +1,265 @@
+"""The deployment of the cell `tsbs-mesh4-heavy` at a small size, on the
+conftest's virtual CPU devices: TSBS `cpu` in four regions by HASH
+(hostname) with `tile.mesh_devices = 4`.  Every shape of the cell's mix is
+ONE shard_map dispatch, equal to the numpy folds and to the same table with
+`tile.mesh_devices = 0`, whether a `double-groupby-1` builds window tiles
+(a 12 h window over 24 h: cover 0.5) or declines (over 13 h: cover 12/13);
+a dispatch handed to the single chip moves `TILE_MESH_INELIGIBLE` and says
+why on its `tile.dispatch`.
+"""
+
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import compare, manifest, program, traffic  # noqa: E402
+
+from greptimedb_tpu.parallel import tile_cache  # noqa: E402
+from greptimedb_tpu.parallel.mesh import region_device_index  # noqa: E402
+from greptimedb_tpu.parallel.tile_cache import TileCacheManager  # noqa: E402
+from greptimedb_tpu.utils import metrics, tracing  # noqa: E402
+
+CELL, HOSTS, CHIPS = "tsbs-mesh4-heavy", 40, 4
+SHAPES = ("double-groupby-1", "lastpoint", "groupby-orderby-limit")
+MESH_COUNTERS = (
+    "TILE_MESH_DISPATCHES", "TILE_MESH_INELIGIBLE", "TILE_MESH_DEGRADED",
+    "TPU_DEVICE_DISPATCHES", "TILE_WINDOW_BUILDS", "TILE_WINDOW_COUNTED",
+    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU",
+)
+
+
+class Fleet:
+    """The cell's table at `hours` hours, loaded as the harness loads it."""
+
+    def __init__(self, home: str, hours: int):
+        self.cell = manifest.Cell(CELL, {"hosts": HOSTS, "hours": hours})
+        self.ds = self.cell.dataset(2**31 + 33)
+        self.db = program.open_database(home, self.cell.config["database"])
+        program.load(self.db, self.ds)
+        program.prewarm(self.db, self.ds.tables)
+        self.literals = {}
+        for shape, lit in traffic.requests(self.cell.traffic, self.ds, 33, 1):
+            if shape in self.literals:
+                break
+            self.literals[shape] = lit
+
+    def sql(self, shape: str) -> str:
+        return self.cell.shapes[shape].request(self.ds, self.literals[shape])["sql"]
+
+    def ask(self, shape: str):
+        """(table, counter moves) of one request of `shape`."""
+        before = {k: getattr(metrics, k).total() for k in MESH_COUNTERS}
+        table = self.db.sql_one(self.sql(shape))
+        return table, {k: getattr(metrics, k).total() - before[k] for k in MESH_COUNTERS}
+
+    def warm(self, shape: str):
+        """The first touch is served from the host while the background
+        builder makes the family's planes and programs."""
+        self.ask(shape)
+        program.wait_builds(self.db)
+
+    def gap(self, shape: str, table) -> tuple:
+        rows = list(zip(*[
+            [int(v.timestamp() * 1000) if hasattr(v, "timestamp") else v
+             for v in table[c].to_pylist()]
+            for c in table.column_names
+        ]))
+        want = self.cell.shapes[shape].reference(self.ds, self.literals[shape])
+        return compare.compare(rows, want)
+
+
+@pytest.fixture(scope="module", params=[24, 13], ids=["window-tile-builds", "window-tile-declines"])
+def fleet(request, tmp_path_factory):
+    # a region holds 40 / 4 hosts x 8640 ticks: far under 2^22 rows, where a
+    # window is not probed at all
+    patch = pytest.MonkeyPatch()
+    patch.setattr(TileCacheManager, "_WINDOW_TILE_MIN_ROWS", 1 << 12)
+    patch.setattr(TileCacheManager, "_WINDOW_TILE_GRID", 1 << 14)
+    f = Fleet(str(tmp_path_factory.mktemp(f"mesh{request.param}")), request.param)
+    f.builds = request.param == 24
+    yield f
+    f.db.close()
+    patch.undo()
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_each_shape_is_one_mesh_dispatch_equal_to_the_fold_and_to_one_chip(fleet, shape):
+    bar = fleet.cell.config["guarantees"][fleet.cell.shapes[shape].BAR]
+    fleet.warm(shape)
+    for _ in range(2):
+        meshed, moved = fleet.ask(shape)
+        assert moved["TILE_MESH_DISPATCHES"] == 1 == moved["TPU_DEVICE_DISPATCHES"], moved
+        assert not moved["TILE_MESH_INELIGIBLE"] and not moved["TILE_MESH_DEGRADED"], moved
+        assert not moved["TPU_FALLBACK_TOTAL"] and not moved["TPU_ROUTED_TO_CPU"], moved
+        keys_ok, gap = fleet.gap(shape, meshed)
+        assert keys_ok and gap <= bar, (shape, gap)
+    if shape == "double-groupby-1":
+        regions = fleet.cell.config["regions"]
+        # the window is drawn once, so its tiles are built in the first touch
+        # and found again; a declined window is counted anew by every request
+        assert moved["TILE_WINDOW_BUILDS"] == 0
+        assert moved["TILE_WINDOW_COUNTED"] == (0 if fleet.builds else regions)
+        tiles = [
+            len(e.window_tiles) for e in fleet.db.query_engine.tile_cache._super.values()
+        ]
+        assert tiles == [1 if fleet.builds else 0] * regions
+    fleet.db.config.tile.mesh_devices = 0
+    try:
+        single, moved = fleet.ask(shape)
+    finally:
+        fleet.db.config.tile.mesh_devices = CHIPS
+    assert moved["TILE_MESH_DISPATCHES"] == 0 and moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+    assert not moved["TILE_MESH_INELIGIBLE"], "the mesh path is off, not declined"
+    assert single.to_pydict() == meshed.to_pydict()
+
+
+def test_planes_lie_where_chunk_device_places_them(fleet):
+    """A region's planes, the time-major copies of `groupby-orderby-limit`
+    included, lie whole on device `region_device_index(r, 4)`, so every
+    device holds a quarter of the rows and none another's; the chunks of a
+    window tile go round robin from there."""
+    for shape in SHAPES:
+        fleet.warm(shape)
+    cache = fleet.db.query_engine.tile_cache
+    devices = cache.placement_devices()[:CHIPS]
+    assert len(cache._super) == CHIPS
+    held = []
+    for rid, entry in cache._super.items():
+        base = region_device_index(rid, CHIPS)
+        assert entry.tm_valid is not None and entry.perm is not None
+        planes = [entry.valid, entry.tm_valid, *entry.cols.values(), *entry.tm_cols.values()]
+        arrays = [x for chunks in planes for x in chunks] + [entry.perm]
+        assert {d for x in arrays for d in x.devices()} == {devices[base]}, rid
+        for wt in entry.window_tiles.values():
+            for chunks in (wt["valid"], *wt["cols"].values()):
+                assert [next(iter(x.devices())) for x in chunks] == [
+                    devices[(base + i) % CHIPS] for i in range(len(chunks))
+                ]
+        held.append((base, entry.num_rows))
+    assert sorted(base for base, _ in held) == list(range(CHIPS))
+    assert {rows for _, rows in held} == {fleet.ds.rows // CHIPS}
+
+
+@pytest.mark.parametrize("planted", ["source", "pass"])
+def test_a_dispatch_handed_to_the_single_chip_is_counted_and_says_why(fleet, planted, monkeypatch):
+    shape = "lastpoint"
+    fleet.warm(shape)
+    if planted == "source":
+        def runs(_sources):
+            raise tile_cache._MeshIneligible("planted: no stacked mesh form")
+        monkeypatch.setattr(tile_cache, "_mesh_runs", runs)
+        why = "planted: no stacked mesh form"
+    else:
+        monkeypatch.setattr(fleet.db.query_engine.config, "disabled_passes", ("mesh_dispatch",))
+        why = "mesh_dispatch pass disabled"
+    tracing.EXPORTER.clear()
+    table, moved = fleet.ask(shape)
+    assert moved["TILE_MESH_INELIGIBLE"] == 1 == moved["TPU_DEVICE_DISPATCHES"], moved
+    assert moved["TILE_MESH_DISPATCHES"] == 0 == moved["TILE_MESH_DEGRADED"], moved
+    answered = [
+        s for s in tracing.EXPORTER.spans()
+        if s.name == "tile.dispatch" and s.attributes.get("mesh_devices") == 0
+    ]
+    assert [s.attributes.get("mesh_ineligible") for s in answered] == [why]
+    keys_ok, gap = fleet.gap(shape, table)
+    assert keys_ok and gap <= fleet.cell.config["guarantees"]["value_rtol_f64"]
+
+
+def test_every_chip_is_held_to_its_own_share_of_the_budget(fleet):
+    """`budget` is one chip's share: four regions on a chip each may hold
+    four shares between them, and none is evicted for the others' bytes."""
+    for shape in SHAPES:
+        fleet.warm(shape)
+    cache = fleet.db.query_engine.tile_cache
+    sizes = {region_device_index(rid, CHIPS): e.nbytes for rid, e in cache._super.items()}
+    assert cache.device_used() == [sizes[d] for d in range(CHIPS)]
+    assert sum(cache.device_used()) == cache._used
+    saved, cache.budget = cache.budget, max(sizes.values())
+    try:
+        with cache._lock:
+            cache._evict_locked(set())
+        assert len(cache._super) == CHIPS and cache._used > 3 * cache.budget
+        fleet.db.config.tile.mesh_devices = 0  # one sum, as on one chip
+        assert cache.device_used() == [cache._used]
+    finally:
+        cache.budget = saved
+        fleet.db.config.tile.mesh_devices = CHIPS
+
+
+def test_an_evicted_region_read_back_from_its_files_answers_at_the_dictionary_epoch(fleet):
+    """A whole entry evicted comes back from its persisted file set with the
+    tag codes at their STORED epoch and no device plane: the repair pass
+    leaves it to the lazy upload, which gathers the codes forward."""
+    for shape in SHAPES:
+        fleet.warm(shape)
+    cache = fleet.db.query_engine.tile_cache
+    with cache._lock:
+        cache._evict_locked(set(), limit=0)
+    assert not cache._super and cache.device_used() == [0] * CHIPS
+    for shape in SHAPES:
+        fleet.warm(shape)
+        table, moved = fleet.ask(shape)
+        assert moved["TILE_MESH_DISPATCHES"] == 1 and not moved["TILE_MESH_DEGRADED"], moved
+        keys_ok, gap = fleet.gap(shape, table)
+        assert keys_ok and gap <= fleet.cell.config["guarantees"][fleet.cell.shapes[shape].BAR]
+
+
+def test_one_region_tables_on_the_same_chip_share_one_chips_budget(tmp_path):
+    """A one-region table lies whole on mesh device 0 (region number 0):
+    three of them are held to ONE chip's share, not to four."""
+    db = program.open_database(
+        str(tmp_path), {"query.fallback_to_cpu": False, "tile.mesh_devices": CHIPS}
+    )
+    try:
+        cache = db.query_engine.tile_cache
+        for t in "abc":
+            db.sql_one(
+                f"CREATE TABLE one_{t} (host STRING, ts TIMESTAMP TIME INDEX, v DOUBLE, "
+                "PRIMARY KEY (host)) WITH (append_mode = 'true')"
+            )
+            rows = ", ".join(f"('h{i % 7}', {1000 * i}, {i}.5)" for i in range(2000))
+            db.sql_one(f"INSERT INTO one_{t} VALUES {rows}")
+            db.sql_one(f"ADMIN flush_table('one_{t}')")
+
+        def ask(t):
+            out = db.sql_one(f"SELECT host, max(v) FROM one_{t} GROUP BY host ORDER BY host")
+            program.wait_builds(db)
+            assert out["max(v)"].to_pylist()[0] == 1995.5
+
+        ask("a")
+        used = cache.device_used()
+        assert used[0] == cache._used > 0 and used[1:] == [0] * (CHIPS - 1)
+        cache.budget = int(2.5 * used[0])  # room for two of the three tables
+        evicted = metrics.TILE_CACHE_EVICTIONS.total()
+        ask("b")
+        assert metrics.TILE_CACHE_EVICTIONS.total() == evicted
+        ask("c")
+        assert metrics.TILE_CACHE_EVICTIONS.total() > evicted
+        assert 0 < cache.device_used()[0] <= cache.budget
+    finally:
+        db.close()
+
+
+def test_a_tag_plane_uploaded_again_after_a_release_is_at_the_dictionary_epoch(fleet):
+    """Where an entry's planes pass half a chip's budget, a query drops the
+    columns it does not read (`release_unneeded`): a `groupby-orderby-limit`
+    drops `hostname`, and the next `lastpoint` uploads it again from the
+    persisted codes, at their stored epoch, after the query's repair pass has
+    run.  It has to be gathered forward there: four regions grew the
+    dictionary in turn, so every region's stored epoch is stale."""
+    for shape in SHAPES:
+        fleet.warm(shape)
+    cache = fleet.db.query_engine.tile_cache
+    for _ in range(2):
+        for entry in list(cache._super.values()):
+            cache.release_unneeded(entry, {"ts", "usage_user"})
+            assert "hostname" not in entry.cols
+        table, moved = fleet.ask("lastpoint")
+        assert moved["TILE_MESH_DISPATCHES"] == 1 and not moved["TILE_MESH_DEGRADED"], moved
+        keys_ok, gap = fleet.gap("lastpoint", table)
+        assert keys_ok and gap <= 1e-9
+        assert all("hostname" in e.cols for e in cache._super.values())
