@@ -1258,6 +1258,7 @@ class Database:
             dictionary=self.dicts.get(key),
             regions=regions,
             append_mode=any(r.append_mode for r in regions),
+            partition_columns=meta.partition_rule.key_columns(),
         )
 
     # ---- tile prewarm (cold path off the query path) ----------------------

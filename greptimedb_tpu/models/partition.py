@@ -51,6 +51,11 @@ class PartitionRule:
     def num_partitions(self) -> int:
         raise NotImplementedError
 
+    def key_columns(self) -> tuple[str, ...]:
+        """The columns whose values decide a row's region (none for one
+        region): a region holds a strict subset of their values."""
+        return tuple(getattr(self, "columns", ()))
+
     def partition_indices(self, table: pa.Table) -> np.ndarray:
         """Per-row partition index [0, num_partitions)."""
         raise NotImplementedError
@@ -217,6 +222,9 @@ class RangePartitionRule(PartitionRule):
 
     def num_partitions(self) -> int:
         return len(self.bounds) + 1
+
+    def key_columns(self) -> tuple[str, ...]:
+        return (self.column,)
 
     def partition_indices(self, table: pa.Table) -> np.ndarray:
         n = table.num_rows

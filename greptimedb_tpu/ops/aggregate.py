@@ -100,6 +100,96 @@ def time_bucket(ts: jnp.ndarray, origin: int, interval: int) -> jnp.ndarray:
     return ((ts - origin) // interval).astype(jnp.int32)
 
 
+# ---- series ordinals ---------------------------------------------------------
+#
+# A source whose series are a strict subset of the dictionary (a region of
+# a table partitioned on its leading key tag) holds that tag's codes with
+# gaps the partition rule left: under HASH (hostname) consecutive hosts of
+# one region lie 2-9 codes apart, a block's gids then span up to
+# 9 x n_buckets slots and the blocked kernels' span guard fails for the
+# whole source.  Sources are (pk, ts) sorted, so equal codes are one run:
+# stage 1 groups by the run's ORDINAL in the source (consecutive series
+# exactly one apart whatever the rule left), and the [G] states are
+# carried back to the table-wide code space before anything merges.
+
+
+@jax.named_scope("series_ordinals")
+def series_ordinals(
+    codes: jnp.ndarray, valid: jnp.ndarray, card: int
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Ordinals of the runs of equal `codes` in one source, and the way
+    back: (ordinal [n] int32 in [0, card), slot_of_code [card] int32,
+    ok scalar bool).
+
+    A row's ordinal is the number of code changes before it: one compare
+    with the previous row and one int32 prefix sum.  The pad tail (the
+    rows after the last valid one, whatever they hold) continues the last
+    run.  `slot_of_code[c]` is the ordinal of code c's run, -1 where the
+    source holds no such run: the code of ordinal k is read at the first
+    row of run k (one search of `card` keys over the non-decreasing
+    ordinals), and a `card`-element scatter inverts it, so runs need not
+    ascend by code.  `ok` is False where ordinals cannot stand for codes:
+    more runs than `card`, or a code in two runs (a source that is not
+    sorted by this tag, e.g. a memtable tail in arrival order); the
+    caller then keeps the codes.  Runs of a code outside [0, card) get no
+    slot: their rows are out of range for the caller's mask as they were."""
+    from .rate import _first_greater, prefix_scan
+
+    n = codes.shape[0]
+    c = codes.astype(jnp.int32)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    last = jnp.max(jnp.where(valid, rows, -1))
+    prev = jnp.concatenate([c[:1], c[:-1]])
+    start = (rows <= last) & ((rows == 0) | (c != prev))
+    (seen,) = prefix_scan(
+        lambda a, b: (a[0] + b[0],), (start.astype(jnp.int32),), (0,)
+    )
+    n_runs = seen[-1]
+    ks = jnp.arange(card, dtype=jnp.int32)
+    first = _first_greater(
+        seen, jnp.zeros(card, jnp.int32), jnp.full(card, n, jnp.int32),
+        ks, n.bit_length(),
+    )  # first row with ordinal + 1 > k
+    code_of = jnp.take(c, first, mode="clip")
+    held = (ks < n_runs) & (code_of >= 0) & (code_of < card)
+    slot_of_code = jnp.full(card, -1, jnp.int32).at[
+        jnp.where(held, code_of, card)
+    ].max(ks, mode="drop")
+    ok = (n_runs <= card) & (jnp.sum(slot_of_code >= 0) == jnp.sum(held))
+    return jnp.clip(seen - 1, 0, card - 1), slot_of_code, ok
+
+
+@jax.named_scope("ordinals_to_codes")
+def ordinal_states_to_codes(
+    state: AggState, slot_of_code: jnp.ndarray, ok: jnp.ndarray
+) -> AggState:
+    """Carry a stage-1 state whose LEADING gid component is a series
+    ordinal (`series_ordinals`) back to the table-wide code space: row c of
+    the [card, G / card] view is the row of code c's ordinal, the
+    aggregate's identity where the source holds no such code.  One gather
+    of G elements per field, against a scan of the source's rows.  Where
+    `ok` is False stage 1 grouped by codes and the state passes as is."""
+    card = slot_of_code.shape[0]
+    absent = (slot_of_code < 0)[:, None]
+    src = jnp.maximum(slot_of_code, 0)
+
+    def carry(arr, identity=lambda dtype: 0):
+        if arr is None:
+            return None
+        rows = jnp.take(arr.reshape(card, -1), src, axis=0)
+        rows = jnp.where(absent, jnp.asarray(identity(arr.dtype), arr.dtype), rows)
+        return jnp.where(ok, rows.reshape(arr.shape), arr)
+
+    return AggState(
+        sums=carry(state.sums),
+        counts=carry(state.counts),
+        mins=carry(state.mins, lambda dtype: jnp.finfo(dtype).max),
+        maxs=carry(state.maxs, lambda dtype: jnp.finfo(dtype).min),
+        last_ts=carry(state.last_ts, lambda dtype: jnp.iinfo(dtype).min),
+        last_val=carry(state.last_val),
+    )
+
+
 # ---- hash group-by ----------------------------------------------------------
 #
 # The alternative to the dense mixed-radix group space: when the PADDED

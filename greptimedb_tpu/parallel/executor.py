@@ -45,10 +45,12 @@ from ..ops.aggregate import (
     finalize,
     hash_group_slots,
     limb_segment_sums,
+    ordinal_states_to_codes,
     psum_states,
     quantize_limbs,
     raw_group_ids,
     segment_aggregate,
+    series_ordinals,
     time_bucket,
 )
 from ..ops.tiles import TileBatch, padded_size, tiles_from_table
@@ -116,6 +118,14 @@ class DistGroupByPlan:
     # so group spaces far past max_groups stay executable.
     agg_strategy: str = "sort"
     hash_slots: int = 0
+    # Stage 1 groups by the source's own series ORDINAL in place of the
+    # gid's leading tag code (ops/aggregate.series_ordinals), and every
+    # state is carried back to code space where `fold` sits.  Set by the
+    # planner where a source holds a strict subset of that tag's
+    # dictionary (a region of a table partitioned on its leading key tag:
+    # the codes a region holds lie apart, and a block's gids past
+    # `block_span`); elsewhere ordinals equal codes and it stays unset.
+    lead_ordinals: bool = False
 
     @property
     def num_groups(self) -> int:
@@ -257,6 +267,11 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
     quantized planes per column (dict col -> (limbs, scale)); missing
     columns quantize in-program from their f64 plane.
 
+    With `plan.lead_ordinals` the gid's leading component is the row's
+    series ordinal in THIS source, not the tag's table-wide code, and
+    every state leaves here carried back to code space (where `fold`
+    sits), so merges, collectives and finalize see code-space states.
+
     With `plan.agg_strategy == "hash"` the caller must pass `hash_table`
     (the [hash_slots] int64 key table threaded across this query's
     sources) and gets back `(states, hash_table')`: group ids are
@@ -297,6 +312,21 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
         components.append((b, plan.n_buckets))
     is_hash = plan.agg_strategy == "hash"
     overflow = None
+    to_codes = None
+    if plan.lead_ordinals:  # a sort plan whose gid leads with a tag
+        lead, card = components[0]
+        ordinal, slot_of_code, by_ordinal = series_ordinals(lead, valid, card)
+        # a code outside the dictionary stays out of range, as raw_group_ids
+        # finds it from the code; where the ordinals cannot stand for the
+        # codes (a source not sorted by this tag) the codes stay
+        mask = mask & (lead >= 0) & (lead < card)
+        components[0] = (
+            jnp.where(by_ordinal, ordinal, lead.astype(jnp.int32)), card
+        )
+
+        def to_codes(state: AggState) -> AggState:
+            return ordinal_states_to_codes(state, slot_of_code, by_ordinal)
+
     if is_hash:
         if hash_table is None:
             raise ValueError("hash agg strategy requires the threaded hash_table")
@@ -341,7 +371,11 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
         )
 
         def fold(state: AggState) -> AggState:
+            if to_codes is not None:
+                state = to_codes(state)
             return reduce_state_axes(state, fold_cards, keep_axes)
+    elif to_codes is not None:
+        fold = to_codes
     else:
         def fold(state: AggState) -> AggState:
             return state

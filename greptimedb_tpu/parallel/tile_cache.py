@@ -396,6 +396,10 @@ class TileContext:
     dictionary: TableDictionary
     regions: list[Region]
     append_mode: bool = False
+    # the columns the table's partition rule splits its rows on (empty
+    # for one region): a region of a rule over a tag holds a strict subset
+    # of that tag's dictionary
+    partition_columns: tuple[str, ...] = ()
 
 
 @dataclass
@@ -3448,6 +3452,8 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
         if not _in_fused_build():
             # builder (ghost) dispatches stay out of the per-query counter
             metrics.TPU_DEVICE_DISPATCHES.inc()
+            if plan.lead_ordinals:
+                metrics.TILE_ORDINAL_GIDS.inc()
         if _in_flow_maintenance():
             metrics.FLOW_DEVICE_DISPATCH_TOTAL.inc()
         hv = jnp.asarray(
@@ -4005,6 +4011,8 @@ def _mesh_run(plan, nullable_cols, mesh, staged, pdyn, hv, program):
     # not double-count against the single-chip dispatch that follows
     if not _in_fused_build():
         metrics.TPU_DEVICE_DISPATCHES.inc()
+        if plan.lead_ordinals:
+            metrics.TILE_ORDINAL_GIDS.inc()
     if _in_flow_maintenance():
         metrics.FLOW_DEVICE_DISPATCH_TOTAL.inc()
     return packed
@@ -6293,6 +6301,18 @@ class TileExecutor:
         block_span = 16
         while block_span < min(span_est, 128):
             block_span <<= 1
+        # a region of a table partitioned on its leading sort tag holds
+        # every n-th code of that tag or so: the span above holds for
+        # consecutive SERIES, so the gid's leading component is the
+        # source's own series ordinal there (DistGroupByPlan.lead_ordinals)
+        lead_ordinals = bool(
+            not is_hash
+            and not time_major
+            and gid_tags
+            and gid_tags[0] == pk[0]
+            and len(ctx.regions) > 1
+            and pk[0] in ctx.partition_columns
+        )
 
         acc_dtype = self.config_acc_dtype()
         hash_slots = 0
@@ -6337,6 +6357,7 @@ class TileExecutor:
             block_span=block_span,
             agg_strategy="hash" if is_hash else "sort",
             hash_slots=hash_slots,
+            lead_ordinals=lead_ordinals,
         )
         dyn_host = {
             "filter_values": filter_vals,
@@ -7503,6 +7524,8 @@ class TileExecutor:
             )
         traces0 = _MEGA_STATS["traces"]
         metrics.TPU_DEVICE_DISPATCHES.inc()
+        if any(cd.key[0].lead_ordinals for cd in cds):
+            metrics.TILE_ORDINAL_GIDS.inc()
         with tracing.span("tile.fused_dispatch", members=len(cds)) as disp:
             packed_all = device_health.supervised_call(
                 "dispatch", lambda: fused(tuple(inputs))

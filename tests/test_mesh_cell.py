@@ -8,9 +8,11 @@ a dispatch handed to the single chip moves `TILE_MESH_INELIGIBLE` and says
 why on its `tile.dispatch`.
 """
 
+import dataclasses
 import os
 import sys
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -28,15 +30,16 @@ SHAPES = ("double-groupby-1", "lastpoint", "groupby-orderby-limit")
 MESH_COUNTERS = (
     "TILE_MESH_DISPATCHES", "TILE_MESH_INELIGIBLE", "TILE_MESH_DEGRADED",
     "TPU_DEVICE_DISPATCHES", "TILE_WINDOW_BUILDS", "TILE_WINDOW_COUNTED",
-    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU",
+    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TILE_ORDINAL_GIDS",
+    "TPU_COMPILE_CACHE_HITS", "TPU_COMPILE_CACHE_MISSES",
 )
 
 
 class Fleet:
     """The cell's table at `hours` hours, loaded as the harness loads it."""
 
-    def __init__(self, home: str, hours: int):
-        self.cell = manifest.Cell(CELL, {"hosts": HOSTS, "hours": hours})
+    def __init__(self, home: str, hours: int, **scale):
+        self.cell = manifest.Cell(CELL, {"hosts": HOSTS, "hours": hours, **scale})
         self.ds = self.cell.dataset(2**31 + 33)
         self.db = program.open_database(home, self.cell.config["database"])
         program.load(self.db, self.ds)
@@ -48,6 +51,11 @@ class Fleet:
             self.literals[shape] = lit
 
     def sql(self, shape: str) -> str:
+        if shape == "high-cpu-1":  # TSBS's, as `chip_smoke.py` asks it: no group key
+            return (
+                f"SELECT count(*) AS n, max(usage_user) AS m FROM {self.ds.table} WHERE usage_user > 90.0 "
+                f"AND hostname = 'host_3' AND ts >= {self.ds.t0} AND ts < {self.ds.end}"
+            )
         return self.cell.shapes[shape].request(self.ds, self.literals[shape])["sql"]
 
     def ask(self, shape: str):
@@ -263,3 +271,172 @@ def test_a_tag_plane_uploaded_again_after_a_release_is_at_the_dictionary_epoch(f
         keys_ok, gap = fleet.gap("lastpoint", table)
         assert keys_ok and gap <= 1e-9
         assert all("hostname" in e.cols for e in cache._super.values())
+
+
+# ---- group ids over a source's own series ordinals (PR 34) ------------------
+
+
+@pytest.fixture(scope="module")
+def one_region(fleet, tmp_path_factory):
+    """The fleet's twin in ONE region on one chip: the same seed, hosts and
+    hours, so the same rows; the deployment of `tsbs-heavy`.  (`fleet`'s
+    patch of the window-tile sizes outlives this fixture.)"""
+    hours = fleet.cell.config["hours"]
+    f = Fleet(str(tmp_path_factory.mktemp(f"one{hours}")), hours, regions=1)
+    f.db.config.tile.mesh_devices = 0
+    f.literals = fleet.literals
+    yield f
+    f.db.close()
+
+
+def rows_close(a, b, rtol):
+    a, b = a.to_pydict(), b.to_pydict()
+    assert list(a) == list(b)
+    for name in a:
+        if a[name] and isinstance(a[name][0], float):
+            np.testing.assert_allclose(a[name], b[name], rtol=rtol, err_msg=name)
+        else:
+            assert a[name] == b[name], name
+
+
+# a dispatch of the shape groups by ordinals: its gid leads with `hostname`
+ORDINAL = {"double-groupby-1": 1, "lastpoint": 1, "high-cpu-1": 0, "groupby-orderby-limit": 0}
+
+
+@pytest.mark.parametrize("mesh_devices", [CHIPS, 0], ids=["mesh4", "one-chip"])
+@pytest.mark.parametrize("shape", ["double-groupby-1", "lastpoint", "high-cpu-1"])
+def test_a_hash_partitioned_table_answers_as_the_one_region_table_by_ordinals(
+    fleet, one_region, shape, mesh_devices
+):
+    fleet.warm(shape)
+    one_region.warm(shape)
+    want, moved = one_region.ask(shape)
+    assert moved["TILE_ORDINAL_GIDS"] == 0, "one region holds the whole dictionary"
+    assert not moved["TPU_FALLBACK_TOTAL"] and not moved["TPU_ROUTED_TO_CPU"], moved
+    fleet.db.config.tile.mesh_devices = mesh_devices
+    try:
+        for _ in range(2):
+            got, moved = fleet.ask(shape)
+            assert moved["TILE_ORDINAL_GIDS"] == ORDINAL[shape] * moved["TPU_DEVICE_DISPATCHES"], moved
+            assert moved["TILE_MESH_DISPATCHES"] == (moved["TPU_DEVICE_DISPATCHES"] if mesh_devices else 0)
+            assert not moved["TPU_FALLBACK_TOTAL"] and not moved["TPU_ROUTED_TO_CPU"], moved
+            assert not moved["TILE_MESH_DEGRADED"] and not moved["TILE_MESH_INELIGIBLE"], moved
+            # the limb sums of a group quantize by the blocks its rows share
+            # with their neighbours, which a region's own plane changes
+            rows_close(got, want, rtol=1e-9 if shape == "high-cpu-1" else 2e-7)
+        if shape != "high-cpu-1":  # the host fast path may answer one host
+            assert moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+            keys_ok, gap = fleet.gap(shape, got)
+            assert keys_ok and gap <= fleet.cell.config["guarantees"][fleet.cell.shapes[shape].BAR]
+    finally:
+        fleet.db.config.tile.mesh_devices = CHIPS
+
+
+def plans_of(f, shape, monkeypatch):
+    """The plans `shape` hands the program cache, and the counter moves of
+    its second warm request."""
+    seen = []
+    real = tile_cache._tile_program_cached
+    monkeypatch.setattr(
+        tile_cache, "_tile_program_cached",
+        lambda plan, nullable, spec: (seen.append(plan), real(plan, nullable, spec))[1],
+    )
+    f.warm(shape)
+    f.ask(shape)
+    _table, moved = f.ask(shape)
+    return seen, moved
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_a_one_region_table_keeps_the_parents_plan_and_program(one_region, shape, monkeypatch):
+    seen, moved = plans_of(one_region, shape, monkeypatch)
+    assert seen and not any(p.lead_ordinals for p in seen)
+    # the field at its default is the parent's plan: the same cache key,
+    # so the same compiled program, found again by the second request
+    parents = {f.name for f in dataclasses.fields(seen[-1])} - {"lead_ordinals"}
+    twin = type(seen[-1])(**{k: getattr(seen[-1], k) for k in parents})
+    assert twin == seen[-1] and hash(twin) == hash(seen[-1])
+    assert moved["TPU_COMPILE_CACHE_HITS"] >= 1 and moved["TPU_COMPILE_CACHE_MISSES"] == 0, moved
+    assert moved["TILE_ORDINAL_GIDS"] == 0 and moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+def test_a_region_of_a_table_partitioned_on_its_leading_tag_plans_ordinals(fleet, shape, monkeypatch):
+    seen, moved = plans_of(fleet, shape, monkeypatch)
+    assert seen and {p.lead_ordinals for p in seen} == {bool(ORDINAL[shape])}
+    assert all(p.time_major for p in seen) == (shape == "groupby-orderby-limit")
+    assert moved["TILE_ORDINAL_GIDS"] == ORDINAL[shape] and moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+    assert moved["TPU_COMPILE_CACHE_MISSES"] == 0, moved
+
+
+def small_table(db, name, partition, hosts):
+    db.sql_one(
+        f"CREATE TABLE {name} (host STRING, dc STRING, ts TIMESTAMP TIME INDEX, v DOUBLE, "
+        f"PRIMARY KEY (host, dc)){partition} WITH (append_mode = 'true')"
+    )
+    put(db, name, hosts, 0)
+
+
+def put(db, name, hosts, t0):
+    rows = ", ".join(
+        f"('{h}', 'dc{i % 3}', {t0 + 1000 * k}, {i * 100 + k}.25)"
+        for i, h in enumerate(hosts) for k in range(50)
+    )
+    db.sql_one(f"INSERT INTO {name} VALUES {rows}")
+    db.sql_one(f"ADMIN flush_table('{name}')")
+
+
+def answers(hosts) -> dict:
+    """What `grouped` returns for hosts written by `put`, in its order."""
+    return {h: (i * 100 + 49.25, 50, i * 100 + 49.25) for i, h in enumerate(hosts)}
+
+
+def grouped(db, name):
+    moved = metrics.TILE_ORDINAL_GIDS.total(), metrics.TPU_DEVICE_DISPATCHES.total()
+    out = db.sql_one(
+        f"SELECT host, max(v) AS hi, count(v) AS n, last_value(v) AS last FROM {name} GROUP BY host ORDER BY host"
+    )
+    program.wait_builds(db)
+    moved = metrics.TILE_ORDINAL_GIDS.total() - moved[0], metrics.TPU_DEVICE_DISPATCHES.total() - moved[1]
+    return {h: (hi, n, last) for h, hi, n, last in zip(*out.to_pydict().values())}, moved
+
+
+@pytest.mark.parametrize("mesh_devices", [CHIPS, 0], ids=["mesh4", "one-chip"])
+def test_a_dictionary_grown_after_the_planes_were_built_is_repaired_before_the_ordinals(tmp_path, mesh_devices):
+    """New hosts that sort before, between and after the old ones move every
+    code; the regions' planes are gathered forward to the new epoch, and the
+    ordinals of a region's runs are carried back to the NEW codes."""
+    db = program.open_database(
+        str(tmp_path), {"query.fallback_to_cpu": False, "tile.mesh_devices": mesh_devices}
+    )
+    try:
+        old = [f"h{i:02d}" for i in range(10, 50, 2)]
+        small_table(db, "grown", " PARTITION BY HASH (host) PARTITIONS 4", old)
+        for _ in range(3):
+            got, moved = grouped(db, "grown")
+        assert moved == (1, 1), moved
+        assert got == answers(old)
+        new = ["h00", "h05", "h21", "h33", "h77", "h99"]
+        put(db, "grown", new, 10_000_000)
+        for _ in range(3):
+            got, moved = grouped(db, "grown")
+        assert moved == (1, 1), moved
+        want = {**answers(old), **answers(new)}
+        assert got == want and list(got) == sorted(want)
+    finally:
+        db.close()
+
+
+def test_a_rule_over_another_column_leaves_the_codes(tmp_path):
+    """Partitioned on `dc`, every region holds every host: the leading sort
+    tag's codes are dense in a region and the plan is the parent's."""
+    db = program.open_database(str(tmp_path), {"query.fallback_to_cpu": False, "tile.mesh_devices": 0})
+    try:
+        hosts = [f"h{i:02d}" for i in range(12)]
+        small_table(db, "by_dc", " PARTITION BY HASH (dc) PARTITIONS 2", hosts)
+        for _ in range(3):
+            got, moved = grouped(db, "by_dc")
+        assert moved == (0, 1), moved
+        assert got == answers(hosts)
+    finally:
+        db.close()

@@ -123,3 +123,28 @@ def test_segment_aggregate_compiles(one_chip):
         return state.sums, state.counts, state.mins, state.maxs
 
     _compile("segment_aggregate 2^22 x 96000", agg, values, gids, mask)
+
+
+def test_series_ordinals_compile(one_chip):
+    """The ordinals of a 2^24-row plane (one int32 prefix scan, a search of
+    4096 keys, a 4096-element scatter) and the carry of a [4096 x 16] state
+    back to codes: `lastpoint` on a region of `tsbs-mesh4-heavy`."""
+    from greptimedb_tpu.ops.aggregate import (
+        AggState,
+        ordinal_states_to_codes,
+        series_ordinals,
+    )
+
+    def lead(codes, valid, sums, last_ts):
+        ordinal, slot_of_code, ok = series_ordinals(codes, valid, 4096)
+        state = ordinal_states_to_codes(
+            AggState(sums=sums, last_ts=last_ts), slot_of_code, ok
+        )
+        return ordinal, state.sums, state.last_ts
+
+    secs = _compile(
+        "series_ordinals + ordinal_states_to_codes, 2^24 rows", lead,
+        _rows(one_chip, jnp.int32, (1 << 24,)), _rows(one_chip, jnp.bool_, (1 << 24,)),
+        _rows(one_chip, jnp.float64, (4096 * 16,)), _rows(one_chip, jnp.int64, (4096 * 16,)),
+    )
+    assert secs < RESET_STRIP_CEILING_S
