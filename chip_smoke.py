@@ -7,10 +7,12 @@ One process, default `device.*` settings, the normal entry points:
     for `--hours` (default 12 = 17.28 M rows), made from `--seed`, ingested
     through `Database.insert_rows` (partition split, WAL, memtable), flushed
     to SSTs and prewarmed; the last 2 h of `usage_user` again as the
-    single-field PromQL metric table `tql_cpu`;
+    single-field PromQL metric table `tql_cpu`, and once more as `me_cpu`,
+    a metric-engine logical table of twelve labels on a physical table;
   * requests, over a real `HttpServer` socket in this process: five TSBS SQL
     shapes on `/v1/sql`, `rate(...)` and `increase(...)` on
-    `/v1/prometheus/api/v1/query_range`, and one InfluxDB line-protocol write
+    `/v1/prometheus/api/v1/query_range` (the `rate` over the logical table
+    too, equal to the mito table's), and one InfluxDB line-protocol write
     that is read back;
   * every answer is compared with an independent numpy fold over the
     seed-generated arrays: group keys, row count and row order exact,
@@ -76,6 +78,11 @@ WARM_REPS = 3
 # under the engine's 1e-6 result bar"), and its sums ride the fixed-point
 # limb kernel (~1e-9 per block): 1e-6, the engine's own documented bar.
 RTOL_F64 = 1e-9
+# the labels of the metric-engine logical table `me_cpu`: nginx's twelve
+ME_LABELS = (
+    "hostname", "region", "datacenter", "rack", "os", "arch", "team", "service",
+    "service_version", "service_environment", "port", "server",
+)
 RTOL_AVG = 1e-6
 
 
@@ -157,7 +164,7 @@ class Dataset:
     def key(self, regions: int) -> str:
         sig = json.dumps({
             "hosts": self.hosts, "hours": self.hours, "seed": self.seed,
-            "regions": regions, "v": 1,
+            "regions": regions, "v": 2,
         }, sort_keys=True)
         return hashlib.sha1(sig.encode()).hexdigest()[:12]
 
@@ -253,6 +260,39 @@ def load(db, ds: Dataset, home: str, regions: int) -> dict:
                 ),
             }))
             t_ing += time.perf_counter() - t0
+            # the same samples as upstream's Prometheus remote write stores
+            # them: a logical table of twelve labels on a metric engine's
+            # physical table (`tile_exec`'s row-range source)
+            db.sql(
+                "CREATE TABLE smoke_phy (ts TIMESTAMP(3) TIME INDEX, "
+                "greptime_value DOUBLE) WITH ('physical_metric_table' = '')"
+            )
+            db.sql(
+                "CREATE TABLE me_cpu (ts TIMESTAMP(3) TIME INDEX, greptime_value "
+                f"DOUBLE, {', '.join(f'{l} STRING' for l in ME_LABELS)}, "
+                f"PRIMARY KEY ({', '.join(ME_LABELS)})) "
+                "ENGINE = metric WITH ('on_physical_table' = 'smoke_phy')"
+            )
+            codes = pa.array(np.tile(np.arange(ds.hosts, dtype=np.int32), n_tql))
+            t0 = time.perf_counter()
+            db.insert_rows("me_cpu", pa.table({
+                **{
+                    l: pa.DictionaryArray.from_arrays(codes, pa.array([
+                        str(name) if l == "hostname" else f"{l}-{(h * 7 + k) % (k + 2)}"
+                        for h, name in enumerate(ds.host_names)
+                    ]))
+                    for k, l in enumerate(ME_LABELS)
+                },
+                "greptime_value": pa.array(
+                    ds.usage_user[first:].reshape(-1), pa.float64()
+                ),
+                "ts": pa.array(
+                    np.broadcast_to(ts, (n_tql, ds.hosts)).reshape(-1),
+                    pa.timestamp("ms"),
+                ),
+            }))
+            t_ing += time.perf_counter() - t0
+            tql_rows *= 2
     t0 = time.perf_counter()
     if not reuse:
         db.storage.flush_all()
@@ -564,7 +604,7 @@ class Counters:
     MUST_NOT_MOVE = ("TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TQL_TILE_DEGRADED")
     WATCHED = MUST_NOT_MOVE + (
         "TPU_DEVICE_DISPATCHES", "TILE_LOWERED_TOTAL", "TQL_TILE_DISPATCHES",
-        "TQL_TILE_COLD_SERVES", "TPU_READBACK_BYTES", "TILE_MESH_DISPATCHES",
+        "TQL_TILE_LOGICAL_DISPATCHES", "TQL_TILE_COLD_SERVES", "TPU_READBACK_BYTES", "TILE_MESH_DISPATCHES",
         "TILE_MESH_DEGRADED", "TILE_MESH_INELIGIBLE", "TPU_DEVICE_FINALIZE",
     )
 
@@ -708,7 +748,7 @@ def smoke_one_chip(ds: Dataset, home: str):
     try:
         emit({"event": "loaded", **load(db, ds, home, regions=1)})
         t0 = time.perf_counter()
-        built = db.prewarm(tables=["cpu", "tql_cpu"])
+        built = db.prewarm(tables=["cpu", "tql_cpu", "me_cpu"])
         for key, stats in built.items():
             check("error" not in stats, f"prewarm {key}: {stats}")
         emit({
@@ -774,6 +814,33 @@ def smoke_one_chip(ds: Dataset, home: str):
                 cold_may_host_serve=True,
             ))
             emit({"event": "compile", "after": name, **_compile_snapshot()})
+
+        # the same rate over the logical table of twelve labels, one rack's
+        # hosts of it: equal to the mito table's, series for series (its own
+        # order is the labels')
+        rack = ME_LABELS.index("rack")
+        hosts = np.array([
+            h for h in ds.host_order() if (h * 7 + rack) % (rack + 2) == 1
+        ])
+        fold = fold_rate(
+            ds, start_s * 1000, end_s * 1000, 60_000, 300_000, hosts, True,
+        )
+        emit(run_request(
+            "rate-logical",
+            lambda: client.query_range(
+                'rate(me_cpu{rack="rack-1"}[5m])', start_s, end_s, 60
+            ),
+            lambda result: sorted(_matrix_rows(result)),
+            sorted(
+                (str(ds.host_names[h]), ts, v)
+                for h in hosts for ts, v in fold[int(h)]
+            ),
+            RTOL_F64, db,
+            ("TPU_DEVICE_DISPATCHES", "TQL_TILE_DISPATCHES",
+             "TQL_TILE_LOGICAL_DISPATCHES"),
+            cold_may_host_serve=True,
+        ))
+        emit({"event": "compile", "after": "rate-logical", **_compile_snapshot()})
 
         # an acknowledged write is read back (the value differs per run,
         # so a reused data home cannot answer from an older write)

@@ -1011,8 +1011,15 @@ class Database:
         else:
             batches = [rows]
         total = 0
+        # a logical table's label columns stay dictionary-encoded where they
+        # come so: the metric engine hashes a `__tsid` per distinct label
+        # set, which the codes give it without a pass over the strings
+        keep = is_logical_meta(meta)
         for b in batches:
-            total += self.write_batch(meta, _conform_batch(b, meta.schema), system=system)
+            total += self.write_batch(
+                meta, _conform_batch(b, meta.schema, keep_dictionaries=keep),
+                system=system,
+            )
         return total
 
     # ---- SHOW/DESCRIBE ----------------------------------------------------
@@ -1233,32 +1240,44 @@ class Database:
             self.process_manager.check_cancelled()  # between-region point
         return out
 
-    def _tile_context(self, scan: TableScan):
+    def _tile_context(self, scan: TableScan, logical: bool = False):
         """TileContext for the HBM tile cache, or None when this scan's
-        source can't be tiled (virtual/logical/external tables)."""
+        source can't be tiled (virtual/external tables).  A metric-engine
+        logical table tiles as its physical table's context with
+        `logical_table_id` set, for the callers that ask with `logical`
+        (the TQL tile path and `prewarm`): its rows are a range of the
+        physical region's planes, which every logical table shares."""
         from .models import information_schema as info
         from .parallel.tile_cache import TileContext
         from .storage import file_engine as fe
 
         if not scan.table or info.is_information_schema(scan.database):
             return None
+        database = scan.database or self.current_database
         try:
             meta = self.catalog.table(scan.table, scan.database)
+            table_id = None
+            if is_logical_meta(meta):
+                if not logical:
+                    return None
+                table_id = meta.table_id
+                meta = self.catalog.table(meta.options[LOGICAL_TABLE_OPT], database)
         except TableNotFoundError:
             return None
-        if is_logical_meta(meta) or fe.is_external_meta(meta):
+        if fe.is_external_meta(meta):
             return None
         try:
             regions = [self.storage.region(rid) for rid in meta.region_ids]
         except Exception:  # noqa: BLE001 — region mid-drop: fall back
             return None
-        key = f"{scan.database or self.current_database}.{scan.table}"
+        key = f"{database}.{meta.name}"
         return TileContext(
             table_key=key,
             dictionary=self.dicts.get(key),
             regions=regions,
             append_mode=any(r.append_mode for r in regions),
             partition_columns=meta.partition_rule.key_columns(),
+            logical_table_id=table_id,
         )
 
     # ---- tile prewarm (cold path off the query path) ----------------------
@@ -1269,13 +1288,17 @@ class Database:
         10-170 s the FIRST query of each TSBS family otherwise pays.
         Explicit form of `tile.prewarm_on_flush`; returns per-table build
         stats.  `tables` restricts to the named tables (bare or
-        db-qualified); best-effort throughout."""
+        db-qualified).  Logical tables of one physical table share its
+        region's planes: they are built once, and each logical table's
+        entry is that build's stats with `physical` naming the table
+        built.  A build that raises is an `error` in the table's stats."""
         from .models import information_schema as info
 
         te = self.query_engine._tile_executor
         if te is None:
             return {}
         out: dict = {}
+        built: dict = {}  # physical table key -> its build's stats
         dbs = [database] if database else self.catalog.databases()
         want = set(tables) if tables else None
         cfg_tables = set(getattr(self.config.tile, "prewarm_tables", ()) or ())
@@ -1288,25 +1311,37 @@ class Database:
                     continue
                 if cfg_tables and meta.name not in cfg_tables and key not in cfg_tables:
                     continue
-                ctx = self._tile_context(TableScan(table=meta.name, database=db))
+                if is_logical_meta(meta) and want is None:
+                    continue  # its physical table is in the walk
+                ctx = self._tile_context(
+                    TableScan(table=meta.name, database=db), logical=True
+                )
                 if ctx is None:
                     continue
-                try:
-                    from .utils.deadline import deadline_scope
-
-                    schema = self._schema_of(meta.name, db)
-                    # arm the per-statement deadline ourselves: sql() does
-                    # this for queries, but prewarm is not a statement —
-                    # without it query.timeout_s would be advisory here
-                    # and a huge consolidation could run unbounded
-                    with deadline_scope(self.config.query.timeout_s):
-                        out[key] = te.prewarm(
-                            ctx, schema,
-                            limbs=getattr(self.config.tile, "prewarm_limbs", True),
-                        )
-                except Exception as e:  # noqa: BLE001 — prewarm never fails callers
-                    out[key] = {"error": repr(e)}
+                if ctx.table_key not in built:
+                    built[ctx.table_key] = self._prewarm_one(te, ctx, db)
+                out[key] = (
+                    {**built[ctx.table_key], "physical": ctx.table_key}
+                    if ctx.logical_table_id is not None else built[ctx.table_key]
+                )
         return out
+
+    def _prewarm_one(self, te, ctx, db: str) -> dict:
+        from .utils.deadline import deadline_scope
+
+        try:
+            schema = self._schema_of(ctx.table_key.split(".", 1)[1], db)
+            # arm the per-statement deadline ourselves: sql() does this
+            # for queries, but prewarm is not a statement — without it
+            # query.timeout_s would be advisory here and a huge
+            # consolidation could run unbounded
+            with deadline_scope(self.config.query.timeout_s):
+                return te.prewarm(
+                    ctx, schema,
+                    limbs=getattr(self.config.tile, "prewarm_limbs", True),
+                )
+        except Exception as e:  # noqa: BLE001 — prewarm never fails callers
+            return {"error": repr(e)}
 
     def _start_flush_prewarmer(self):
         """tile.prewarm_on_flush: coalesce flush notifications per table
@@ -1818,9 +1853,13 @@ def compute_altered_schema(stmt, schema: Schema) -> Schema:
     raise UnsupportedError(f"unsupported ALTER action: {stmt.action}")
 
 
-def _conform_batch(batch: pa.RecordBatch, schema: Schema) -> pa.RecordBatch:
-    """Reorder/cast incoming batch columns to the table schema."""
-    arrays = []
+def _conform_batch(
+    batch: pa.RecordBatch, schema: Schema, keep_dictionaries: bool = False
+) -> pa.RecordBatch:
+    """Reorder/cast incoming batch columns to the table schema; with
+    `keep_dictionaries` a dictionary-encoded column of the wanted value
+    type passes as it is (the batch then carries its own Arrow schema)."""
+    arrays, kept = [], False
     for col in schema.columns:
         i = batch.schema.get_field_index(col.name)
         if i < 0:
@@ -1828,9 +1867,16 @@ def _conform_batch(batch: pa.RecordBatch, schema: Schema) -> pa.RecordBatch:
         else:
             arr = batch.column(i)
             want = col.data_type.to_arrow()
-            if arr.type != want:
+            if (
+                keep_dictionaries and pa.types.is_dictionary(arr.type)
+                and arr.type.value_type == want
+            ):
+                kept = True
+            elif arr.type != want:
                 arr = arr.cast(want)
             arrays.append(arr)
+    if kept:
+        return pa.RecordBatch.from_arrays(arrays, names=[c.name for c in schema.columns])
     return pa.RecordBatch.from_arrays(arrays, schema=schema.to_arrow())
 
 

@@ -31,6 +31,7 @@ import json
 import os
 import threading
 
+import numpy as np
 import pyarrow as pa
 
 from ..datatypes.data_type import ConcreteDataType
@@ -69,13 +70,73 @@ def is_logical_meta(meta: TableMeta) -> bool:
 def tsid_hash(pairs: list[tuple[str, str]]) -> int:
     """Stable 64-bit series id from sorted (label, value) pairs (reference
     row_modifier.rs TsidGenerator).  Signed so it fits arrow int64."""
-    h = hashlib.blake2b(digest_size=8)
-    for k, v in sorted(pairs):
-        h.update(k.encode())
-        h.update(b"\x00")
-        h.update(str(v).encode())
-        h.update(b"\x01")
-    return int.from_bytes(h.digest(), "little", signed=True)
+    digest = hashlib.blake2b(
+        b"".join(
+            b"%b\x00%b\x01" % (k.encode(), str(v).encode())
+            for k, v in sorted(pairs)
+        ),
+        digest_size=8,
+    ).digest()
+    return int.from_bytes(digest, "little", signed=True)
+
+
+def _batch_tsids(metric: str, labels: dict, n: int):
+    """The `__tsid` of every row of a batch, `tsid_hash` of the row's
+    non-null (label, value) pairs and `__name__`: hashed once per DISTINCT
+    label set.  Each label column is dictionary-encoded (as it comes, or
+    by one hash pass), the rows' codes are folded into one int64 key a
+    row, and the distinct keys are the distinct label sets; a key space
+    past 2^62 is made dense again before the next column joins it."""
+    import pyarrow.compute as pc
+
+    from ..utils import metrics
+
+    if n == 0:
+        return np.zeros(0, np.int64)
+    names, values, indices = [], [], []
+    key = np.zeros(n, np.int64)
+    space = 1
+    for name, col in labels.items():
+        if isinstance(col, pa.ChunkedArray):
+            col = col.combine_chunks()
+        if not pa.types.is_dictionary(col.type):
+            col = pc.dictionary_encode(col)
+        vals = col.dictionary.to_pylist()
+        # a row's digit in the key: 0 = the label is absent from the row,
+        # else 1 + the value's index
+        index = col.indices
+        if index.null_count:
+            index = pc.fill_null(index, -1)
+        index = index.to_numpy(zero_copy_only=False)
+        card = len(vals) + 1
+        if space * card >= 1 << 62:
+            dense = pc.dictionary_encode(pa.array(key))
+            key = dense.indices.to_numpy().astype(np.int64)
+            space = len(dense.dictionary)
+        # in place: a fresh 8 B x rows array a step costs more than the step
+        np.multiply(key, card, out=key)
+        np.add(key, index, out=key)
+        key += 1
+        space *= card
+        names.append(name)
+        values.append(vals)
+        indices.append(index)
+    # distinct keys by one hash pass; a set's first row by one scatter (of
+    # the rows written back to front the first is the one that stays)
+    sets = pc.dictionary_encode(pa.array(key))
+    inverse = sets.indices.to_numpy()
+    first = np.empty(len(sets.dictionary), np.int64)
+    first[inverse[::-1]] = np.arange(n - 1, -1, -1)
+    picked = [index[first].tolist() for index in indices]
+    hashes = np.empty(len(first), np.int64)
+    for i in range(len(first)):
+        pairs = [("__name__", metric)]
+        for name, vals, at in zip(names, values, picked):
+            if at[i] >= 0 and vals[at[i]] is not None:
+                pairs.append((name, vals[at[i]]))
+        hashes[i] = tsid_hash(pairs)
+    metrics.METRIC_TSID_HASHES.inc(len(first))
+    return hashes[inverse]
 
 
 class MetadataRegion:
@@ -404,21 +465,15 @@ class MetricEngine:
         fields = meta.schema.field_columns()
         if fields:
             remap[phys_val] = fields[0].name
-        # Vectorised tsid: per-row hash over the (label, value) pairs.
-        label_values = {
-            name: batch.column(batch.schema.get_field_index(name)).to_pylist()
-            for name in label_cols
-            if batch.schema.get_field_index(name) >= 0
-        }
-        tsids = []
-        for i in range(n):
-            pairs = [
-                (name, vals[i])
-                for name, vals in label_values.items()
-                if vals[i] is not None
-            ]
-            pairs.append(("__name__", meta.name))
-            tsids.append(tsid_hash(pairs))
+        tsids = _batch_tsids(
+            meta.name,
+            {
+                name: batch.column(batch.schema.get_field_index(name))
+                for name in label_cols
+                if batch.schema.get_field_index(name) >= 0
+            },
+            n,
+        )
         # Conform to the physical schema: logical ts/val keep their names
         # (schemas share them); absent physical labels become nulls.
         by_name = {batch.schema.field(i).name: batch.column(i) for i in range(batch.num_columns)}
@@ -426,7 +481,7 @@ class MetricEngine:
         for col in phys_schema.columns:
             source = remap.get(col.name, col.name)
             if col.name == TABLE_ID_COL:
-                arrays.append(pa.array([meta.table_id] * n, pa.int64()))
+                arrays.append(pa.array(np.full(n, meta.table_id, np.int64)))
             elif col.name == TSID_COL:
                 arrays.append(pa.array(tsids, pa.int64()))
             elif source in by_name:

@@ -114,6 +114,29 @@ def time_bucket(ts: jnp.ndarray, origin: int, interval: int) -> jnp.ndarray:
 
 
 @jax.named_scope("series_ordinals")
+def run_ordinals(keys, lo, hi) -> jnp.ndarray:
+    """Ordinal [n] int32 of each row's series among the rows [lo, hi) of a
+    (series, ts) sorted source: the number of rows of [lo, row] at which a
+    plane of `keys` (one or more [n] planes, compared together) differs
+    from the row before, less one; row `lo` opens series 0.  One compare a
+    plane with the previous row and one int32 prefix sum, whatever the
+    number of planes or of distinct values.  Rows before `lo` read -1 and
+    rows from `hi` on continue the last series: the caller masks both."""
+    from .rate import prefix_scan
+
+    n = keys[0].shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    start = rows == lo
+    for k in keys:
+        start = start | (k != jnp.concatenate([k[:1], k[:-1]]))
+    start = start & (rows >= lo) & (rows < hi)
+    (seen,) = prefix_scan(
+        lambda a, b: (a[0] + b[0],), (start.astype(jnp.int32),), (0,)
+    )
+    return seen - 1
+
+
+@jax.named_scope("series_ordinals")
 def series_ordinals(
     codes: jnp.ndarray, valid: jnp.ndarray, card: int
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:

@@ -400,6 +400,54 @@ class TileContext:
     # for one region): a region of a rule over a tag holds a strict subset
     # of that tag's dictionary
     partition_columns: tuple[str, ...] = ()
+    # a metric-engine logical table: key, dictionary and regions above are
+    # its PHYSICAL table's, and this is the `__table_id` its rows carry
+    logical_table_id: int | None = None
+
+
+@dataclass
+class SeriesTable:
+    """The series of one region's consolidated rows, read once per plane
+    build off the (pk, ts) sorted host planes: the rows of series k are
+    [starts[k], starts[k + 1]) and `codes[k]` its dictionary code per pk
+    column (`tags`), at the dictionary epoch in `key`.  A metric engine's
+    physical region holds its logical tables one after the other
+    (`__table_id` leads the key), so a logical table is a range of series
+    and of rows.  What a request derives from it (label tuples, label
+    order) is kept in `memo` for the next: at most `MEMO_MAX` of them,
+    the oldest going first, so a client that sends ever new `by` sets
+    grows nothing."""
+
+    MEMO_MAX = 32
+
+    key: tuple
+    tags: tuple[str, ...]
+    starts: np.ndarray  # [S + 1] int64
+    codes: np.ndarray  # [S, len(tags)] int32
+    memo: dict = field(default_factory=dict)
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    @property
+    def nbytes(self) -> int:
+        return self.starts.nbytes + self.codes.nbytes
+
+    def remember(self, key, make):
+        """`memo[key]`, made by `make()` where it is not there yet."""
+        if key not in self.memo:
+            if len(self.memo) >= self.MEMO_MAX:
+                self.memo.pop(next(iter(self.memo)))
+            self.memo[key] = make()
+        return self.memo[key]
+
+    def code_range(self, tag: str, code: int) -> tuple[int, int]:
+        """Series [s_lo, s_hi) whose LEADING key column holds `code`."""
+        col = self.codes[:, self.tags.index(tag)]
+        return (
+            int(np.searchsorted(col, code, side="left")),
+            int(np.searchsorted(col, code, side="right")),
+        )
 
 
 @dataclass
@@ -461,6 +509,9 @@ class _SuperTiles:
     # the first window probe (TileCacheManager._ts_runs), dropped wherever
     # the plane is replaced or grows
     ts_run_starts: np.ndarray | None = None
+    # the region's series (TileCacheManager.series_table), keyed by the
+    # file set and the dictionary epoch it was read at
+    series_table: SeriesTable | None = None
     host_epochs: dict[str, int] = field(default_factory=dict)
     file_row_offsets: np.ndarray | None = None
     # the cold-serve router answered from host once: the next grouped
@@ -2209,6 +2260,7 @@ class TileCacheManager:
             }
             entry.file_row_offsets = new_offsets
             entry.ts_run_starts = None  # the plane grew: its runs moved
+            entry.series_table = None  # and its series' rows with them
             entry.keep_host = None
             entry.keep_prefix = None
             entry.valid_dedup = None
@@ -2786,6 +2838,44 @@ class TileCacheManager:
             ))
         return out
 
+    def series_table(
+        self, entry: _SuperTiles, dictionary: TableDictionary, pk_cols,
+    ) -> SeriesTable | None:
+        """The entry's `SeriesTable` over `pk_cols`, made at the first
+        request after a plane build (or a dictionary growth), kept on the
+        entry and charged to the host budget like the sorted planes it is
+        read from.  The caller holds the table lock and has run
+        `repair_super`, so the sorted host codes are the dictionary's
+        current ones.  None where the entry has no sorted host planes."""
+        pk_cols = tuple(pk_cols)
+        key = (entry.file_ids, entry.num_rows, dictionary.epoch, pk_cols)
+        table = entry.series_table
+        if table is not None and table.key == key:
+            return table
+        if entry.order is None or any(c not in entry.sorted_host for c in pk_cols):
+            return None
+        n = entry.num_rows
+        planes = [np.asarray(entry.sorted_host[c][:n]) for c in pk_cols]
+        change = np.zeros(max(n - 1, 0), bool)
+        for plane in planes:
+            change |= plane[1:] != plane[:-1]
+        first = np.concatenate([[0], np.flatnonzero(change) + 1]) if n else np.zeros(0, np.int64)
+        table = SeriesTable(
+            key=key, tags=pk_cols,
+            starts=np.concatenate([first, [n]]).astype(np.int64),
+            codes=np.stack([plane[first] for plane in planes], axis=1).astype(np.int32)
+            if pk_cols else np.zeros((len(first), 0), np.int32),
+        )
+        with self._lock:
+            added = table.nbytes - (
+                entry.series_table.nbytes if entry.series_table is not None else 0
+            )
+            entry.series_table = table
+            entry.host_nbytes += added
+            if self._super.get(entry.region_id) is entry:
+                self._host_used += added
+        return table
+
     def ensure_dedup_keep(self, entry: _SuperTiles) -> bool:
         """Build (once per file-set) the last-write-wins keep plane from
         the sorted host encodes: a row survives unless the NEXT row holds
@@ -2931,9 +3021,12 @@ class TileCacheManager:
         consolidation + sorted planes (what the cold-serve router and the
         selective host fast path read) — the prewarm form.
 
-        Best-effort like prewarm: a region that cannot tile is skipped,
-        never an error.  Callers serialize whole-table builds through
-        `build_gate` so concurrent builders coalesce."""
+        A region without flushed files is skipped; a region whose build
+        RAISES is skipped too, logged, and named under `errors` in the
+        stats, so that a caller who asked for the planes (`prewarm`) can
+        tell a table that cannot build from one that has nothing to build.
+        Callers serialize whole-table builds through `build_gate` so
+        concurrent builders coalesce."""
         t0 = time.perf_counter()
         pk = [c.name for c in schema.tag_columns()]
         ts_name = schema.time_index.name if schema.time_index else None
@@ -2982,6 +3075,7 @@ class TileCacheManager:
         dedup_any = any(m.dedup for m in manifests)
         built = 0
         built_entries: list[_SuperTiles] = []
+        errors: list[str] = []
         pinned_ids = {r.region_id for r in ctx.regions}
         log = logging.getLogger("greptimedb_tpu.tile")
         # the table lock is taken PER REGION (the prewarm discipline): a
@@ -3049,7 +3143,8 @@ class TileCacheManager:
                         )
                 except QueryTimeoutError:
                     raise
-                except Exception:  # noqa: BLE001 — fused build is best-effort
+                except Exception as exc:  # noqa: BLE001 — one region's failure is not the table's
+                    errors.append(f"region {region.region_id}: {exc!r}")
                     log.warning(
                         "fused build skipped region %s", region.region_id,
                         exc_info=True,
@@ -3071,6 +3166,7 @@ class TileCacheManager:
             "regions_built": built,
             "manifests": len(manifests),
             "ms": round((time.perf_counter() - t0) * 1000.0, 1),
+            **({"errors": errors} if errors else {}),
         }
 
 
@@ -6463,8 +6559,9 @@ class TileExecutor:
         costs, paid at flush time (tile.prewarm_on_flush) or explicitly
         (Database.prewarm) instead of on the first query of each TSBS
         family.  XLA compiles still happen on first dispatch but ride the
-        persistent compilation cache (utils/jax_env.py).  Best-effort: a
-        region that cannot tile is skipped, never an error."""
+        persistent compilation cache (utils/jax_env.py).  A region with
+        nothing flushed is skipped; one whose build raises is logged and
+        reported as `error` in the stats."""
         t0 = time.perf_counter()
         built = 0
         pk = [c.name for c in schema.tag_columns()]
@@ -6511,8 +6608,10 @@ class TileExecutor:
                 "regions_built": out.get("regions_built", 0),
                 "ms": round(ms, 1),
                 **({"coalesced": True} if out.get("coalesced") else {}),
+                **({"error": "; ".join(out["errors"])} if out.get("errors") else {}),
             }
         pinned_ids = {r.region_id for r in ctx.regions}
+        errors: list[str] = []
         nonnull = [
             c
             for c in value_cols
@@ -6547,7 +6646,8 @@ class TileExecutor:
                         )
                 except QueryTimeoutError:
                     raise
-                except Exception:  # noqa: BLE001 — prewarm is best-effort
+                except Exception as exc:  # noqa: BLE001 — one region's failure is not the table's
+                    errors.append(f"region {region.region_id}: {exc!r}")
                     logging.getLogger("greptimedb_tpu.tile").warning(
                         "prewarm skipped region %s", region.region_id,
                         exc_info=True,
@@ -6558,7 +6658,10 @@ class TileExecutor:
         if built:
             metrics.PREWARM_BUILDS.inc(built)
         metrics.PREWARM_MS.observe(ms)
-        return {"regions_built": built, "ms": round(ms, 1)}
+        return {
+            "regions_built": built, "ms": round(ms, 1),
+            **({"error": "; ".join(errors)} if errors else {}),
+        }
 
     # -- host fast path ------------------------------------------------------
     _HOST_PATH_MAX_ROWS = 4 << 20

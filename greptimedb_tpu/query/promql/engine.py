@@ -14,6 +14,7 @@ reference's ragged range-vector matrices (RangeManipulate).
 
 from __future__ import annotations
 
+import dataclasses
 import re
 from dataclasses import dataclass, field
 
@@ -23,6 +24,7 @@ import pyarrow as pa
 import pyarrow.compute as pc
 
 from ...datatypes.schema import SemanticType
+from ...metric.engine import is_logical_meta
 from ...utils import tracing
 from ...utils.errors import PlanError, UnsupportedError
 from ..logical_plan import TableScan
@@ -38,6 +40,7 @@ from .parser import (
     VectorSelector,
     parse_promql,
 )
+from .tile_exec import COLD_SERVE, LegacyFallbackDisabled, TqlTileExecutor
 
 DEFAULT_LOOKBACK_MS = 300_000  # Prometheus' 5m lookback delta
 
@@ -89,6 +92,23 @@ class Scalar:
 _TILE_UNSET = object()
 
 
+def _vector_selectors(node):
+    """Every vector selector of an expression."""
+    if isinstance(node, VectorSelector):
+        yield node
+    elif dataclasses.is_dataclass(node):
+        for f in dataclasses.fields(node):
+            child = getattr(node, f.name)
+            for c in child if isinstance(child, list) else (child,):
+                yield from _vector_selectors(c)
+
+
+def label_sort_key(values: tuple) -> tuple:
+    """Sort key of a series' label values: column by column, an absent
+    label as the empty string it is to Prometheus."""
+    return tuple("" if v is None else v for v in values)
+
+
 class PromqlEngine:
     def __init__(self, db, lookback_ms: int = DEFAULT_LOOKBACK_MS):
         self.db = db
@@ -109,8 +129,6 @@ class PromqlEngine:
                 and getattr(cfg, "tql", None) is not None
                 and cfg.tql.tile
             ):
-                from .tile_exec import TqlTileExecutor
-
                 self._tile = TqlTileExecutor(self.db)
         return self._tile
 
@@ -118,6 +136,7 @@ class PromqlEngine:
     def query_range(self, promql: str, start_ms: int, end_ms: int, step_ms: int) -> pa.Table:
         with tracing.stage("query.parse"):
             ast = parse_promql(promql)
+        label_order = self._reads_logical_tables(ast)
         out = self._eval(ast, start_ms, end_ms, step_ms)
         if isinstance(out, Scalar):
             steps = np.arange(start_ms, end_ms + 1, step_ms, dtype=np.int64)
@@ -125,7 +144,30 @@ class PromqlEngine:
                 {"ts": pa.array(steps, pa.timestamp("ms")), "value": out.row(len(steps)).copy()}
             )
         with tracing.stage("tql.assemble"):
-            return _matrix_to_table(out.drop_empty())
+            out = out.drop_empty()
+            if label_order:
+                turn = sorted(
+                    range(len(out.label_values)),
+                    key=lambda i: label_sort_key(out.label_values[i]),
+                )
+                out = Matrix(
+                    out.label_names, [out.label_values[i] for i in turn],
+                    out.values[turn], out.steps,
+                )
+            return _matrix_to_table(out)
+
+    def _reads_logical_tables(self, ast) -> bool:
+        """Every metric the expression reads is a metric-engine logical
+        table.  Their series come in `__tsid` order, a hash's, from the
+        tile path and the legacy scan alike, so the finished answer is put
+        in label order; an expression that reads a mito table keeps the
+        order its key gives."""
+        catalog, database = self.db.catalog, self.db.current_database
+        metrics_read = {sel.metric for sel in _vector_selectors(ast)}
+        return bool(metrics_read) and all(
+            catalog.has_table(m, database) and is_logical_meta(catalog.table(m, database))
+            for m in metrics_read
+        )
 
     def query_instant(self, promql: str, time_ms: int) -> pa.Table:
         return self.query_range(promql, time_ms, time_ms, max(1, 1000))
@@ -291,6 +333,7 @@ class PromqlEngine:
         # ineligible shape, tile failure) falls through to the legacy
         # scan-and-upload evaluation below, bit-for-bit tql.tile=false
         tile = self._tile_exec()
+        first_touch = False
         if tile is not None:
             at_ms = self._resolve_at(sel.at_spec, start, end)
             s0, e0, st0 = (
@@ -298,15 +341,16 @@ class PromqlEngine:
                 else (at_ms, at_ms, max(step, 1))
             )
             out = tile.try_range_eval(func, sel, range_ms, s0, e0, st0)
-            if out is not None:
+            if isinstance(out, Matrix):
                 return (
                     out if at_ms is None
                     else self._broadcast_fixed(out, start, end, step)
                 )
+            first_touch = out is COLD_SERVE
         return self._with_at(
             sel.at_spec, start, end, step,
             lambda s, e, st: self._range_from_samples(
-                func, self._fetch(sel, s - range_ms, e), range_ms, s, e, st
+                func, self._fetch(sel, s - range_ms, e, first_touch), range_ms, s, e, st
             ),
         )
 
@@ -593,16 +637,19 @@ class PromqlEngine:
             return None
         agg = (node.op, node.by, node.without)
         at_ms = self._resolve_at(sel.at_spec, start, end)
+        # a fold's first touch (COLD_SERVE) is no answer either: the
+        # caller evaluates per series, which asks the tile path again
         if at_ms is None:
-            return tile.try_range_eval(
+            out = tile.try_range_eval(
                 func, sel, range_ms, start, end, step, agg=agg
             )
+            return out if isinstance(out, Matrix) else None
         fixed = tile.try_range_eval(
             func, sel, range_ms, at_ms, at_ms, max(step, 1), agg=agg
         )
         return (
-            None if fixed is None
-            else self._broadcast_fixed(fixed, start, end, step)
+            self._broadcast_fixed(fixed, start, end, step)
+            if isinstance(fixed, Matrix) else None
         )
 
     def _eval_binary(self, node: BinaryExpr, start, end, step):
@@ -747,9 +794,17 @@ class PromqlEngine:
         return Matrix(m.label_names, m.label_values, vals, m.steps)
 
     # ---- data fetch --------------------------------------------------------
-    def _fetch(self, sel: VectorSelector, t_lo: int, t_hi: int):
+    def _fetch(self, sel: VectorSelector, t_lo: int, t_hi: int, first_touch: bool = False):
         """Scan the metric table; returns sorted flat (series, ts, value)
-        columns plus the series label decode."""
+        columns plus the series label decode.  Under `tql.legacy_fallback
+        = false` this scan answers a family's designed `first_touch` (the
+        tile path has just said COLD_SERVE) and nothing else."""
+        if (
+            not first_touch
+            and self._tile_exec() is not None
+            and not self.db.config.tql.legacy_fallback
+        ):
+            raise LegacyFallbackDisabled("the expression has no tile form")
         meta = self.db.catalog.table(sel.metric, self.db.current_database)
         schema = meta.schema
         ts_col = schema.time_index.name
@@ -1033,9 +1088,12 @@ def _matrix_to_table(m: Matrix) -> pa.Table:
     present = ~np.isnan(m.values)
     cols: dict[str, object] = {}
     s_idx, w_idx = np.nonzero(present)
+    # a label column is its series' values taken by the rows' series: one
+    # Arrow take a label, not a Python object a cell
+    rows = pa.array(s_idx.astype(np.int32))
     for li, name in enumerate(m.label_names):
-        vals = [m.label_values[s][li] for s in s_idx]
-        cols[name] = vals
+        per_series = pa.array([lv[li] for lv in m.label_values])
+        cols[name] = per_series.take(rows)
     cols["ts"] = pa.array(m.steps[w_idx], pa.timestamp("ms"))
     cols["value"] = m.values[present]
     return pa.table(cols)

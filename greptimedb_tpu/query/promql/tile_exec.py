@@ -29,11 +29,29 @@ Routing ladder (the `tql_tile` optimizer pass, off-switch `tql.tile`):
           upload-per-query path, bit-for-bit `tql.tile = false` behavior.
 
 Compiled programs are cached per SHAPE BUCKET (padded series space,
-padded step count, padded windows-per-sample, chunk geometry), with the
-evaluation grid (start/step/range), time bounds and matcher literals as
-dynamic inputs — the literal-insensitive `_plan_fp` discipline — so a
-dashboard sliding its window re-hits the compile cache with zero
-host->device plane traffic.
+padded step count, padded windows-per-sample, padded group space, chunk
+geometry), with the evaluation grid (start/step/range), time bounds and
+matcher literals as dynamic inputs — the literal-insensitive `_plan_fp`
+discipline — so a dashboard sliding its window re-hits the compile cache
+with zero host->device plane traffic.
+
+Series and labels.  A row's series id is the ORDINAL of its run of equal
+key codes in the (pk, ts)-sorted planes (`ops/aggregate.run_ordinals`), so
+the series space is the series that exist whatever the key's width.  What
+a request needs of the labels it reads off the region's `SeriesTable`
+(`parallel/tile_cache.py`: run starts and codes per key column, made once
+per plane build): matchers fold to one bool a series, a `by` aggregation
+to one group id a series, both dynamic inputs; the answer's label columns
+come from the same table.
+
+Logical tables.  A metric-engine logical table (Prometheus remote write,
+OTLP metrics) has no planes of its own: its rows are ONE RANGE of its
+physical region's planes (`__table_id` leads the key, `__tsid` follows;
+both ride the planes as dictionary codes like any tag).  The program is
+handed a padded power-of-two slice of the chunks that range touches
+(`_slice_plan`, `_logical_slice`: static size, dynamic offset — tables of
+about one size share a program) and compares `__tsid` alone; its work
+grows with the table's rows, not the region's.
 
 Parity contract (tests/test_tql_tile.py): per-series delta/*_over_time
 values, instant vectors, matcher filtering and the by-label folds are
@@ -63,6 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...ops.aggregate import run_ordinals
 from ...ops.rate import (
     WindowStats,
     extrapolated_rate_dyn,
@@ -75,7 +94,7 @@ from ...ops.rate import (
 )
 from ...utils import flight_recorder, metrics
 from ...utils import tracing
-from ...utils.errors import QueryTimeoutError
+from ...utils.errors import QueryTimeoutError, UnsupportedError
 from ...utils.fault_injection import fire as _fault_fire
 from .. import passes
 from ..logical_plan import TableScan
@@ -115,47 +134,94 @@ class _Ineligible(Exception):
     to the legacy scan path — never an error."""
 
 
+class LegacyFallbackDisabled(UnsupportedError):
+    """`tql.legacy_fallback = false` and the statement has no answer from
+    the device: the message names what sent it towards the legacy scan."""
+
+    def __init__(self, reason: str):
+        super().__init__(
+            f"tql.legacy_fallback = false: no answer from the device tiles ({reason})"
+        )
+
+
+# `try_range_eval`'s answer at a family's first touch: the planes are
+# being built in the background and this one evaluation is the legacy
+# scan's by design, whatever `tql.legacy_fallback` says
+COLD_SERVE = object()
+
+
+def _logical_slice(chunks, cut, off):
+    """Rows [off, off + L) of the planes that `chunks` lay end to end: the
+    rows of one logical table (and the neighbours its padded size reaches),
+    cut out of the region's planes on the device.  `cut` = (L, head, tail)
+    is static: of several chunks the first is entered `head` rows in and
+    the last `tail` rows deep, so what is copied is at most 2 L rows plus
+    the chunks in between, never the region; `off` is dynamic and counts
+    from that entry point, so another logical table of the same padded
+    size runs the same program."""
+    size, head, tail = cut
+    with jax.named_scope("logical_slice"):
+        if len(chunks) == 1:
+            plane = chunks[0]
+        else:
+            plane = jnp.concatenate(
+                [chunks[0][head:], *chunks[1:-1], chunks[-1][:tail]]
+            )
+        return jax.lax.dynamic_slice(plane, (off,), (size,))
+
+
 def _region_stats(src, dyn, rsig, csig):
     """Traced per-region pipeline: planes -> per-(series, window) stats +
-    per-series presence.  `src` = (tag_chunks..., ts_chunks, val_chunks,
-    null_chunks|None, valid_chunks); shapes come from `rsig`, query
-    structure from `csig`."""
-    (tag_chunks, ts_chunks, val_chunks, null_chunks, valid_chunks) = src
-    (func, _agg, s_pad, w_pad, k, radices, unit_ns, mask_spec, _gid) = csig
+    per-series presence.  `src` = (key_chunks..., ts_chunks, val_chunks,
+    null_chunks|None, valid_chunks, rows): the planes and, dynamic, where
+    the source's rows and series lie in them; shapes and the static cut of
+    a logical table come from `rsig`, query structure from `csig`."""
+    (key_chunks, ts_chunks, val_chunks, null_chunks, valid_chunks, at) = src
+    (func, _agg, s_pad, w_pad, k, unit_ns, _g_pad, matched) = csig
+    _planes, cut = rsig
 
     def cat(chunks):
+        if cut is not None:
+            return _logical_slice(chunks, cut, at["off"])
         return chunks[0] if len(chunks) == 1 else jnp.concatenate(list(chunks))
 
-    codes = [cat(c) for c in tag_chunks]
+    keys = [cat(c) for c in key_chunks]
     ts_nat = cat(ts_chunks)
     valid = cat(valid_chunks)
     vf = cat(val_chunks).astype(jnp.float64)
     if null_chunks is not None:
         vf = jnp.where(cat(null_chunks), vf, jnp.nan)
 
-    # fetch-range membership in the column's NATIVE unit — the exact
-    # region-scan bound semantics ([lo, hi) exclusive upper)
-    in_fetch = valid & (ts_nat >= dyn["lo"]) & (ts_nat < dyn["hi"])
-    for c in codes:
+    # the source's own rows [lo, hi) of what was cut (a mito table: all of
+    # it), and fetch-range membership in the column's NATIVE unit — the
+    # exact region-scan bound semantics ([lo, hi) exclusive upper)
+    rows = jnp.arange(ts_nat.shape[0], dtype=jnp.int32)
+    in_fetch = (
+        valid & (rows >= at["lo"]) & (rows < at["hi"])
+        & (ts_nat >= dyn["lo"]) & (ts_nat < dyn["hi"])
+    )
+    for c in keys:
         in_fetch = in_fetch & (c >= 0)
-    for (ti, card_pad), mask in zip(mask_spec, dyn["masks"]):
-        c = codes[ti]
-        in_fetch = (
-            in_fetch
-            & (c < card_pad)
-            & jnp.take(mask, jnp.clip(c, 0, card_pad - 1))
-        )
 
-    # mixed-radix series id over the pk tag codes (the same code space
-    # the (pk, ts) super-tile sort ordered rows by, so the rows are in
+    # series id = the ordinal of the row's run of equal key codes among the
+    # source's rows, from the source's first series on: the planes are
+    # (pk codes..., ts) sorted, so a series is one run and the rows are in
     # (series, ts) order — contiguity is what the reset scan needs, the
-    # order what `range_windows_dyn` searches; its docstring has the
-    # precondition and why these planes meet it)
-    sid = jnp.zeros(ts_nat.shape, jnp.int32)
-    stride = 1
-    for c, r in zip(reversed(codes), reversed(radices)):
-        sid = sid + c.astype(jnp.int32) * stride
-        stride *= r
+    # order what `range_windows_dyn` searches (its docstring has the
+    # precondition and why these planes meet it).  The ordinal costs the
+    # same whatever the key's width or its tags' cardinalities, and the
+    # series space is as large as the series that exist.
+    if keys:
+        sid = at["sid0"] + run_ordinals(keys, at["lo"], at["hi"])
+    else:
+        sid = jnp.zeros(ts_nat.shape, jnp.int32)
+    sid = jnp.clip(sid, 0, s_pad - 1)
+    # label matchers: one bool a series, folded on the host from the
+    # per-series label table, read here by the row's series (a gather over
+    # the rows: left out where every series passes)
+    if matched:
+        with jax.named_scope("label_gids"):
+            in_fetch = in_fetch & jnp.take(dyn["sel"], sid)
 
     # native -> ms exactly like the legacy fetch (truncating div), then
     # the offset modifier shift
@@ -184,7 +250,7 @@ def _finalize(stats: WindowStats, dyn, csig):
     """Traced tail: window stats -> [S, W] matrix (NaN = undefined) and,
     when an aggregation is fused, the grouped [G, W] matrix using the
     exact host formulas from PromqlEngine._eval_aggregate."""
-    (func, agg, s_pad, w_pad, _k, radices, _unit, _mask, keep_idx) = csig
+    (func, agg, s_pad, w_pad, _k, _unit, g_pad, _matched) = csig
     if func in _RATE_KINDS:
         vals, defined = extrapolated_rate_dyn(
             stats, dyn["start"], dyn["step"], dyn["range"], w_pad, func
@@ -199,14 +265,11 @@ def _finalize(stats: WindowStats, dyn, csig):
         return mat
     with jax.named_scope("by_fold"):
         op = agg
-        # the sid -> gid map is derivable from (radices, keep_idx) — built
-        # here at TRACE time so it constant-folds into the compiled program
-        # and never costs the warm path a per-query numpy pass
-        gidmap = _gid_map(radices, list(keep_idx))
-        gid = jnp.asarray(gidmap)
-        g_pad = 1
-        for i in keep_idx:
-            g_pad *= radices[i]
+        # the series -> group map comes with the request: the host reads it
+        # off the per-series label table (made once a plane build), so the
+        # group space is the groups that exist
+        with jax.named_scope("label_gids"):
+            gid = dyn["gid"]
         present = ~jnp.isnan(mat)
         zeroed = jnp.where(present, mat, 0.0)
         sums = jax.ops.segment_sum(zeroed, gid, num_segments=g_pad)
@@ -300,8 +363,11 @@ class TqlTileExecutor:
         """Evaluate `func` over sel[range_ms] on the eval grid
         (start..end@step, all ms) from device tiles; `agg` fuses a
         by-label aggregation: (op, by_labels|None, without_labels|None).
-        Returns an engine Matrix, or None to fall back to the legacy
-        path (reason recorded on the `tql_tile` pass trace)."""
+        Returns an engine Matrix; COLD_SERVE at a family's first touch,
+        or None (the path is off, or the shape ineligible: reason recorded
+        on the `tql_tile` pass trace), both for the legacy path to answer;
+        under `tql.legacy_fallback = false` an ineligible shape raises
+        `LegacyFallbackDisabled` instead."""
         cfg = getattr(self.db, "config", None)
         tql_cfg = getattr(cfg, "tql", None)
         if tql_cfg is None or not tql_cfg.tile:
@@ -332,7 +398,7 @@ class TqlTileExecutor:
                 with tracing.stage("tql.plan", func=func) as plan:
                     try:
                         return self._attempt(
-                            func, sel, range_ms, start, end, step, agg
+                            func, sel, range_ms, start, end, step, agg, plan
                         )
                     except _Ineligible as ie:
                         plan.set(ineligible=str(ie))
@@ -343,6 +409,7 @@ class TqlTileExecutor:
             if not _in_fused_build():
                 metrics.TQL_TILE_INELIGIBLE.inc()
             passes.note("tql_tile", False, f"{ie}: legacy scan path")
+            self._no_legacy(f"ineligible: {ie}")
             return None
         except Exception as exc:  # noqa: BLE001 — degrade, never fail
             metrics.TQL_TILE_DEGRADED.inc()
@@ -359,29 +426,26 @@ class TqlTileExecutor:
                 f"tile-path failure ({type(exc).__name__}): degraded to "
                 "the legacy scan path",
             )
+            self._no_legacy(f"tile-path failure: {exc!r}")
             return None
 
+    def _no_legacy(self, reason: str):
+        """`tql.legacy_fallback = false`: what would now be answered from
+        the legacy scan fails instead, naming why (the builder's own ghost
+        run answers nobody and has nothing to refuse)."""
+        from ...parallel.tile_cache import _in_fused_build
+
+        if not self.db.config.tql.legacy_fallback and not _in_fused_build():
+            raise LegacyFallbackDisabled(reason)
+
     # ---- attempt -----------------------------------------------------------
-    def _attempt(self, func, sel, range_ms, start, end, step, agg):
+    def _attempt(self, func, sel, range_ms, start, end, step, agg, plan):
         db = self.db
         meta = db.catalog.table(sel.metric, db.current_database)
         schema = meta.schema
         if schema.time_index is None:
             raise _Ineligible("metric table has no time index")
-        ts_name = schema.time_index.name
         tags = [c.name for c in schema.tag_columns()]
-        fields = schema.field_columns()
-        value_col = None
-        for cand in ("greptime_value", "value", "val"):
-            if any(f.name == cand for f in fields):
-                value_col = cand
-                break
-        if value_col is None:
-            if len(fields) != 1:
-                raise _Ineligible(
-                    f"metric has {len(fields)} fields; expected one"
-                )
-            value_col = fields[0].name
 
         steps = np.arange(start, end + 1, step, dtype=np.int64)
         w = len(steps)
@@ -402,7 +466,7 @@ class TqlTileExecutor:
             (eq_matchers if mt.op in ("=", "!=") else regex_matchers).append(mt)
 
         scan = TableScan(table=sel.metric, database=db.current_database)
-        ctx = db._tile_context(scan)
+        ctx = db._tile_context(scan, logical=True)
         if ctx is None:
             raise _Ineligible("table source cannot tile")
         if not ctx.regions:
@@ -412,9 +476,15 @@ class TqlTileExecutor:
             for r in ctx.regions
         ) and not ctx.append_mode:
             raise _Ineligible("last_non_null merge mode")
+        src = self._source(ctx, meta, tags)
+        if src.table_id is not None:
+            plan.set(logical_table=sel.metric, table_id=src.table_id)
+            if self.cache.mesh_devices() > 0:
+                raise _Ineligible("logical table under tile.mesh_devices")
+        ts_name, value_col = src.ts, src.value
 
         # fetch bounds: scan time_range semantics in the native unit
-        unit_ns = schema.time_index.data_type.timestamp_unit_ns()
+        unit_ns = src.schema.time_index.data_type.timestamp_unit_ns()
         offset = sel.offset_ms
         t_lo = start - range_ms
         lo_nat = (t_lo - offset) * 1_000_000 // unit_ns
@@ -439,10 +509,7 @@ class TqlTileExecutor:
                 sources_meta = self._acquire_regions(
                     ctx, lo_nat, hi_nat, ts_name, pinned
                 )
-                warm = all(
-                    self._warm_entry(s, tags, ts_name, value_col)
-                    for s in sources_meta
-                )
+                warm = all(self._warm_entry(s, src) for s in sources_meta)
                 if not warm:
                     if (
                         fused
@@ -452,7 +519,7 @@ class TqlTileExecutor:
                         # FIRST touch of the family: answer from the
                         # legacy scan now, build in the background
                         self._schedule_build(
-                            fp, ctx, schema, sources_meta, value_col, ts_name,
+                            fp, ctx, src, sources_meta,
                             func, sel, range_ms, start, end, step, agg,
                         )
                         metrics.TQL_TILE_COLD_SERVES.inc()
@@ -466,34 +533,65 @@ class TqlTileExecutor:
                             "family build scheduled",
                             cold=True,
                         )
-                        return None
+                        return COLD_SERVE
                     # known family gone stale (post-flush delta), fused
                     # builds off, or already inside the builder: build
                     # synchronously — delta-extend keeps this O(delta)
-                    self._build_sync(
-                        ctx, schema, sources_meta, value_col, ts_name
-                    )
+                    self._build_sync(ctx, src, sources_meta)
                     sources_meta = self._acquire_regions(
                         ctx, lo_nat, hi_nat, ts_name, pinned
                     )
-                    if not all(
-                        self._warm_entry(s, tags, ts_name, value_col)
-                        for s in sources_meta
-                    ):
+                    if not all(self._warm_entry(s, src) for s in sources_meta):
                         raise _Ineligible("planes did not build")
-                pk = [c.name for c in schema.tag_columns()]
                 self.cache.repair_super(
-                    [s["entry"] for s in sources_meta], dictionary, pk
+                    [s["entry"] for s in sources_meta], dictionary, src.pk
                 )
                 return self._dispatch(
-                    func, agg, sources_meta, dictionary, tags, ts_name,
-                    value_col, unit_ns, offset, lo_nat, hi_nat,
-                    start, end, step, steps, range_ms,
-                    eq_matchers, regex_matchers,
+                    func, agg, sources_meta, dictionary, src, unit_ns,
+                    offset, lo_nat, hi_nat, start, end, step, steps,
+                    range_ms, eq_matchers, regex_matchers, plan,
                 )
             finally:
                 for r in pinned:
                     r.unpin_scan()
+
+    def _source(self, ctx, meta, tags) -> "_Source":
+        """Which planes answer `meta`'s table: its own for a mito table;
+        for a metric-engine logical table its PHYSICAL table's, of whose
+        key the program compares `__tsid` alone (the slice is one
+        `__table_id`'s rows)."""
+        from ...metric.engine import TS_COL, TSID_COL, VAL_COL
+
+        schema = meta.schema
+        if ctx.logical_table_id is None:
+            fields = schema.field_columns()
+            value_col = None
+            for cand in ("greptime_value", "value", "val"):
+                if any(f.name == cand for f in fields):
+                    value_col = cand
+                    break
+            if value_col is None:
+                if len(fields) != 1:
+                    raise _Ineligible(
+                        f"metric has {len(fields)} fields; expected one"
+                    )
+                value_col = fields[0].name
+            pk = tuple(tags)
+            return _Source(
+                tags=tuple(tags), schema=schema, pk=pk, key_cols=pk,
+                ts=schema.time_index.name, value=value_col, table_id=None,
+            )
+        phys = self.db.catalog.table(
+            ctx.table_key.split(".", 1)[1], self.db.current_database
+        )
+        return _Source(
+            tags=tuple(tags), schema=phys.schema,
+            pk=tuple(c.name for c in phys.schema.tag_columns()),
+            key_cols=(TSID_COL,),
+            ts=phys.options.get("ts_col", TS_COL),
+            value=phys.options.get("val_col", VAL_COL),
+            table_id=ctx.logical_table_id,
+        )
 
     # ---- region acquisition ------------------------------------------------
     def _acquire_regions(self, ctx, lo_nat, hi_nat, ts_name, pinned):
@@ -552,12 +650,12 @@ class TqlTileExecutor:
             })
         return out
 
-    def _warm_entry(self, item, tags, ts_name, value_col):
+    def _warm_entry(self, item, src):
         """True when every plane this query needs is device-resident."""
         entry = item["entry"]
         if entry is None or entry.valid is None:
             return False
-        need = list(tags) + [ts_name, value_col]
+        need = [*src.key_cols, src.ts, src.value]
         if any(c not in entry.cols for c in need):
             return False
         if item["dedup"] and entry.valid_dedup is None:
@@ -565,13 +663,12 @@ class TqlTileExecutor:
         return True
 
     # ---- cold: background / synchronous builds -----------------------------
-    def _manifest(self, ctx, schema, value_col, ts_name, dedup):
+    def _manifest(self, ctx, src, dedup):
         from ...parallel.tile_cache import PlaneManifest
 
-        pk = tuple(c.name for c in schema.tag_columns())
         return PlaneManifest(
-            table_key=ctx.table_key, tag_cols=pk, ts_col=ts_name,
-            value_cols=(value_col,), dedup=dedup,
+            table_key=ctx.table_key, tag_cols=src.pk, ts_col=src.ts,
+            value_cols=(src.value,), dedup=dedup,
         )
 
     def _family_fp(self, ctx, value_col, func, agg, eq_matchers,
@@ -588,12 +685,13 @@ class TqlTileExecutor:
             None if agg[2] is None else tuple(agg[2]),
         )
         return (ctx.table_key, ctx.append_mode,
-                ("tql", value_col, func in _RATE_KINDS, structure, agg_fp))
+                ("tql", ctx.logical_table_id, value_col, func in _RATE_KINDS,
+                 structure, agg_fp))
 
-    def _schedule_build(self, fp, ctx, schema, sources_meta, value_col,
-                        ts_name, func, sel, range_ms, start, end, step, agg):
+    def _schedule_build(self, fp, ctx, src, sources_meta,
+                        func, sel, range_ms, start, end, step, agg):
         dedup = any(s["dedup"] for s in sources_meta)
-        manifest = self._manifest(ctx, schema, value_col, ts_name, dedup)
+        manifest = self._manifest(ctx, src, dedup)
 
         def ghost():
             # runs on the fused worker inside fused_build_scope(): the
@@ -601,19 +699,21 @@ class TqlTileExecutor:
             # the compile + dispatch for the family's geometry
             self.try_range_eval(func, sel, range_ms, start, end, step, agg)
 
-        self.executor.fused_schedule_custom(fp, manifest, ctx, schema, ghost)
+        self.executor.fused_schedule_custom(
+            fp, manifest, ctx, src.schema, ghost
+        )
 
-    def _build_sync(self, ctx, schema, sources_meta, value_col, ts_name):
+    def _build_sync(self, ctx, src, sources_meta):
         """Synchronous plane build (tile.fused_build off, or the ghost
         run finishing what the union build skipped)."""
-        pk = [c.name for c in schema.tag_columns()]
+        pk = list(src.pk)
         pinned_ids = {r.region_id for r in ctx.regions}
         for item in sources_meta:
-            if self._warm_entry(item, pk, ts_name, value_col):
+            if self._warm_entry(item, src):
                 continue
             entry, _excluded = self.cache.super_tiles(
-                item["region"], ctx.dictionary, item["metas"], pk, ts_name,
-                [value_col], pinned_ids, pk,
+                item["region"], ctx.dictionary, item["metas"], pk, src.ts,
+                [src.value], pinned_ids, pk,
             )
             if entry is None:
                 raise _Ineligible("region cannot tile")
@@ -622,23 +722,27 @@ class TqlTileExecutor:
             item["entry"] = entry
 
     # ---- dispatch ----------------------------------------------------------
-    def _dispatch(self, func, agg, sources_meta, dictionary, tags, ts_name,
-                  value_col, unit_ns, offset, lo_nat, hi_nat,
-                  start, end, step, steps, range_ms,
-                  eq_matchers, regex_matchers):
+    def _dispatch(self, func, agg, sources_meta, dictionary, src, unit_ns,
+                  offset, lo_nat, hi_nat, start, end, step, steps, range_ms,
+                  eq_matchers, regex_matchers, plan):
         from ...parallel.tile_cache import _in_fused_build
 
         cfg = self.db.config
+        tags = list(src.tags)
         for item in sources_meta:
-            if not self._warm_entry(item, tags, ts_name, value_col):
+            if not self._warm_entry(item, src):
                 raise _Ineligible("needed planes not resident")
 
+        # --- the series that exist: each region's run of the global series
+        # space, and of a logical table its rows in the region's planes ---
+        spans = self._series_spans(sources_meta, dictionary, src)
+        n_series = sum(sp.s_hi - sp.s_lo for sp in spans)
+        if src.table_id is not None and n_series == 0:
+            return _empty_matrix(tags, agg, steps)  # as the legacy scan
+        labels = _SeriesLabels(spans, tags, dictionary)
+
         # --- geometry buckets (pow2: sliding queries share programs) ---
-        cards = [max(dictionary.cardinality(t), 1) for t in tags]
-        radices = tuple(_pow2(c) for c in cards)
-        s_pad = 1
-        for r in radices:
-            s_pad *= r
+        s_pad = _pow2(max(n_series, 1))
         w = len(steps)
         w_pad = _pow2(w)
         k = _pow2(max(-(-range_ms // step), 1))
@@ -647,90 +751,100 @@ class TqlTileExecutor:
                 f"series*steps cells {s_pad}x{w_pad} exceed tql.max_cells"
             )
 
-        # --- matcher masks (dynamic [card_pad] bools per filtered tag) ---
-        mask_arrays: dict[int, np.ndarray] = {}
-
-        def mask_for(ti):
-            if ti not in mask_arrays:
-                card_pad = radices[ti]
-                m = np.zeros(card_pad, dtype=bool)
-                m[: cards[ti]] = True
-                mask_arrays[ti] = m
-            return mask_arrays[ti]
-
+        # --- matchers: a bool per series, folded from code masks over the
+        # per-series label table (a dynamic input: literals never recompile)
+        selected = np.zeros(s_pad, bool)
+        selected[:n_series] = (labels.codes >= 0).all(axis=1)
         for mt in eq_matchers:
-            ti = tags.index(mt.label)
-            m = mask_for(ti)
+            col = labels.codes[:, tags.index(mt.label)]
             code = dictionary.code_of(mt.label, mt.value)
             if mt.op == "=":
-                sel_mask = np.zeros(len(m), dtype=bool)
-                if code >= 0:
-                    sel_mask[code] = True
-                mask_arrays[ti] = m & sel_mask
+                selected[:n_series] &= (col == code) & (code >= 0)
             else:  # != — scan-filter semantics: null rows do not match
-                if code >= 0:
-                    m[code] = False
-                nc = _null_code(dictionary, mt.label)
-                if nc >= 0:
-                    m[nc] = False
+                selected[:n_series] &= (
+                    (col != code) & (col != _null_code(dictionary, mt.label))
+                )
         for mt in regex_matchers:
-            ti = tags.index(mt.label)
-            m = mask_for(ti)
             pat = re.compile(mt.value)
-            values = dictionary.values(mt.label)
-            rx = np.zeros(len(m), dtype=bool)
-            for code, v in enumerate(values):
-                rx[code] = bool(pat.fullmatch(v if v is not None else ""))
+            hit = np.array([
+                bool(pat.fullmatch(v if v is not None else ""))
+                for v in dictionary.values(mt.label)
+            ] + [False], bool)
             if mt.op == "!~":
-                rx[: len(values)] = ~rx[: len(values)]
-            mask_arrays[ti] = m & rx
-        mask_spec = tuple(sorted((ti, radices[ti]) for ti in mask_arrays))
-        masks = tuple(mask_arrays[ti] for ti, _c in mask_spec)
+                hit[:-1] = ~hit[:-1]
+            col = labels.codes[:, tags.index(mt.label)]
+            selected[:n_series] &= hit[np.where(col < len(hit) - 1, col, -1)]
 
-        # --- fused aggregation structure ---
+        # --- fused aggregation structure: a group id per series ---
         agg_op = None
         keep: list[str] = []
-        keep_idx: list[int] = []
+        gids = g_pad = None
         if agg is not None:
             agg_op, by, without = agg
             if by is not None:
                 keep = [l for l in by if l in tags]
             elif without is not None:
                 keep = [l for l in tags if l not in without]
-            keep_idx = [tags.index(l) for l in keep]
+            gids = labels.group_ids(keep)
+            g_pad = _pow2(max(len(gids.keys), 1))
 
-        csig = (
-            func, agg_op, s_pad, w_pad, k, radices, unit_ns, mask_spec,
-            tuple(keep_idx),
-        )
+        matched = not bool(selected[:n_series].all())
+        csig = (func, agg_op, s_pad, w_pad, k, unit_ns, g_pad, matched)
 
         # --- device sources ---
         sources = []
         region_sigs = []
-        for item in sources_meta:
+        plane_rows = 0
+        for item, sp in zip(sources_meta, spans):
             entry = item["entry"]
             valid = entry.valid_dedup if item["dedup"] else entry.valid
-            null_chunks = (
-                tuple(entry.nulls[value_col])
-                if value_col in entry.nulls else None
-            )
-            src = (
-                tuple(tuple(entry.cols[t]) for t in tags),
-                tuple(entry.cols[ts_name]),
-                tuple(entry.cols[value_col]),
-                null_chunks,
+            planes = [
+                tuple(tuple(entry.cols[t]) for t in src.key_cols),
+                tuple(entry.cols[src.ts]),
+                tuple(entry.cols[src.value]),
+                tuple(entry.nulls[src.value])
+                if src.value in entry.nulls else None,
                 tuple(valid),
-            )
-            rsig = _source_sig(src)
-            sources.append(src)
-            region_sigs.append(rsig)
+            ]
+            at = {"sid0": np.int32(sp.sid0)}
+            if src.table_id is None:
+                cut = None
+                at.update(off=np.int32(0), lo=np.int32(0), hi=np.int32(entry.pad))
+                plane_rows += entry.pad
+            else:
+                # the table's rows [r_lo, r_hi) as a padded slice of the
+                # chunks it touches: static size, dynamic place
+                lengths = [int(c.shape[0]) for c in planes[1]]
+                first, last, cut, off, base = _slice_plan(
+                    lengths, sp.r_lo, sp.r_hi
+                )
+                touched = slice(first, last + 1)
+                planes = [
+                    tuple(chunks[touched] for chunks in planes[0]),
+                    *(p if p is None else p[touched] for p in planes[1:]),
+                ]
+                at.update(
+                    off=np.int32(off), lo=np.int32(sp.r_lo - base),
+                    hi=np.int32(sp.r_hi - base),
+                )
+                plane_rows += cut[0]
+                plan.set(slice_rows=cut[0], series=n_series)
+            source = (*planes, at)
+            sources.append(source)
+            region_sigs.append((_source_sig(source), cut))
 
         dyn = {
             "lo": np.int64(lo_nat), "hi": np.int64(hi_nat),
             "offset": np.int64(offset), "start": np.int64(start),
             "step": np.int64(step), "range": np.int64(range_ms),
-            "nsteps": np.int64(w), "masks": masks,
+            "nsteps": np.int64(w),
         }
+        if matched:
+            dyn["sel"] = selected
+        if gids is not None:
+            by_series = np.zeros(s_pad, np.int32)
+            by_series[:n_series] = gids.of_series
+            dyn["gid"] = by_series
 
         ghost = _in_fused_build()
         mesh_n = self.cache.mesh_devices()
@@ -745,7 +859,8 @@ class TqlTileExecutor:
                 )
             else:
                 sources = [
-                    _colocate(src, self.cache.devices[0]) for src in sources
+                    _colocate(source, self.cache.devices[0])
+                    for source in sources
                 ]
                 fn = _full_program((csig, tuple(region_sigs)))
                 if not ghost:
@@ -760,6 +875,9 @@ class TqlTileExecutor:
         )
         if not ghost:
             metrics.TQL_TILE_DISPATCHES.inc()
+            metrics.TQL_TILE_PLANE_ROWS.inc(plane_rows)
+            if src.table_id is not None:
+                metrics.TQL_TILE_LOGICAL_DISPATCHES.inc()
             if reductions_for(func):
                 metrics.TQL_TILE_SEGMENT_STATS.inc()
         passes.note(
@@ -772,9 +890,31 @@ class TqlTileExecutor:
         )
         with tracing.stage("tql.assemble"):
             return self._assemble(
-                np_mat, np_pres, dictionary, tags, steps, w, agg_op, keep,
-                radices, keep_idx, pregathered,
+                np_mat, np_pres, labels, steps, w, keep, gids, pregathered
             )
+
+    def _series_spans(self, sources_meta, dictionary, src) -> list:
+        """Per region: where the source's series lie in the region's
+        series table and its rows in the planes, and the first of its ids
+        in the global series space (regions in scan order)."""
+        from ...metric.engine import TABLE_ID_COL
+
+        spans, sid0 = [], 0
+        for item in sources_meta:
+            table = self.cache.series_table(item["entry"], dictionary, src.pk)
+            if table is None:
+                raise _Ineligible("no sorted host planes for the series table")
+            if src.table_id is None:
+                s_lo, s_hi = 0, len(table)
+            else:
+                code = dictionary.code_of(TABLE_ID_COL, src.table_id)
+                s_lo, s_hi = table.code_range(TABLE_ID_COL, code) if code >= 0 else (0, 0)
+            spans.append(_Span(
+                table, s_lo, s_hi, int(table.starts[s_lo]),
+                int(table.starts[s_hi]), sid0,
+            ))
+            sid0 += s_hi - s_lo
+        return spans
 
     def _mesh_dispatch(self, csig, sources, region_sigs, dyn, sources_meta,
                        ghost):
@@ -844,73 +984,34 @@ class TqlTileExecutor:
         return np_mat, np_pres, pregathered
 
     # ---- host assembly -----------------------------------------------------
-    def _assemble(self, np_mat, np_pres, dictionary, tags, steps, w,
-                  agg_op, keep, radices, keep_idx, pregathered=None):
+    def _assemble(self, np_mat, np_pres, labels, steps, w, keep, gids,
+                  pregathered):
+        """Fetched matrix -> engine Matrix, in the legacy scan's order:
+        regions in scan order, pk-sorted within each (= ascending series
+        id), groups at first appearance along it.  (Over a logical table
+        that is `__tsid` order, a hash's: `PromqlEngine.query_range` puts
+        the finished answer in label order, whichever path made it.)"""
         from .engine import Matrix
 
-        # legacy series order: regions in scan order, dictionary-code
-        # (= pk-sorted) order within each region, first appearance wins
-        order = (
-            pregathered if pregathered is not None else _legacy_order(np_pres)
+        order = np.asarray(
+            pregathered if pregathered is not None else _legacy_order(np_pres),
+            np.int64,
         )
-
-        value_lists = [dictionary.values(t) for t in tags]
-
-        def decode_sid(sid):
-            out = []
-            stride = 1
-            codes = []
-            for r in reversed(radices):
-                codes.append((sid // stride) % r)
-                stride *= r
-            codes.reverse()
-            for c, vals in zip(codes, value_lists):
-                out.append(vals[c] if c < len(vals) else None)
-            return tuple(out)
-
-        if agg_op is None:
-            label_values = [decode_sid(s) for s in order]
-            if pregathered is not None:
-                values = np_mat[:, :w] if order else np.zeros((0, w))
-            else:
-                values = (
-                    np_mat[np.asarray(order, dtype=np.int64)][:, :w]
-                    if order else np.zeros((0, w))
-                )
-            return Matrix(list(tags), label_values, values, steps)
-
-        # grouped result: legacy group order = first appearance of each
-        # group key along the legacy series order.  Only PRESENT sids
-        # need a gid — computed directly from the radix arithmetic, so
-        # the host never materializes the full [S_pad] map
-        g_order: list[int] = []
-        g_seen: set[int] = set()
-        for s in order:
-            g = _gid_of(s, radices, keep_idx)
-            if g not in g_seen:
-                g_seen.add(g)
-                g_order.append(g)
-        kept_value_lists = [value_lists[i] for i in keep_idx]
-        kept_radices = [radices[i] for i in keep_idx]
-
-        def decode_gid(gid):
-            out = []
-            stride = 1
-            codes = []
-            for r in reversed(kept_radices):
-                codes.append((gid // stride) % r)
-                stride *= r
-            codes.reverse()
-            for c, vals in zip(codes, kept_value_lists):
-                out.append(vals[c] if c < len(vals) else None)
-            return tuple(out)
-
-        label_values = [decode_gid(g) for g in g_order]
-        values = (
-            np_mat[np.asarray(g_order, dtype=np.int64)][:, :w]
-            if g_order else np.zeros((0, w))
+        if gids is None:
+            tuples = labels.tuples()
+            at = np.arange(len(order)) if pregathered is not None else order
+            return Matrix(
+                list(labels.tags), [tuples[s] for s in order],
+                np_mat[at][:, :w] if len(order) else np.zeros((0, w)), steps,
+            )
+        # grouped: the groups that hold a present series
+        g_order = list(dict.fromkeys(gids.of_series[order].tolist()))
+        keys = labels.group_tuples(keep, gids)
+        return Matrix(
+            list(keep), [keys[g] for g in g_order],
+            np_mat[np.asarray(g_order, np.int64)][:, :w]
+            if g_order else np.zeros((0, w)), steps,
         )
-        return Matrix(list(keep), label_values, values, steps)
 
 
 # ---- helpers ---------------------------------------------------------------
@@ -931,43 +1032,120 @@ def _legacy_order(np_pres) -> list[int]:
     return order
 
 
-def _gid_of(sid: int, radices, keep_idx) -> int:
-    """Group id of ONE series id (mixed radix over the kept tag subset,
-    keep order) — the scalar form of `_gid_map` for host-side decode of
-    the few present sids."""
-    codes = []
-    stride = 1
-    for r in reversed(radices):
-        codes.append((sid // stride) % r)
-        stride *= r
-    codes.reverse()
-    gid = 0
-    g_stride = 1
-    for i in reversed(keep_idx):
-        gid += codes[i] * g_stride
-        g_stride *= radices[i]
-    return gid
+@dataclasses.dataclass(frozen=True)
+class _Source:
+    """Which planes answer a metric and how its series are told apart."""
+
+    tags: tuple  # the answer's label columns
+    schema: object  # of the table that owns the planes
+    pk: tuple  # that table's key columns: the series table's columns
+    key_cols: tuple  # key planes the program compares row to row
+    ts: str
+    value: str
+    table_id: int | None  # a logical table's `__table_id`
 
 
-def _gid_map(radices, keep_idx) -> np.ndarray:
-    """sid -> group id over the kept tag subset (mixed radix, keep
-    order)."""
-    s_pad = 1
-    for r in radices:
-        s_pad *= r
-    sids = np.arange(s_pad, dtype=np.int64)
-    codes = []
-    stride = 1
-    for r in reversed(radices):
-        codes.append((sids // stride) % r)
-        stride *= r
-    codes.reverse()
-    gid = np.zeros(s_pad, dtype=np.int64)
-    g_stride = 1
-    for i in reversed(keep_idx):
-        gid = gid + codes[i] * g_stride
-        g_stride *= radices[i]
-    return gid.astype(np.int32)
+@dataclasses.dataclass(frozen=True)
+class _Span:
+    """One region's share of a source: series [s_lo, s_hi) of its series
+    table, rows [r_lo, r_hi) of its planes, ids from `sid0` on."""
+
+    table: object
+    s_lo: int
+    s_hi: int
+    r_lo: int
+    r_hi: int
+    sid0: int
+
+
+@dataclasses.dataclass(frozen=True)
+class _GroupIds:
+    of_series: np.ndarray  # [n_series] int32
+    keys: np.ndarray  # [groups, len(keep)] label codes
+
+
+class _SeriesLabels:
+    """The answer's labels of every series of a source, by global series
+    id: codes straight off the regions' series tables; what costs a pass
+    over the series (decoded tuples, label order, group ids) is made once
+    a plane build and kept in the table's `memo` where the source is one
+    region's, which a logical table's always is."""
+
+    def __init__(self, spans, tags, dictionary):
+        self.tags, self.dictionary = list(tags), dictionary
+        self._spans = spans
+        cols = None
+        parts = []
+        for sp in spans:
+            cols = [sp.table.tags.index(t) for t in tags]
+            parts.append(sp.table.codes[sp.s_lo:sp.s_hi][:, cols])
+        self.codes = (
+            parts[0] if len(parts) == 1 else np.concatenate(parts)
+        ) if parts else np.zeros((0, len(tags)), np.int32)
+
+    def _memo(self, what, make):
+        if len(self._spans) != 1:
+            return make()
+        sp = self._spans[0]
+        return sp.table.remember((what, sp.s_lo, sp.s_hi, tuple(self.tags)), make)
+
+    def _decode(self, codes: np.ndarray, names) -> list:
+        lists = [self.dictionary.values(t) for t in names]
+        return [
+            tuple(
+                vals[c] if 0 <= c < len(vals) else None
+                for c, vals in zip(row, lists)
+            )
+            for row in codes.tolist()
+        ]
+
+    def tuples(self) -> list:
+        return self._memo("tuples", lambda: self._decode(self.codes, self.tags))
+
+    def group_ids(self, keep) -> _GroupIds:
+        def make():
+            cols = [self.tags.index(l) for l in keep]
+            if not cols or not len(self.codes):
+                return _GroupIds(
+                    np.zeros(len(self.codes), np.int32),
+                    np.zeros((1, len(cols)), np.int32),
+                )
+            keys, of_series = np.unique(
+                self.codes[:, cols], axis=0, return_inverse=True
+            )
+            return _GroupIds(of_series.reshape(-1).astype(np.int32), keys)
+
+        return self._memo(("gids", tuple(keep)), make)
+
+    def group_tuples(self, keep, gids: _GroupIds) -> list:
+        return self._memo(
+            ("group_tuples", tuple(keep)), lambda: self._decode(gids.keys, keep)
+        )
+
+
+def _slice_plan(lengths, r_lo: int, r_hi: int):
+    """Where a logical table's rows [r_lo, r_hi) lie in planes stored as
+    chunks of `lengths`: (first, last) chunk touched, the program's static
+    `cut` = (L, head, tail) (see `_logical_slice`), the dynamic offset
+    into what the cut leaves, and the plane row the slice starts at.  L is
+    a power of two from an offset on a 1024-row boundary, so tables of
+    about one size share a program; it never leaves the planes."""
+    total = sum(lengths)
+    start = (r_lo // 1024) * 1024
+    size = _pow2(max(r_hi - start, 1024))
+    if size >= total:
+        size, start = total, 0
+    else:
+        start = min(start, total - size)
+    ends = np.cumsum(lengths)
+    first = int(np.searchsorted(ends, start, side="right"))
+    last = int(np.searchsorted(ends, start + size - 1, side="right"))
+    begin = int(ends[first] - lengths[first])
+    if first == last:
+        return first, last, (size, 0, size), start - begin, start
+    head = lengths[first] - min(size, lengths[first])
+    tail = min(size, lengths[last])
+    return first, last, (size, head, tail), start - begin - head, start
 
 
 def _null_code(dictionary, name) -> int:
@@ -979,7 +1157,7 @@ def _source_sig(src):
     def leaf_sig(chunks):
         return tuple((tuple(c.shape), str(c.dtype)) for c in chunks)
 
-    tags, ts, vals, nulls, valid = src
+    tags, ts, vals, nulls, valid, _at = src
     return (
         tuple(leaf_sig(t) for t in tags), leaf_sig(ts), leaf_sig(vals),
         None if nulls is None else leaf_sig(nulls), leaf_sig(valid),
@@ -997,13 +1175,14 @@ def _colocate(src, device):
             return x
         return jax.device_put(x, device)
 
-    tags, ts, vals, nulls, valid = src
+    tags, ts, vals, nulls, valid, at = src
     return (
         tuple(tuple(move(c) for c in t) for t in tags),
         tuple(move(c) for c in ts),
         tuple(move(c) for c in vals),
         None if nulls is None else tuple(move(c) for c in nulls),
         tuple(move(c) for c in valid),
+        at,
     )
 
 

@@ -49,7 +49,13 @@ class _ColumnDict:
 
     def value_set(self) -> pa.Array:
         if self._value_set is None or len(self._value_set) != len(self.values):
-            self._value_set = pa.array(self.values, pa.string())
+            # an int64 tag (the metric engine's `__table_id` / `__tsid`)
+            # keeps its values as ints: sorted numerically, as the region
+            # sorts the column
+            ints = bool(self.values) and isinstance(self.values[0], int)
+            self._value_set = pa.array(
+                self.values, pa.int64() if ints else pa.string()
+            )
         return self._value_set
 
     def all_values(self) -> list:
@@ -58,7 +64,8 @@ class _ColumnDict:
 
 
 class TableDictionary:
-    """Sorted value<->code tables for every string tag column of one table."""
+    """Sorted value<->code tables for every tag column of one table:
+    strings, and the int64 tags of a metric engine's physical table."""
 
     def __init__(self, path: str | None = None):
         self._path = path

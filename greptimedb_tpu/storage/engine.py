@@ -313,8 +313,14 @@ class TimeSeriesEngine:
                     pass
 
     def flush_all(self):
+        """Every region's rows in SSTs on return.  The background flushes
+        are waited out however long they take: `wait_idle`'s default of
+        30 s is for tests and shutdown, and a bulk load's backlog outlasts
+        it (a logical-table load of 10 M rows: `flush_s` read 30.0 s in
+        every run, the cap), which left rows in a frozen memtable behind a
+        caller that had been told they were flushed."""
         if self.flusher is not None:
-            self.flusher.wait_idle()
+            self.flusher.wait_idle(timeout=float("inf"))
         for rid in self.region_ids():
             self.flush_region(rid)
 

@@ -483,6 +483,13 @@ class TqlConfig:
     # rows — the compacted [series_out, steps] readback.  Below it one
     # batched round-trip wins (RTT-bound, not byte-bound).
     compact_readback_kb: int = 1024
+    # False: a TQL statement the tile path cannot answer from the device
+    # fails, naming the reason, where it would be answered from the legacy
+    # scan with no sign (the guarantee `query.fallback_to_cpu = false`
+    # gives SQL).  The one legacy answer left is a family's designed first
+    # touch (`greptime_tql_tile_cold_serves_total`), served while its
+    # planes build.
+    legacy_fallback: bool = True
 
 
 @dataclasses.dataclass

@@ -386,6 +386,21 @@ TQL_TILE_SEGMENT_STATS = REGISTRY.counter(
     "over the plane (avg/sum/min/max_over_time); the rate family, count, "
     "last and timestamp() read rows by position and never move it",
 )
+TQL_TILE_LOGICAL_DISPATCHES = REGISTRY.counter(
+    "greptime_tql_tile_logical_dispatch_total",
+    "TQL tile dispatches whose source is a metric-engine logical table: a "
+    "row range of its physical region's planes",
+)
+TQL_TILE_PLANE_ROWS = REGISTRY.counter(
+    "greptime_tql_tile_plane_rows_total",
+    "Padded rows of the planes handed to the TQL tile program, summed over "
+    "dispatches: the region's for a mito table, the slice's for a logical one",
+)
+METRIC_TSID_HASHES = REGISTRY.counter(
+    "greptime_metric_tsid_hashes_total",
+    "Label sets hashed to a __tsid by the metric engine's write path (one "
+    "per distinct label set of a batch, not one per row)",
+)
 TQL_TILE_COLD_SERVES = REGISTRY.counter(
     "greptime_tql_tile_cold_serves_total",
     "Cold TQL queries answered from the legacy scan while their family's "

@@ -45,8 +45,11 @@ def test_rehearsal_passes_and_reports_the_cpu(tmp_path):
     assert set(queries) == {
         "single-groupby-1-1-1", "double-groupby-1", "high-cpu-1", "lastpoint",
         "groupby-orderby-limit", "edge-lastpoint", "edge-orderby-limit",
-        "rate", "increase-1",
+        "rate", "increase-1", "rate-logical",
     }
+    # the logical table of twelve labels answers for one rack's hosts (of
+    # ten: hosts 1 and 6) as the mito table does for them
+    assert queries["rate-logical"]["rows_out"] * 5 == queries["rate"]["rows_out"]
     for e in queries.values():
         assert len(e["warm_ms"]) == 3 and all(d >= 1 for d in e["dispatches"])
     # the values the generator never draws were compared exactly

@@ -148,3 +148,38 @@ def test_series_ordinals_compile(one_chip):
         _rows(one_chip, jnp.float64, (4096 * 16,)), _rows(one_chip, jnp.int64, (4096 * 16,)),
     )
     assert secs < RESET_STRIP_CEILING_S
+
+
+@pytest.mark.parametrize("chunks,cut,agg", [
+    (1, (1 << 21, 0, 1 << 21), "sum"),          # inside one chunk, with the by fold
+    (2, (1 << 21, (1 << 22) - (1 << 21), 1 << 21), None),  # across a chunk boundary
+])
+def test_logical_table_program_compiles(one_chip, chunks, cut, agg):
+    """The whole TQL tile program over a metric-engine logical table, as
+    `prom-metric-engine-range` runs it: a 2^21-row slice cut on the device
+    out of 2^22-row chunks at a dynamic offset, series by `run_ordinals`,
+    a matcher's bool and a group id per series as dynamic inputs."""
+    from greptimedb_tpu.query.promql import tile_exec
+
+    rows = 1 << 22
+    csig = ("rate", agg, 4096, 32, 8, 1_000_000, 16 if agg else None, True)
+
+    def planes(dtype):
+        return tuple(_rows(one_chip, dtype, (rows,)) for _ in range(chunks))
+
+    at = {k: _rows(one_chip, jnp.int32, ()) for k in ("sid0", "off", "lo", "hi")}
+    src = ((planes(jnp.int32),), planes(jnp.int64), planes(jnp.float64), None,
+           planes(jnp.bool_), at)
+    dyn = {
+        k: _rows(one_chip, jnp.int64, ())
+        for k in ("lo", "hi", "offset", "start", "step", "range", "nsteps")
+    }
+    dyn["sel"] = _rows(one_chip, jnp.bool_, (4096,))
+    if agg:
+        dyn["gid"] = _rows(one_chip, jnp.int32, (4096,))
+    program = tile_exec._full_program((csig, ((tile_exec._source_sig(src), cut),)))
+    t0 = time.perf_counter()
+    program.lower((src,), dyn).compile()
+    secs = time.perf_counter() - t0
+    print(f"logical-table program, {chunks} chunk(s): compiled for v5e in {secs:.1f} s")
+    assert secs < RESET_STRIP_CEILING_S
