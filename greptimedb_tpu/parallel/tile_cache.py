@@ -239,6 +239,24 @@ def _window_tile_bytes(entry) -> int:
     return sum(wt["nbytes"] for wt in list(entry.window_tiles.values()))
 
 
+# The most a group's limb quantization bound may be of its |sum| before the
+# query reruns in exact f64.  An avg over a large group space is shipped as
+# float32, one more rounding of up to 2^-24 = 5.96e-8: together under 1.1e-7,
+# inside the 1.2e-7 a deployment's `value_rtol_avg_f32` states.  (At 1e-7,
+# until PR 36, a window's edge bucket of two or three small rows could pass
+# the verdict and read 1.6e-7: `tsbs-mesh4-heavy`, seed 3600001003.)
+_LIMB_VERDICT_RTOL = 5e-8
+
+# why `ensure_window_tile` served no tile, as the `window_tile` pass notes it
+_WINDOW_DECLINED = {
+    "unprobed": "plane under the window-tile floor, or without its sorted ts",
+    "empty": "no row in the window",
+    "cover": "window covers most of retention",
+    "resident": "planes resident and their masked scan cheaper than a tile's build",
+    "build": "tile build declined (a null plane or host encode is missing)",
+}
+
+
 @functools.partial(jax.jit, static_argnums=2)
 def _permuted_chunks(chunks, perm, bounds):
     """A region's chunks in `perm`'s row order, chunked again by `bounds`.
@@ -2510,14 +2528,59 @@ class TileCacheManager:
                 self._evict_locked(pinned_regions | {entry.region_id})
         return out
 
-    # window tiles engage when the window covers less than this fraction
-    # of the entry's rows (otherwise the full super-tile is cheaper than
-    # building a nearly-as-big copy).  The cover is COUNTED from the runs
-    # of the sorted ts plane (_window_ranges) before anything is gathered,
-    # so a window over it costs two searches, not a pass over the plane
-    _WINDOW_TILE_MAX_COVER = 0.5
+    # A window tile is a compact copy of one window's rows, made by the HOST
+    # (a fancy-gather of each needed column, an upload, a limb quantize) so
+    # that the device scans pad(n) rows instead of the region's padded
+    # plane.  The window's rows are COUNTED first from the runs of the
+    # sorted ts plane (_window_ranges: two searches a run, nothing
+    # allocated); whether the tile is then built follows where the region's
+    # full planes are:
+    #  - NOT on the device (the fused planner's deferred upload, planes
+    #    dropped by release_unneeded or evicted, retention beyond the
+    #    chip's share): the alternative is to upload the planes, and a tile
+    #    uploads n rows instead.  Built while the window covers at most
+    #    _WINDOW_TILE_MAX_COVER of the rows (over it the tile is nearly as
+    #    big as the plane).
+    #  - resident: the tile spares device time only, the masked scan of
+    #    the padded rows it leaves out, and a window is as a rule drawn
+    #    once.  Built only where n * _WINDOW_BUILD_NS_PER_ROW is less than
+    #    (entry.pad - pad(n)) * _WINDOW_SCAN_NS_PER_ROW: a cover under
+    #    about 4 % of a plane, a "last hour" panel over days.
+    # The two costs are v5e readings of `tsbs-mesh4-heavy` (PERF.md section
+    # 5): the build from the ledger's PR 35 row, `window_build_ms` 261.16
+    # ms/query x 3 shapes / 4 regions / 4.32 M rows of three columns = 45 ns
+    # (PR 36's parent run read 52: 225.4 ms a build); the scan from PR 36's
+    # traced run, 35.06 ms of a chip's busy time a `double-groupby-1` over
+    # its region's 2^24 padded rows = 2.1 ns (`tsbs-heavy`: 62 ms over 2^25 =
+    # 1.8).  At these a 12 h window of 24 h pays 194 ms of host a region to
+    # spare 18 ms of device.
+    _WINDOW_TILE_MAX_COVER = 0.5  # the bound where the planes are not resident
+    _WINDOW_BUILD_NS_PER_ROW = 45.0  # host: gather + upload + quantize a window row
+    _WINDOW_SCAN_NS_PER_ROW = 2.1  # device: masked scan of a padded plane row
     _WINDOW_TILE_MIN_ROWS = 1 << 22  # below this the full scan is cheap
     _WINDOW_TILE_GRID = 1 << 22  # a tile's rows pad to a multiple of this
+
+    def _window_pad(self, n: int) -> int:
+        """A tile's padded rows: a 2^22 grid, so that compile shapes stay
+        few and chunks stay BLOCK_ROWS multiples."""
+        grid = self._WINDOW_TILE_GRID
+        return -(-n // grid) * grid
+
+    def _planes_resident(
+        self, entry: _SuperTiles, cols_needed: list[str],
+        limb_cols: set[str], dedup: bool,
+    ) -> bool:
+        """Whether a masked scan of `entry` could start now: the valid (or
+        dedup keep) plane and every needed column on the device, a limb
+        column as its limb plane or as the f64 plane that is quantized
+        from."""
+        with self._lock:
+            if (entry.valid_dedup if dedup else entry.valid) is None:
+                return False
+            return all(
+                c in entry.cols or (c in limb_cols and c in entry.limb_cols)
+                for c in cols_needed
+            )
 
     def ensure_window_tile(
         self,
@@ -2528,22 +2591,28 @@ class TileCacheManager:
         limb_cols: set[str],
         dedup: bool,
         dict_epoch: int,
-    ):
-        """Build (or fetch) the compact device tile for one query window.
-        The window's rows are one contiguous range per ascending run of
-        the sorted ts plane, found by two searches per run; their count
-        (less the rows the dedup keep plane drops, so stale versions never
-        even upload) decides BEFORE anything the size of the plane is
-        allocated whether a tile is built.  If so: the ranges' rows,
-        an mmap fancy-gather of each needed column, upload in chunk-device
-        order, limb planes quantized from the gathered values.  Returns a
-        list of source tuples (cols, valid, nulls, perm, limbs) or None
-        when the window doesn't qualify.  Rows keep their (pk, ts) order,
-        so the blocked kernel geometry holds on the compacted tile."""
-        if entry.num_rows < self._WINDOW_TILE_MIN_ROWS:
-            return None
-        if ts_name not in entry.sorted_host:
-            return None
+    ) -> tuple[list | None, str | None]:
+        """Fetch, or build where it pays, the compact device tile for one
+        query window: `(sources, None)`, a list of source tuples (cols,
+        valid, nulls, perm, limbs), or `(None, declined)` with the reason
+        no tile serves the window.  A tile that exists for the exact
+        window is used.  Otherwise the window's rows are one contiguous
+        range per ascending run of the sorted ts plane, found by two
+        searches per run; their count `n` (less the rows the dedup keep
+        plane drops, so stale versions never even upload) decides BEFORE
+        anything the size of the plane is allocated (see the comment over
+        `_WINDOW_TILE_MAX_COVER`): `"empty"` for no row, `"cover"` for more
+        than half the entry's rows, `"resident"` where the entry's planes
+        are on the device and their masked scan costs less than the build;
+        `"unprobed"` is a plane under `_WINDOW_TILE_MIN_ROWS` or without
+        its sorted ts or keep plane, `"build"` a build that found a null
+        plane or host encode missing.  The build: the ranges' rows, an mmap
+        fancy-gather of each needed column, upload in chunk-device order,
+        limb planes quantized from the gathered values.  Rows keep their
+        (pk, ts) order, so the blocked kernel geometry holds on the
+        compacted tile."""
+        if entry.num_rows < self._WINDOW_TILE_MIN_ROWS or ts_name not in entry.sorted_host:
+            return None, "unprobed"
         key = (int(window[0]), int(window[1]), bool(dedup))
         cols_needed = list(
             dict.fromkeys([c for c in need_cols if c != ts_name] + [ts_name])
@@ -2569,7 +2638,7 @@ class TileCacheManager:
                     and c not in missing
                 ]
                 if not missing and not missing_limbs:
-                    return self._window_sources(wt, need_cols, limb_cols)
+                    return self._window_sources(wt, need_cols, limb_cols), None
                 # EXTEND the cached tile: build only the missing planes
                 # and merge them in (the round-4 code rebuilt everything
                 # and then DISCARDED the rebuild in its race branch,
@@ -2593,7 +2662,7 @@ class TileCacheManager:
         ranges = None
         if missing:
             if dedup and not self.ensure_dedup_keep(entry):
-                return None
+                return None, "unprobed"
             *ranges, n = self._window_ranges(entry, window, ts_name, dedup)
             metrics.TILE_WINDOW_COUNTED.inc()
             if snap is not None and n != snap["rows"]:
@@ -2602,13 +2671,23 @@ class TileCacheManager:
                 snap = None
                 missing = list(cols_needed)
                 missing_limbs = []
-            if n == 0 or n > entry.num_rows * self._WINDOW_TILE_MAX_COVER:
-                return None
+            if n == 0:
+                return None, "empty"
+            if n > entry.num_rows * self._WINDOW_TILE_MAX_COVER:
+                return None, "cover"
+            spared = entry.pad - self._window_pad(n)
+            if (
+                n * self._WINDOW_BUILD_NS_PER_ROW >= spared * self._WINDOW_SCAN_NS_PER_ROW
+                and self._planes_resident(entry, cols_needed, limb_cols, dedup)
+            ):
+                metrics.TILE_WINDOW_RESIDENT_SCANS.inc()
+                return None, "resident"
         with tracing.stage("tile.window_build", region=entry.region_id, rows=n):
-            return self._build_window_tile(
+            wsrc = self._build_window_tile(
                 entry, key, need_cols, limb_cols, dict_epoch,
                 snap, missing, missing_limbs, n, ranges,
             )
+        return wsrc, ("build" if wsrc is None else None)
 
     def _build_window_tile(
         self, entry: _SuperTiles, key: tuple, need_cols: set[str],
@@ -2624,17 +2703,15 @@ class TileCacheManager:
             _range_rows(*ranges, entry.keep_host if key[2] else None)
             if ranges is not None else None
         )
-        # pad to a 2^22 grid: bounded compile-shape variety, chunks stay
-        # BLOCK_ROWS multiples.  Window tiles dispatch at 2^22-row chunks
+        # Window tiles dispatch at 2^22-row chunks
         # (not the 2^24 super-tile chunk): a 10-column limb program over a
         # 2^24 chunk allocates multi-GB transients (f64->bf16 casts, digit
         # planes, masks for every column scheduled concurrently) — the
         # round-4 driver dg-all OOM.  Equal-size chunks also mean ONE
         # compile shape per tile, and the size is stable across column
         # extensions (cached planes and new planes must chunk identically).
-        grid = self._WINDOW_TILE_GRID
-        pad = -(-n // grid) * grid
-        bounds = _chunk_bounds(pad, min(self.chunk_rows, grid))
+        pad = self._window_pad(n)
+        bounds = _chunk_bounds(pad, min(self.chunk_rows, self._WINDOW_TILE_GRID))
 
         # nullable columns without a persisted null plane can't build
         # their gathered mask here — full super-tile path owns those.
@@ -3353,7 +3430,8 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
     int_dtype = jnp.int32 if (needs_exact_counts or not pack_bytes) else jnp.uint8
     # columns whose sums carry a quantization-error bound (limb mode):
     # the program appends a one-byte verdict — 1 iff every group's bound
-    # is within 1e-7 of |sum| — and the caller reruns in exact f64 on 0
+    # is within _LIMB_VERDICT_RTOL of |sum| — and the caller reruns in
+    # exact f64 on 0
     limb_err_cols = (
         TileExecutor._limb_sum_cols(plan) if plan.acc_dtype == "limb" else []
     )
@@ -3509,7 +3587,7 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
                 err = merged["__limb_err:" + col].sums
                 s = merged[col].sums
                 ok = ok & jnp.all(
-                    err <= jnp.maximum(jnp.abs(s) * 1e-7, 1e-12)
+                    err <= jnp.maximum(jnp.abs(s) * _LIMB_VERDICT_RTOL, 1e-12)
                 )
             flat.append(ok.astype(jnp.uint8).reshape(1))
         if is_hash:
@@ -5344,12 +5422,17 @@ class TileExecutor:
                     # windowed query over deep retention: gather ONLY the
                     # in-window (and dedup-surviving) rows into a compact
                     # tile — the kernel then scans the window, not the
-                    # retention (reference prunes SSTs/row-groups by time)
-                    with tracing.stage("tile.window", region=s.region_id):
-                        wsrc = self.cache.ensure_window_tile(
+                    # retention (reference prunes SSTs/row-groups by time).
+                    # Where the region's planes are already on the device a
+                    # tile is built only if that is cheaper than scanning
+                    # them with the window as a mask (ensure_window_tile)
+                    with tracing.stage("tile.window", region=s.region_id) as st:
+                        wsrc, declined = self.cache.ensure_window_tile(
                             s, window, use_ts, self._plan_cols(plan),
                             set(limb_need), dedup, ctx.dictionary.epoch,
                         )
+                        if declined:
+                            st.set(declined=declined)
                     if wsrc is not None:
                         passes.note(
                             "window_tile", True,
@@ -5360,8 +5443,9 @@ class TileExecutor:
                         continue
                     passes.note(
                         "window_tile", False,
-                        "window covers most of retention (or tile build "
-                        "declined): full-tile scan with device masking",
+                        _WINDOW_DECLINED[declined] + ": full-tile scan with "
+                        "device masking",
+                        region=s.region_id, declined=declined,
                     )
                 if s.region_id in deferred_upload:
                     # lazy full-plane upload: only reached when the window
@@ -7703,9 +7787,10 @@ class TileExecutor:
             return None
         if plan.acc_dtype == "limb" and self._limb_sum_cols(plan):
             if buf[-1] == 0:
-                # quantization-error bound exceeded 1e-7 of some group's
-                # sum (mixed-magnitude data sharing blocks): caller must
-                # rerun with exact f64 accumulation
+                # quantization-error bound exceeded _LIMB_VERDICT_RTOL of
+                # some group's sum (mixed-magnitude data sharing blocks, a
+                # group of a few small rows): caller must rerun with exact
+                # f64 accumulation
                 metrics.TILE_LIMB_RERUNS.inc()
                 return None
         if spec is not None:

@@ -248,6 +248,7 @@ TILE_PERSIST_HITS = REGISTRY.counter("greptime_tile_persist_hits_total", "Super-
 TILE_PERSIST_WRITES = REGISTRY.counter("greptime_tile_persist_writes_total", "Super-tile consolidations written to the persisted store")
 TILE_WINDOW_BUILDS = REGISTRY.counter("greptime_tile_window_builds_total", "Compact window tiles gathered from sorted encodes")
 TILE_WINDOW_COUNTED = REGISTRY.counter("greptime_tile_window_counted_total", "Window-tile probes whose row count came from the run bounds of the sorted ts plane (less the builds: probes that declined without touching the plane)")
+TILE_WINDOW_RESIDENT_SCANS = REGISTRY.counter("greptime_tile_window_resident_scans_total", "Counted window-tile probes that declined because the region's full planes were on the device and their masked scan was cheaper than the tile's host build")
 TILE_ORDINAL_GIDS = REGISTRY.counter("greptime_tile_ordinal_gids_total", "Tile dispatches whose plan groups by the source's own series ordinals in place of the leading tag's table-wide codes (a region of a table partitioned on that tag)")
 TILE_HOST_FAST_PATH = REGISTRY.counter("greptime_tile_host_fast_path_total", "Selective queries served from the sorted host encode cache")
 TILE_STREAM_QUERIES = REGISTRY.counter("greptime_tile_stream_total", "Queries whose working set exceeded the HBM budget, executed region-streamed")

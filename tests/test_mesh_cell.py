@@ -2,10 +2,12 @@
 conftest's virtual CPU devices: TSBS `cpu` in four regions by HASH
 (hostname) with `tile.mesh_devices = 4`.  Every shape of the cell's mix is
 ONE shard_map dispatch, equal to the numpy folds and to the same table with
-`tile.mesh_devices = 0`, whether a `double-groupby-1` builds window tiles
-(a 12 h window over 24 h: cover 0.5) or declines (over 13 h: cover 12/13);
-a dispatch handed to the single chip moves `TILE_MESH_INELIGIBLE` and says
-why on its `tile.dispatch`.
+`tile.mesh_devices = 0`.  A `double-groupby-1` scans its regions' resident
+planes with the window as a mask, whether the window declines as dearer to
+build than to scan (12 h over 24 h: cover 0.5) or by its cover (over 13 h:
+12/13); where the planes are NOT on the device at the decision, the 24 h
+table gets its window tiles.  A dispatch handed to the single chip moves
+`TILE_MESH_INELIGIBLE` and says why on its `tile.dispatch`.
 """
 
 import dataclasses
@@ -29,7 +31,7 @@ CELL, HOSTS, CHIPS = "tsbs-mesh4-heavy", 40, 4
 SHAPES = ("double-groupby-1", "lastpoint", "groupby-orderby-limit")
 MESH_COUNTERS = (
     "TILE_MESH_DISPATCHES", "TILE_MESH_INELIGIBLE", "TILE_MESH_DEGRADED",
-    "TPU_DEVICE_DISPATCHES", "TILE_WINDOW_BUILDS", "TILE_WINDOW_COUNTED",
+    "TPU_DEVICE_DISPATCHES", "TILE_WINDOW_BUILDS", "TILE_WINDOW_COUNTED", "TILE_WINDOW_RESIDENT_SCANS",
     "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TILE_ORDINAL_GIDS",
     "TPU_COMPILE_CACHE_HITS", "TPU_COMPILE_CACHE_MISSES",
 )
@@ -80,7 +82,7 @@ class Fleet:
         return compare.compare(rows, want)
 
 
-@pytest.fixture(scope="module", params=[24, 13], ids=["window-tile-builds", "window-tile-declines"])
+@pytest.fixture(scope="module", params=[24, 13], ids=["window-resident-scan", "window-cover-declines"])
 def fleet(request, tmp_path_factory):
     # a region holds 40 / 4 hosts x 8640 ticks: far under 2^22 rows, where a
     # window is not probed at all
@@ -88,7 +90,7 @@ def fleet(request, tmp_path_factory):
     patch.setattr(TileCacheManager, "_WINDOW_TILE_MIN_ROWS", 1 << 12)
     patch.setattr(TileCacheManager, "_WINDOW_TILE_GRID", 1 << 14)
     f = Fleet(str(tmp_path_factory.mktemp(f"mesh{request.param}")), request.param)
-    f.builds = request.param == 24
+    f.half = request.param == 24  # the window covers half a region, not 12/13
     yield f
     f.db.close()
     patch.undo()
@@ -107,14 +109,14 @@ def test_each_shape_is_one_mesh_dispatch_equal_to_the_fold_and_to_one_chip(fleet
         assert keys_ok and gap <= bar, (shape, gap)
     if shape == "double-groupby-1":
         regions = fleet.cell.config["regions"]
-        # the window is drawn once, so its tiles are built in the first touch
-        # and found again; a declined window is counted anew by every request
+        # the planes are resident, so no tile is built, in the family's build
+        # or by a request: a declined window is counted anew by every request,
+        # and says whether the scan's price or the cover declined it
         assert moved["TILE_WINDOW_BUILDS"] == 0
-        assert moved["TILE_WINDOW_COUNTED"] == (0 if fleet.builds else regions)
-        tiles = [
-            len(e.window_tiles) for e in fleet.db.query_engine.tile_cache._super.values()
-        ]
-        assert tiles == [1 if fleet.builds else 0] * regions
+        assert moved["TILE_WINDOW_COUNTED"] == regions
+        assert moved["TILE_WINDOW_RESIDENT_SCANS"] == (regions if fleet.half else 0)
+        entries = fleet.db.query_engine.tile_cache._super.values()
+        assert [len(e.window_tiles) for e in entries] == [0] * regions
     fleet.db.config.tile.mesh_devices = 0
     try:
         single, moved = fleet.ask(shape)
@@ -125,11 +127,60 @@ def test_each_shape_is_one_mesh_dispatch_equal_to_the_fold_and_to_one_chip(fleet
     assert single.to_pydict() == meshed.to_pydict()
 
 
+def test_planes_not_on_the_device_at_the_decision_still_get_their_window_tile(fleet):
+    """Deep retention's path: with a region's planes released, a window of
+    half its rows is gathered into a tile (it uploads the window's rows, not
+    the plane), whose chunks go round robin from the region's chip; the
+    answer is the resident scan's and the fold's.  A cover of 12/13 declines
+    there too, and the planes come back."""
+    shape = "double-groupby-1"
+    fleet.warm(shape)
+    scanned, moved = fleet.ask(shape)
+    assert moved["TILE_WINDOW_BUILDS"] == 0 and moved["TILE_MESH_DISPATCHES"] == 1, moved
+    cache = fleet.db.query_engine.tile_cache
+    devices = cache.placement_devices()[:CHIPS]
+    regions = fleet.cell.config["regions"]
+    try:
+        for entry in list(cache._super.values()):
+            cache.release_unneeded(entry, set())
+            assert not entry.cols and not entry.limb_cols
+        tiled, moved = fleet.ask(shape)
+        assert moved["TILE_MESH_DISPATCHES"] == 1 == moved["TPU_DEVICE_DISPATCHES"], moved
+        assert not moved["TILE_MESH_INELIGIBLE"] and not moved["TILE_MESH_DEGRADED"], moved
+        assert moved["TILE_WINDOW_COUNTED"] == regions and not moved["TILE_WINDOW_RESIDENT_SCANS"], moved
+        assert moved["TILE_WINDOW_BUILDS"] == (regions if fleet.half else 0), moved
+        for rid, entry in cache._super.items():
+            base = region_device_index(rid, CHIPS)
+            assert len(entry.window_tiles) == (1 if fleet.half else 0)
+            # a region served by its tile never uploads its planes
+            assert ("hostname" in entry.cols) == (not fleet.half)
+            for wt in entry.window_tiles.values():
+                assert wt["rows"] == entry.num_rows // 2 and len(wt["valid"]) == 3
+                for chunks in (wt["valid"], *wt["cols"].values()):
+                    assert [next(iter(x.devices())) for x in chunks] == [
+                        devices[(base + i) % CHIPS] for i in range(len(chunks))
+                    ]
+        keys_ok, gap = fleet.gap(shape, tiled)
+        assert keys_ok and gap <= fleet.cell.config["guarantees"][fleet.cell.shapes[shape].BAR]
+        # a group's limb sums quantize by the blocks its rows share with
+        # their neighbours, which the tile's own rows change
+        rows_close(tiled, scanned, rtol=2e-7)
+        again, moved = fleet.ask(shape)  # the window's tiles are found, not counted
+        assert moved["TILE_WINDOW_COUNTED"] == (0 if fleet.half else regions), moved
+        assert moved["TILE_WINDOW_BUILDS"] == 0 and again.to_pydict() == tiled.to_pydict()
+    finally:
+        # leave the module's fleet as the other tests expect it: no tile, and
+        # the planes back on the device (a `lastpoint` reads every one)
+        for entry in list(cache._super.values()):
+            cache.release_unneeded(entry, {"no such column"})
+            assert not entry.window_tiles
+        fleet.warm("lastpoint")
+
+
 def test_planes_lie_where_chunk_device_places_them(fleet):
     """A region's planes, the time-major copies of `groupby-orderby-limit`
     included, lie whole on device `region_device_index(r, 4)`, so every
-    device holds a quarter of the rows and none another's; the chunks of a
-    window tile go round robin from there."""
+    device holds a quarter of the rows and none another's."""
     for shape in SHAPES:
         fleet.warm(shape)
     cache = fleet.db.query_engine.tile_cache
@@ -142,11 +193,7 @@ def test_planes_lie_where_chunk_device_places_them(fleet):
         planes = [entry.valid, entry.tm_valid, *entry.cols.values(), *entry.tm_cols.values()]
         arrays = [x for chunks in planes for x in chunks] + [entry.perm]
         assert {d for x in arrays for d in x.devices()} == {devices[base]}, rid
-        for wt in entry.window_tiles.values():
-            for chunks in (wt["valid"], *wt["cols"].values()):
-                assert [next(iter(x.devices())) for x in chunks] == [
-                    devices[(base + i) % CHIPS] for i in range(len(chunks))
-                ]
+        assert not entry.window_tiles
         held.append((base, entry.num_rows))
     assert sorted(base for base, _ in held) == list(range(CHIPS))
     assert {rows for _, rows in held} == {fleet.ds.rows // CHIPS}

@@ -146,3 +146,20 @@ def test_disabling_host_fast_path_still_serves(db):
     decisions = _pass_lines(out)
     assert not decisions.get("host_fast_path", "").startswith("fired")
     assert db.sql_one(q)["m"].to_pylist() == ref
+
+
+def test_explain_analyze_says_why_a_window_declined_over_resident_planes(db):
+    """Once a full scan has put the region's planes on the device, a window
+    whose tile would cost more to build than its masked scan declines, and
+    the pass says so, not "covers most of retention" (its cover is 12 %)."""
+    from greptimedb_tpu.utils import metrics
+
+    _setup(db)
+    db.sql_one("SELECT host, avg(usage_user) AS au FROM cpu GROUP BY host")
+    scans = metrics.TILE_WINDOW_RESIDENT_SCANS.get()
+    decisions = _pass_lines(db.sql_one("EXPLAIN ANALYZE " + WINDOWED))
+    assert metrics.TILE_WINDOW_RESIDENT_SCANS.get() == scans + 1
+    assert not decisions["window_tile"].startswith("fired"), decisions
+    assert "planes resident" in decisions["window_tile"] and "declined=resident" in decisions["window_tile"]
+    entry = next(iter(db.query_engine.tile_cache._super.values()))
+    assert not entry.window_tiles

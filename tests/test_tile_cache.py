@@ -455,11 +455,13 @@ def test_window_tile_min_rows_floor(floor, monkeypatch):
     )
     cache = TileCacheManager(budget_bytes=1 << 30)
     counted, builds = metrics.TILE_WINDOW_COUNTED.get(), metrics.TILE_WINDOW_BUILDS.get()
-    src = cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
+    src, declined = cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
     if floor == "below":
-        assert src is None and not entry.window_tiles and entry.ts_run_starts is None
+        assert src is None and declined == "unprobed"
+        assert not entry.window_tiles and entry.ts_run_starts is None
         assert metrics.TILE_WINDOW_COUNTED.get() == counted
         return
+    assert declined is None
     assert metrics.TILE_WINDOW_COUNTED.get() == counted + 1
     assert metrics.TILE_WINDOW_BUILDS.get() == builds + 1
     ts_sorted = entry.sorted_host["ts"]
@@ -471,9 +473,72 @@ def test_window_tile_min_rows_floor(floor, monkeypatch):
     valid = np.concatenate([np.asarray(c) for c in wt["valid"]])
     assert valid[: len(want)].all() and not valid[len(want):].any()
     # the same window again is a lookup: neither counted nor built
-    assert cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
+    again, declined = cache.ensure_window_tile(entry, (5000, 15000), "ts", {"ts"}, set(), False, 0)
+    assert again and declined is None
     assert metrics.TILE_WINDOW_COUNTED.get() == counted + 1
     assert metrics.TILE_WINDOW_BUILDS.get() == builds + 1
+
+
+# ticks of 4000 a series in the window: a cover of the plane's 24,000 rows
+_DECISION_TICKS = {"half": 2000, "under_break_even": 100, "most": 3600, "none": 0}
+
+
+@pytest.mark.parametrize("resident,cover,declined", [
+    (True, "half", "resident"), (True, "under_break_even", None),
+    (True, "most", "cover"), (True, "none", "empty"),
+    (False, "half", None), (False, "under_break_even", None),
+    (False, "most", "cover"), (False, "none", "empty"),
+])
+def test_window_tile_is_built_where_it_is_cheaper_than_the_scan_it_saves(
+    resident, cover, declined, monkeypatch
+):
+    """The decision of `ensure_window_tile` after the count: over planes
+    that are not on the device a tile is built up to a cover of one half
+    (it uploads the window's rows instead of the plane); over resident
+    planes only where the host's build costs less than the device's masked
+    scan of the padded rows the tile would spare; a window over most of
+    the rows, or over none, declines wherever the planes are."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from greptimedb_tpu.parallel.tile_cache import TileCacheManager
+
+    codes = np.repeat(np.arange(6, dtype=np.int32), 4000)
+    ts = np.tile(np.arange(4000, dtype=np.int64) * 10, 6)
+    entry = _host_entry(codes, ts)
+    monkeypatch.setattr(TileCacheManager, "_WINDOW_TILE_MIN_ROWS", len(ts))
+    monkeypatch.setattr(TileCacheManager, "_WINDOW_TILE_GRID", 1 << 10)
+    cache = TileCacheManager(budget_bytes=1 << 30)
+    if resident:
+        pad = np.zeros(entry.pad - len(ts), np.int64)
+        entry.valid = [jnp.asarray(np.arange(entry.pad) < len(ts))]
+        entry.cols = {
+            name: [jnp.asarray(np.r_[plane, pad.astype(plane.dtype)])]
+            for name, plane in entry.sorted_host.items()
+        }
+    n = 6 * _DECISION_TICKS[cover]
+    # what the rule compares, at its constants: 24,000 rows pad to 2^15
+    build_ns = n * TileCacheManager._WINDOW_BUILD_NS_PER_ROW
+    scan_ns = (entry.pad - cache._window_pad(n)) * TileCacheManager._WINDOW_SCAN_NS_PER_ROW
+    assert (build_ns < scan_ns) == (cover in ("under_break_even", "none"))
+    before = {
+        k: getattr(metrics, k).get()
+        for k in ("TILE_WINDOW_COUNTED", "TILE_WINDOW_BUILDS", "TILE_WINDOW_RESIDENT_SCANS")
+    }
+    window = (5000, 5000 + 10 * _DECISION_TICKS[cover])
+    src, why = cache.ensure_window_tile(entry, window, "ts", {"host", "ts"}, set(), False, 0)
+    moved = {k: getattr(metrics, k).get() - v for k, v in before.items()}
+    assert why == declined and (src is None) == (declined is not None)
+    assert moved == {
+        "TILE_WINDOW_COUNTED": 1,
+        "TILE_WINDOW_BUILDS": int(declined is None),
+        "TILE_WINDOW_RESIDENT_SCANS": int(declined == "resident"),
+    }
+    if declined is None:
+        wt = entry.window_tiles[(*window, False)]
+        assert wt["rows"] == n and set(wt["cols"]) == {"host", "ts"}
+    else:
+        assert not entry.window_tiles
 
 
 def test_window_tile_declines_by_count_without_a_pass_over_the_plane(db, monkeypatch):
@@ -525,12 +590,48 @@ def test_window_tile_declines_by_count_without_a_pass_over_the_plane(db, monkeyp
     assert metrics.TILE_WINDOW_BUILDS.get() == builds and not entry.window_tiles
     assert _tile_count() == lowered + 1  # answered by the full-tile scan on the device
     notes = [d for d in trace.decisions if d.name == "window_tile"]
-    assert [(d.fired, d.why) for d in notes] == [(
-        False,
-        "window covers most of retention (or tile build declined): "
-        "full-tile scan with device masking",
+    assert [(d.fired, d.why, d.attrs["declined"]) for d in notes] == [(
+        False, "window covers most of retention: full-tile scan with device masking", "cover",
     )]
     assert t1.to_pydict()["c"] == [7800] * 8
+
+
+def test_limb_verdict_keeps_a_few_row_group_inside_the_float32_bar(db):
+    """A group of one small row beside blocks of large values: its limb bound
+    (half a quantization step, 2^-23 at a block maximum under 128) is 5.7e-8
+    of its sum, which with the float32 rounding of a shipped avg (5.96e-8)
+    would pass the 1.2e-7 the deployments guarantee.  The verdict holds the
+    bound to `_LIMB_VERDICT_RTOL` and the query reruns in exact f64."""
+    import numpy as np
+
+    from greptimedb_tpu.parallel import tile_cache
+
+    db.config.query.disabled_passes = ("cold_host_serve",)  # device-path mechanics under test
+    _mk_cpu_table(db)
+    n = 65536
+    # "a" sorts first: its row opens the first block, beside 4095 rows of "h0"
+    hosts = np.append(np.repeat([f"h{i}" for i in range(8)], n // 8), "a")
+    ts = np.append(np.tile(np.arange(n // 8, dtype=np.int64) * 1000, 8), 0)
+    vals = np.append(np.random.default_rng(36).uniform(90, 100, n), 2.1)
+    half_step = 2.0 ** -23
+    assert tile_cache._LIMB_VERDICT_RTOL < half_step / 2.1 < 1e-7
+    assert tile_cache._LIMB_VERDICT_RTOL + 2.0 ** -24 < 1.2e-7
+    db.insert_rows("cpu", pa.table({
+        "host": pa.array(hosts),
+        "region": pa.array(np.repeat("r0", n + 1)),
+        "ts": pa.array(ts, pa.timestamp("ms")),
+        "usage_user": pa.array(vals),
+        "usage_system": pa.array(vals),
+    }))
+    db.sql("ADMIN flush_table('cpu')")
+    q = "SELECT host, avg(usage_user) AS au, count(*) AS c FROM cpu GROUP BY host ORDER BY host"
+    reruns, lowered = metrics.TILE_LIMB_RERUNS.get(), _tile_count()
+    got = db.sql_one(q).to_pydict()
+    assert _tile_count() == lowered + 1, "tile path did not engage"
+    assert metrics.TILE_LIMB_RERUNS.get() == reruns + 1, "verdict did not fire"
+    assert got["host"][0] == "a" and got["c"][0] == 1 and got["au"][0] == 2.1
+    for i in range(8):
+        np.testing.assert_allclose(got["au"][i + 1], vals[i * (n // 8):(i + 1) * (n // 8)].mean(), rtol=1e-12)
 
 
 def test_query_deadline_aborts_cpu_scan(db):
