@@ -924,22 +924,21 @@ class Database:
                     meta.name, meta.database, pa.Table.from_batches([batch])
                 )
             return affected
-        import time as _time
-
         from .utils import metrics as _metrics
         from .utils.memory import batch_nbytes
 
         table = pa.Table.from_batches([batch])
         affected = 0
-        t_split = _time.perf_counter()
-        parts = meta.partition_rule.split(table)
-        _metrics.INGEST_SPLIT_MS.observe((_time.perf_counter() - t_split) * 1000)
-        region_ids = meta.region_ids  # includes any repartition generation base
-        # system writes (event recorder) bypass the user write budget
-        with self.memory.write_guard(0 if system else batch_nbytes(batch)):
+        with tracing.stage("write.split") as split:
+            parts = meta.partition_rule.split(table)
             non_empty = [
                 (i, part) for i, part in enumerate(parts) if part.num_rows
             ]
+            split.set(regions=len(non_empty))
+        _metrics.INGEST_SPLIT_MS.observe(split.duration_s * 1000)
+        region_ids = meta.region_ids  # includes any repartition generation base
+        # system writes (event recorder) bypass the user write budget
+        with self.memory.write_guard(0 if system else batch_nbytes(batch)):
             # Pipeline through the sharded worker loops so per-region WAL
             # appends overlap (reference Inserter fans per-region requests
             # out concurrently, insert.rs:409-427, onto worker.rs write
@@ -964,30 +963,22 @@ class Database:
                 futures = []
                 for i, part in non_empty:
                     for b in part.to_batches():
-                        futures.append(
-                            (region_ids[i], b.num_rows,
-                             self.storage.submit_write(region_ids[i], b))
+                        # the worker fills this request's own dict with what
+                        # its `write.wal` / `write.memtable` stages measured,
+                        # before the future resolves: concurrent callers'
+                        # writes cannot be mis-attributed to this span
+                        stages: dict = {}
+                        futures.append((
+                            region_ids[i], b.num_rows, stages,
+                            self.storage.submit_write(region_ids[i], b, stages),
+                        ))
+                for rid, rows, stages, f in futures:
+                    with tracing.span("write.region", region=rid, rows=rows) as sp:
+                        affected += f.result(timeout=60)
+                        sp.attributes.update(
+                            (k, round(v, 3) if isinstance(v, float) else v)
+                            for k, v in stages.items()
                         )
-                parent = tracing.current_span()
-                for rid, rows, f in futures:
-                    if parent is None:
-                        affected += f.result(timeout=60)
-                        continue
-                    with tracing.span(
-                        "write.region", parent=parent, region=rid, rows=rows
-                    ) as sp:
-                        affected += f.result(timeout=60)
-                        # per-stage wall of the write THIS future covered:
-                        # the worker stamps it on the future before
-                        # resolving, so concurrent callers' writes cannot
-                        # be mis-attributed to this statement's span
-                        for k, v in (
-                            getattr(f, "stage_ms", None) or {}
-                        ).items():
-                            sp.set_attribute(
-                                f"{k}_ms" if k != "group" else "group_writes",
-                                round(v, 3) if isinstance(v, float) else v,
-                            )
             else:
                 for i, part in non_empty:
                     for b in part.to_batches():
@@ -1014,12 +1005,17 @@ class Database:
         # a logical table's label columns stay dictionary-encoded where they
         # come so: the metric engine hashes a `__tsid` per distinct label
         # set, which the codes give it without a pass over the strings
+        from .utils.metrics import WRITE_BATCH_S
+
         keep = is_logical_meta(meta)
         for b in batches:
-            total += self.write_batch(
-                meta, _conform_batch(b, meta.schema, keep_dictionaries=keep),
-                system=system,
-            )
+            with tracing.stage("write.batch", table=table, rows=b.num_rows) as st:
+                total += self.write_batch(
+                    meta, _conform_batch(b, meta.schema, keep_dictionaries=keep),
+                    system=system,
+                )
+            if tracing.counting():
+                WRITE_BATCH_S.inc(st.duration_s)
         return total
 
     # ---- SHOW/DESCRIBE ----------------------------------------------------

@@ -764,9 +764,14 @@ class Frontend:
         region_ids = meta.region_ids
         trace_parent = tracing.current_span()
         with self.admission.admit(meta.database, kind="write"):
-            for i, part in enumerate(meta.partition_rule.split(table)):
-                if part.num_rows == 0:
-                    continue
+            with tracing.stage("write.split") as split:
+                non_empty = [
+                    (i, part) for i, part in enumerate(meta.partition_rule.split(table))
+                    if part.num_rows
+                ]
+                split.set(regions=len(non_empty))
+            metrics.INGEST_SPLIT_MS.observe(split.duration_s * 1000)
+            for i, part in non_empty:
                 rid = region_ids[i]
                 for b in part.to_batches():
                     with _maybe_span(
@@ -792,9 +797,13 @@ class Frontend:
             batches = [rows]
         from ..database import _conform_batch
 
-        return sum(
-            self.write_batch(meta, _conform_batch(b, meta.schema)) for b in batches
-        )
+        total = 0
+        for b in batches:
+            with tracing.stage("write.batch", table=table, rows=b.num_rows) as st:
+                total += self.write_batch(meta, _conform_batch(b, meta.schema))
+            if tracing.counting():
+                metrics.WRITE_BATCH_S.inc(st.duration_s)
+        return total
 
     # ---- SHOW / DESCRIBE ---------------------------------------------------
     def _show(self, stmt: ShowStmt):

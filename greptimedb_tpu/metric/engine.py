@@ -38,6 +38,7 @@ from ..datatypes.data_type import ConcreteDataType
 from ..datatypes.schema import ColumnSchema, Schema, SemanticType
 from ..models.catalog import DEFAULT_SCHEMA, TableMeta, region_id
 from ..storage.sst import ScanPredicate, _apply_residual
+from ..utils import tracing
 from ..utils.errors import (
     InvalidArgumentsError,
     TableAlreadyExistsError,
@@ -81,18 +82,25 @@ def tsid_hash(pairs: list[tuple[str, str]]) -> int:
 
 
 def _batch_tsids(metric: str, labels: dict, n: int):
-    """The `__tsid` of every row of a batch, `tsid_hash` of the row's
-    non-null (label, value) pairs and `__name__`: hashed once per DISTINCT
-    label set.  Each label column is dictionary-encoded (as it comes, or
-    by one hash pass), the rows' codes are folded into one int64 key a
-    row, and the distinct keys are the distinct label sets; a key space
-    past 2^62 is made dense again before the next column joins it."""
+    """The `__tsid` of every row of a batch."""
+    hashes, inverse = _label_set_tsids(metric, labels, n)
+    return hashes[inverse]
+
+
+def _label_set_tsids(metric: str, labels: dict, n: int):
+    """(the `__tsid` of each DISTINCT label set of a batch, each row's
+    index into them): `tsid_hash` of the set's non-null (label, value)
+    pairs and `__name__`, hashed once a set.  Each label column is
+    dictionary-encoded (as it comes, or by one hash pass), the rows' codes
+    are folded into one int64 key a row, and the distinct keys are the
+    distinct label sets; a key space past 2^62 is made dense again before
+    the next column joins it."""
     import pyarrow.compute as pc
 
     from ..utils import metrics
 
     if n == 0:
-        return np.zeros(0, np.int64)
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
     names, values, indices = [], [], []
     key = np.zeros(n, np.int64)
     space = 1
@@ -136,7 +144,7 @@ def _batch_tsids(metric: str, labels: dict, n: int):
                 pairs.append((name, vals[at[i]]))
         hashes[i] = tsid_hash(pairs)
     metrics.METRIC_TSID_HASHES.inc(len(first))
-    return hashes[inverse]
+    return hashes, inverse
 
 
 class MetadataRegion:
@@ -442,6 +450,16 @@ class MetricEngine:
         """Inject __table_id/__tsid and write into the data region
         (reference row_modifier.rs + engine/put.rs)."""
         phys_meta = self.db.catalog.table(meta.options[LOGICAL_TABLE_OPT], meta.database)
+        with tracing.stage("write.logical", table=meta.name, rows=batch.num_rows) as st:
+            phys_batch = self._physical_batch(meta, phys_meta, batch, st)
+        return self.db.write_batch(phys_meta, phys_batch)
+
+    def _physical_batch(
+        self, meta: TableMeta, phys_meta: TableMeta, batch: pa.RecordBatch, st
+    ) -> pa.RecordBatch:
+        """A logical table's batch on the physical schema; `st`, the
+        `write.logical` stage, takes `labels` and `tsids` (the distinct
+        label sets hashed)."""
         # SNAPSHOT the physical schema once: concurrent logical-table
         # creation widens the physical table by REPLACING phys_meta.schema
         # (_ensure_physical_labels under _ddl_lock), and round 4 read it
@@ -465,7 +483,7 @@ class MetricEngine:
         fields = meta.schema.field_columns()
         if fields:
             remap[phys_val] = fields[0].name
-        tsids = _batch_tsids(
+        hashes, inverse = _label_set_tsids(
             meta.name,
             {
                 name: batch.column(batch.schema.get_field_index(name))
@@ -474,6 +492,8 @@ class MetricEngine:
             },
             n,
         )
+        tsids = hashes[inverse]
+        st.set(labels=len(label_cols), tsids=len(hashes))
         # Conform to the physical schema: logical ts/val keep their names
         # (schemas share them); absent physical labels become nulls.
         by_name = {batch.schema.field(i).name: batch.column(i) for i in range(batch.num_columns)}
@@ -492,8 +512,7 @@ class MetricEngine:
                 arrays.append(arr)
             else:
                 arrays.append(pa.nulls(n, col.data_type.to_arrow()))
-        phys_batch = pa.RecordBatch.from_arrays(arrays, schema=phys_schema.to_arrow())
-        return self.db.write_batch(phys_meta, phys_batch)
+        return pa.RecordBatch.from_arrays(arrays, schema=phys_schema.to_arrow())
 
     # ---- read path --------------------------------------------------------
     def scan_logical(self, meta: TableMeta, scan) -> list[pa.Table]:

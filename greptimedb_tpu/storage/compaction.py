@@ -23,6 +23,7 @@ from collections import defaultdict
 
 import pyarrow as pa
 
+from ..utils import metrics, tracing
 from .memtable import _SEQ_COL, _sort_and_dedup
 from .region import Region, _undict
 from .sst import FileMeta, interleaved_overlap_unsafe
@@ -231,35 +232,39 @@ def infer_window_ms(files: list[FileMeta]) -> int:
 def compact_files(region: Region, group: list[FileMeta]) -> FileMeta | None:
     """Merge one window's files: read, concat, sort(+dedup unless the
     region is append_mode — duplicates are semantically kept there), write
-    level-1."""
+    level-1 (`sst.encode` / `sst.index` with `level=1`)."""
     import numpy as np
 
-    tables = []
-    for meta in group:
-        t = region.read_sst(meta)
-        if t.num_rows:
-            tables.append(_undict(t))
+    with tracing.stage(
+        "compact.read", files=len(group), bytes=sum(m.file_size for m in group)
+    ):
+        tables = []
+        for meta in group:
+            t = region.read_sst(meta)
+            if t.num_rows:
+                tables.append(_undict(t))
     if not tables:
         return None
-    merged = pa.concat_tables(tables, promote_options="permissive")
-    if region.merge_mode == "last_non_null" and not region.append_mode:
-        # fieldwise merge is associative: the compacted row carries the
-        # newest non-null value per field among its inputs, and future
-        # reads fieldwise-merge it with newer sources exactly as if the
-        # versions were still separate (reference dedup.rs LastNonNull)
-        from .merge import _SEQ, _dedup_chunk
+    with tracing.stage("compact.merge", rows=sum(t.num_rows for t in tables)):
+        merged = pa.concat_tables(tables, promote_options="permissive")
+        if region.merge_mode == "last_non_null" and not region.append_mode:
+            # fieldwise merge is associative: the compacted row carries the
+            # newest non-null value per field among its inputs, and future
+            # reads fieldwise-merge it with newer sources exactly as if the
+            # versions were still separate (reference dedup.rs LastNonNull)
+            from .merge import _SEQ, _dedup_chunk
 
-        key_cols = [c.name for c in region.schema.tag_columns()]
-        if region.schema.time_index is not None:
-            key_cols.append(region.schema.time_index.name)
-        seq = pa.array(np.arange(merged.num_rows, dtype=np.int64))
-        merged = merged.append_column(_SEQ, seq)
-        merged = _dedup_chunk(merged, key_cols, region.schema, True, "last_non_null")
-    else:
-        seq = pa.array(np.arange(merged.num_rows, dtype=np.int64))
-        merged = merged.append_column(_SEQ_COL, seq)
-        merged = _sort_and_dedup(merged, region.schema, dedup=not region.append_mode)
-        merged = merged.drop_columns([_SEQ_COL])
+            key_cols = [c.name for c in region.schema.tag_columns()]
+            if region.schema.time_index is not None:
+                key_cols.append(region.schema.time_index.name)
+            seq = pa.array(np.arange(merged.num_rows, dtype=np.int64))
+            merged = merged.append_column(_SEQ, seq)
+            merged = _dedup_chunk(merged, key_cols, region.schema, True, "last_non_null")
+        else:
+            seq = pa.array(np.arange(merged.num_rows, dtype=np.int64))
+            merged = merged.append_column(_SEQ_COL, seq)
+            merged = _sort_and_dedup(merged, region.schema, dedup=not region.append_mode)
+            merged = merged.drop_columns([_SEQ_COL])
     return region.sst_writer.write(merged, level=1)
 
 
@@ -308,46 +313,59 @@ def compact_region(
         files = region.files()
         window = window_ms or infer_window_ms(files)
         picks = pick_compaction(files, window, max_active_runs, max_inactive_runs)
-        # dedup correctness depends on WRITE order: compact_files assigns
-        # its dedup sequence by concat position, so every merge list must
-        # follow manifest (flush) order — the pickers sort by cost/time
-        # for SELECTION only
-        manifest_pos = {f.file_id: i for i, f in enumerate(files)}
-        gate = _memory_gate(memory_mb)
-        done = 0
-        for group in picks:
-            # oversized merges split into budget-sized sub-merges; each
-            # sub-merge output is a sorted run, so the next round's run
-            # count still drops even when one pass can't merge everything
-            for sub in split_group_for_memory(group, gate.budget):
-                sub = sorted(sub, key=lambda m: manifest_pos[m.file_id])
-                if not region.append_mode and interleaved_overlap_unsafe(
-                    sub, files, manifest_pos
-                ):
-                    # a partial merge here would resurrect overwritten
-                    # values — widen to the safe closure (pulls the
-                    # interleaved overwrites into the merge) instead of
-                    # skipping, so refused picks never starve
-                    sub = widen_for_order(sub, files, manifest_pos)
-                    if (
-                        sum(f.file_size for f in sub) * _DECODE_FACTOR
-                        > gate.budget
-                    ):
-                        continue  # closure too big this round
-                est = min(
-                    sum(f.file_size for f in sub) * _DECODE_FACTOR, gate.budget
-                )
-                gate.acquire(est)
-                try:
-                    new_meta = compact_files(region, sub)
-                finally:
-                    gate.release(est)
-                adds = [new_meta] if new_meta is not None else []
-                if region.apply_compaction(adds, [f.file_id for f in sub]):
-                    done += 1
-                elif new_meta is not None:
-                    # commit refused (a flush interleaved an overlapping
-                    # file mid-merge): the output must not enter the
-                    # manifest — discard it and retry a later round
-                    region.sst_reader.delete(new_meta.file_id)
+        if not picks:
+            # no stage for a round with nothing to merge: the scheduler's
+            # idle tick leaves no annotation and moves no counter
+            return 0
+        with tracing.stage("compact.region", region=region.region_id, picks=len(picks)) as st:
+            done = _merge_picks(region, files, picks, _memory_gate(memory_mb))
+            st.set(merges=done)
         return done
+
+
+def _merge_picks(region: Region, files: list[FileMeta], picks: list, gate) -> int:
+    # dedup correctness depends on WRITE order: compact_files assigns
+    # its dedup sequence by concat position, so every merge list must
+    # follow manifest (flush) order — the pickers sort by cost/time
+    # for SELECTION only
+    manifest_pos = {f.file_id: i for i, f in enumerate(files)}
+    done = 0
+    for group in picks:
+        # oversized merges split into budget-sized sub-merges; each
+        # sub-merge output is a sorted run, so the next round's run
+        # count still drops even when one pass can't merge everything
+        for sub in split_group_for_memory(group, gate.budget):
+            sub = sorted(sub, key=lambda m: manifest_pos[m.file_id])
+            if not region.append_mode and interleaved_overlap_unsafe(
+                sub, files, manifest_pos
+            ):
+                # a partial merge here would resurrect overwritten
+                # values — widen to the safe closure (pulls the
+                # interleaved overwrites into the merge) instead of
+                # skipping, so refused picks never starve
+                sub = widen_for_order(sub, files, manifest_pos)
+                if (
+                    sum(f.file_size for f in sub) * _DECODE_FACTOR
+                    > gate.budget
+                ):
+                    continue  # closure too big this round
+            est = min(
+                sum(f.file_size for f in sub) * _DECODE_FACTOR, gate.budget
+            )
+            gate.acquire(est)
+            try:
+                new_meta = compact_files(region, sub)
+            finally:
+                gate.release(est)
+            adds = [new_meta] if new_meta is not None else []
+            if region.apply_compaction(adds, [f.file_id for f in sub]):
+                done += 1
+                metrics.COMPACTION_INPUT_BYTES.inc(sum(f.stored_bytes for f in sub))
+                metrics.COMPACTION_OUTPUT_BYTES.inc(sum(f.stored_bytes for f in adds))
+            elif new_meta is not None:
+                # commit refused (a flush interleaved an overlapping
+                # file mid-merge): the output must not enter the
+                # manifest — discard it and retry a later round
+                metrics.COMPACTION_DISCARDED_BYTES.inc(new_meta.stored_bytes)
+                region.sst_reader.delete(new_meta.file_id)
+    return done

@@ -223,34 +223,36 @@ class TimeSeriesEngine:
             return list(self._regions)
 
     # ---- request routing --------------------------------------------------
-    def write(self, region_id: int, batch: pa.RecordBatch) -> int:
+    def write(self, region_id: int, batch: pa.RecordBatch, stages: dict | None = None) -> int:
         region = self.region(region_id)
-        if self.buffer_mgr.should_stall():
-            # Under pressure: flush the biggest offenders synchronously
-            # instead of rejecting (single-process analogue of stalling).
-            metrics.WRITE_STALL_TOTAL.inc()
-            for rid in self.buffer_mgr.pick_flush_candidates():
-                self.flush_region(rid)
-                if not self.buffer_mgr.should_stall():
-                    break
-        rows = region.write(batch)
+        self._relieve_stall()
+        rows = region.write(batch, stages)
         self._post_write(region_id, region)
         return rows
 
-    def write_group(self, region_id: int, batches: list[pa.RecordBatch]) -> list[int]:
+    def write_group(
+        self, region_id: int, batches: list[pa.RecordBatch], stages: dict | None = None
+    ) -> list[int]:
         """Group-commit write (ingest.group_commit): one WAL frame for the
         whole group, per-write entry ids and row counts.  Same stall /
         flush-pressure envelope as `write`."""
         region = self.region(region_id)
-        if self.buffer_mgr.should_stall():
-            metrics.WRITE_STALL_TOTAL.inc()
-            for rid in self.buffer_mgr.pick_flush_candidates():
-                self.flush_region(rid)
-                if not self.buffer_mgr.should_stall():
-                    break
-        rows = region.write_group(batches)
+        self._relieve_stall()
+        rows = region.write_group(batches, stages)
         self._post_write(region_id, region)
         return rows
+
+    def _relieve_stall(self):
+        """Under pressure: flush the biggest offenders synchronously
+        instead of rejecting (single-process analogue of stalling).  The
+        seconds the write waits here are `greptime_mito_write_stall_seconds_total`."""
+        if not self.buffer_mgr.should_stall():
+            return
+        metrics.WRITE_STALL_TOTAL.inc()
+        for rid in self.buffer_mgr.pick_flush_candidates():
+            self.flush_region(rid, cause="stall")
+            if not self.buffer_mgr.should_stall():
+                break
 
     def _post_write(self, region_id: int, region: Region):
         self.buffer_mgr.set_region_usage(region_id, region.memtable.memory_usage)
@@ -260,37 +262,31 @@ class TimeSeriesEngine:
             if self.flusher is not None:
                 self.flusher.schedule(region_id)
             else:
-                self.flush_region(region_id)
+                self.flush_region(region_id, cause="threshold")
 
     def delete(self, region_id: int, keys: pa.Table) -> int:
         """Tombstone-delete rows by (primary key, time index) keys.
         Tombstones are memtable writes too, so the same stall/flush
         backpressure as `write` applies."""
         region = self.region(region_id)
-        if self.buffer_mgr.should_stall():
-            metrics.WRITE_STALL_TOTAL.inc()
-            for rid in self.buffer_mgr.pick_flush_candidates():
-                self.flush_region(rid)
-                if not self.buffer_mgr.should_stall():
-                    break
+        self._relieve_stall()
         deleted = region.delete(keys)
-        self.buffer_mgr.set_region_usage(region_id, region.memtable.memory_usage)
-        if self.buffer_mgr.should_flush_region(region_id) or self.buffer_mgr.should_flush_engine():
-            if self.flusher is not None:
-                self.flusher.schedule(region_id)
-            else:
-                self.flush_region(region_id)
+        self._post_write(region_id, region)
         return deleted
 
     def truncate_region(self, region_id: int):
         self.region(region_id).truncate()
         self.buffer_mgr.set_region_usage(region_id, 0)
 
-    def flush_region(self, region_id: int):
+    def flush_region(self, region_id: int, cause: str = "manual"):
+        """`cause` names who asked, for the `flush.region` stage: `stall`
+        (a foreground write under pressure), `threshold` (the write buffer's
+        limits, off the write path where the flush scheduler runs) or
+        `manual` (ADMIN, `flush_all`, migration, close)."""
         region = self._regions.get(region_id)
         if region is None:
             return
-        added = region.flush()
+        added = region.flush(cause)
         self.buffer_mgr.set_region_usage(region_id, region.memtable.memory_usage)
         if added and self.compactor is not None:
             self.compactor.notify_flush(region_id)
@@ -382,11 +378,12 @@ class TimeSeriesEngine:
                     )
         return self._workers
 
-    def submit_write(self, region_id: int, batch: pa.RecordBatch):
+    def submit_write(self, region_id: int, batch: pa.RecordBatch, stages: dict | None = None):
         """Queue a write on the region's worker loop; returns a Future of
         affected rows (pipelined ingest: protocol servers overlap decode
-        of the next request with this write's WAL+memtable apply)."""
-        return self.workers.submit_write(region_id, batch)
+        of the next request with this write's WAL+memtable apply).  The
+        caller's `stages` dict is filled before the future resolves."""
+        return self.workers.submit_write(region_id, batch, stages)
 
     def pending_writes(self, region_id: int) -> bool:
         """True when the region's worker loop has queued requests — i.e.

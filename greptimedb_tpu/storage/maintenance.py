@@ -110,7 +110,7 @@ class FlushScheduler:
                 rid = self._pending.pop()
                 self._inflight.add(rid)
             try:
-                self.engine.flush_region(rid)
+                self.engine.flush_region(rid, cause="threshold")
             except Exception:  # noqa: BLE001 — a failed flush retries on the
                 # next threshold trip; the WAL still holds the data
                 pass

@@ -25,7 +25,7 @@ import numpy as np
 import pyarrow as pa
 
 from ..datatypes.schema import Schema
-from ..utils import metrics
+from ..utils import metrics, tracing
 from ..utils.deadline import check_deadline
 from ..utils.errors import IllegalStateError, RegionReadonlyError
 from .manifest import ManifestManager
@@ -166,13 +166,11 @@ class Region:
         # set once the follower watermark is released (close/promotion);
         # an in-flight sync round must not re-pin the shared log after it
         self._lw_released = False
-        # Pipelined ingest: parallel per-SST flush encode pool width, the
+        # Pipelined ingest: parallel per-SST flush encode pool width and the
         # optional write-buffer freeze hook (set by the engine when
         # ingest.flush_overlap is on — flush moves the frozen memtable's
         # bytes out of the mutable budget so writes keep flowing during
-        # the encode), and the last write's per-stage wall (wal/memtable
-        # ms — the write.region span attrs; single-writer-per-region makes
-        # the unlocked read safe).
+        # the encode).
         # clamp to REAL cores: on a 1-core box the pool (and the window
         # slicing keyed off it) is pure overhead — more files, more index
         # builds, zero parallelism
@@ -182,7 +180,6 @@ class Region:
             cores = os.cpu_count() or 1
         self.flush_workers = max(1, min(flush_workers, cores))
         self.buffer_mgr = None
-        self.last_write_stage_ms: dict = {}
         self._conform_cache: tuple | None = None
         self._replay_wal()
 
@@ -203,8 +200,10 @@ class Region:
         return replayed
 
     # ---- write ------------------------------------------------------------
-    def write(self, batch: pa.RecordBatch) -> int:
-        """WAL append then memtable insert; returns affected rows."""
+    def write(self, batch: pa.RecordBatch, stages: dict | None = None) -> int:
+        """WAL append then memtable insert; returns affected rows.  A
+        caller's `stages` dict takes what the two stages measured
+        (`wal_ms`, `memtable_ms`): the `write.region` span's attributes."""
         with self._lock:
             # the writable check lives INSIDE the lock: set_writable(False)
             # (migration downgrade) takes the same lock, so once the fence
@@ -213,26 +212,25 @@ class Region:
             if not self.writable:
                 raise RegionReadonlyError(f"region {self.region_id} is read-only")
             batch = self._conform(batch)
-            t0 = time.perf_counter()
-            self.wal.append(batch)
-            t1 = time.perf_counter()
-            self.sequence += 1
-            self.memtable.write(batch, self.sequence)
-            t2 = time.perf_counter()
+            with tracing.stage("write.wal", bytes=batch.nbytes, group=1) as wal:
+                self.wal.append(batch)
+            with tracing.stage("write.memtable", rows=batch.num_rows) as mem:
+                self.sequence += 1
+                self.memtable.write(batch, self.sequence)
             self.applied_entry_id = self.wal.last_entry_id
-        wal_ms, mem_ms = (t1 - t0) * 1000, (t2 - t1) * 1000
-        self.last_write_stage_ms = {"wal": wal_ms, "memtable": mem_ms}
-        metrics.INGEST_WAL_MS.observe(wal_ms)
-        metrics.INGEST_MEMTABLE_MS.observe(mem_ms)
+        self._note_write(wal, mem, stages)
         metrics.INGEST_WRITES_TOTAL.inc()
         metrics.WRITE_ROWS_TOTAL.inc(batch.num_rows)
         return batch.num_rows
 
-    def write_group(self, batches: list[pa.RecordBatch]) -> list[int]:
+    def write_group(
+        self, batches: list[pa.RecordBatch], stages: dict | None = None
+    ) -> list[int]:
         """Group commit (ingest.group_commit): one WAL frame for a whole
         region-worker drain group, one entry id AND one sequence per write
         — live state equals a crash replay of the same frame entry for
-        entry.  Returns per-write affected row counts in order."""
+        entry.  Returns per-write affected row counts in order; `stages`
+        as in `write`, with `group_writes`."""
         from ..utils import fault_injection
 
         if not batches:
@@ -244,30 +242,35 @@ class Region:
                 "ingest.group_commit", region_id=self.region_id, n=len(batches)
             )
             conformed = [self._conform(b) for b in batches]
-            t0 = time.perf_counter()
-            append_group = getattr(self.wal, "append_group", None)
-            if append_group is not None:
-                append_group(conformed)
-            else:  # a WAL impl without group frames: per-write appends
+            rows = [b.num_rows for b in conformed]
+            with tracing.stage(
+                "write.wal", bytes=sum(b.nbytes for b in conformed), group=len(batches)
+            ) as wal:
+                append_group = getattr(self.wal, "append_group", None)
+                if append_group is not None:
+                    append_group(conformed)
+                else:  # a WAL impl without group frames: per-write appends
+                    for b in conformed:
+                        self.wal.append(b)
+            with tracing.stage("write.memtable", rows=sum(rows)) as mem:
+                # one sequence per write, exactly like replay assigns them
                 for b in conformed:
-                    self.wal.append(b)
-            t1 = time.perf_counter()
-            # one sequence per write, exactly like replay assigns them
-            for b in conformed:
-                self.sequence += 1
-                self.memtable.write(b, self.sequence)
-            t2 = time.perf_counter()
+                    self.sequence += 1
+                    self.memtable.write(b, self.sequence)
             self.applied_entry_id = self.wal.last_entry_id
-        wal_ms, mem_ms = (t1 - t0) * 1000, (t2 - t1) * 1000
-        self.last_write_stage_ms = {
-            "wal": wal_ms, "memtable": mem_ms, "group": len(batches),
-        }
-        metrics.INGEST_WAL_MS.observe(wal_ms)
-        metrics.INGEST_MEMTABLE_MS.observe(mem_ms)
+        self._note_write(wal, mem, stages, group_writes=len(batches))
         metrics.INGEST_WRITES_TOTAL.inc(len(batches))
-        rows = [b.num_rows for b in conformed]
         metrics.WRITE_ROWS_TOTAL.inc(sum(rows))
         return rows
+
+    @staticmethod
+    def _note_write(wal, mem, stages: dict | None, **more):
+        """The two histograms observe what the stages measured (one clock)."""
+        wal_ms, mem_ms = wal.duration_s * 1000, mem.duration_s * 1000
+        metrics.INGEST_WAL_MS.observe(wal_ms)
+        metrics.INGEST_MEMTABLE_MS.observe(mem_ms)
+        if stages is not None:
+            stages.update(wal_ms=wal_ms, memtable_ms=mem_ms, **more)
 
     def _conform(self, batch: pa.RecordBatch) -> pa.RecordBatch:
         """Project a write onto the region's current schema (+ the __op
@@ -324,14 +327,29 @@ class Region:
         return deleted
 
     # ---- flush ------------------------------------------------------------
-    def flush(self) -> list[FileMeta]:
+    def flush(self, cause: str = "manual") -> list[FileMeta]:
         """Freeze the memtable, write one SST per time window, commit the
         manifest edit, truncate WAL.  The frozen memtable stays scannable
         (in _frozen_memtables) until the manifest edit lands, so concurrent
-        scans never see the flush-in-progress rows vanish."""
+        scans never see the flush-in-progress rows vanish.  `cause` is why
+        the engine asked: `stall` (a foreground write waits for it),
+        `threshold` or `manual`."""
+        if self.memtable.is_empty():
+            return []  # no stage for a flush with nothing to do (`flush_all`)
+        with tracing.stage("flush.region", cause=cause, region=self.region_id) as st:
+            added, committed = self._flush(st)
+        if committed:
+            metrics.FLUSH_TOTAL.inc()
+            metrics.FLUSH_ELAPSED.observe(st.duration_s)
+        if cause == "stall":
+            metrics.WRITE_STALL_S.inc(st.duration_s)
+        return added
+
+    def _flush(self, st) -> tuple[list[FileMeta], bool]:
+        """(files added, whether the edit was committed)."""
         with self._lock:
             if self.memtable.is_empty():
-                return []
+                return [], False
             frozen = self.memtable
             frozen_bytes = frozen.memory_usage
             frozen_entry_id = self.wal.last_entry_id
@@ -344,13 +362,17 @@ class Region:
                 # while this encode runs; the flushing bucket keeps the
                 # total bounded (see WriteBufferManager.should_stall)
                 self.buffer_mgr.freeze_region(self.region_id, frozen_bytes)
-        t0 = time.perf_counter()
         try:
-            added = self._encode_sst_windows(frozen)
+            # no counter of its own: its time stays with `flush.region`,
+            # `flush.sort` and the encode pool's stages
+            with tracing.stage("flush.windows") as windows:
+                added = self._encode_sst_windows(frozen)
         finally:
             if self.buffer_mgr is not None:
                 self.buffer_mgr.unfreeze_region(self.region_id, frozen_bytes)
-        metrics.INGEST_FLUSH_ENCODE_MS.observe((time.perf_counter() - t0) * 1000)
+        metrics.INGEST_FLUSH_ENCODE_MS.observe(windows.duration_s * 1000)
+        metrics.FLUSH_SST_BYTES.inc(sum(m.stored_bytes for m in added))
+        st.set(rows=sum(m.num_rows for m in added), files=len(added))
         with self._lock:
             truncated = self.manifest_mgr.manifest.truncated_entry_id or 0
             if truncated >= frozen_entry_id:
@@ -361,10 +383,10 @@ class Region:
                 if frozen in self._frozen_memtables:
                     self._frozen_memtables.remove(frozen)
                 self._garbage_files.extend(
-                (m.file_id, time.time()) for m in added
-            )
+                    (m.file_id, time.time()) for m in added
+                )
                 self._purge_garbage_locked()
-                return []
+                return [], False
             self.manifest_mgr.apply(
                 {
                     "kind": "edit",
@@ -376,9 +398,7 @@ class Region:
             )
             self._frozen_memtables.remove(frozen)
         self.wal.obsolete(frozen_entry_id)
-        metrics.FLUSH_TOTAL.inc()
-        metrics.FLUSH_ELAPSED.observe(time.perf_counter() - t0)
-        return added
+        return added, True
 
     # Rows per SST slice when one time window dominates a flush: a
     # window's sorted run splits into consecutive slices (disjoint key
@@ -397,12 +417,13 @@ class Region:
         exactly like any other L0 run split.  Output order stays window
         order (slices in run order), so manifest positions are
         deterministic."""
-        parts = frozen.split_by_time_partition(
-            # last_non_null must NOT last-row-dedup on flush: older
-            # versions' non-null fields are still live until the READ-side
-            # fieldwise merge combines them
-            dedup=not self.append_mode and self.merge_mode != "last_non_null"
-        )
+        with tracing.stage("flush.sort"):
+            parts = frozen.split_by_time_partition(
+                # last_non_null must NOT last-row-dedup on flush: older
+                # versions' non-null fields are still live until the READ-side
+                # fieldwise merge combines them
+                dedup=not self.append_mode and self.merge_mode != "last_non_null"
+            )
         tables: list[pa.Table] = []
         for _w, t in parts:
             if (self.flush_workers > 1

@@ -20,7 +20,7 @@ import pyarrow.compute as pc
 import pyarrow.parquet as pq
 
 from ..datatypes.schema import Schema
-from ..utils import fault_injection, metrics
+from ..utils import fault_injection, metrics, tracing
 from ..utils.deadline import check_deadline, current_deadline
 from . import index as idx
 from .index import BLOOM_BLOB, FULLTEXT_BLOB, INVERTED_BLOB, VECTOR_BLOB
@@ -57,6 +57,11 @@ class FileMeta:
     # this field existed).  The device tile cache only aggregates files it
     # can PROVE tombstone-free.
     num_deletes: int = 0
+
+    @property
+    def stored_bytes(self) -> int:
+        """Parquet file + index sidecar."""
+        return self.file_size + self.index_file_size
 
     def to_dict(self) -> dict:
         return {
@@ -237,45 +242,49 @@ class SstWriter:
         """Write one sorted table as one SST file; returns its FileMeta."""
         if table.num_rows == 0:
             return None
-        ts_name = self.schema.time_index.name if self.schema.time_index else None
-        if ts_name is not None:
-            ts = pc.cast(table[ts_name], pa.int64())
-            t_min, t_max = pc.min(ts).as_py(), pc.max(ts).as_py()
-        else:
-            t_min = t_max = 0
-        num_deletes = 0
-        if "__op" in table.column_names:
-            num_deletes = int(
-                pc.sum(
-                    pc.fill_null(pc.cast(table["__op"], pa.int64()), 0)
-                ).as_py()
-                or 0
-            )
-        # Dictionary-encode tag columns: small files + pre-built codes for TPU.
-        for tag in self.schema.tag_columns():
-            if tag.name in table.column_names and not pa.types.is_dictionary(
-                table.schema.field(tag.name).type
-            ):
-                i = table.schema.get_field_index(tag.name)
-                table = table.set_column(
-                    i, tag.name, pc.dictionary_encode(table[tag.name].combine_chunks())
+        with tracing.stage("sst.encode", level=level, rows=table.num_rows) as st:
+            ts_name = self.schema.time_index.name if self.schema.time_index else None
+            if ts_name is not None:
+                ts = pc.cast(table[ts_name], pa.int64())
+                t_min, t_max = pc.min(ts).as_py(), pc.max(ts).as_py()
+            else:
+                t_min = t_max = 0
+            num_deletes = 0
+            if "__op" in table.column_names:
+                num_deletes = int(
+                    pc.sum(
+                        pc.fill_null(pc.cast(table["__op"], pa.int64()), 0)
+                    ).as_py()
+                    or 0
                 )
-        file_id = uuid.uuid4().hex
-        key = f"{file_id}.parquet"
-        scratch = self.store.scratch_path(key)
-        pq.write_table(
-            table,
-            scratch,
-            row_group_size=self.row_group_size,
-            compression="zstd",
-            use_dictionary=True,
-        )
-        file_size = os.path.getsize(scratch)
-        self.store.put_file(key, scratch)
+            # Dictionary-encode tag columns: small files + pre-built codes for TPU.
+            for tag in self.schema.tag_columns():
+                if tag.name in table.column_names and not pa.types.is_dictionary(
+                    table.schema.field(tag.name).type
+                ):
+                    i = table.schema.get_field_index(tag.name)
+                    table = table.set_column(
+                        i, tag.name, pc.dictionary_encode(table[tag.name].combine_chunks())
+                    )
+            file_id = uuid.uuid4().hex
+            key = f"{file_id}.parquet"
+            scratch = self.store.scratch_path(key)
+            pq.write_table(
+                table,
+                scratch,
+                row_group_size=self.row_group_size,
+                compression="zstd",
+                use_dictionary=True,
+            )
+            file_size = os.path.getsize(scratch)
+            self.store.put_file(key, scratch)
+            st.set(bytes=file_size)
         indexed, index_size = ([], 0)
         if self.index_enable:
             try:
-                indexed, index_size = self._build_indexes(table, file_id)
+                with tracing.stage("sst.index") as st:
+                    indexed, index_size = self._build_indexes(table, file_id)
+                    st.set(columns=len(indexed), bytes=index_size)
             except Exception as e:  # noqa: BLE001 — an index build failure
                 # must never lose the data write: the SST lands without a
                 # sidecar (unpruned but correct), and the failure is loud

@@ -28,6 +28,8 @@ class _WriteRequest:
     region_id: int
     batch: pa.RecordBatch
     future: Future
+    # the submitter's own dict for the write's stage durations, or None
+    stages: dict | None = None
 
 
 class RegionWorkerLoop:
@@ -100,49 +102,38 @@ class RegionWorkerLoop:
         for r in reqs:
             by_region.setdefault(r.region_id, []).append(r)
         for rid, group in by_region.items():
+            # what the write's `write.wal` / `write.memtable` stages measured,
+            # handed back in each request's own dict BEFORE its future
+            # resolves: a per-request value, so a concurrent caller's later
+            # write on this region can never be mis-attributed to this
+            # statement's write.region span
+            stages: dict = {}
             try:
                 if len(group) == 1:
-                    rows = self.engine.write(rid, group[0].batch)
-                    self._stamp_stages(rid, group)
-                    group[0].future.set_result(rows)
-                    continue
-                write_group = getattr(self.engine, "write_group", None)
-                if write_group is not None and getattr(
-                    getattr(self.engine, "config", None),
-                    "ingest_group_commit", True,
-                ):
-                    rows_list = write_group(rid, [g.batch for g in group])
-                    self._stamp_stages(rid, group)
-                    for g, n in zip(group, rows_list):
-                        g.future.set_result(n)
-                    continue
-                merged = pa.Table.from_batches(
-                    [g.batch for g in group]
-                ).combine_chunks()
-                self.engine.write(
-                    rid, merged.to_batches()[0]
-                    if merged.num_rows
-                    else group[0].batch
-                )
-                self._stamp_stages(rid, group)
-                for g in group:
-                    g.future.set_result(g.batch.num_rows)
+                    rows_list = [self.engine.write(rid, group[0].batch, stages)]
+                elif getattr(self.engine.config, "ingest_group_commit", True):
+                    rows_list = self.engine.write_group(
+                        rid, [g.batch for g in group], stages
+                    )
+                else:
+                    merged = pa.Table.from_batches(
+                        [g.batch for g in group]
+                    ).combine_chunks()
+                    self.engine.write(
+                        rid, merged.to_batches()[0]
+                        if merged.num_rows
+                        else group[0].batch,
+                        stages,
+                    )
+                    rows_list = [g.batch.num_rows for g in group]
+                for g, n in zip(group, rows_list):
+                    if g.stages is not None:
+                        g.stages.update(stages)
+                    g.future.set_result(n)
             except Exception as e:  # noqa: BLE001 — deliver per-request
                 for g in group:
                     if not g.future.done():
                         g.future.set_exception(e)
-
-    def _stamp_stages(self, rid: int, group: list[_WriteRequest]):
-        """Attach the write's per-stage wall to each request's future
-        BEFORE resolving it: the submitting thread reads it off the
-        future, so a concurrent caller's later write on this region can
-        never be mis-attributed to this statement's write.region span."""
-        try:
-            stages = self.engine.region(rid).last_write_stage_ms
-        except Exception:  # noqa: BLE001 — attribution only
-            return
-        for g in group:
-            g.future.stage_ms = stages
 
 
 class WorkerGroup:
@@ -157,9 +148,13 @@ class WorkerGroup:
     def _worker_for(self, region_id: int) -> RegionWorkerLoop:
         return self.workers[region_id % len(self.workers)]
 
-    def submit_write(self, region_id: int, batch: pa.RecordBatch) -> Future:
+    def submit_write(
+        self, region_id: int, batch: pa.RecordBatch, stages: dict | None = None
+    ) -> Future:
+        """`stages`, the caller's own dict, holds `wal_ms` / `memtable_ms`
+        (and `group_writes` of a merged frame) once the future resolves."""
         fut: Future = Future()
-        self._worker_for(region_id).submit(_WriteRequest(region_id, batch, fut))
+        self._worker_for(region_id).submit(_WriteRequest(region_id, batch, fut, stages))
         return fut
 
     def write(self, region_id: int, batch: pa.RecordBatch, timeout: float = 60.0) -> int:

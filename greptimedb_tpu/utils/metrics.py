@@ -200,10 +200,27 @@ FLUSH_TOTAL = REGISTRY.counter("greptime_mito_flush_total", "Memtable flushes")
 FLUSH_ELAPSED = REGISTRY.histogram("greptime_mito_flush_elapsed", "Flush seconds")
 COMPACTION_TOTAL = REGISTRY.counter("greptime_mito_compaction_total", "Compactions")
 WRITE_STALL_TOTAL = REGISTRY.counter("greptime_mito_write_stall_total", "Write stalls")
+WRITE_STALL_S = REGISTRY.counter(
+    "greptime_mito_write_stall_seconds_total",
+    "Seconds foreground writes spent in their synchronous stall flush "
+    "(the `flush.region` stages of cause=stall)")
+FLUSH_SST_BYTES = REGISTRY.counter(
+    "greptime_mito_flush_sst_bytes_total", "Parquet + index bytes of the level-0 files flushes wrote")
+COMPACTION_INPUT_BYTES = REGISTRY.counter(
+    "greptime_mito_compaction_input_bytes_total",
+    "File + index bytes read by compaction merges that committed")
+COMPACTION_OUTPUT_BYTES = REGISTRY.counter(
+    "greptime_mito_compaction_output_bytes_total",
+    "File + index bytes written by compaction merges that committed")
+COMPACTION_DISCARDED_BYTES = REGISTRY.counter(
+    "greptime_mito_compaction_discarded_bytes_total",
+    "File + index bytes of merge outputs whose commit was refused")
 # Pipelined columnar ingest: per-stage timings + WAL frame accounting.
 # The stage histograms split a write's wall time between partition-split
-# (frontend), WAL append and memtable apply; flush_encode covers the
-# Parquet+index encode of one flush.  The frame counters are the
+# (frontend), WAL append and memtable apply; flush_encode covers the sort,
+# Parquet and index encode of one flush.  Each observes the duration of its
+# stage (`write.split`, `write.wal`, `write.memtable`, `flush.windows`; and
+# FLUSH_ELAPSED above that of `flush.region`): one clock.  The frame counters are the
 # group-commit observability contract: with ingest.group_commit on,
 # wal_frames_total grows SLOWER than writes_total (merged frames), and
 # group_writes_total counts the write entries those merged frames carried.
@@ -827,9 +844,12 @@ TILE_HEALTH_INVALIDATIONS = REGISTRY.counter(
 )
 
 # ---- stage clocks (utils/tracing.py `stage`) --------------------------------
-# SELF seconds per stage of the request path: a stage's duration minus what
-# its counted descendants covered, so the counters of one request sum to
-# HTTP_REQUEST_S's move.  One module-level Counter each (benchmark readers
+# SELF seconds per stage of the request path and of the write path: a
+# stage's duration minus what its counted descendants covered, so the
+# counters of one request sum to HTTP_REQUEST_S's move, and those of one
+# direct `insert_rows` batch to WRITE_BATCH_S's.  A counter holds seconds ON
+# THE THREAD that ran the stage: the flush pool's and the region workers'
+# stages are roots on their own threads and overlap their callers in wall time.  One module-level Counter each (benchmark readers
 # find counters by these names); STAGE_SELF_S below is the only registry,
 # and a stage whose name is not in it is transparent.
 
@@ -870,6 +890,29 @@ HTTP_REQUEST_S = REGISTRY.counter(
     "greptime_http_request_seconds_total",
     "Inclusive seconds of every HTTP request (the `http.request` stage)",
 )
+STAGE_SELF_S_WRITE_BATCH = _stage_self_s(
+    "write.batch", "conform, admission, write guard, waiting on region futures, flow mirror")
+STAGE_SELF_S_WRITE_LOGICAL = _stage_self_s(
+    "write.logical", "a logical table's batch onto the physical schema: remap, __tsid hashes, casts")
+STAGE_SELF_S_WRITE_SPLIT = _stage_self_s("write.split", "partition-rule split of a batch")
+STAGE_SELF_S_WRITE_WAL = _stage_self_s("write.wal", "WAL append: IPC encode, write, fsync")
+STAGE_SELF_S_WRITE_MEMTABLE = _stage_self_s("write.memtable", "memtable apply")
+STAGE_SELF_S_FLUSH_REGION = _stage_self_s(
+    "flush.region", "freeze, waiting on the encode pool, manifest edit, WAL truncation")
+STAGE_SELF_S_FLUSH_SORT = _stage_self_s(
+    "flush.sort", "a frozen memtable's sort, dedup and time-window split")
+STAGE_SELF_S_SST_ENCODE = _stage_self_s(
+    "sst.encode", "one SST's statistics, dictionary encode, Parquet write and store")
+STAGE_SELF_S_SST_INDEX = _stage_self_s(
+    "sst.index", "one SST's bloom / inverted / term / vector builds and puffin write")
+STAGE_SELF_S_COMPACT_REGION = _stage_self_s(
+    "compact.region", "a compaction round that merges: memory gate, order checks, manifest commit")
+STAGE_SELF_S_COMPACT_READ = _stage_self_s("compact.read", "SST decode of a merge's inputs")
+STAGE_SELF_S_COMPACT_MERGE = _stage_self_s("compact.merge", "concat, sort and dedup of a merge")
+WRITE_BATCH_S = REGISTRY.counter(
+    "greptime_write_batch_seconds_total",
+    "Inclusive seconds of every `insert_rows` batch (the `write.batch` stage)",
+)
 # how often `servers/http.py` renders a records answer by column, and how
 # often a column's type sends it through the per-cell `_json_value` instead
 HTTP_RENDER_COLUMNAR_CELLS = REGISTRY.counter(
@@ -901,4 +944,16 @@ STAGE_SELF_S: dict[str, Counter] = {
     "tile.decode": STAGE_SELF_S_TILE_DECODE,
     "tql.plan": STAGE_SELF_S_TQL_PLAN,
     "tql.assemble": STAGE_SELF_S_TQL_ASSEMBLE,
+    "write.batch": STAGE_SELF_S_WRITE_BATCH,
+    "write.logical": STAGE_SELF_S_WRITE_LOGICAL,
+    "write.split": STAGE_SELF_S_WRITE_SPLIT,
+    "write.wal": STAGE_SELF_S_WRITE_WAL,
+    "write.memtable": STAGE_SELF_S_WRITE_MEMTABLE,
+    "flush.region": STAGE_SELF_S_FLUSH_REGION,
+    "flush.sort": STAGE_SELF_S_FLUSH_SORT,
+    "sst.encode": STAGE_SELF_S_SST_ENCODE,
+    "sst.index": STAGE_SELF_S_SST_INDEX,
+    "compact.region": STAGE_SELF_S_COMPACT_REGION,
+    "compact.read": STAGE_SELF_S_COMPACT_READ,
+    "compact.merge": STAGE_SELF_S_COMPACT_MERGE,
 }

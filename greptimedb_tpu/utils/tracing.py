@@ -28,6 +28,13 @@ host plane, on the device trace's clock) and the stage's SELF time added to
 its counter in `metrics.STAGE_SELF_S` (duration minus what its counted
 descendants covered; a name with no counter is transparent).  `span(name)`
 is a stage plus the trace identity: ids, parent, collector, export.
+
+A counter holds seconds on the THREAD that ran the stage.  The stack of open
+stages crosses a thread hop only where the context is copied and the caller
+blocks (`kernel_executor.run`); a pool whose tasks run side by side (the
+flush encode pool, the region workers) gets no copy, so its stages are roots
+on their own threads: concurrent children would make a parent's self time
+negative.
 """
 
 from __future__ import annotations
@@ -302,6 +309,14 @@ def counters_muted():
         yield
     finally:
         _muted.reset(token)
+
+
+def counting() -> bool:
+    """Whether a stage closed here would move its counter: what a site that
+    adds a root's inclusive seconds by hand asks (`WRITE_BATCH_S`: the
+    self-trace writer inserts under `suppressed()`), so that the root and
+    its parts move together."""
+    return not _suppress.get() and not _muted.get()
 
 
 def _plain(attrs: dict) -> dict:
