@@ -131,20 +131,32 @@ def strip_counter_resets_segmented(
     """`strip_counter_resets` for PADDED tile planes: invalid rows (pad
     rows, dedup losers, rows outside the fetch range) may sit BETWEEN a
     series' samples, so "previous sample" means the previous VALID row of
-    the same series, found with a running max over valid row indices.
+    the same series.  It is a CARRY, as `_window_rows` carries its keys:
+    one prefix scan brings the last valid row's series id and value
+    forward over every row, and the carry shifted one place is the
+    previous valid row's (series id -1: there is none; valid rows' series
+    ids are >= 0).  Not a gather of that row: this chip gathers at ≈ 250 ×
+    a scan's cost a row, and two gathers over the plane were most of a
+    PromQL `rate` request.  Pure selection, so the values compared and
+    added are the stored ones, bit for bit.
     The accumulation is `strip_counter_resets`' (a running sum of reset
     adds restarting at each series' first valid row); invalid rows
     contribute exact 0.0 terms, so on the same logical sample sequence
     the two agree to the last ulp or two (the scan tree's shape follows
     the row positions).  Only valid rows' outputs are meaningful."""
-    n = series.shape[0]
-    idx = jnp.arange(n, dtype=jnp.int32)
-    last_valid = _running_max(jnp.where(valid, idx, -1))
-    prev_idx = jnp.concatenate([jnp.full((1,), -1, jnp.int32), last_valid[:-1]])
-    safe_prev = jnp.clip(prev_idx, 0, None)
-    pv = jnp.take(values, safe_prev)
-    ps = jnp.take(series, safe_prev)
-    same = valid & (prev_idx >= 0) & (ps == series)
+
+    def carry(a, b):
+        take = b[0] >= 0
+        return jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1])
+
+    sid_c, val_c = prefix_scan(
+        carry,
+        (jnp.where(valid, series, -1), jnp.where(valid, values, 0.0)),
+        (-1, 0.0),
+    )
+    ps = _shift_right(sid_c, 1, -1)
+    pv = _shift_right(val_c, 1, 0.0)
+    same = valid & (ps >= 0) & (ps == series)
     reset_add = jnp.where(same & (values < pv), pv, 0.0)
     return values + _sum_since_start(valid & ~same, reset_add)
 

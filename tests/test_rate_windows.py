@@ -311,6 +311,103 @@ def test_the_rate_program_holds_no_scatter_and_sum_over_time_still_does():
     assert inside == []
 
 
+# ---- the reset strip: a carry, bit-equal to the gather it replaced -----------
+
+
+def _gather_strip(sid, vals, valid):
+    """The strip as it was until PR 38: the previous valid row's index by a
+    running max, its value and series GATHERED; the segmented sum is the
+    module's own (unchanged), so the outputs can be held to the bit."""
+    from greptimedb_tpu.ops.rate import _sum_since_start
+
+    last_valid = np.maximum.accumulate(np.where(valid, np.arange(len(sid)), -1))
+    prev = np.concatenate([[-1], last_valid[:-1]])
+    pv, ps = vals[np.clip(prev, 0, None)], sid[np.clip(prev, 0, None)]
+    same = valid & (prev >= 0) & (ps == sid)
+    with np.errstate(invalid="ignore"):
+        reset_add = np.where(same & (vals < pv), pv, 0.0)
+    return vals + np.asarray(_sum_since_start(jnp.asarray(valid & ~same), jnp.asarray(reset_add)))
+
+
+def _strip_case(name):
+    """(series ids, values, valid) of one named case; series sorted, as the
+    tile planes hold them."""
+    nan = np.nan
+    if name == "invalid_rows_between_samples":
+        sid = [0] * 7 + [1] * 6
+        vals = [5, 99, 7, 9, 1, 50, 3, 4, 8, 2, 77, 6, 1]
+        valid = [1, 0, 1, 1, 0, 1, 1, 1, 0, 1, 0, 1, 1]
+    elif name == "pad_tail":
+        sid = [0, 0, 0, 1, 1, 1] + [0] * 10
+        vals = [10, 4, 12, 3, 1, 2] + [0] * 10
+        valid = [1] * 6 + [0] * 10
+    elif name == "reset_at_a_series_first_valid_row":
+        sid, vals, valid = [0, 0, 1, 1, 2], [100, 200, 5, 6, 1], [1] * 5
+    elif name == "series_whose_first_rows_are_invalid":
+        sid = [0, 0, 1, 1, 1, 1, 2, 2]
+        vals = [3, 9, 500, 600, 7, 2, 0, 1]
+        valid = [1, 1, 0, 0, 1, 1, 0, 1]
+    elif name == "nan_values":
+        sid = [0, 0, 0, 0, 1, 1, 1]
+        vals = [4, nan, 2, 1, nan, 3, nan]
+        valid = [1] * 7
+    elif name == "all_invalid":
+        sid, vals, valid = [0, 0, 1, 1], [9, 1, 8, 2], [0] * 4
+    elif name == "one_row":
+        sid, vals, valid = [0], [42.5], [1]
+    else:  # 2^16 random rows: resets, NaNs, invalid rows and a pad tail
+        rng = np.random.default_rng(3800001)
+        n, real = 1 << 16, 60_000
+        sid = np.zeros(n, np.int32)
+        sid[:real] = np.sort(rng.integers(0, 700, real))
+        vals = np.zeros(n)
+        vals[:real] = np.cumsum(rng.uniform(0, 10, real))
+        vals[:real][rng.random(real) < 0.02] = rng.uniform(0, 50, 1)[0]
+        vals[:real][rng.random(real) < 0.01] = np.nan
+        valid = np.zeros(n, bool)
+        valid[:real] = rng.random(real) < 0.85
+    return (np.asarray(sid, np.int32), np.asarray(vals, np.float64),
+            np.asarray(valid, bool))
+
+
+STRIP_CASES = [
+    "invalid_rows_between_samples", "pad_tail", "reset_at_a_series_first_valid_row",
+    "series_whose_first_rows_are_invalid", "nan_values", "all_invalid", "one_row",
+    "random_2_16_rows",
+]
+
+
+@pytest.mark.parametrize("name", STRIP_CASES)
+def test_reset_strip_is_bit_equal_to_the_gather_form(name):
+    sid, vals, valid = _strip_case(name)
+    got = np.asarray(strip_counter_resets_segmented(
+        jnp.asarray(sid), jnp.asarray(vals), jnp.asarray(valid)
+    ))
+    want = _gather_strip(sid, vals, valid)
+    assert np.array_equal(got.view(np.int64), want.view(np.int64))
+
+
+def _primitives(jaxpr):
+    """Every primitive name of a jaxpr, its sub-jaxprs' included."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def test_reset_strip_holds_no_gather():
+    n = 1 << 16
+    closed = jax.make_jaxpr(strip_counter_resets_segmented)(
+        jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.float64), jnp.zeros(n, bool)
+    )
+    assert "gather" not in _primitives(closed.jaxpr)
+    # the walk finds a gather where there is one
+    taken = jax.make_jaxpr(lambda v, i: jnp.take(v, i))(jnp.zeros(n), jnp.zeros(n, jnp.int32))
+    assert "gather" in _primitives(taken.jaxpr)
+
+
 # ---- the planes the tile program hands the kernel ---------------------------
 
 
