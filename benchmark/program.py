@@ -12,7 +12,12 @@ from __future__ import annotations
 
 import time
 
-MUST_NOT_MOVE = ("TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TQL_TILE_DEGRADED")
+# a window that moves any of these left the path its cell states: the CPU,
+# the legacy TQL scan, or (`tile.mesh_devices`) one chip where the mesh serves
+MUST_NOT_MOVE = (
+    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TQL_TILE_DEGRADED",
+    "TILE_MESH_DEGRADED", "TILE_MESH_INELIGIBLE",
+)
 
 
 def open_database(home: str, settings: dict):

@@ -25,13 +25,14 @@ def test_ordinal_gid_dispatches_reads_its_counter_per_request():
     assert spec["reader"] == "counter_delta"
     assert spec["args"] == {"counter": "TILE_ORDINAL_GIDS", "per_request": True}
     entry = [p for p in manifest.manifest()["per_layer"] if p["name"] == NAME]
-    assert len(entry) == 1 and entry[0] == manifest.manifest()["per_layer"][-1], "appended, last"
+    assert len(entry) == 1
     for key in ("name", "layer", "unit", "better", "source", "moves"):
         assert spec[key] == entry[0][key], key
     assert (spec["layer"], spec["better"], spec["moves"]) == (
         "kernels (ops/aggregate.py tile program)", "higher", "sql_qps",
     )
-    assert entry[0]["workloads"] == ["tsbs-heavy", "tsbs-mesh4-heavy"]
+    # the two SQL cells; a later SQL cell may append itself
+    assert {"tsbs-heavy", "tsbs-mesh4-heavy"} <= set(entry[0]["workloads"])
     mesh = manifest.Cell("tsbs-mesh4-heavy")
     assert NAME in {p["name"] for p in mesh.per_layer}
     assert NAME in {p["name"] for p in manifest.Cell("tsbs-heavy").per_layer}

@@ -26,25 +26,22 @@ LABELS = sorted([
     "hostname", "region", "datacenter", "rack", "os", "arch", "team", "service",
     "service_version", "service_environment", "port", "server",
 ])
-# the per-layer metrics this PR wrote.  A new `per_layer` entry goes at the
-# END of the list, and `test_ordinal_gid_dispatches.py` (PR 34) holds the list's
-# last entry to its own; only a `benchmark` PR may edit that file.  So their
-# files are here, tested, and nothing below keeps a later PR from listing them
-# with these `workloads`; until then the counters they read are in every
-# result line's `run.moved`
+# the per-layer metrics PR 35 wrote, listed in the manifest by PR 39 with
+# these `workloads`
 WRITTEN = {
     "tql_logical_dispatches_per_query": ("TQL_TILE_LOGICAL_DISPATCHES", [CELL]),
     "tql_plane_rows_per_query": ("TQL_TILE_PLANE_ROWS", ["prom-rate-range", CELL]),
 }
-# not `tql_plan_ms`, `tql_assemble_ms`, `tql_legacy_per_query`,
-# `tql_dispatches_per_query`, `rate_roofline`: `test_prom_rate_range.py` holds
-# their lists to `prom-rate-range` alone, and only a `benchmark` PR may edit it
+# metrics of other cells whose code this cell crosses; the PromQL five
+# (`tql_plan_ms` … `rate_roofline`) joined them in PR 39
 JOINED = {
     "tql_segment_stats_per_query", "dispatches_per_query.sql",
     "readback_bytes_per_query", "device_idle_pct.sql", "compile_s", "compiles_in_window.sql",
     "prewarm_s", "ingest_krows_per_s", "http_self_ms", "http_render_ms", "http_write_ms",
     "parse_ms", "dispatch_ms", "readback_wait_ms", "client_side_ms",
     "render_columnar_cells_per_query",
+    "tql_plan_ms", "tql_assemble_ms", "tql_legacy_per_query", "tql_dispatches_per_query",
+    "rate_roofline",
 }
 
 
@@ -115,11 +112,10 @@ def test_the_new_files_cross_refer():
         assert spec["reader"] == "counter_delta" and spec["moves"] == "sql_qps"
         assert spec["args"] == {"counter": counter, "per_request": True}
         assert spec["layer"] == "PromQL tile path" and spec["source"] == "program_counter"
-        if name in per_layer:  # listed by a later PR: as its file says
-            entry = per_layer[name]
-            assert entry["workloads"] == cells
-            for key in ("layer", "unit", "better", "source", "moves"):
-                assert entry[key] == spec[key], key
+        entry = per_layer[name]
+        assert set(cells) <= set(entry["workloads"])  # a later cell may append itself
+        for key in ("layer", "unit", "better", "source", "moves"):
+            assert entry[key] == spec[key], key
     reports = {p["name"] for p in m["per_layer"] if CELL in p.get("workloads", [CELL])}
     assert reports >= JOINED
     for name in JOINED:
@@ -232,6 +228,16 @@ def test_roofline_bytes_are_the_samples_the_request_must_read(cell, ds):
     assert cell.shapes["me-sum-by-region"].ticks(ds, lit) == (31, 241)
     assert roofline.shape_bytes(cell.shapes["me-sum-by-region"], ds, lit) == 210 * 400 * 20
     assert roofline.shape_bytes(cell.shapes["me-increase-host"], ds, lit) == 210 * 20
+    # `rate_roofline` reads a number over this cell's shapes (a traced slice
+    # of one cycle at 819 GB/s, 1 ms busy); the CPU's trace has no device plane
+    least = sum(
+        roofline.least_seconds(roofline.shape_bytes(shape, ds, lit), "TPU v5 lite")
+        for shape in cell.shapes.values()
+    )
+    run = {"trace": {"busy_s": 1e-3}, "traced_least_s": least}
+    assert cell.read_metric("layer_metrics", "rate_roofline", run) == pytest.approx(
+        100.0 * (210 * 401 * 20) / 819e9 / 1e-3
+    )
 
 
 @pytest.mark.parametrize("trace", ["0", "1"])
@@ -254,19 +260,20 @@ def test_cell_rehearses_on_the_cpu_and_the_float32_control_fails(trace):
         assert set(metrics) == listed >= {"sql_qps", "setup_s"}
         return
     # no device plane on the CPU: the trace's readers report nothing, never 0
-    assert set(metrics) == listed - {"device_idle_pct.sql"}
+    assert set(metrics) == listed - {"device_idle_pct.sql", "rate_roofline"}
     assert metrics["dispatches_per_query.sql"]["value"] == 1.0
     assert metrics["compiles_in_window.sql"]["value"] == 0.0
-    # the counters of the metrics whose lists this PR may not join, and of
-    # the two it wrote, read through the harness as a listed metric is
     assert moved["TQL_TILE_DISPATCHES"] == moved["TPU_DEVICE_DISPATCHES"] == result["attempted"]
-    run = {"requests": result["attempted"], "counters": moved}
-    here = manifest.Cell(CELL)
-    assert here.read_metric("layer_metrics", "tql_logical_dispatches_per_query", run) == 1.0
+    assert metrics["tql_dispatches_per_query"]["value"] == 1.0
+    assert metrics["tql_legacy_per_query"]["value"] == 0.0
+    assert metrics["tql_plan_ms"]["value"] > 0 and metrics["tql_assemble_ms"]["value"] > 0
+    assert metrics["tql_logical_dispatches_per_query"]["value"] == 1.0
     # 36,000 rows a logical table of a 252,000-row region: a 2^16-row slice
     # of planes padded to 2^18 rows
-    assert here.read_metric("layer_metrics", "tql_plane_rows_per_query", run) == 1 << 16
+    assert metrics["tql_plane_rows_per_query"]["value"] == 1 << 16
+    assert metrics["write_logical_s"]["value"] > 0
     # a program without the counters (the parent): nothing, and no raise
+    here = manifest.Cell(CELL)
     assert here.read_metric(
         "layer_metrics", "tql_plane_rows_per_query", {"requests": 8, "counters": {}}
     ) is None

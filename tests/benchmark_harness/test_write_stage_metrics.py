@@ -1,11 +1,8 @@
 """The write path's per-layer metrics (PR 37): fifteen metric files and the
 reader `counter_setup`, which reads a counter of the program over SET-UP (the
-process's total less the window's delta).  `BENCHMARK.json` lists none of them
-yet: a new `per_layer` entry goes at the END of the list, and
-`test_ordinal_gid_dispatches.py` (PR 34) holds the list's last entry to its
-own, which only a `benchmark` PR may edit.  So the files are here, tested,
-with the entries that PR appends built from them below; a program without the
-counters (the parent) reads nothing for each and raises nothing.
+process's total less the window's delta).  `BENCHMARK.json` lists them since
+PR 39, each entry built from its file below; a program without the counters
+(the parent) reads nothing for each and raises nothing.
 """
 
 import os
@@ -38,6 +35,8 @@ COUNTS = {
 }
 MAY_READ_ZERO = {"compaction_rewritten_mb", "write_stall_s"}
 ALL = sorted([*STAGES, *COUNTS])
+# as PERF.md section 7 asked for them (PR 37), and as the manifest lists them
+ORDER = [*STAGES, *COUNTS]
 
 
 def _spec(name: str) -> dict:
@@ -45,18 +44,23 @@ def _spec(name: str) -> dict:
 
 
 def _entry(name: str) -> dict:
-    """The `per_layer` entry a `benchmark` PR appends for this file."""
+    """The `per_layer` entry built from this file, as PR 39 appended it."""
     spec = _spec(name)
     entry = {key: spec[key] for key in ("name", "unit", "better", "source", "layer", "moves")}
     entry["workloads"] = [ME] if name == "write_logical_s" else list(CELLS)
     return entry
 
 
-def test_there_are_fifteen_and_the_manifest_lists_none_yet():
-    assert len(ALL) == 15
-    listed = {p["name"] for p in manifest.manifest()["per_layer"]}
-    assert not listed & set(ALL)
-    assert manifest.manifest()["per_layer"][-1]["name"] == "ordinal_gid_dispatches_per_query"
+def test_there_are_fifteen_and_the_manifest_lists_each_as_its_file_says():
+    assert len(ALL) == 15 and sorted(ORDER) == ALL
+    per_layer = manifest.manifest()["per_layer"]
+    listed = [p for p in per_layer if p["name"] in STAGES or p["name"] in COUNTS]
+    assert [p["name"] for p in listed] == ORDER
+    for entry in listed:
+        want = _entry(entry["name"])
+        # every key as built; a later cell may append itself to `workloads`
+        assert {**entry, "workloads": want["workloads"]} == want, entry["name"]
+        assert set(want["workloads"]) <= set(entry["workloads"]), entry["name"]
 
 
 @pytest.mark.parametrize("name", ALL)
@@ -87,10 +91,7 @@ def test_a_metric_file_names_its_counter_and_the_keys_the_manifest_wants(name):
     assert len(name) <= 64 and " " not in entry["unit"] and len(entry["unit"]) <= 16
 
 
-def test_the_entries_appended_put_each_metric_in_its_cells(monkeypatch):
-    plain = manifest.manifest()
-    appended = {**plain, "per_layer": plain["per_layer"] + [_entry(name) for name in ALL]}
-    monkeypatch.setattr(manifest, "manifest", lambda: appended)
+def test_the_entries_put_each_metric_in_its_cells():
     for cell in CELLS:
         here = {p["name"] for p in manifest.Cell(cell).per_layer}
         assert here >= set(ALL) - {"write_logical_s"}, cell
