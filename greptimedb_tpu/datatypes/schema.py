@@ -45,6 +45,11 @@ class ColumnSchema:
     # ANN sidecar at flush.
     vector_dim: int | None = None
     vector_index: bool = False
+    # A TAG column's SSTs get a term (inverted) index beside their bloom
+    # filters unless this is off (reference region option
+    # `index.inverted_index.ignore_column_ids`, which the metric engine sets
+    # on its data region for `__tsid`).
+    inverted_index: bool = True
 
     def __post_init__(self):
         if self.semantic_type == SemanticType.TIMESTAMP:
@@ -85,6 +90,7 @@ class ColumnSchema:
             "fulltext": self.fulltext,
             "vector_dim": self.vector_dim,
             "vector_index": self.vector_index,
+            "inverted_index": self.inverted_index,
         }
 
     @classmethod
@@ -99,6 +105,7 @@ class ColumnSchema:
             fulltext=d.get("fulltext", False),
             vector_dim=d.get("vector_dim"),
             vector_index=d.get("vector_index", False),
+            inverted_index=d.get("inverted_index", True),
         )
 
 

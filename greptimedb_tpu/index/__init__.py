@@ -27,18 +27,20 @@ from .reader import TermIndexReader
 from .segmented import (
     TERM_META_BLOB,
     TERM_SEGMENT_BLOB,
+    Postings,
     SegmentedTermIndex,
-    build_term_postings,
     build_token_postings,
+    term_postings,
     write_term_index,
 )
 
 __all__ = [
     "TERM_META_BLOB",
     "TERM_SEGMENT_BLOB",
+    "Postings",
     "SegmentedTermIndex",
     "TermIndexReader",
-    "build_term_postings",
     "build_token_postings",
+    "term_postings",
     "write_term_index",
 ]

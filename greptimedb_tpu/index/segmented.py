@@ -76,13 +76,6 @@ INDEX_DEGRADED = metrics.REGISTRY.counter(
 # ---- varint codec -----------------------------------------------------------
 
 
-def _write_varint(out: bytearray, v: int):
-    while v >= 0x80:
-        out.append((v & 0x7F) | 0x80)
-        v >>= 7
-    out.append(v)
-
-
 def _read_varint(buf: bytes, pos: int) -> tuple[int, int]:
     v = 0
     shift = 0
@@ -98,55 +91,70 @@ def _read_varint(buf: bytes, pos: int) -> tuple[int, int]:
 # ---- build ------------------------------------------------------------------
 
 
-def build_term_postings(
-    column: pa.Array, segment_rows: int
-) -> tuple[list[bytes], list[np.ndarray], int]:
-    """Tag column -> (sorted term bytes, per-term row-segment id arrays,
-    n_row_segments).  Vectorized via dictionary encoding, like the legacy
-    inverted builder, but with NO cardinality cap — segmenting is what
-    makes high cardinality affordable."""
-    from ..storage.index import _encode_value
+class Postings:
+    """A column's term index before it is cut into blobs: `terms`, the
+    distinct term bytes in ascending order (an arrow binary array), and
+    the row-segment ids of term i, `segs[starts[i]:starts[i + 1]]`,
+    ascending."""
 
-    n = len(column)
-    if isinstance(column, pa.ChunkedArray):
-        column = column.combine_chunks()
-    if pa.types.is_dictionary(column.type):
-        column = pc.cast(column, column.type.value_type)
-    n_segs = (n + segment_rows - 1) // segment_rows
-    d = pc.dictionary_encode(column)
-    dict_vals = d.dictionary.to_pylist()
-    codes = np.asarray(
-        pc.fill_null(pc.cast(d.indices, pa.int64()), len(dict_vals)), dtype=np.int64
+    def __init__(self, terms: pa.Array, starts: np.ndarray, segs: np.ndarray, n_segs: int):
+        self.terms, self.starts, self.segs, self.n_segs = terms, starts, segs, n_segs
+
+    def __len__(self) -> int:
+        return len(self.terms)
+
+    @classmethod
+    def from_lists(cls, terms: list[bytes], postings: list[np.ndarray], n_segs: int):
+        counts = np.array([len(p) for p in postings], np.int64)
+        return cls(
+            pa.array(terms, pa.binary()),
+            np.concatenate([[0], np.cumsum(counts)]).astype(np.int64),
+            np.concatenate(postings).astype(np.int64) if postings else np.zeros(0, np.int64),
+            n_segs,
+        )
+
+
+def term_postings(column, segment_rows: int) -> Postings:
+    """Tag column (or its `storage.index.ColumnTerms`) -> its `Postings`,
+    with NO cardinality cap — segmenting is what makes high cardinality
+    affordable.  A term is `_encode_value(v)[:MAX_TERM_BYTES]` (NULL rows:
+    `_encode_value(None)`); values whose terms coincide share one posting
+    list, the union of theirs."""
+    from ..storage.index import ColumnTerms, _encode_value
+
+    col = column if isinstance(column, ColumnTerms) else ColumnTerms(column)
+    n_segs = -(-col.n // segment_rows)
+    seg, code = col.segment_pairs(segment_rows)
+    keys = col.keys()
+    merge = not (
+        pa.types.is_string(col.values.type) or pa.types.is_large_string(col.values.type)
+        or pa.types.is_integer(col.values.type)
     )
-    seg_ids = np.arange(n, dtype=np.int64) // segment_rows
-    # unique (code, row-seg) pairs; nulls ride code == len(dict_vals)
-    pair = codes * n_segs + seg_ids
-    pair = np.unique(pair)
-    pcodes = pair // n_segs
-    psegs = (pair % n_segs).astype(np.int64)
-    keys = [_encode_value(v)[:MAX_TERM_BYTES] for v in dict_vals]
-    if (codes == len(dict_vals)).any():
-        keys.append(_encode_value(None))
-    # group by term bytes (several dict values can normalize to one key);
-    # pair is sorted, so pcodes is sorted — run boundaries, not per-code
-    # masks (a mask per code is O(terms * pairs))
-    by_key: dict[bytes, list[np.ndarray]] = {}
-    uniq, starts = np.unique(pcodes, return_index=True)
-    ends = np.append(starts[1:], len(pcodes))
-    for code, s, e in zip(uniq, starts, ends):
-        by_key.setdefault(keys[int(code)], []).append(psegs[s:e])
-    terms = sorted(by_key)
-    postings = [
-        np.unique(np.concatenate(by_key[t])) if len(by_key[t]) > 1 else by_key[t][0]
-        for t in terms
-    ]
-    return terms, postings, n_segs
+    if len(keys) and pc.max(pc.binary_length(keys)).as_py() > MAX_TERM_BYTES:
+        keys, merge = pc.binary_slice(keys, 0, MAX_TERM_BYTES), True
+    keys = pa.concat_arrays([keys.cast(pa.binary()), pa.array([_encode_value(None)], pa.binary())])
+    merge = merge or (col.has_null and pc.any(pc.equal(keys[:-1], keys[-1])).as_py())
+    if merge:
+        # one id per distinct term bytes
+        enc = pc.dictionary_encode(keys)
+        distinct, key_of_code = enc.dictionary, np.asarray(enc.indices, np.int64)
+    else:
+        distinct, key_of_code = keys, np.arange(len(keys), dtype=np.int64)
+    order = np.asarray(pc.sort_indices(distinct), np.int64)
+    rank = np.empty(len(order), np.int64)
+    rank[order] = np.arange(len(order), dtype=np.int64)
+    # (term rank, segment) pairs ascending; coinciding terms leave doubles
+    pair = np.sort(rank[key_of_code[code]] * max(n_segs, 1) + seg)
+    if merge and len(pair):
+        pair = pair[np.append(True, pair[1:] != pair[:-1])]
+    ranks, segs = pair // max(n_segs, 1), pair % max(n_segs, 1)
+    first = np.flatnonzero(np.append(True, ranks[1:] != ranks[:-1])) if len(ranks) else ranks
+    terms = distinct.take(pa.array(order[ranks[first]]))
+    return Postings(terms, np.append(first, len(pair)).astype(np.int64), segs, n_segs)
 
 
-def build_token_postings(
-    column: pa.Array, segment_rows: int
-) -> tuple[list[bytes], list[np.ndarray], int]:
-    """Tokenized text column -> sorted token postings (fulltext kind)."""
+def build_token_postings(column: pa.Array, segment_rows: int) -> Postings:
+    """Tokenized text column -> its token `Postings` (fulltext kind)."""
     from ..storage.index import tokenize
 
     n = len(column)
@@ -167,22 +175,7 @@ def build_token_postings(
         tok_by_bytes.setdefault(t.encode()[:MAX_TERM_BYTES], set()).update(segs)
     terms = sorted(tok_by_bytes)
     postings = [np.array(sorted(tok_by_bytes[t]), dtype=np.int64) for t in terms]
-    return terms, postings, n_segs
-
-
-def _encode_segment(terms: list[bytes], postings: list[np.ndarray]) -> bytes:
-    out = bytearray()
-    out += struct.pack("<I", len(terms))
-    for t in terms:
-        out += struct.pack("<H", len(t))
-        out += t
-    for p in postings:
-        _write_varint(out, len(p))
-        prev = 0
-        for v in p.tolist():
-            _write_varint(out, v - prev)
-            prev = v
-    return bytes(out)
+    return Postings.from_lists(terms, postings, n_segs)
 
 
 def _decode_segment(buf: bytes) -> dict[bytes, np.ndarray]:
@@ -207,36 +200,91 @@ def _decode_segment(buf: bytes) -> dict[bytes, np.ndarray]:
     return out
 
 
+def _varints(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Every value as an LEB128 varint (seven bits a byte, low first, the
+    high bit set on all but the last), end to end: (the bytes, the offset
+    at which each value's bytes start)."""
+    v = values.astype(np.uint64)
+    width = np.ones(len(v), np.int64)
+    for shift in range(7, 64, 7):
+        width += v >= np.uint64(1 << shift)
+    at = np.cumsum(width) - width
+    which = np.repeat(np.arange(len(v)), width)
+    j = (np.arange(int(width.sum())) - at[which]).astype(np.uint64)
+    out = ((v[which] >> (np.uint64(7) * j)) & np.uint64(0x7F)).astype(np.uint8)
+    out[j < (width[which] - 1).astype(np.uint64)] |= 0x80
+    return out, at
+
+
+def _segment_streams(postings: Postings) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+    """The two halves of a segment blob for every term, end to end, for
+    the blobs to cut from: (the `<H` length + bytes of each term, each
+    term's offset in them, the varint posting lists — a term's count, then
+    its segment ids as deltas from 0 — and each term's offset in them);
+    either offset array has one entry past the last term."""
+    from ..storage.index import binary_parts
+
+    data, offs = binary_parts(postings.terms)
+    lens = np.diff(offs)
+    n = len(lens)
+    at = offs[:-1] + 2 * np.arange(n)
+    dict_stream = np.empty(len(data) + 2 * n, np.uint8)
+    dict_stream[at] = lens & 0xFF
+    dict_stream[at + 1] = lens >> 8
+    owner = np.repeat(np.arange(n), lens)
+    dict_stream[np.arange(len(data)) + 2 * (owner + 1)] = np.frombuffer(data, np.uint8)
+    # a term's list: its count, then its segment ids as deltas from 0
+    counts = np.diff(postings.starts)
+    deltas = np.diff(postings.segs, prepend=0)
+    deltas[postings.starts[:-1]] = postings.segs[postings.starts[:-1]]
+    head = postings.starts[:-1] + np.arange(n)
+    values = np.empty(n + len(deltas), np.int64)
+    is_head = np.zeros(len(values), bool)
+    is_head[head] = True
+    values[is_head], values[~is_head] = counts, deltas
+    var_stream, var_at = _varints(values)
+    return (
+        dict_stream, np.append(at, len(dict_stream)),
+        var_stream, np.append(var_at[head], len(var_stream)),
+    )
+
+
 def write_term_index(
     writer,
     column: str,
     kind: str,
-    terms: list[bytes],
-    postings: list[np.ndarray],
+    flat: Postings,
     *,
     segment_rows: int,
     n_rows: int,
-    n_segs: int,
     seg_terms: int = 512,
 ) -> int:
-    """Emit the meta blob + one segment blob per `seg_terms` terms into
-    `writer` (a PuffinWriter).  Returns the number of segment blobs."""
+    """Emit the meta blob + one segment blob per `seg_terms` terms of
+    `flat` into `writer` (a PuffinWriter).  A segment blob is its term
+    count (`<I`), its terms' halves of `_segment_streams` and then their
+    posting lists, cut out of streams made for all terms at once
+    (`_decode_segment` reads it).  Returns the number of segment blobs."""
+    n_terms = len(flat)
     fences: list[str] = []
     seg_lens: list[int] = []
     n_written = 0
-    for start in range(0, len(terms), seg_terms):
-        seg_t = terms[start : start + seg_terms]
-        seg_p = postings[start : start + seg_terms]
+    if n_terms:
+        dict_stream, dict_at, var_stream, var_at = _segment_streams(flat)
+        firsts = flat.terms.take(pa.array(np.arange(0, n_terms, seg_terms))).to_pylist()
+    for start in range(0, n_terms, seg_terms):
+        stop = min(start + seg_terms, n_terms)
         # latin-1 maps bytes 1:1 into JSON-safe codepoints, so the fence
         # round-trips EXACTLY even when MAX_TERM_BYTES truncation cut a
         # multibyte character in half — a utf-8 'replace' decode would
         # mangle such a fence and misroute every lookup near it (wrongly
         # pruning row groups that hold the term)
-        fences.append(seg_t[0].decode("latin-1"))
-        seg_lens.append(len(seg_t))
+        fences.append(firsts[start // seg_terms].decode("latin-1"))
+        seg_lens.append(stop - start)
         writer.add_blob(
             TERM_SEGMENT_BLOB,
-            _encode_segment(seg_t, seg_p),
+            struct.pack("<I", stop - start)
+            + dict_stream[dict_at[start]:dict_at[stop]].tobytes()
+            + var_stream[var_at[start]:var_at[stop]].tobytes(),
             {"column": column, "kind": kind, "seg": n_written},
         )
         n_written += 1
@@ -245,8 +293,8 @@ def write_term_index(
         "kind": kind,
         "segment_rows": segment_rows,
         "n_rows": n_rows,
-        "n_segs": n_segs,
-        "n_terms": len(terms),
+        "n_segs": flat.n_segs,
+        "n_terms": n_terms,
         "seg_terms": seg_terms,
         "fences": fences,
         "seg_lens": seg_lens,

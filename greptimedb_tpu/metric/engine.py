@@ -37,6 +37,7 @@ import pyarrow as pa
 from ..datatypes.data_type import ConcreteDataType
 from ..datatypes.schema import ColumnSchema, Schema, SemanticType
 from ..models.catalog import DEFAULT_SCHEMA, TableMeta, region_id
+from ..storage.index import binary_parts
 from ..storage.sst import ScanPredicate, _apply_residual
 from ..utils import tracing
 from ..utils.errors import (
@@ -48,6 +49,8 @@ from ..utils.errors import (
 # Synthetic physical columns (reference row_modifier.rs injects the same pair).
 TABLE_ID_COL = "__table_id"
 TSID_COL = "__tsid"
+# Prometheus' metric-name label, which a series id hashes as the table's name
+NAME_LABEL = "__name__"
 
 # Table-option keys (reference metric-engine consts PHYSICAL_TABLE_METADATA_KEY
 # / LOGICAL_TABLE_METADATA_KEY).
@@ -109,7 +112,7 @@ def _label_set_tsids(metric: str, labels: dict, n: int):
             col = col.combine_chunks()
         if not pa.types.is_dictionary(col.type):
             col = pc.dictionary_encode(col)
-        vals = col.dictionary.to_pylist()
+        vals = col.dictionary
         # a row's digit in the key: 0 = the label is absent from the row,
         # else 1 + the value's index
         index = col.indices
@@ -135,16 +138,40 @@ def _label_set_tsids(metric: str, labels: dict, n: int):
     inverse = sets.indices.to_numpy()
     first = np.empty(len(sets.dictionary), np.int64)
     first[inverse[::-1]] = np.arange(n - 1, -1, -1)
-    picked = [index[first].tolist() for index in indices]
-    hashes = np.empty(len(first), np.int64)
-    for i in range(len(first)):
-        pairs = [("__name__", metric)]
-        for name, vals, at in zip(names, values, picked):
-            if at[i] >= 0 and vals[at[i]] is not None:
-                pairs.append((name, vals[at[i]]))
-        hashes[i] = tsid_hash(pairs)
+    hashes = _tsid_hashes(metric, names, values, [index[first] for index in indices])
     metrics.METRIC_TSID_HASHES.inc(len(first))
     return hashes, inverse
+
+
+def _tsid_hashes(metric: str, names: list, values: list, picked: list) -> np.ndarray:
+    """`tsid_hash` of every label set: set i carries label `names[j]` with
+    value `values[j][picked[j][i]]` (absent where that index is < 0 or the
+    value is None), and `__name__` = `metric` (no label has that name:
+    `_ensure_physical_labels`).  The hashed bytes of all sets are joined
+    by arrow, label by label in `tsid_hash`'s order, so only the digest is
+    a step a set."""
+    import pyarrow.compute as pc
+
+    n_sets = len(picked[0]) if picked else 1
+    parts = {NAME_LABEL: pa.array(np.full(n_sets, f"{NAME_LABEL}\x00{metric}\x01", object), pa.string())}
+    for name, vals, at in zip(names, values, picked):
+        if pa.types.is_string(vals.type) or pa.types.is_large_string(vals.type):
+            text = vals
+        else:  # str(v), as tsid_hash writes a value
+            text = pa.array([None if v is None else str(v) for v in vals.to_pylist()], pa.string())
+        chosen = text.take(pa.array(at, pa.int64(), mask=at < 0))
+        parts[name] = pc.binary_join_element_wise(f"{name}\x00", chosen, "\x01", "")
+    joined = pc.binary_join_element_wise(
+        *[parts[name] for name in sorted(parts)], "",
+        null_handling="replace", null_replacement="",
+    )
+    data, offs = binary_parts(joined)
+    blake = hashlib.blake2b
+    digests = b"".join([
+        blake(data[a:b], digest_size=8).digest()
+        for a, b in zip(offs[:-1].tolist(), offs[1:].tolist())
+    ])
+    return np.frombuffer(digests, "<i8").astype(np.int64)
 
 
 class MetadataRegion:
@@ -237,7 +264,13 @@ class MetricEngine:
             ColumnSchema(ts_col, ConcreteDataType.TIMESTAMP_MILLISECOND, SemanticType.TIMESTAMP),
             ColumnSchema(val_col, ConcreteDataType.FLOAT64, SemanticType.FIELD),
             ColumnSchema(TABLE_ID_COL, ConcreteDataType.INT64, SemanticType.TAG, nullable=False),
-            ColumnSchema(TSID_COL, ConcreteDataType.INT64, SemanticType.TAG, nullable=False),
+            # no term index on the series hash, a term a series that no
+            # query filters on (reference engine/options.rs sets
+            # `index.inverted_index.ignore_column_ids` on the data region)
+            ColumnSchema(
+                TSID_COL, ConcreteDataType.INT64, SemanticType.TAG, nullable=False,
+                inverted_index=False,
+            ),
         ]
         meta = self.db.catalog.create_table(
             name,
@@ -432,6 +465,12 @@ class MetricEngine:
             os.remove(path)
 
     def _ensure_physical_labels(self, phys_meta: TableMeta, labels: list[str]):
+        if NAME_LABEL in labels:
+            # the metric's name is its table's (reference servers/src/
+            # prom_store.rs takes it out of the labels); `tsid_hash` adds it
+            raise InvalidArgumentsError(
+                f"a metric-engine table may not have a label named {NAME_LABEL!r}"
+            )
         missing = [l for l in labels if not phys_meta.schema.has_column(l)]
         if not missing:
             return

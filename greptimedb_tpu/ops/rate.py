@@ -319,7 +319,7 @@ def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
         jnp.full(num_series + 1, n, jnp.int32),
         jnp.arange(num_series + 1, dtype=jnp.int32) - 1, n.bit_length(),
     )
-    lo, nxt = s_lo[:-1, None], s_lo[1:, None]
+    lo, nxt = s_lo[None, :-1], s_lo[None, 1:]
     hi = jnp.where(nxt > 0, jnp.take(last_valid, nxt - 1, mode="clip") + 1, 0)
     hi = jnp.maximum(hi, lo)
     rounds = 32 - jax.lax.clz(jnp.max(hi - lo))
@@ -331,11 +331,15 @@ def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
     # bits the search runs on 32-bit keys, a row's distance from that edge
     # saturated at both ends (exact: a saturated key still compares with
     # every d as the row's ts does with the target); a 64-bit gather and
-    # compare cost the chip three times as much.
-    w = jnp.arange(n_steps, dtype=jnp.int64)[None, :]
+    # compare cost the chip three times as much.  The search is laid out
+    # [2, W, S], the series on the minor axis: the chip tiles an array's
+    # minor axis by 128 lanes, and [2, S, W] with a few steps there held
+    # 128 / W times the cells (a 6-step panel over 2^20 series: 5.34 GB of
+    # temporaries by the compiler's count, 2.16 GB laid out so).
+    w = jnp.arange(n_steps, dtype=jnp.int64)[:, None]
     edge = start - range_
     d = jnp.stack([w * step, w * step + range_])
-    run = jnp.broadcast_to(lo, (2, num_series, n_steps))
+    run = jnp.broadcast_to(lo, (2, n_steps, num_series))
     stop = jnp.iinfo(jnp.int32).max
 
     def narrow():
@@ -353,7 +357,7 @@ def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
     )
     count = jnp.where(w < n_steps_actual, before[1] - before[0], 0)
     last_row = jnp.take(last_valid, bounds[1] - 1, mode="clip")
-    return count, bounds[0], last_row
+    return count.T, bounds[0].T, last_row.T
 
 
 @jax.named_scope("range_windows")

@@ -605,6 +605,17 @@ class PlaneManifest:
     dedup: bool = False  # LWW keep plane
 
 
+def _files_overlap(regions) -> bool:
+    """Whether some region's SSTs overlap in time (rows of one key may then
+    sit in two files, and last-write-wins needs the keep plane)."""
+    for region in regions:
+        metas, _mems, _version = region.tile_snapshot()
+        ranges = sorted(m.time_range for m in metas)
+        if any(a[1] >= b[0] for a, b in zip(ranges, ranges[1:])):
+            return True
+    return False
+
+
 class _FamilyBuild:
     """One in-flight fused family build: the leader runs the consolidated
     build + priming dispatch; concurrent queries of the family wait on
@@ -3263,10 +3274,11 @@ def _encode_host_tiles(
     nulls: dict[str, np.ndarray] = {}
     epochs: dict[str, int] = {}
     nbytes = 0
+    # every tag column's new values first, the dictionary saved once
+    dictionary.update_table(table, [name for name in columns if name in tag_cols])
     for name in columns:
         col = table[name]
         if name in tag_cols:
-            dictionary.update(name, col)
             np_arr = dictionary.encode(name, col)
             epochs[name] = dictionary.epoch
         elif name == ts_col:
@@ -6675,6 +6687,10 @@ class TileExecutor:
                 ts_col=ts_name,
                 value_cols=tuple(value_cols),
                 limb_cols=tuple(nonnull) if limb_wanted else (),
+                # last-write-wins where files overlap in time: a query over
+                # such a table reads the keep plane, and built here its first
+                # touch dispatches instead of evaluating every series cold
+                dedup=not ctx.append_mode and _files_overlap(ctx.regions),
             )
             self.cache.record_manifest(manifest)
             with self.cache.build_gate(ctx.table_key) as leader:

@@ -23,7 +23,8 @@ Routing ladder (the `tql_tile` optimizer pass, off-switch `tql.tile`):
           NEXT query is warm; with fused builds off the planes build
           synchronously like the pre-fused SQL ladder;
   legacy  any ineligibility (memtable rows in the window, tombstones,
-          unsupported matcher target, series*steps cell bound) or ANY
+          unsupported matcher target, an evaluation past the device
+          memory the plane cache leaves free) or ANY
           tile-path failure — fault point `tql.tile`,
           `greptime_tql_tile_degraded_total` — falls back to the
           upload-per-query path, bit-for-bit `tql.tile = false` behavior.
@@ -746,15 +747,11 @@ class TqlTileExecutor:
         w = len(steps)
         w_pad = _pow2(w)
         k = _pow2(max(-(-range_ms // step), 1))
-        if s_pad * w_pad > int(cfg.tql.max_cells):
-            raise _Ineligible(
-                f"series*steps cells {s_pad}x{w_pad} exceed tql.max_cells"
-            )
 
         # --- matchers: a bool per series, folded from code masks over the
         # per-series label table (a dynamic input: literals never recompile)
         selected = np.zeros(s_pad, bool)
-        selected[:n_series] = (labels.codes >= 0).all(axis=1)
+        selected[:n_series] = labels.coded()
         for mt in eq_matchers:
             col = labels.codes[:, tags.index(mt.label)]
             code = dictionary.code_of(mt.label, mt.value)
@@ -862,7 +859,18 @@ class TqlTileExecutor:
                     _colocate(source, self.cache.devices[0])
                     for source in sources
                 ]
-                fn = _full_program((csig, tuple(region_sigs)))
+                sig = (csig, tuple(region_sigs))
+                fn = _full_program(sig)
+                need = _program_bytes(sig, fn, (tuple(sources), dyn))
+                free = _device_free(self.cache)
+                plan.set(device_bytes=need)
+                if need > free:
+                    raise _Ineligible(
+                        f"the evaluation holds {need} bytes on the device "
+                        f"({s_pad} series x {w_pad} steps"
+                        + (f", {g_pad} groups" if g_pad else "")
+                        + f"); the plane cache leaves {free} bytes free"
+                    )
                 if not ghost:
                     metrics.TPU_DEVICE_DISPATCHES.inc()
                 mat, pres = fn(tuple(sources), dyn)
@@ -875,6 +883,7 @@ class TqlTileExecutor:
         )
         if not ghost:
             metrics.TQL_TILE_DISPATCHES.inc()
+            metrics.TQL_TILE_SERIES.inc(n_series)
             metrics.TQL_TILE_PLANE_ROWS.inc(plane_rows)
             if src.table_id is not None:
                 metrics.TQL_TILE_LOGICAL_DISPATCHES.inc()
@@ -961,8 +970,9 @@ class TqlTileExecutor:
         with tracing.span("tile.readback") as rb:
             if compact_ok and mat.size * 8 > threshold:
                 np_pres = [np.asarray(p) for p in jax.device_get(pres)]
-                pregathered = _legacy_order(np_pres)
-                if pregathered:
+                with tracing.stage("tql.order"):
+                    pregathered = _legacy_order(np_pres)
+                if len(pregathered):
                     sel = jnp.asarray(np.asarray(pregathered, np.int32))
                     np_mat = np.asarray(jax.device_get(jnp.take(mat, sel, axis=0)))
                 else:
@@ -993,43 +1003,98 @@ class TqlTileExecutor:
         the finished answer in label order, whichever path made it.)"""
         from .engine import Matrix
 
-        order = np.asarray(
-            pregathered if pregathered is not None else _legacy_order(np_pres),
-            np.int64,
-        )
+        with tracing.stage("tql.order"):
+            if gids is not None and len(np_pres) == 1 \
+                    and np_pres[0][:len(gids.of_series)].all():
+                # every series present: the order over all of them, made
+                # once a plane build
+                order, g_order = None, labels.group_order(keep, gids)
+            else:
+                order = (
+                    pregathered if pregathered is not None
+                    else _legacy_order(np_pres)
+                )
+                # grouped: the groups that hold a present series, at the first
+                g_order = None if gids is None else _first_appearance(
+                    gids.of_series[order], len(gids.keys)
+                )
         if gids is None:
             tuples = labels.tuples()
             at = np.arange(len(order)) if pregathered is not None else order
             return Matrix(
-                list(labels.tags), [tuples[s] for s in order],
+                list(labels.tags), [tuples[s] for s in order.tolist()],
                 np_mat[at][:, :w] if len(order) else np.zeros((0, w)), steps,
             )
-        # grouped: the groups that hold a present series
-        g_order = list(dict.fromkeys(gids.of_series[order].tolist()))
         keys = labels.group_tuples(keep, gids)
         return Matrix(
-            list(keep), [keys[g] for g in g_order],
-            np_mat[np.asarray(g_order, np.int64)][:, :w]
-            if g_order else np.zeros((0, w)), steps,
+            list(keep), [keys[g] for g in g_order.tolist()],
+            np_mat[g_order][:, :w] if len(g_order) else np.zeros((0, w)), steps,
         )
 
 
 # ---- helpers ---------------------------------------------------------------
 
 
-def _legacy_order(np_pres) -> list[int]:
+def _legacy_order(np_pres) -> np.ndarray:
     """The legacy scan's series order: regions in scan order, pk-sorted
     (= dictionary-code ascending) within a region, first appearance
     wins."""
-    order: list[int] = []
-    seen: set[int] = set()
-    for p in np_pres:
-        for sid in np.nonzero(p)[0]:
-            s = int(sid)
-            if s not in seen:
-                seen.add(s)
-                order.append(s)
-    return order
+    parts = [np.flatnonzero(p) for p in np_pres]
+    if len(parts) == 1:
+        return parts[0].astype(np.int64)
+    cat = np.concatenate(parts).astype(np.int64) if parts else np.zeros(0, np.int64)
+    _, first = np.unique(cat, return_index=True)
+    return cat[np.sort(first)]
+
+
+def _first_appearance(ids: np.ndarray, n_ids: int) -> np.ndarray:
+    """The distinct values of `ids` (each in [0, n_ids)) in the order of
+    their first appearance: one scatter of the positions written back to
+    front, so that the first of each value is the one that stays."""
+    n = len(ids)
+    first = np.full(n_ids, n, np.int64)
+    first[ids[::-1]] = np.arange(n - 1, -1, -1)
+    seen = np.flatnonzero(first < n)
+    return seen[np.argsort(first[seen], kind="stable")]
+
+
+_PROGRAM_BYTES: dict = {}
+
+
+def _program_bytes(sig, fn, args) -> int:
+    """Device bytes a compiled program holds beside its inputs while it
+    runs, its temporaries and its outputs, by the compiler's own memory
+    analysis, once a program signature (the compile is the one the first
+    call would make, and the call then finds it).  What the [2, S, W]
+    window search, the window statistics and a `by` fold's [G, W] hold,
+    the padding of the chip's tiles included, and the copies of the
+    planes' slice the scans make."""
+    got = _PROGRAM_BYTES.get(sig)
+    if got is None:
+        analysis = fn.lower(*args).compile().memory_analysis()
+        got = 0 if analysis is None else int(
+            analysis.temp_size_in_bytes + analysis.output_size_in_bytes
+        )
+        _PROGRAM_BYTES[sig] = got
+    return got
+
+
+_DEVICE_LIMIT: dict = {}
+
+
+def _device_free(cache) -> int:
+    """What the plane cache leaves free on its first device: the device's
+    memory limit (`memory_stats`; the cache's budget where the backend
+    reports none) less the planes the cache holds there."""
+    device = cache.devices[0]
+    limit = _DEVICE_LIMIT.get(device)
+    if limit is None:
+        try:
+            limit = int((device.memory_stats() or {}).get("bytes_limit") or 0)
+        except Exception:  # noqa: BLE001 — a backend without the call
+            limit = 0
+        _DEVICE_LIMIT[device] = limit
+    return (limit or int(cache.budget)) - int(cache.device_used()[0])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1074,14 +1139,17 @@ class _SeriesLabels:
     def __init__(self, spans, tags, dictionary):
         self.tags, self.dictionary = list(tags), dictionary
         self._spans = spans
-        cols = None
-        parts = []
-        for sp in spans:
-            cols = [sp.table.tags.index(t) for t in tags]
-            parts.append(sp.table.codes[sp.s_lo:sp.s_hi][:, cols])
-        self.codes = (
-            parts[0] if len(parts) == 1 else np.concatenate(parts)
-        ) if parts else np.zeros((0, len(tags)), np.int32)
+
+        def codes():
+            parts = [
+                sp.table.codes[sp.s_lo:sp.s_hi][:, [sp.table.tags.index(t) for t in tags]]
+                for sp in spans
+            ]
+            return (
+                parts[0] if len(parts) == 1 else np.concatenate(parts)
+            ) if parts else np.zeros((0, len(tags)), np.int32)
+
+        self.codes = self._memo("codes", codes)
 
     def _memo(self, what, make):
         if len(self._spans) != 1:
@@ -1090,17 +1158,23 @@ class _SeriesLabels:
         return sp.table.remember((what, sp.s_lo, sp.s_hi, tuple(self.tags)), make)
 
     def _decode(self, codes: np.ndarray, names) -> list:
-        lists = [self.dictionary.values(t) for t in names]
-        return [
-            tuple(
-                vals[c] if 0 <= c < len(vals) else None
-                for c, vals in zip(row, lists)
-            )
-            for row in codes.tolist()
-        ]
+        """A tuple of label values a row of `codes` (a code outside a
+        column's values: None), made a column at a time."""
+        columns = []
+        for j, name in enumerate(names):
+            vals = self.dictionary.values(name)
+            lut = np.empty(len(vals) + 1, object)
+            lut[:len(vals)] = vals
+            col = codes[:, j]
+            columns.append(lut[np.where((col >= 0) & (col < len(vals)), col, len(vals))])
+        return list(zip(*columns)) if columns else [() for _ in range(len(codes))]
 
     def tuples(self) -> list:
         return self._memo("tuples", lambda: self._decode(self.codes, self.tags))
+
+    def coded(self) -> np.ndarray:
+        """Per series: every label has a code (the matchers' base)."""
+        return self._memo("coded", lambda: (self.codes >= 0).all(axis=1))
 
     def group_ids(self, keep) -> _GroupIds:
         def make():
@@ -1116,6 +1190,13 @@ class _SeriesLabels:
             return _GroupIds(of_series.reshape(-1).astype(np.int32), keys)
 
         return self._memo(("gids", tuple(keep)), make)
+
+    def group_order(self, keep, gids: _GroupIds) -> np.ndarray:
+        """The groups in the order of their first series."""
+        return self._memo(
+            ("group_order", tuple(keep)),
+            lambda: _first_appearance(gids.of_series, len(gids.keys)),
+        )
 
     def group_tuples(self, keep, gids: _GroupIds) -> list:
         return self._memo(

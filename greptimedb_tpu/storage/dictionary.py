@@ -94,23 +94,24 @@ class TableDictionary:
         if not self._path:
             return
         tmp = self._path + ".tmp"
+        # one `dumps` (the C encoder) and one write: `json.dump` streams
+        # through the pure-Python encoder, a call a value
+        text = json.dumps({
+            "epoch": self.epoch,
+            "columns": {
+                n: {"values": c.values, "has_null": c.has_null}
+                for n, c in self._cols.items()
+            },
+        })
         with open(tmp, "w") as f:
-            json.dump(
-                {
-                    "epoch": self.epoch,
-                    "columns": {
-                        n: {"values": c.values, "has_null": c.has_null}
-                        for n, c in self._cols.items()
-                    },
-                },
-                f,
-            )
+            f.write(text)
         os.replace(tmp, self._path)
 
     # ---- growth ------------------------------------------------------------
-    def update(self, name: str, col: pa.Array | pa.ChunkedArray) -> bool:
+    def update(self, name: str, col: pa.Array | pa.ChunkedArray, save: bool = True) -> bool:
         """Insert any unseen values of `col`; returns True if the dictionary
-        grew (codes of existing values may have shifted — see perm_since)."""
+        grew (codes of existing values may have shifted — see perm_since).
+        `save=False` leaves the file to the caller's next save."""
         if pa.types.is_dictionary(col.type):
             col = pc.cast(col, col.type.value_type)
         uniq = pc.unique(col)
@@ -154,14 +155,19 @@ class TableDictionary:
                     while len(hist) < self.epoch + 1:
                         hist.append(None)
             self.epoch += 1
-            self._save_locked()
+            if save:
+                self._save_locked()
             return True
 
     def update_table(self, table: pa.Table, columns: list[str]) -> bool:
+        """`update` of each of `columns`, the file saved once."""
         grew = False
-        for name in columns:
-            if name in table.column_names:
-                grew |= self.update(name, table[name])
+        with self._lock:
+            for name in columns:
+                if name in table.column_names:
+                    grew |= self.update(name, table[name], save=False)
+            if grew:
+                self._save_locked()
         return grew
 
     # ---- encode ------------------------------------------------------------

@@ -106,28 +106,153 @@ def _encode_value(v) -> bytes:
 # ---- build ------------------------------------------------------------------
 
 
+def binary_parts(arr: pa.Array) -> tuple[bytes, np.ndarray]:
+    """(data, offsets) of a binary / string array: value i is
+    data[offsets[i]:offsets[i + 1]]."""
+    wide = pa.types.is_large_binary(arr.type) or pa.types.is_large_string(arr.type)
+    offsets = np.frombuffer(arr.buffers()[1], np.int64 if wide else np.int32)
+    offsets = offsets[arr.offset:arr.offset + len(arr) + 1].astype(np.int64)
+    data = arr.buffers()[2]
+    return (data.to_pybytes() if data is not None else b""), offsets
+
+
+class ColumnTerms:
+    """A tag column's distinct values as the indexes key them, made once
+    for its bloom and term builds: `codes`, each row's index into
+    `values` (a NULL row: `len(values)`, the null slot), and on demand the
+    values' `_encode_value` bytes, their hashes, and the distinct codes of
+    each segment.  Distinct is arrow's hash equality, the one `pc.unique`
+    judges a segment by."""
+
+    def __init__(self, column):
+        if isinstance(column, pa.ChunkedArray):
+            column = column.combine_chunks()
+        self.n = len(column)
+        if pa.types.is_dictionary(column.type):
+            # unify the dictionary's own duplicates; its unused entries hold
+            # no row and so reach no segment
+            unified = pc.dictionary_encode(column.dictionary)
+            self.values = unified.dictionary
+            null = len(self.values)
+            remap = np.asarray(pc.fill_null(unified.indices, null), np.int64)
+            remap = np.append(remap, null)
+            at = np.asarray(pc.fill_null(column.indices, len(remap) - 1), np.int64)
+            self.codes = remap[at]
+        else:
+            enc = pc.dictionary_encode(column)
+            self.values = enc.dictionary
+            self.codes = np.asarray(
+                pc.fill_null(pc.cast(enc.indices, pa.int64()), len(self.values)), np.int64
+            )
+        self.has_null = bool(self.n) and bool((self.codes == len(self.values)).any())
+        self._keys = self._hashes = None
+        self._pairs: dict[int, tuple] = {}
+
+    def keys(self) -> pa.Array:
+        """`_encode_value(v)` of each distinct value, in `values` order."""
+        if self._keys is None:
+            t = self.values.type
+            if pa.types.is_string(t) or pa.types.is_large_string(t):
+                # str(v).encode(): the utf-8 arrow already holds
+                self._keys = self.values.cast(
+                    pa.large_binary() if pa.types.is_large_string(t) else pa.binary()
+                )
+            elif pa.types.is_integer(t):
+                # str(int): arrow's decimal text is Python's, digit for digit
+                self._keys = pc.cast(self.values, pa.string()).cast(pa.binary())
+            else:
+                self._keys = pa.array(
+                    [_encode_value(v) for v in self.values.to_pylist()], pa.binary()
+                )
+        return self._keys
+
+    def hashes(self) -> np.ndarray:
+        """[len(values) + 1, 2] uint64: `_hash2` of each key, the null
+        slot's (`_encode_value(None)`) last; the second word made odd."""
+        if self._hashes is None:
+            data, offs = binary_parts(self.keys())
+            data, blake = memoryview(data), hashlib.blake2b
+            digests = b"".join([
+                blake(data[a:b], digest_size=16).digest()
+                for a, b in zip(offs[:-1].tolist(), offs[1:].tolist())
+            ] + [blake(_encode_value(None), digest_size=16).digest()])
+            h = np.frombuffer(digests, "<u8").reshape(-1, 2).astype(np.uint64)
+            h[:, 1] |= np.uint64(1)
+            self._hashes = h
+        return self._hashes
+
+    def segment_pairs(self, segment_rows: int) -> tuple[np.ndarray, np.ndarray]:
+        """(segment, code) of every distinct code of every segment of
+        `segment_rows` rows, ascending by segment, then code."""
+        got = self._pairs.get(segment_rows)
+        if got is not None:
+            return got
+        n, n_codes = self.n, len(self.values) + 1
+        n_segs = -(-n // segment_rows)
+        # the first row of each run of one code inside a segment (a sorted
+        # file's tag is runs, so these are few), as segment * n_codes + code
+        head = np.zeros(n, bool)
+        head[::segment_rows] = True
+        head[1:] |= self.codes[1:] != self.codes[:-1]
+        at = np.flatnonzero(head)
+        key = (at // segment_rows) * n_codes + self.codes[at]
+        if n_segs * n_codes <= 4 * len(key) + 4096:
+            # few values: a mark a (segment, code)
+            mark = np.zeros(n_segs * n_codes, bool)
+            mark[key] = True
+            key = np.flatnonzero(mark)
+        else:
+            key = np.unique(key)
+        got = (key // n_codes, key % n_codes)
+        self._pairs[segment_rows] = got
+        return got
+
+
 def build_bloom_index(
-    column: pa.Array, segment_rows: int = DEFAULT_SEGMENT_ROWS, fpp: float = BLOOM_FPP
+    column, segment_rows: int = DEFAULT_SEGMENT_ROWS, fpp: float = BLOOM_FPP
 ) -> bytes:
     """One bloom filter per segment; blob = header json + concatenated filters
-    (reference index/src/bloom_filter/creator.rs)."""
-    n = len(column)
-    if isinstance(column, pa.ChunkedArray):
-        column = column.combine_chunks()
-    if pa.types.is_dictionary(column.type):
-        column = pc.cast(column, column.type.value_type)
-    segs = []
-    for start in range(0, n, segment_rows):
-        seg = column.slice(start, segment_rows)
-        distinct = pc.unique(seg)
-        bf = BloomFilter.with_capacity(len(distinct), fpp)
-        for v in distinct.to_pylist():
-            bf.add(_encode_value(v))
-        segs.append(bf.to_bytes())
+    (reference index/src/bloom_filter/creator.rs).  Each segment's filter
+    is `BloomFilter.with_capacity(its distinct values)` with every distinct
+    value `add`ed, made for all segments at once: the sizes by the same
+    float formulas, the probe positions by the same uint64 arithmetic, so
+    the blob is the filter-by-filter one byte for byte.  `column` may be a
+    `ColumnTerms` already made for it."""
+    terms = column if isinstance(column, ColumnTerms) else ColumnTerms(column)
+    n = terms.n
+    seg, code = terms.segment_pairs(segment_rows)
+    n_segs = -(-n // segment_rows)
+    items = np.bincount(seg, minlength=n_segs).astype(np.int64)
+    # BloomFilter.with_capacity, segment by segment
+    nbits = np.maximum(
+        (-items * np.log(fpp) / (np.log(2) ** 2)).astype(np.int64), 256
+    )
+    k = np.minimum(
+        np.maximum(np.rint(nbits / items * np.log(2)).astype(np.int64), 1), 16
+    )
+    size = 8 + (nbits + 7) // 8
+    start = np.cumsum(size) - size
+    body = np.zeros(int(size.sum()) * 8, bool)
+    if len(seg):
+        # BloomFilter.add of each (segment, value): bit (h1 + i h2) mod nbits
+        # of the segment's bits, for i < k; probe i for the pairs whose k
+        # passes it
+        h = terms.hashes()[code]
+        h1, h2 = h[:, 0], h[:, 1]
+        kk, nb, base = k[seg], nbits[seg].astype(np.uint64), (start[seg] + 8) * 8
+        for i in range(int(k.max())):
+            live = kk > i
+            if not live.all():
+                h1, h2, kk, nb, base = h1[live], h2[live], kk[live], nb[live], base[live]
+            body[base + ((h1 + np.uint64(i) * h2) % nb).astype(np.int64)] = True
+    out = np.packbits(body, bitorder="little")
+    # each filter's (nbits, k) header, as BloomFilter.to_bytes packs it
+    head = np.stack([nbits, k], axis=1).astype("<u4").view(np.uint8)
+    out[start[:, None] + np.arange(8)] = head.reshape(-1, 8)
     header = json.dumps(
-        {"segment_rows": segment_rows, "n_rows": n, "seg_sizes": [len(s) for s in segs]}
+        {"segment_rows": segment_rows, "n_rows": n, "seg_sizes": size.tolist()}
     ).encode()
-    return struct.pack("<I", len(header)) + header + b"".join(segs)
+    return struct.pack("<I", len(header)) + header + out.tobytes()
 
 
 def build_inverted_index(

@@ -115,8 +115,11 @@ class Memtable:
             return [(0, table)]
         ts = pc.cast(table[ts_col.name], pa.int64()).to_numpy(zero_copy_only=False)
         starts = _partition_starts(ts, self.time_partition_ms)
+        windows = np.unique(starts)
+        if len(windows) == 1:  # the whole table: no copy of it
+            return [(int(windows[0]), table)]
         out = []
-        for start in np.unique(starts):
+        for start in windows:
             mask = starts == start
             out.append((int(start), table.filter(pa.array(mask))))
         return out
@@ -142,18 +145,19 @@ def _sort_and_dedup(table: pa.Table, schema: Schema, dedup: bool) -> pa.Table:
         seq = np.asarray(
             table[_SEQ_COL].combine_chunks(), dtype=np.int64
         )
-        order = np.lexsort(tuple(reversed(msf + [seq])))
-        table = table.take(pa.array(order))
-        if not dedup or table.num_rows <= 1:
-            return table
+        order = np.lexsort(tuple(reversed(_fold_keys(msf + [seq]))))
         n = table.num_rows
-        same = np.ones(n - 1, dtype=bool)
-        for arr in eq_cols:
-            a = arr[order]
-            same &= a[:-1] == a[1:]
-        keep = np.ones(n, dtype=bool)
-        keep[:-1] = ~same
-        return table.filter(pa.array(keep))
+        if dedup and n > 1:
+            # the rows that stay, found on the codes: the table is copied
+            # once, in sorted order and without the rows that lose
+            same = np.ones(n - 1, dtype=bool)
+            for arr in eq_cols:
+                a = arr[order]
+                same &= a[:-1] == a[1:]
+            keep = np.ones(n, dtype=bool)
+            keep[:-1] = ~same
+            order = order[keep]
+        return table.take(pa.array(order))
     sort_keys = [(k, "ascending") for k in keys] + [(_SEQ_COL, "ascending")]
     idx = pc.sort_indices(table, sort_keys=sort_keys)
     table = table.take(idx)
@@ -174,6 +178,34 @@ def _sort_and_dedup(table: pa.Table, schema: Schema, dedup: bool) -> pa.Table:
     keep = np.ones(n, dtype=bool)
     keep[:-1] = ~same  # row i dropped if identical key to row i+1 (later seq)
     return table.filter(pa.array(keep))
+
+
+def _fold_keys(msf: list[np.ndarray]) -> list[np.ndarray]:
+    """Lexsort keys (most significant first) with consecutive keys folded
+    into one int64 while the product of their value ranges stays under
+    2^62: (a, b) orders as (a - min a) * range(b) + (b - min b) does, so
+    the order is the same and a lexsort makes a pass a folded key, not a
+    key.  A key whose own range is past 2^62 stays as it is."""
+    out: list[np.ndarray] = []
+    acc, span = None, 1
+    for k in msf:
+        lo, hi = (int(k.min()), int(k.max())) if len(k) else (0, 0)
+        width = hi - lo + 1
+        if width >= 1 << 62:
+            if acc is not None:
+                out.append(acc)
+            out.append(k)
+            acc, span = None, 1
+        elif acc is not None and span * width < 1 << 62:
+            acc = acc * width + (k - lo)
+            span *= width
+        else:
+            if acc is not None:
+                out.append(acc)
+            acc, span = k - lo, width
+    if acc is not None:
+        out.append(acc)
+    return out
 
 
 def _key_codes(table: pa.Table, keys: list[str]):

@@ -168,6 +168,7 @@ class SstWriter:
         from .. import index as term_index
 
         cols = [c.name for c in self.schema.tag_columns() if c.name in table.column_names]
+        no_terms = {c.name for c in self.schema.tag_columns() if not c.inverted_index}
         ft_cols = [
             c.name
             for c in self.schema.columns
@@ -186,17 +187,18 @@ class SstWriter:
         for name in cols:
             col = table[name]
             col = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
-            bloom = idx.build_bloom_index(col, self.index_segment_rows)
+            # the column's distinct values, made once for both indexes
+            terms = idx.ColumnTerms(col)
+            bloom = idx.build_bloom_index(terms, self.index_segment_rows)
             writer.add_blob(BLOOM_BLOB, bloom, {"column": name})
-            if self.index_segmented:
-                terms, postings, n_segs = term_index.build_term_postings(
-                    col, self.index_segment_rows
-                )
-                if len(terms) <= self.index_max_terms:
+            if name in no_terms:  # its bloom stays
+                pass
+            elif self.index_segmented:
+                postings = term_index.term_postings(terms, self.index_segment_rows)
+                if len(postings) <= self.index_max_terms:
                     term_index.write_term_index(
-                        writer, name, "inverted", terms, postings,
-                        segment_rows=self.index_segment_rows,
-                        n_rows=len(col), n_segs=n_segs,
+                        writer, name, "inverted", postings,
+                        segment_rows=self.index_segment_rows, n_rows=len(col),
                         seg_terms=self.index_segment_terms,
                     )
             else:
@@ -210,14 +212,11 @@ class SstWriter:
             col = table[name]
             col = col.combine_chunks() if isinstance(col, pa.ChunkedArray) else col
             if self.index_segmented:
-                toks, postings, n_segs = term_index.build_token_postings(
-                    col, self.index_segment_rows
-                )
-                if toks and len(toks) <= self.index_max_terms:
+                toks = term_index.build_token_postings(col, self.index_segment_rows)
+                if len(toks) and len(toks) <= self.index_max_terms:
                     term_index.write_term_index(
-                        writer, name, "fulltext", toks, postings,
-                        segment_rows=self.index_segment_rows,
-                        n_rows=len(col), n_segs=n_segs,
+                        writer, name, "fulltext", toks,
+                        segment_rows=self.index_segment_rows, n_rows=len(col),
                         seg_terms=self.index_segment_terms,
                     )
                     if name not in indexed:

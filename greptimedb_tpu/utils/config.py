@@ -474,10 +474,6 @@ class TqlConfig:
     to that path too (`greptime_tql_tile_degraded_total`)."""
 
     tile: bool = True
-    # Upper bound on padded series x padded steps cells per evaluation
-    # ([S, W] f64 window-stat planes live on device); beyond it the
-    # query stays on the legacy path.
-    max_cells: int = 1 << 22
     # Per-series results larger than this fetch in TWO round-trips:
     # presence first, then a device-side gather of only the present
     # rows — the compacted [series_out, steps] readback.  Below it one
@@ -1017,12 +1013,6 @@ class Config:
             raise ConfigError(
                 "tql.tile must be a boolean (warm TQL device tile path; "
                 f"false = legacy upload-per-query evaluation); got {tq.tile!r}"
-            )
-        if not isinstance(tq.max_cells, int) or isinstance(tq.max_cells, bool) \
-                or tq.max_cells < 1:
-            raise ConfigError(
-                "tql.max_cells must be a positive integer bound on padded "
-                f"series x steps cells per evaluation; got {tq.max_cells!r}"
             )
         if not isinstance(tq.compact_readback_kb, int) \
                 or isinstance(tq.compact_readback_kb, bool) \

@@ -414,6 +414,11 @@ TQL_TILE_PLANE_ROWS = REGISTRY.counter(
     "Padded rows of the planes handed to the TQL tile program, summed over "
     "dispatches: the region's for a mito table, the slice's for a logical one",
 )
+TQL_TILE_SERIES = REGISTRY.counter(
+    "greptime_tql_tile_series_total",
+    "Series a TQL tile dispatch evaluates (the source's series that exist, "
+    "before padding), summed over dispatches",
+)
 METRIC_TSID_HASHES = REGISTRY.counter(
     "greptime_metric_tsid_hashes_total",
     "Label sets hashed to a __tsid by the metric engine's write path (one "
@@ -886,6 +891,8 @@ STAGE_SELF_S_TQL_PLAN = _stage_self_s(
     "tql.plan", "TQL tile path's host work around the dispatch: catalog, masks, grid, residency")
 STAGE_SELF_S_TQL_ASSEMBLE = _stage_self_s(
     "tql.assemble", "fetched PromQL matrix to labelled series, then to the Arrow table")
+STAGE_SELF_S_TQL_ORDER = _stage_self_s(
+    "tql.order", "the answer's series order and group order over the present series")
 HTTP_REQUEST_S = REGISTRY.counter(
     "greptime_http_request_seconds_total",
     "Inclusive seconds of every HTTP request (the `http.request` stage)",
@@ -944,6 +951,7 @@ STAGE_SELF_S: dict[str, Counter] = {
     "tile.decode": STAGE_SELF_S_TILE_DECODE,
     "tql.plan": STAGE_SELF_S_TQL_PLAN,
     "tql.assemble": STAGE_SELF_S_TQL_ASSEMBLE,
+    "tql.order": STAGE_SELF_S_TQL_ORDER,
     "write.batch": STAGE_SELF_S_WRITE_BATCH,
     "write.logical": STAGE_SELF_S_WRITE_LOGICAL,
     "write.split": STAGE_SELF_S_WRITE_SPLIT,

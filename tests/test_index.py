@@ -355,6 +355,11 @@ def test_million_term_index_bounded_lookup(tmp_path):
     assert bytes_read < meta.index_file_size / 50
 
 
+def _lists(flat):
+    """A `Postings` as (its term bytes, each term's segment id array)."""
+    return flat.terms.to_pylist(), np.split(flat.segs, flat.starts[1:-1]) if len(flat) else []
+
+
 def test_fence_keys_roundtrip_mid_multibyte_truncation(tmp_path):
     """A term truncated mid-multibyte-character at MAX_TERM_BYTES can
     become a segment fence; the latin-1 JSON round-trip must reproduce
@@ -367,15 +372,15 @@ def test_fence_keys_roundtrip_mid_multibyte_truncation(tmp_path):
         + ["zz_" + long_tail] * 5  # truncation cuts a 2-byte char in half
         + ["zz_zz"] * 5
     )
-    terms, postings, n_segs = term_index.build_term_postings(col, 10)
+    flat = term_index.term_postings(col, 10)
+    terms, postings, n_segs = *_lists(flat), flat.n_segs
     # the truncated term's bytes end mid-character
     trunc = [t for t in terms if t.startswith(b"zz_\xc3")][0]
     assert len(trunc) == seg.MAX_TERM_BYTES
     p = str(tmp_path / "f.puffin")
     w = PuffinWriter(p)
     term_index.write_term_index(
-        w, "h", "inverted", terms, postings,
-        segment_rows=10, n_rows=len(col), n_segs=n_segs, seg_terms=8,
+        w, "h", "inverted", flat, segment_rows=10, n_rows=len(col), seg_terms=8,
     )
     w.finish()
     import json as _json
@@ -391,3 +396,169 @@ def test_fence_keys_roundtrip_mid_multibyte_truncation(tmp_path):
         expect = np.zeros(n_segs, bool)
         expect[post] = True
         assert (bm == expect).all(), t[:40]
+
+
+# ---- the index builds against the loops they replaced ---------------------
+
+import json as _json_ref
+import struct as _struct_ref
+
+import pyarrow.compute as _pc_ref
+
+
+def _loop_bloom(column, segment_rows, fpp=idx.BLOOM_FPP):
+    """`build_bloom_index` as a loop a segment and a value."""
+    n = len(column)
+    if pa.types.is_dictionary(column.type):
+        column = _pc_ref.cast(column, column.type.value_type)
+    segs = []
+    for start in range(0, n, segment_rows):
+        distinct = _pc_ref.unique(column.slice(start, segment_rows))
+        bf = idx.BloomFilter.with_capacity(len(distinct), fpp)
+        for v in distinct.to_pylist():
+            bf.add(idx._encode_value(v))
+        segs.append(bf.to_bytes())
+    header = _json_ref.dumps(
+        {"segment_rows": segment_rows, "n_rows": n, "seg_sizes": [len(s) for s in segs]}
+    ).encode()
+    return _struct_ref.pack("<I", len(header)) + header + b"".join(segs)
+
+
+def _loop_postings(column, segment_rows):
+    """`term_postings` as a dict of term bytes to segment sets."""
+    from greptimedb_tpu.index.segmented import MAX_TERM_BYTES
+
+    if pa.types.is_dictionary(column.type):
+        column = _pc_ref.cast(column, column.type.value_type)
+    by_key: dict = {}
+    for i, v in enumerate(column.to_pylist()):
+        by_key.setdefault(idx._encode_value(v)[:MAX_TERM_BYTES], set()).add(i // segment_rows)
+    terms = sorted(by_key)
+    return terms, [np.array(sorted(by_key[t]), np.int64) for t in terms]
+
+
+def _loop_segment_blob(terms, postings):
+    """`_encode_segment` as a loop a term and a posting."""
+    def varint(out, v):
+        while v >= 0x80:
+            out.append((v & 0x7F) | 0x80)
+            v >>= 7
+        out.append(v)
+
+    out = bytearray(_struct_ref.pack("<I", len(terms)))
+    for t in terms:
+        out += _struct_ref.pack("<H", len(t)) + t
+    for p in postings:
+        varint(out, len(p))
+        prev = 0
+        for v in p.tolist():
+            varint(out, v - prev)
+            prev = v
+    return bytes(out)
+
+
+class _Blobs:
+    def __init__(self):
+        self.blobs = []
+
+    def add_blob(self, kind, data, props):
+        self.blobs.append((kind, data, props))
+
+
+def _tag_column(kind: str, rows_a_series: int, n: int, rng) -> pa.Array:
+    """A sorted file's tag column: runs of `rows_a_series` rows a series."""
+    series = -(-n // rows_a_series)
+    ids = np.repeat(np.arange(series), rows_a_series)[:n]
+    if kind == "hostname":
+        values = np.array([f"host_{i}" for i in rng.permutation(series)])
+        return pa.array(values[ids])
+    if kind == "region":  # few values, a drawn one a series
+        return pa.array(np.array([f"region-{i}" for i in range(9)])[rng.integers(0, 9, series)][ids])
+    if kind == "nullable":
+        values = [None if rng.random() < 0.2 else f"v{rng.integers(0, 50)}" for _ in range(series)]
+        return pa.array([values[i] for i in ids], pa.string())
+    if kind == "tsid":  # the metric engine's int64 series hash
+        return pa.array(rng.integers(-(2 ** 63), 2 ** 63 - 1, series, dtype=np.int64)[ids])
+    if kind == "int64-null":
+        values = rng.integers(-5, 5, series)
+        return pa.array([None if values[i] == 0 else int(values[i]) for i in ids], pa.int64())
+    if kind == "dictionary":
+        return pa.array(np.array(["x", "y", "z"])[rng.integers(0, 3, series)][ids]).dictionary_encode()
+    raise ValueError(kind)
+
+
+@pytest.mark.parametrize("rows_a_series", [360, 30, 10])
+@pytest.mark.parametrize("kind", ["hostname", "region", "nullable", "tsid", "int64-null", "dictionary"])
+def test_the_index_blobs_are_the_loops_byte_for_byte(kind, rows_a_series):
+    """The bloom blob and every term-index blob made for all segments at
+    once are the per-segment, per-value loops' bytes (old sidecars and new
+    ones read alike)."""
+    rng = np.random.default_rng(rows_a_series)
+    n = 20_000
+    col = _tag_column(kind, rows_a_series, n, rng)
+    for segment_rows in (1024, 100):
+        assert idx.build_bloom_index(col, segment_rows) == _loop_bloom(col, segment_rows)
+        terms, postings = _loop_postings(col, segment_rows)
+        flat = term_index.term_postings(idx.ColumnTerms(col), segment_rows)
+        got_terms, got_postings = _lists(flat)
+        assert got_terms == terms and flat.n_segs == -(-n // segment_rows)
+        assert all((a == b).all() for a, b in zip(got_postings, postings))
+        new, old = _Blobs(), _Blobs()
+        term_index.write_term_index(
+            new, "c", "inverted", flat, segment_rows=segment_rows, n_rows=n, seg_terms=64,
+        )
+        term_index.write_term_index(
+            old, "c", "inverted", term_index.Postings.from_lists(terms, postings, flat.n_segs),
+            segment_rows=segment_rows, n_rows=n, seg_terms=64,
+        )
+        assert new.blobs == old.blobs
+        segments = [b for b in new.blobs if b[0] == TERM_SEGMENT_BLOB]
+        assert [data for _k, data, _p in segments] == [
+            _loop_segment_blob(terms[i:i + 64], postings[i:i + 64])
+            for i in range(0, len(terms), 64)
+        ]
+
+
+def test_the_metric_engines_series_hash_gets_a_bloom_and_no_term_index(tmp_path):
+    """`__tsid` is a term a series that no query filters on: the metric
+    engine declares it without a term index, as upstream's data region
+    ignores it; its bloom and the other tags' indexes stay, and a mito
+    column of the same name keeps its term index."""
+    from greptimedb_tpu.database import Database
+
+    db = Database(data_home=str(tmp_path / "db"))
+    db.sql("CREATE TABLE phy (ts TIMESTAMP TIME INDEX, val DOUBLE) "
+           "WITH ('physical_metric_table' = '')")
+    tsid = db.catalog.table("phy").schema.column("__tsid")
+    assert not tsid.inverted_index
+    assert not Schema.from_json(db.catalog.table("phy").schema.to_json()).column("__tsid").inverted_index
+    db.close()
+    plain = ColumnSchema("__tsid", ConcreteDataType.INT64, SemanticType.TAG)
+    assert plain.inverted_index
+    schema = Schema(columns=[
+        ColumnSchema("ts", ConcreteDataType.TIMESTAMP_MILLISECOND, SemanticType.TIMESTAMP),
+        ColumnSchema("__tsid", ConcreteDataType.INT64, SemanticType.TAG, inverted_index=False),
+        ColumnSchema("host", ConcreteDataType.STRING, SemanticType.TAG),
+        ColumnSchema("v", ConcreteDataType.FLOAT64, SemanticType.FIELD),
+    ])
+    n = 3000
+    table = pa.table({
+        "ts": pa.array(np.arange(n, dtype=np.int64), pa.timestamp("ms")),
+        "__tsid": pa.array(np.repeat(np.arange(30, dtype=np.int64) * 7919, 100)),
+        "host": pa.array([f"h{i // 100}" for i in range(n)]),
+        "v": pa.array(np.zeros(n)),
+    })
+    w = SstWriter(str(tmp_path), schema, row_group_size=500, index_segment_rows=100)
+    meta = w.write(table)
+    assert meta.indexed_columns == ["__tsid", "host"]
+    blobs = PuffinReader(str(tmp_path / f"{meta.file_id}.puffin")).blobs()
+    kinds = {(b.blob_type, b.properties.get("column")) for b in blobs}
+    assert (idx.BLOOM_BLOB, "__tsid") in kinds and (idx.BLOOM_BLOB, "host") in kinds
+    assert (TERM_META_BLOB, "host") in kinds and (TERM_META_BLOB, "__tsid") not in kinds
+    r = SstReader(str(tmp_path), schema)
+    t = r.read(meta, ScanPredicate(filters=[("__tsid", "=", 7919 * 3)]))
+    assert t.num_rows == 100 and set(t["host"].to_pylist()) == {"h3"}
+    mito = Schema(columns=[plain if c.name == "__tsid" else c for c in schema.columns])
+    meta = SstWriter(str(tmp_path), mito, row_group_size=500, index_segment_rows=100).write(table)
+    blobs = PuffinReader(str(tmp_path / f"{meta.file_id}.puffin")).blobs()
+    assert (TERM_META_BLOB, "__tsid") in {(b.blob_type, b.properties.get("column")) for b in blobs}

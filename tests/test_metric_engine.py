@@ -177,3 +177,17 @@ def test_tsid_stability():
     b = tsid_hash([("dc", "eu"), ("host", "a")])
     assert a == b  # order-insensitive
     assert a != tsid_hash([("host", "b"), ("dc", "eu")])
+
+
+def test_a_label_named_like_the_metric_name_is_refused(db):
+    """`__name__` is the metric itself, the logical table's name, and a
+    series id already hashes it as such: no label column may take it."""
+    from greptimedb_tpu.utils.errors import InvalidArgumentsError
+
+    _create_phy(db)
+    with pytest.raises(InvalidArgumentsError, match="__name__"):
+        db.sql(
+            "CREATE TABLE t1 (ts TIMESTAMP TIME INDEX, val DOUBLE, "
+            "__name__ STRING PRIMARY KEY) WITH ('on_physical_table' = 'phy')"
+        )
+    assert not db.catalog.has_table("t1")

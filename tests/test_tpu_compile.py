@@ -183,3 +183,38 @@ def test_logical_table_program_compiles(one_chip, chunks, cut, agg):
     secs = time.perf_counter() - t0
     print(f"logical-table program, {chunks} chunk(s): compiled for v5e in {secs:.1f} s")
     assert secs < RESET_STRIP_CEILING_S
+
+
+def test_million_series_panel_program_compiles_and_fits(one_chip):
+    """The TQL tile program of `prom-1m-series-rules`' panel: a logical table
+    of 2^20 series and 2^24 rows in four 2^22-row chunks, 6 steps padded to
+    8, a `sum by` fold into 16 groups.  What it holds beside its inputs, by
+    the compiler's memory analysis (the number `tile_exec._program_bytes`
+    reads before a dispatch), is 2.17 GB with the window search's series on
+    the minor axis (5.34 GB with its 8 steps there, padded to 128 lanes)."""
+    from greptimedb_tpu.query.promql import tile_exec
+
+    chunk, s_pad, w_pad, g_pad = 1 << 22, 1 << 20, 8, 16
+    csig = ("rate", "sum", s_pad, w_pad, 8, 1_000_000, g_pad, False)
+
+    def planes(dtype):
+        return tuple(_rows(one_chip, dtype, (chunk,)) for _ in range(4))
+
+    at = {k: _rows(one_chip, jnp.int32, ()) for k in ("sid0", "off", "lo", "hi")}
+    src = ((planes(jnp.int32),), planes(jnp.int64), planes(jnp.float64), None,
+           planes(jnp.bool_), at)
+    dyn = {
+        k: _rows(one_chip, jnp.int64, ())
+        for k in ("lo", "hi", "offset", "start", "step", "range", "nsteps")
+    }
+    dyn["gid"] = _rows(one_chip, jnp.int32, (s_pad,))
+    program = tile_exec._full_program(
+        (csig, ((tile_exec._source_sig(src), (4 * chunk, 0, chunk)),))
+    )
+    t0 = time.perf_counter()
+    analysis = program.lower((src,), dyn).compile().memory_analysis()
+    secs = time.perf_counter() - t0
+    held = analysis.temp_size_in_bytes + analysis.output_size_in_bytes
+    print(f"million-series panel program: compiled for v5e in {secs:.1f} s, holds {held} B")
+    assert secs < RESET_STRIP_CEILING_S
+    assert held < 3 << 30

@@ -56,14 +56,13 @@ class Fleet:
     """The ground truth: per metric a [ticks, hosts] array, per label the
     hosts' values; shaped like a benchmark dataset for `promql_ref`."""
 
-    def __init__(self, hosts, ticks, seed=5, restart=None):
+    def __init__(self, hosts, ticks, seed=5, restart=None, restarts=()):
         self.hosts, self.ticks, self.scrape_s, self.t0 = hosts, ticks, SCRAPE_S, T0
         rng = np.random.default_rng(seed)
         self.samples = {}
         for metric in METRICS:
             state = np.cumsum(np.abs(rng.normal(5, 1, (ticks, hosts))), axis=0) + 1000.0
-            if restart is not None:
-                h, r = restart
+            for h, r in ([restart] if restart is not None else []) + list(restarts):
                 state[r:, h] -= state[r, h]
             self.samples[metric] = np.floor(state)
         self.label_values = {
@@ -205,8 +204,33 @@ def fleet_db():
     db.close()
 
 
-S0 = T0 // 1000 + 600  # the half hour from minute 10: every window whole
+@pytest.fixture(scope="module")
+def wide_db():
+    # 2^14 series of twelve labels in one logical table, 5 % of them
+    # restarting at a drawn scrape; 12 minutes of 10 s scrapes
+    rng = np.random.default_rng(14)
+    hosts = 1 << 14
+    restarts = [
+        (int(h), int(rng.integers(1, 72)))
+        for h in rng.choice(hosts, hosts // 20, replace=False)
+    ]
+    fleet = Fleet(hosts=hosts, ticks=72, restarts=restarts)
+    db = _db()
+    db.insert_rows("nginx_requests", fleet.table("requests"))
+    db.storage.flush_all()
+    stats = db.prewarm(tables=["nginx_requests"])
+    yield db, fleet, stats
+    db.close()
 
+
+S0 = T0 // 1000 + 600  # the half hour from minute 10: every window whole
+# where each fleet's requests start, their span and step, and the padded
+# rows of the slice the program is handed: 24 hosts x 360 scrapes = 8,640
+# rows a table, a 2^14-row slice of the region's 2^15-row planes; 2^14
+# hosts x 72 scrapes, the region's one table, all of its 2^21-row planes
+FLEETS = {"small": ("fleet_db", S0, 1800, 60, 1 << 14), "wide": ("wide_db", T0 // 1000 + 330, None, 60, 1 << 21)}
+
+# (expression, metric, hosts, per second, by, op[, fleet, span s])
 CASES = {
     "rate-all": ("rate(nginx_requests[5m])", "requests", range(24), True, None, "sum"),
     "increase-hostname": (
@@ -226,33 +250,133 @@ CASES = {
         "avg by (datacenter) (rate(nginx_handled[5m]))", "handled", range(24), True,
         "datacenter", "avg",
     ),
+    # a recording rule's instant and a panel's six steps over 2^14 series
+    "wide-instant-sum-by-service": (
+        "sum by (service) (rate(nginx_requests[5m]))", "requests", None, True,
+        "service", "sum", "wide", 0,
+    ),
+    "wide-6-steps-sum-by-region": (
+        "sum by (region) (rate(nginx_requests[5m]))", "requests", None, True,
+        "region", "sum", "wide", 300,
+    ),
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_tile_is_legacy_is_reference(fleet_db, case):
-    db, fleet, _ = fleet_db
-    promql, metric, hosts, per_second, by, op = CASES[case]
-    q = _tql(S0, promql)
+def test_tile_is_legacy_is_reference(request, case):
+    promql, metric, hosts, per_second, by, op, *where = CASES[case]
+    which, span_s = where or ("small", None)
+    fixture, start_s, span, step_s, plane_rows = FLEETS[which]
+    span_s = span if span_s is None else span_s
+    db, fleet, _ = request.getfixturevalue(fixture)
+    hosts = range(fleet.hosts) if hosts is None else hosts
+    q = _tql(start_s, promql, span_s=span_s, step_s=step_s)
     _warm(db, q)
     before = {k: getattr(m, k).total() for k in (
         "TQL_TILE_DISPATCHES", "TQL_TILE_LOGICAL_DISPATCHES", "TQL_TILE_INELIGIBLE",
-        "TPU_DEVICE_DISPATCHES", "TQL_TILE_PLANE_ROWS",
+        "TPU_DEVICE_DISPATCHES", "TQL_TILE_PLANE_ROWS", "TQL_TILE_SERIES",
     )}
     got = db.sql_one(q)
     moved = {k: getattr(m, k).total() - v for k, v in before.items()}
     assert moved["TQL_TILE_DISPATCHES"] == moved["TQL_TILE_LOGICAL_DISPATCHES"] == 1
     assert moved["TPU_DEVICE_DISPATCHES"] == 1 and moved["TQL_TILE_INELIGIBLE"] == 0
-    # 24 hosts x 360 scrapes = 8,640 rows a table: a 2^14-row slice of the
-    # region's 2^15-row planes
-    assert moved["TQL_TILE_PLANE_ROWS"] == 1 << 14
+    assert moved["TQL_TILE_PLANE_ROWS"] == plane_rows
+    # every series of the table is evaluated, whatever the matchers keep
+    assert moved["TQL_TILE_SERIES"] == fleet.hosts
     want_names = [by] if by else LABELS
     assert got.column_names == [*want_names, "ts", "value"]
-    legacy = _legacy(db, q)
-    assert legacy.column_names == got.column_names
-    _close(_rows(got), _rows(legacy), 1e-12, case + ": tile vs legacy")
-    want = _reference(fleet, metric, list(hosts), S0 * 1000, per_second, by, op)
+    if which == "small":  # the legacy scan takes tens of seconds at 2^14 series
+        legacy = _legacy(db, q)
+        assert legacy.column_names == got.column_names
+        _close(_rows(got), _rows(legacy), 1e-12, case + ": tile vs legacy")
+    want = _reference(
+        fleet, metric, list(hosts), start_s * 1000, per_second, by, op,
+        span_s=span_s, step_s=step_s,
+    )
+    assert len(want) == (span_s // step_s + 1) * len({_label(by, h) for h in hosts}) if by \
+        else len(want)
     _close(_rows(got), want, 1e-9, case + ": tile vs reference")
+
+
+def _loop_order(np_pres):
+    """The series order as a loop over the regions' presence made it."""
+    order, seen = [], set()
+    for p in np_pres:
+        for sid in np.nonzero(p)[0]:
+            if int(sid) not in seen:
+                seen.add(int(sid))
+                order.append(int(sid))
+    return order
+
+
+@pytest.mark.parametrize("regions,seed", [(1, 0), (2, 1), (3, 2), (5, 3)])
+def test_the_series_and_group_order_are_the_loops(regions, seed):
+    """Shuffled presence over several regions, which may overlap: the
+    series at first appearance, regions in scan order, and the groups at
+    their first present series, as `dict.fromkeys` over that order."""
+    from greptimedb_tpu.query.promql import tile_exec as te
+
+    rng = np.random.default_rng(seed)
+    s, groups = 4096, 37
+    pres = [rng.random(s) < rng.uniform(0.05, 0.9) for _ in range(regions)]
+    order = te._legacy_order(pres)
+    assert order.tolist() == _loop_order(pres)
+    gid = rng.permutation(np.arange(s) % groups).astype(np.int32)
+    got = te._first_appearance(gid[order], groups)
+    assert got.tolist() == list(dict.fromkeys(gid[order].tolist()))
+    every = te._first_appearance(gid, groups)
+    assert every.tolist() == list(dict.fromkeys(gid.tolist()))
+
+
+def test_an_evaluation_past_the_free_device_memory_declines_naming_its_bytes(
+    fleet_db, monkeypatch,
+):
+    from greptimedb_tpu.query.promql import tile_exec as te
+    from greptimedb_tpu.utils import tracing
+
+    db, fleet, _ = fleet_db
+    q = _tql(S0, "sum by (region) (rate(nginx_requests[5m]))")
+    want = _rows(_warm(db, q))
+    seen = []
+    plain = tracing.stage.set
+    monkeypatch.setattr(
+        tracing.stage, "set", lambda self, **a: (seen.append(a), plain(self, **a))[1]
+    )
+    _close(_rows(db.sql_one(q)), want, 1e-12)
+    # the compiler's count of what the fused program holds beside the planes
+    [need] = [a["device_bytes"] for a in seen if "device_bytes" in a]
+    assert need > 32 * 32 * 8  # at least the [series, steps] matrix
+    cache = db.query_engine.tile_cache
+    device = cache.devices[0]
+    saved = te._DEVICE_LIMIT.get(device, "absent")
+    try:
+        # tql.legacy_fallback = false: the statement fails, naming the bytes
+        te._DEVICE_LIMIT[device] = cache.device_used()[0] + need - 1
+        db.config.tql.legacy_fallback = False
+        with pytest.raises(Exception, match=(
+            f"the evaluation holds {need} bytes on the device \\(32 series x 32 "
+            f"steps, 4 groups\\); the plane cache leaves {need - 1} bytes free"
+        )):
+            db.sql_one(q)
+        db.config.tql.legacy_fallback = True
+        # no room for the fold nor for the per-series evaluation the engine
+        # asks for next: the legacy scan answers
+        te._DEVICE_LIMIT[device] = cache.device_used()[0] + 1
+        before = m.TQL_TILE_INELIGIBLE.total(), m.TQL_TILE_DISPATCHES.total()
+        _close(_rows(db.sql_one(q)), want, 1e-12)
+        assert m.TQL_TILE_INELIGIBLE.total() - before[0] == 2
+        assert m.TQL_TILE_DISPATCHES.total() == before[1]
+        # the byte it lacked, and it is the device's again
+        te._DEVICE_LIMIT[device] = cache.device_used()[0] + need
+        before = m.TQL_TILE_DISPATCHES.total()
+        _close(_rows(db.sql_one(q)), want, 1e-12)
+        assert m.TQL_TILE_DISPATCHES.total() - before == 1
+    finally:
+        db.config.tql.legacy_fallback = True
+        if saved == "absent":
+            te._DEVICE_LIMIT.pop(device, None)
+        else:
+            te._DEVICE_LIMIT[device] = saved
 
 
 def test_series_come_in_label_order_on_both_paths(fleet_db):
