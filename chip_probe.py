@@ -134,17 +134,20 @@ def main(seed: int):
 
     timed("strip_counter_resets_segmented",
           jax.jit(R.strip_counter_resets_segmented), d_sid, d_val, d_valid)
-    timed("running max (int32 prefix_scan)", jax.jit(R._running_max),
-          jnp.where(d_valid, jnp.arange(ROWS, dtype=jnp.int32), -1))
     timed("segmented f64 sum (prefix_scan)", jax.jit(R._sum_since_start),
           d_ts == 0, d_val)
 
+    # the series' row bounds, as the tile path reads them off the series
+    # table: the pad series empty at the end of the plane
+    bounds = np.full(S_PAD + 1, ROWS, np.int32)
+    bounds[:SERIES + 1] = np.arange(SERIES + 1, dtype=np.int32) * SAMPLES
+    d_bounds = jax.device_put(bounds)
+
     def windows(sid, ts, v, valid, start, nsteps, raw=None, reduce=R.REDUCTIONS):
         return R.range_windows_dyn(
-            sid, ts, v, valid, start=start, step=STEP_MS, range_=RANGE_MS,
-            n_steps=W_PAD, k=K, num_series=S_PAD, n_steps_actual=nsteps,
-            raw_values=raw, reduce=reduce,
-        )
+            sid, ts, v, valid, d_bounds, start=start, step=STEP_MS, range_=RANGE_MS,
+            n_steps=W_PAD, k=K, n_steps_actual=nsteps, raw_values=raw, reduce=reduce,
+        )[0]
 
     fields = ("count", "first_ts", "last_ts", "first_val", "first_raw",
               "last_val", "sum", "min", "max")
@@ -167,9 +170,9 @@ def main(seed: int):
     args = (d_sid, d_ts, d_val, d_valid, start, nsteps)
     timed("rate program: strip + range_windows_dyn + extrapolated_rate_dyn",
           jax.jit(rate_program), *args, reps=3, scatters=0)
-    timed("window rows: carry scan + series bounds + two searches + counts",
-          lambda *a: R._window_rows(*a, n_steps=W_PAD, num_series=S_PAD),
-          d_sid, d_ts, d_valid, start, np.int64(STEP_MS), np.int64(RANGE_MS), nsteps)
+    timed("window rows: carry scan + runs from the bounds + two searches + counts",
+          lambda *a: R._window_rows(*a, n_steps=W_PAD),
+          d_ts, d_valid, d_bounds, start, np.int64(STEP_MS), np.int64(RANGE_MS), nsteps)
     cells = jax.block_until_ready(
         (jnp.arange(S_PAD * W_PAD, dtype=jnp.int32) * 7) % (SERIES * SAMPLES)
     )

@@ -7,9 +7,10 @@ implementing Prometheus' extrapolated rate — itself a port of Prometheus'
 `extrapolatedRate`).
 
 Design: no ragged range-vector matrix is materialized (dynamic shapes).
-The samples arrive as flat columns sorted by (series, ts), so the rows of a
-(series, window) cell are one contiguous run of the plane: two binary
-searches find it, and the statistics `rate` / `increase` / `delta` /
+The samples arrive as flat columns sorted by (series, ts) with each
+series' run bounds beside them, so the rows of a (series, window) cell
+are one contiguous run of the plane: two binary searches inside the
+series' run find it, and the statistics `rate` / `increase` / `delta` /
 `count_over_time` / `last_over_time` / `timestamp()` read (count, first and
 last timestamp and value) are a difference of row counts and gathers at the
 run's ends — selections, no arithmetic, no scatter.  Only sum / min / max
@@ -32,6 +33,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclass(frozen=True)
@@ -101,14 +103,6 @@ def prefix_scan(combine, xs: tuple, identity: tuple) -> tuple:
     )
     ys = combine(before, ys)
     return tuple(y.reshape(padded)[:n] for y in ys)
-
-
-def _running_max(x: jnp.ndarray) -> jnp.ndarray:
-    """Inclusive running maximum of a non-negative-or-(-1) index array."""
-    (out,) = prefix_scan(
-        lambda a, b: (jnp.maximum(a[0], b[0]),), (x,), (-1,)
-    )
-    return out
 
 
 def _sum_since_start(starts: jnp.ndarray, adds: jnp.ndarray) -> jnp.ndarray:
@@ -220,7 +214,7 @@ def range_windows(
     values: jnp.ndarray,
     valid: jnp.ndarray,
     spec: RangeSpec,
-    num_series: int,
+    run_bounds,
     acc_dtype=jnp.float64,
     raw_values: jnp.ndarray | None = None,
     reduce: tuple[str, ...] = REDUCTIONS,
@@ -229,17 +223,17 @@ def range_windows(
 
     Window w covers (t_w - range, t_w] with t_w = start + w*step —
     Prometheus range selector semantics (left-open, right-closed).
-    `raw_values`: the samples as stored, where `values` went through
-    `strip_counter_resets`; `reduce`: see `range_windows_dyn`, whose
-    precondition on the rows' order holds here too.
+    `run_bounds`, `raw_values` and `reduce`: see `range_windows_dyn`,
+    whose precondition on the rows' order holds here too; its presence
+    is left out.
     """
-    return range_windows_dyn(
-        series, ts, values, valid,
+    stats, _present = range_windows_dyn(
+        series, ts, values, valid, run_bounds,
         start=spec.start, step=spec.step, range_=spec.range_,
         n_steps=spec.num_steps, k=spec.windows_per_sample,
-        num_series=num_series, acc_dtype=acc_dtype, raw_values=raw_values,
-        reduce=reduce,
+        acc_dtype=acc_dtype, raw_values=raw_values, reduce=reduce,
     )
+    return stats
 
 
 def _first_greater(keys, lo, hi, target, rounds):
@@ -258,71 +252,66 @@ def _first_greater(keys, lo, hi, target, rounds):
     return jax.lax.fori_loop(0, rounds, halve, lo)
 
 
-@functools.partial(jax.jit, static_argnames=("num_series",))
-def series_present(series, valid, num_series: int) -> jnp.ndarray:
-    """[num_series] bools: which series hold a valid row.  By position, as
-    `_window_rows` bounds a series' run (and under `range_windows_dyn`'s
-    precondition): the first row that carries a key >= s carries s itself
-    where s has a sample.  One int32 scan and a search of 4096-wide
-    gathers, where a `segment_max` over the plane is a scatter."""
-    n = series.shape[0]
-    if n == 0:
-        return jnp.zeros(num_series, bool)
-    sid_c = _running_max(jnp.where(valid, series.astype(jnp.int32), -1))
-    s = jnp.arange(num_series, dtype=jnp.int32)
-    first = _first_greater(
-        sid_c, jnp.zeros(num_series, jnp.int32), jnp.full(num_series, n, jnp.int32),
-        s - 1, n.bit_length(),
-    )
-    return (first < n) & (jnp.take(sid_c, first, mode="clip") == s)
+def sorted_run_bounds(series, num_series: int) -> np.ndarray:
+    """[num_series + 1] int32 run bounds of host rows sorted by series
+    with no row outside [0, num_series): series s is rows
+    [bounds[s], bounds[s + 1]) (the legacy scan's dense samples, a test's
+    plane)."""
+    return np.searchsorted(
+        np.asarray(series), np.arange(num_series + 1), side="left"
+    ).astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_steps", "num_series"))
-def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
-                 *, n_steps: int, num_series: int):
+@functools.partial(jax.jit, static_argnames=("n_steps",))
+def _window_rows(ts, valid, bounds, start, step, range_, n_steps_actual,
+                 *, n_steps: int):
     """Row positions of every (series, window) cell: (count, first_row,
-    last_row), each [num_series, n_steps] int32; the rows are meaningful
-    where count > 0.  See `range_windows_dyn` for the order it needs."""
+    last_row), each [num_series, n_steps] int32, the rows meaningful where
+    count > 0; and [num_series] bools, which series hold a valid row.
+    `bounds` is the [num_series + 1] int32 run bounds: series s is rows
+    [bounds[s], bounds[s + 1]).  See `range_windows_dyn` for the order it
+    needs."""
     n = ts.shape[0]
     tsmin = jnp.iinfo(jnp.int64).min
     rows = jnp.arange(n, dtype=jnp.int32)
 
     # Over every row, the last VALID row at or before it: its index, its
-    # (series, ts) key, and how many valid rows the plane holds up to
-    # here.  Whatever an invalid row holds (a pad row's zeros, a dedup
-    # loser, a series the matcher masked) is never read: the carried keys
-    # are non-decreasing because the valid rows' are.
+    # ts, and how many valid rows the plane holds up to here; and, read
+    # back to front, the nearest valid row at or after it.  Whatever an
+    # invalid row holds (a pad row's zeros, a dedup loser, a series the
+    # matcher masked) is never read: the carried keys are non-decreasing
+    # within a run because the valid rows' are.
     def carry(a, b):
         take = b[0] >= 0
         return (
             jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1]),
-            jnp.where(take, b[2], a[2]), a[3] + b[3],
+            a[2] + b[2], jnp.maximum(a[3], b[3]),
         )
 
-    last_valid, sid_c, ts_c, n_valid = prefix_scan(
+    last_valid, ts_c, n_valid, back = prefix_scan(
         carry,
         (
             jnp.where(valid, rows, -1),
-            jnp.where(valid, series.astype(jnp.int32), -1),
             jnp.where(valid, ts.astype(jnp.int64), tsmin),
             valid.astype(jnp.int32),
+            jnp.where(valid[::-1], rows, -1),
         ),
-        (-1, -1, tsmin, 0),
+        (-1, tsmin, 0, -1),
     )
+    next_valid = (n - 1 - back)[::-1]  # n past the last valid row
 
-    # a series' run [lo, hi): from the first row that carries a key >= s to
-    # one past its last valid row (the rows after that, up to the next
-    # series' first valid row, carry the same key and hold no sample: a
-    # masked neighbour or the pad tail must not lengthen the search)
-    s_lo = _first_greater(
-        sid_c, jnp.zeros(num_series + 1, jnp.int32),
-        jnp.full(num_series + 1, n, jnp.int32),
-        jnp.arange(num_series + 1, dtype=jnp.int32) - 1, n.bit_length(),
-    )
-    lo, nxt = s_lo[None, :-1], s_lo[None, 1:]
-    hi = jnp.where(nxt > 0, jnp.take(last_valid, nxt - 1, mode="clip") + 1, 0)
+    # a series' run [lo, hi): from its first valid row to one past its
+    # last (the rows around them, up to the neighbours' valid rows, carry
+    # another series' key or hold no sample: a masked neighbour or the pad
+    # tail must not lengthen the search); a series without one is empty
+    b_lo, b_hi = bounds[:-1], bounds[1:]
+    lo = jnp.take(next_valid, b_lo, mode="fill", fill_value=n)
+    hi = jnp.where(b_hi > 0, jnp.take(last_valid, b_hi - 1, mode="clip") + 1, 0)
+    present = lo < hi
     hi = jnp.maximum(hi, lo)
     rounds = 32 - jax.lax.clz(jnp.max(hi - lo))
+    num_series = bounds.shape[0] - 1
+    lo, hi = lo[None, :], hi[None, :]
 
     # a window's rows [a, b) inside the run: a the first row past
     # t_w - range, b the first past t_w; both are valid rows (the carried
@@ -351,13 +340,13 @@ def _window_rows(series, ts, valid, start, step, range_, n_steps_actual,
     def wide():
         return _first_greater(ts_c, run, hi, edge + d, rounds)
 
-    bounds = jax.lax.cond((n_steps - 1) * step + range_ < stop, narrow, wide)
+    found = jax.lax.cond((n_steps - 1) * step + range_ < stop, narrow, wide)
     before = jnp.where(
-        bounds > 0, jnp.take(n_valid, bounds - 1, mode="clip"), 0
+        found > 0, jnp.take(n_valid, found - 1, mode="clip"), 0
     )
     count = jnp.where(w < n_steps_actual, before[1] - before[0], 0)
-    last_row = jnp.take(last_valid, bounds[1] - 1, mode="clip")
-    return count.T, bounds[0].T, last_row.T
+    last_row = jnp.take(last_valid, found[1] - 1, mode="clip")
+    return count.T, found[0].T, last_row.T, present
 
 
 @jax.named_scope("range_windows")
@@ -366,17 +355,17 @@ def range_windows_dyn(
     ts: jnp.ndarray,
     values: jnp.ndarray,
     valid: jnp.ndarray,
+    run_bounds,
     start,
     step,
     range_,
     n_steps: int,
     k: int,
-    num_series: int,
     acc_dtype=jnp.float64,
     n_steps_actual=None,
     raw_values: jnp.ndarray | None = None,
     reduce: tuple[str, ...] = REDUCTIONS,
-) -> WindowStats:
+) -> tuple[WindowStats, jnp.ndarray]:
     """`range_windows` with the evaluation grid split into STATIC shape
     parameters (`n_steps`, `k` — the [S*W] layout and the per-sample
     window unroll of the reductions) and DYNAMIC values (`start`/`step`/
@@ -386,30 +375,44 @@ def range_windows_dyn(
     re-tracing.  `n_steps_actual` (dynamic, defaults to `n_steps`) masks
     the padded windows past the real grid.
 
-    **The order it needs.**  Among the VALID rows, `series` is
-    non-decreasing and `ts` is non-decreasing within a series, so the rows
-    of a cell are one run [a, b) of the plane.  Invalid rows may sit
-    anywhere and hold anything: the kernel carries the last valid row's
-    key over them (`_window_rows`), so a pad row's zeros at the tail or a
-    masked series' rows in the middle break nothing.  Valid rows whose
-    series is outside [0, num_series) are in no cell.  What each caller
-    hands it:
+    Returns the stats and [num_series] bools, which series hold a valid
+    row (a scanned series with no windowed sample still occupies a
+    matrix row in the legacy path — `absent()` and binary ops see it).
+
+    **The run bounds.**  `run_bounds` is an int32 [num_series + 1] array
+    (its length gives the series space): series s is rows
+    [run_bounds[s], run_bounds[s + 1]) of the plane, an empty run where
+    the two are equal.  The caller knows them, so the kernel searches no
+    series' run: `tile_exec._dispatch` reads them off the region's
+    `SeriesTable`, `sorted_run_bounds` makes them of host rows sorted by
+    series.
+
+    **The order it needs.**  A series' VALID rows lie in its run, `ts`
+    non-decreasing among them, so the rows of a cell are one run [a, b)
+    of the plane; `series` holds s on them (the reductions group by it).
+    Invalid rows may sit anywhere and hold anything: the kernel carries
+    the last valid row's key over them (`_window_rows`), so a pad row's
+    zeros at the tail or a masked series' rows in the middle break
+    nothing.  Valid rows outside every run are in no cell.  What each
+    caller hands it:
 
     * `tile_exec._region_stats` (the one-jit program and the mesh
       partials): the super-tile planes, whose real rows [0, num_rows) the
       cache sorted by (pk codes..., ts) at consolidation and keeps so
       through delta merges and dictionary repairs (value-sorted codes, the
       NULL slot last: a repair is an order-preserving map); `series` is
-      the codes' mixed radix and `ts` a monotone map of the stored one, so
-      ALL real rows are in (series, ts) order, valid or not, and the pad
-      rows [num_rows, pad) (code 0, ts 0, never valid) sit at the tail.
+      the codes' run ordinals and `ts` a monotone map of the stored one,
+      so ALL real rows are in (series, ts) order, valid or not, and the
+      pad rows [num_rows, pad) (code 0, ts 0, never valid) sit at the
+      tail; the series table's run starts are the bounds.
     * `engine._range_from_samples` (the legacy scan and subqueries): dense
-      samples in `np.lexsort((ts, sid))` order, every row valid, no pad.
+      samples in `np.lexsort((ts, sid))` order, every row valid, no pad,
+      the bounds `sorted_run_bounds(sid, num_series)`.
 
     **By position.**  count, first_ts, last_ts, first_val, last_val and
-    first_raw are selections: two searches over the carried keys find
-    [a, b), a difference of valid-row counts is `count`, and five gathers
-    read the first and the last row.  No arithmetic touches a value, so
+    first_raw are selections: two searches over the carried keys inside
+    the series' run find [a, b), a difference of valid-row counts is
+    `count`, and five gathers read the first and the last row.  No arithmetic touches a value, so
     the six are bit-equal to a loop over (series, window).  Of duplicate
     (series, ts) among valid rows (an append-mode table the dedup plane
     does not cover; Prometheus data has none) the FIRST in plane order is
@@ -426,6 +429,7 @@ def range_windows_dyn(
     prefix-sum difference rounds differently from a segment sum and
     min / max want a range-minimum structure.  On the chip such a
     reduction over 64-bit values lowers to a scatter of ~90 ns a row."""
+    num_series = run_bounds.shape[0] - 1
     num_groups = num_series * n_steps
     if n_steps_actual is None:
         n_steps_actual = n_steps
@@ -444,13 +448,14 @@ def range_windows_dyn(
         sum=fill(0), min=fill(big), max=fill(small),
     )
     if ts.shape[0] == 0:  # a subquery whose every point is NaN
-        return empty
+        return empty, jnp.zeros(num_series, bool)
 
+    count, first_row, last_row, present = _window_rows(
+        ts, valid, run_bounds, start, step, range_, n_steps_actual,
+        n_steps=n_steps,
+    )
     count, first_row, last_row = (
-        x.reshape(num_groups) for x in _window_rows(
-            series, ts, valid, start, step, range_, n_steps_actual,
-            n_steps=n_steps, num_series=num_series,
-        )
+        x.reshape(num_groups) for x in (count, first_row, last_row)
     )
     live = count > 0
 
@@ -472,7 +477,7 @@ def range_windows_dyn(
         last_val=at(v, last_row, empty.last_val),
     )
     if not reduce:
-        return stats
+        return stats, present
 
     segs = num_groups + 1
     sum_ = jnp.zeros(segs, acc_dtype)
@@ -498,7 +503,7 @@ def range_windows_dyn(
             )
     return dataclasses.replace(
         stats, sum=sum_[:num_groups], min=min_[:num_groups], max=max_[:num_groups]
-    )
+    ), present
 
 
 def extrapolated_rate(

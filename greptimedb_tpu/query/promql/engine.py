@@ -363,6 +363,7 @@ class PromqlEngine:
             over_time,
             range_windows,
             reductions_for,
+            sorted_run_bounds,
             strip_counter_resets,
         )
 
@@ -382,10 +383,10 @@ class PromqlEngine:
         if raw is not None:
             v = strip_counter_resets(s, raw, valid)
         # `flat` is in np.lexsort((ts, sid)) order, every row a sample:
-        # the order `range_windows` searches
+        # the order `range_windows` searches, each series' rows one run
         stats = range_windows(
-            s, t, v, valid, spec, num_series=num_series, raw_values=raw,
-            reduce=reductions_for(func),
+            s, t, v, valid, spec, sorted_run_bounds(series_ids, num_series),
+            raw_values=raw, reduce=reductions_for(func),
         )
         if func in _RATE_FUNCS:
             vals, defined = extrapolated_rate(stats, spec, func)

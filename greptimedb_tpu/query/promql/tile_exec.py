@@ -42,8 +42,9 @@ the series space is the series that exist whatever the key's width.  What
 a request needs of the labels it reads off the region's `SeriesTable`
 (`parallel/tile_cache.py`: run starts and codes per key column, made once
 per plane build): matchers fold to one bool a series, a `by` aggregation
-to one group id a series, both dynamic inputs; the answer's label columns
-come from the same table.
+to one group id a series, and the run starts to each series' row bounds
+(`_run_bounds`, so the kernel searches no series' run over the plane), all
+dynamic inputs; the answer's label columns come from the same table.
 
 Logical tables.  A metric-engine logical table (Prometheus remote write,
 OTLP metrics) has no planes of its own: its rows are ONE RANGE of its
@@ -90,7 +91,6 @@ from ...ops.rate import (
     over_time,
     range_windows_dyn,
     reductions_for,
-    series_present,
     strip_counter_resets_segmented,
 )
 from ...utils import flight_recorder, metrics
@@ -174,8 +174,9 @@ def _logical_slice(chunks, cut, off):
 def _region_stats(src, dyn, rsig, csig):
     """Traced per-region pipeline: planes -> per-(series, window) stats +
     per-series presence.  `src` = (key_chunks..., ts_chunks, val_chunks,
-    null_chunks|None, valid_chunks, rows): the planes and, dynamic, where
-    the source's rows and series lie in them; shapes and the static cut of
+    null_chunks|None, valid_chunks, at): the planes and, dynamic, where
+    the source's rows and series lie in them (`at["bounds"]`: each
+    series' rows, `_run_bounds`); shapes and the static cut of
     a logical table come from `rsig`, query structure from `csig`."""
     (key_chunks, ts_chunks, val_chunks, null_chunks, valid_chunks, at) = src
     (func, _agg, s_pad, w_pad, k, unit_ns, _g_pad, matched) = csig
@@ -233,18 +234,14 @@ def _region_stats(src, dyn, rsig, csig):
     raw = vf if func in ("rate", "increase") else None
     if raw is not None:
         vf = strip_counter_resets_segmented(sid, raw, in_fetch)
-    stats = range_windows_dyn(
-        sid, ts_ms, vf, in_fetch,
+    # each series' rows [bounds[s], bounds[s + 1]) come with the request
+    # (`_run_bounds`), the presence per series with the stats
+    return range_windows_dyn(
+        sid, ts_ms, vf, in_fetch, at["bounds"],
         start=dyn["start"], step=dyn["step"], range_=dyn["range"],
-        n_steps=w_pad, k=k, num_series=s_pad,
-        n_steps_actual=dyn["nsteps"], raw_values=raw,
+        n_steps=w_pad, k=k, n_steps_actual=dyn["nsteps"], raw_values=raw,
         reduce=reductions_for(func),
     )
-    # scan-presence per series (a scanned series with no windowed sample
-    # still occupies a matrix row in the legacy path — `absent()` and
-    # binary ops see it)
-    presence = series_present(sid, in_fetch, s_pad)
-    return stats, presence
 
 
 def _finalize(stats: WindowStats, dyn, csig):
@@ -805,7 +802,7 @@ class TqlTileExecutor:
             ]
             at = {"sid0": np.int32(sp.sid0)}
             if src.table_id is None:
-                cut = None
+                cut, base = None, 0
                 at.update(off=np.int32(0), lo=np.int32(0), hi=np.int32(entry.pad))
                 plane_rows += entry.pad
             else:
@@ -826,6 +823,7 @@ class TqlTileExecutor:
                 )
                 plane_rows += cut[0]
                 plan.set(slice_rows=cut[0], series=n_series)
+            at["bounds"] = _run_bounds(sp, s_pad, base, int(at["hi"]))
             source = (*planes, at)
             sources.append(source)
             region_sigs.append((_source_sig(source), cut))
@@ -848,7 +846,7 @@ class TqlTileExecutor:
         with tracing.span(
             "tile.dispatch", strategy="tql", func=func,
             series=s_pad, steps=w, regions=len(sources),
-            mesh_devices=mesh_n,
+            mesh_devices=mesh_n, run_bounds=len(sources),
         ) as disp:
             if mesh_n > 0 and len(sources) > 1:
                 mat, pres = self._mesh_dispatch(
@@ -883,6 +881,7 @@ class TqlTileExecutor:
         )
         if not ghost:
             metrics.TQL_TILE_DISPATCHES.inc()
+            metrics.TQL_TILE_RUN_BOUNDS.inc(len(sources))
             metrics.TQL_TILE_SERIES.inc(n_series)
             metrics.TQL_TILE_PLANE_ROWS.inc(plane_rows)
             if src.table_id is not None:
@@ -1202,6 +1201,27 @@ class _SeriesLabels:
         return self._memo(
             ("group_tuples", tuple(keep)), lambda: self._decode(gids.keys, keep)
         )
+
+
+def _run_bounds(sp: _Span, s_pad: int, base: int, hi: int) -> np.ndarray:
+    """The program's `run_bounds` for one source: [s_pad + 1] int32, series
+    s (global id) is rows [bounds[s], bounds[s + 1]) of what the program
+    sees (plane row - `base`, the row a logical table's slice starts at).
+    The source's series are its series table's runs; another region's,
+    before them, are empty at the source's first row, and the pad series
+    after them empty at `hi`, the end of the source's rows.  Made once a
+    plane build, in the table's memo, as the group ids are."""
+
+    def make():
+        n = sp.s_hi - sp.s_lo
+        bounds = np.full(s_pad + 1, hi, np.int32)
+        bounds[:sp.sid0] = sp.r_lo - base
+        bounds[sp.sid0:sp.sid0 + n + 1] = sp.table.starts[sp.s_lo:sp.s_hi + 1] - base
+        return bounds
+
+    return sp.table.remember(
+        ("run_bounds", sp.s_lo, sp.s_hi, sp.sid0, s_pad, base, hi), make
+    )
 
 
 def _slice_plan(lengths, r_lo: int, r_hi: int):

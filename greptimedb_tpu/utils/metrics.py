@@ -414,6 +414,12 @@ TQL_TILE_PLANE_ROWS = REGISTRY.counter(
     "Padded rows of the planes handed to the TQL tile program, summed over "
     "dispatches: the region's for a mito table, the slice's for a logical one",
 )
+TQL_TILE_RUN_BOUNDS = REGISTRY.counter(
+    "greptime_tql_tile_run_bounds_total",
+    "Sources of TQL tile dispatches whose series' row bounds the program was "
+    "handed, read off the region's series table (the kernel searches no "
+    "series' run over the plane)",
+)
 TQL_TILE_SERIES = REGISTRY.counter(
     "greptime_tql_tile_series_total",
     "Series a TQL tile dispatch evaluates (the source's series that exist, "

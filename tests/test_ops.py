@@ -25,6 +25,7 @@ from greptimedb_tpu.ops.rate import (
     extrapolated_rate,
     over_time,
     range_windows,
+    sorted_run_bounds,
     strip_counter_resets,
 )
 from greptimedb_tpu.ops.tiles import padded_size, tiles_from_table
@@ -194,7 +195,7 @@ def test_range_windows_and_rate_regular_grid():
     series = jnp.zeros(n, dtype=jnp.int32)
     valid = jnp.ones(n, dtype=bool)
     adj = strip_counter_resets(series, jnp.asarray(vals_np), valid)
-    stats = range_windows(series, jnp.asarray(ts_np), adj, valid, spec, num_series=1)
+    stats = range_windows(series, jnp.asarray(ts_np), adj, valid, spec, sorted_run_bounds(series, 1))
     rate, defined = extrapolated_rate(stats, spec, "rate")
     rate = np.asarray(rate)[np.asarray(defined)]
     # Perfect 1/s counter -> rate 1.0 everywhere (extrapolation exact on grid).
@@ -210,7 +211,7 @@ def test_over_time_functions():
     ts = jnp.asarray(np.array([10, 40, 70, 100], dtype=np.int64))
     vals = jnp.asarray(np.array([1.0, 5.0, 3.0, 7.0]))
     valid = jnp.ones(4, dtype=bool)
-    stats = range_windows(series, ts, vals, valid, spec, num_series=1)
+    stats = range_windows(series, ts, vals, valid, spec, sorted_run_bounds(series, 1))
     for func, want in [
         ("avg_over_time", 4.0),
         ("sum_over_time", 16.0),
@@ -231,7 +232,7 @@ def test_range_windows_overlapping_windows():
     ts = jnp.asarray(np.array([50, 150, 250], dtype=np.int64))
     vals = jnp.asarray(np.array([1.0, 2.0, 3.0]))
     valid = jnp.ones(3, dtype=bool)
-    stats = range_windows(series, ts, vals, valid, spec, num_series=1)
+    stats = range_windows(series, ts, vals, valid, spec, sorted_run_bounds(series, 1))
     counts = np.asarray(stats.count)
     # windows: (−100,100]→{50}, (0,200]→{50,150}, (100,300]→{150,250}
     np.testing.assert_array_equal(counts, [1, 2, 2])

@@ -1,7 +1,8 @@
 """`ops/rate.range_windows_dyn` finds a window's first and last sample by
-row position (two searches over the sorted plane and a gather); here it is
-held, field for field and bit for bit, to a loop over (series, window),
-and its `rate` program to holding no scatter."""
+row position (two searches inside the series' run, whose bounds the caller
+hands in, and a gather); here it is held, field for field and bit for bit,
+to a loop over (series, window), and its `rate` program to holding no
+scatter and no loop but the window search."""
 
 import dataclasses
 import re
@@ -18,7 +19,7 @@ from greptimedb_tpu.ops.rate import (
     over_time,
     range_windows_dyn,
     reductions_for,
-    series_present,
+    sorted_run_bounds,
     strip_counter_resets_segmented,
 )
 
@@ -71,10 +72,31 @@ def _plane(rng, n_series, per_series, scrape, jitter, t0=0, gap_share=0.0):
     return sid, ts, vals
 
 
+def _pad_tail(sid, ts, vals, valid, pad):
+    """The cache's pad rows at the tail: code 0, ts 0, value 0, never valid."""
+    return (
+        np.concatenate([sid, np.zeros(pad, np.int32)]),
+        np.concatenate([ts, np.zeros(pad, np.int64)]),
+        np.concatenate([vals, np.zeros(pad)]),
+        np.concatenate([valid, np.zeros(pad, bool)]),
+    )
+
+
+def _bounds(real_sid, n_series, n):
+    """Run bounds as `tile_exec._run_bounds` makes them: the runs of the
+    real rows' series (sorted), every series past them empty at `n`, the
+    end of the rows the program sees (the pad tail joins the last run)."""
+    n_data = int(real_sid.max()) + 1 if len(real_sid) else 0
+    bounds = np.full(n_series + 1, n, np.int32)
+    bounds[:n_data + 1] = sorted_run_bounds(real_sid, n_data)
+    return bounds
+
+
 def _case(name):
-    """(sid, ts, vals, valid, grid) of one named case; grid = (start, step,
-    range_, n_steps, n_actual, k, n_series)."""
+    """(sid, ts, vals, valid, bounds, grid) of one named case; grid =
+    (start, step, range_, n_steps, n_actual, k, n_series)."""
     rng = np.random.default_rng(sum(map(ord, name)))
+    bounds = None
     if name == "regular_grid":
         sid, ts, vals = _plane(rng, 5, 60, 10_000, 0)
         valid = np.ones(len(ts), bool)
@@ -88,11 +110,8 @@ def _case(name):
         sid, ts, vals = _plane(rng, 6, 50, 10_000, 3_000)
         valid = rng.random(len(ts)) > 0.2  # dedup losers, rows out of the fetch
         valid[sid == 2] = False  # a series the matcher masks
-        pad = 37  # the cache's pad rows: code 0, ts 0, value 0, never valid
-        sid = np.concatenate([sid, np.zeros(pad, np.int32)])
-        ts = np.concatenate([ts, np.zeros(pad, np.int64)])
-        vals = np.concatenate([vals, np.zeros(pad)])
-        valid = np.concatenate([valid, np.zeros(pad, bool)])
+        bounds = _bounds(sid, 8, len(sid) + 37)
+        sid, ts, vals, valid = _pad_tail(sid, ts, vals, valid, 37)
         grid = (100_000, 30_000, 120_000, 16, 16, 4, 8)  # 2 series beyond the data
     elif name == "padded_steps_and_k":
         sid, ts, vals = _plane(rng, 4, 80, 10_000, 2_000)
@@ -158,9 +177,57 @@ def _case(name):
         sid, ts, vals = _plane(rng, 3, 20, 10_000, 0)
         valid = np.zeros(len(ts), bool)
         grid = (0, 10_000, 30_000, 8, 8, 3, 3)
+    elif name == "invalid_rows_between_a_series_samples_hold_anything":
+        # dedup losers and rows the matcher drops between one series'
+        # samples, holding another series' id and a ts past every window
+        sid, ts, vals = _plane(rng, 4, 50, 10_000, 2_000)
+        valid = np.ones(len(ts), bool)
+        junk = (sid == 1) & (np.arange(len(ts)) % 3 == 1)
+        valid[junk] = False
+        sid = np.where(junk, 3, sid).astype(np.int32)
+        ts = np.where(junk, 10**12, ts)
+        bounds = sorted_run_bounds(np.repeat(np.arange(4), 50), 4)
+        grid = (100_000, 20_000, 60_000, 16, 16, 3, 4)
+    elif name == "series_with_no_row_in_the_fetch":
+        # the first, a middle and the last series: every row outside the
+        # fetch range (invalid), so each is an empty run, not present
+        sid, ts, vals = _plane(rng, 6, 30, 10_000, 1_000)
+        valid = rng.random(len(ts)) > 0.1
+        valid[np.isin(sid, [0, 3, 5])] = False
+        grid = (50_000, 30_000, 90_000, 8, 8, 3, 6)
+    elif name == "pad_series_past_the_data":
+        # the series space padded to a power of two: four empty runs at
+        # the end of the rows
+        sid, ts, vals = _plane(rng, 4, 40, 10_000, 0)
+        valid = rng.random(len(ts)) > 0.1
+        bounds = _bounds(sid, 8, len(sid))
+        grid = (60_000, 30_000, 60_000, 8, 8, 2, 8)
+    elif name == "pad_tail_after_a_valid_last_row":
+        # the last real row valid, the pad rows' zeros right after it
+        sid, ts, vals = _plane(rng, 3, 40, 10_000, 0)
+        valid = np.ones(len(ts), bool)
+        bounds = _bounds(sid, 4, len(sid) + 100)
+        sid, ts, vals, valid = _pad_tail(sid, ts, vals, valid, 100)
+        grid = (60_000, 30_000, 60_000, 8, 8, 2, 4)
+    elif name == "logical_slice_off_and_lo":
+        # a logical table's slice: the neighbours' rows before the
+        # table's first row (`at["lo"]` > 0; their series id clipped to 0,
+        # never valid) and after its last (they continue its last series
+        # and lie in no run: the last bound is the table's `at["hi"]`)
+        sid, ts, vals = _plane(rng, 5, 30, 10_000, 2_000)
+        valid = rng.random(len(ts)) > 0.1
+        head, tail = 23, 41
+        bounds = head + sorted_run_bounds(sid, 5)
+        sid = np.concatenate([np.zeros(head, np.int32), sid, np.full(tail, 4, np.int32)])
+        ts = np.concatenate([rng.integers(0, 10**9, head), ts, rng.integers(0, 10**9, tail)])
+        vals = np.concatenate([np.zeros(head), vals, np.zeros(tail)])
+        valid = np.concatenate([np.zeros(head, bool), valid, np.zeros(tail, bool)])
+        grid = (60_000, 30_000, 90_000, 8, 8, 3, 5)
     else:
         raise KeyError(name)
-    return sid, ts, vals, valid, grid
+    if bounds is None:
+        bounds = sorted_run_bounds(sid, grid[-1])
+    return sid, ts, vals, valid, bounds, grid
 
 
 CASES = [
@@ -168,32 +235,33 @@ CASES = [
     "padded_steps_and_k", "start_off_the_grid_negative_offset", "ns_scale_timestamps",
     "duplicate_series_ts", "span_past_32_bits", "rows_far_outside_the_grid",
     "span_one_under_the_32_bit_stop", "span_at_the_32_bit_stop", "one_row", "no_row", "no_valid_row",
+    "invalid_rows_between_a_series_samples_hold_anything", "series_with_no_row_in_the_fetch",
+    "pad_series_past_the_data", "pad_tail_after_a_valid_last_row", "logical_slice_off_and_lo",
 ]
 
 
 @pytest.mark.parametrize("name", CASES)
 def test_range_windows_equal_a_loop_over_series_and_windows(name):
-    """All nine fields bit-equal to the loop, with a raw plane beside the
-    (here arbitrarily) adjusted one, jitted with a traced grid as the tile
-    program calls it."""
-    sid, ts, vals, valid, grid = _case(name)
+    """All nine fields and the presence bit-equal to the loop, with a raw
+    plane beside the (here arbitrarily) adjusted one, jitted with a traced
+    grid and the run bounds as the tile program calls it."""
+    sid, ts, vals, valid, bounds, grid = _case(name)
     start, step, range_, n_steps, n_actual, k, n_series = grid
     raw = vals[::-1].copy()  # any other plane: first_raw is a selection from it
     want = _loop(sid, ts, vals, raw, valid, start, step, range_, n_steps, n_actual, n_series)
 
     @jax.jit
-    def run(sid, ts, vals, raw, valid, start, step, range_, n_actual):
-        stats = range_windows_dyn(
-            sid, ts, vals, valid, start=start, step=step, range_=range_,
-            n_steps=n_steps, k=k, num_series=n_series,
-            n_steps_actual=n_actual, raw_values=raw,
+    def run(sid, ts, vals, raw, valid, bounds, start, step, range_, n_actual):
+        stats, present = range_windows_dyn(
+            sid, ts, vals, valid, bounds, start=start, step=step, range_=range_,
+            n_steps=n_steps, k=k, n_steps_actual=n_actual, raw_values=raw,
         )
-        return dataclasses.astuple(stats) + (series_present(sid, valid, n_series),)
+        return dataclasses.astuple(stats) + (present,)
 
     *got, present = run(
         jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(raw),
-        jnp.asarray(valid), np.int64(start), np.int64(step), np.int64(range_),
-        np.int64(n_actual),
+        jnp.asarray(valid), jnp.asarray(bounds), np.int64(start), np.int64(step),
+        np.int64(range_), np.int64(n_actual),
     )
     for f, field in zip(dataclasses.fields(WindowStats), got):
         np.testing.assert_array_equal(np.asarray(field), want[f.name], f.name)
@@ -202,17 +270,19 @@ def test_range_windows_equal_a_loop_over_series_and_windows(name):
     )
     if name == "gaps_longer_than_the_range":
         assert (want["count"] == 0).any() and (want["count"] == 1).any()
+    if name == "series_with_no_row_in_the_fetch":
+        assert not np.asarray(present)[[0, 3, 5]].any()
 
 
 def test_without_a_raw_plane_first_raw_is_first_val_and_unasked_reductions_keep_their_fill():
-    sid, ts, vals, valid, grid = _case("invalid_rows_masked_series_pad_tail")
+    sid, ts, vals, valid, bounds, grid = _case("invalid_rows_masked_series_pad_tail")
     start, step, range_, n_steps, n_actual, k, n_series = grid
     want = _loop(sid, ts, vals, vals, valid, start, step, range_, n_steps, n_actual, n_series)
     for reduce in [(), ("sum",), ("min",), ("max",), REDUCTIONS]:
-        got = range_windows_dyn(  # eager, python ints: the legacy scan's call
+        got, _present = range_windows_dyn(  # eager, python ints: the legacy scan's call
             jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(valid),
-            start=start, step=step, range_=range_, n_steps=n_steps, k=k,
-            num_series=n_series, reduce=reduce,
+            bounds, start=start, step=step, range_=range_, n_steps=n_steps, k=k,
+            reduce=reduce,
         )
         np.testing.assert_array_equal(np.asarray(got.first_raw), want["first_val"])
         fills = {"sum": 0.0, "min": F64.max, "max": F64.min}
@@ -232,8 +302,9 @@ def test_duplicate_series_ts_read_by_position():
     ts = jnp.asarray(np.array([10, 10, 20, 30, 30], np.int64))
     vals = jnp.asarray(np.array([9.0, 1.0, 5.0, 2.0, 8.0]))
     valid = jnp.asarray(np.array([True, True, True, True, False]))
-    got = range_windows_dyn(
-        sid, ts, vals, valid, start=30, step=10, range_=25, n_steps=1, k=4, num_series=1,
+    got, _present = range_windows_dyn(
+        sid, ts, vals, valid, sorted_run_bounds(sid, 1),
+        start=30, step=10, range_=25, n_steps=1, k=4,
     )
     assert int(got.count[0]) == 4
     assert (float(got.first_val[0]), float(got.last_val[0])) == (9.0, 2.0)
@@ -248,14 +319,14 @@ def test_reductions_for_names_what_over_time_reads():
     assert reductions_for("min_over_time") == ("min",)
     assert reductions_for("max_over_time") == ("max",)
     # what `over_time` reads of a stats whose other reductions are fills
-    sid, ts, vals, valid, grid = _case("regular_grid")
+    sid, ts, vals, valid, bounds, grid = _case("regular_grid")
     start, step, range_, n_steps, n_actual, k, n_series = grid
-    args = (jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(valid))
-    kw = dict(start=start, step=step, range_=range_, n_steps=n_steps, k=k, num_series=n_series)
-    whole = range_windows_dyn(*args, **kw)
+    args = (jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(valid), bounds)
+    kw = dict(start=start, step=step, range_=range_, n_steps=n_steps, k=k)
+    whole, _present = range_windows_dyn(*args, **kw)
     for func in ("avg_over_time", "sum_over_time", "min_over_time", "max_over_time",
                  "count_over_time", "last_over_time"):
-        lean = range_windows_dyn(*args, **kw, reduce=reductions_for(func))
+        lean, _present = range_windows_dyn(*args, **kw, reduce=reductions_for(func))
         for a, b in zip(over_time(lean, func), over_time(whole, func)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -269,14 +340,13 @@ def _scatters_by_scope(func):
     whether their location lies inside the `range_windows` scope."""
     n, n_series, n_steps, k = 4096, 16, 8, 4
 
-    def program(sid, ts, raw, valid, start, step, range_, n_actual):
+    def program(sid, ts, raw, valid, bounds, start, step, range_, n_actual):
         v = strip_counter_resets_segmented(sid, raw, valid) if func == "rate" else raw
-        stats = range_windows_dyn(
-            sid, ts, v, valid, start=start, step=step, range_=range_,
-            n_steps=n_steps, k=k, num_series=n_series, n_steps_actual=n_actual,
+        stats, present = range_windows_dyn(
+            sid, ts, v, valid, bounds, start=start, step=step, range_=range_,
+            n_steps=n_steps, k=k, n_steps_actual=n_actual,
             raw_values=raw if func == "rate" else None, reduce=reductions_for(func),
         )
-        present = series_present(sid, valid, n_series)  # beside it in the tile program
         if func == "rate":
             return extrapolated_rate_dyn(stats, start, step, range_, n_steps, "rate"), present
         return over_time(stats, func), present
@@ -285,7 +355,7 @@ def _scatters_by_scope(func):
     lines = jax.jit(program).lower(
         jax.ShapeDtypeStruct((n,), jnp.int32), jax.ShapeDtypeStruct((n,), jnp.int64),
         jax.ShapeDtypeStruct((n,), jnp.float64), jax.ShapeDtypeStruct((n,), jnp.bool_),
-        scalar, scalar, scalar, scalar,
+        jax.ShapeDtypeStruct((n_series + 1,), jnp.int32), scalar, scalar, scalar, scalar,
     ).as_text(debug_info=True).splitlines()
     # `#loc7 = loc("jit(program)/range_windows/scatter-add"(#loc3))`
     names = dict(
@@ -511,3 +581,127 @@ def test_consolidated_planes_are_in_series_ts_order_after_a_delta_merge_too():
                 assert a == b
     finally:
         db.close()
+
+
+# ---- the tile program's loops ------------------------------------------------
+
+
+def _loops(jaxpr, under=()):
+    """Every loop primitive (`while`, `scan`: a `fori_loop` with a static
+    trip count traces to `scan`) of a jaxpr, its sub-jaxprs' included, each
+    as the path of loop and `cond` primitives that holds it."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        here = under + (name,) if name in ("cond", "while", "scan") else under
+        if name in ("while", "scan"):
+            found.append(here)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _loops(sub, here)
+    return found
+
+
+def _panel_program_jaxpr(n=1 << 12, s_pad=256, w_pad=8, g_pad=16):
+    """The TQL tile program of a `sum by (…) (rate(…[5m]))` panel over a
+    logical table's slice (the `prom-1m-series-rules` shape, small)."""
+    from greptimedb_tpu.query.promql import tile_exec
+
+    csig = ("rate", "sum", s_pad, w_pad, 8, 1_000_000, g_pad, False)
+    at = {k: np.int32(0) for k in ("sid0", "off", "lo", "hi")}
+    at["bounds"] = np.zeros(s_pad + 1, np.int32)
+    src = (((np.zeros(n, np.int32),),), (np.zeros(n, np.int64),), (np.zeros(n),), None,
+           (np.zeros(n, bool),), at)
+    dyn = {k: np.int64(0) for k in ("lo", "hi", "offset", "start", "step", "range", "nsteps")}
+    dyn["gid"] = np.zeros(s_pad, np.int32)
+    program = tile_exec._full_program((csig, ((tile_exec._source_sig(src), (n, 0, n)),)))
+    return jax.make_jaxpr(program)((src,), dyn).jaxpr
+
+
+def test_the_panel_program_searches_no_series_run_over_the_plane():
+    """The program's only loops are the window search's, one in each branch
+    of its `cond` (the 32-bit and the 64-bit keys): no search of the
+    series' run starts over the plane, no running max for presence."""
+    assert sorted(_loops(_panel_program_jaxpr())) == [("cond", "while"), ("cond", "while")]
+
+
+def test_the_loop_walk_finds_a_search_over_the_plane():
+    """What the program held until its run bounds came with the request:
+    a search of each series' first row over the whole plane, outside any
+    `cond`, with as many rounds as the plane's length needs."""
+    from greptimedb_tpu.ops.rate import _first_greater
+
+    n, s = 1 << 12, 256
+
+    def search(keys):
+        return _first_greater(
+            keys, jnp.zeros(s + 1, jnp.int32), jnp.full(s + 1, n, jnp.int32),
+            jnp.arange(s + 1, dtype=jnp.int32) - 1, n.bit_length(),
+        )
+
+    assert _loops(jax.make_jaxpr(search)(jnp.zeros(n, jnp.int32)).jaxpr) == [("scan",)]
+
+
+def test_a_logical_slice_reads_its_series_at_the_bounds_made_for_it():
+    """A logical table's rows, cut out of two chunks of its region's planes
+    at a dynamic offset (`_slice_plan`, `_logical_slice`: off > 0, the
+    table's first row lo > 0 into the slice), read at the run bounds that
+    `_run_bounds` makes of the region's series table: bit-equal to the
+    loop over the table's own rows."""
+    from greptimedb_tpu.parallel.tile_cache import SeriesTable
+    from greptimedb_tpu.query.promql.tile_exec import (
+        _logical_slice,
+        _run_bounds,
+        _slice_plan,
+        _Span,
+    )
+
+    rng = np.random.default_rng(4101)
+    lengths = rng.integers(20, 120, 90)  # rows a series: three tables' series
+    starts = np.concatenate([[0], np.cumsum(lengths)]).astype(np.int64)
+    s_lo, s_hi = 30, 60  # the middle table's series
+    chunk = 4096
+    total = 2 * chunk
+    assert starts[-1] <= total
+    region_sid = np.zeros(total, np.int32)
+    region_ts = np.zeros(total, np.int64)
+    for s in range(len(lengths)):
+        rows = slice(starts[s], starts[s + 1])
+        region_sid[rows] = s
+        region_ts[rows] = np.sort(rng.integers(0, 600_000, lengths[s]))
+    region_valid = np.arange(total) < starts[-1]
+    region_valid &= rng.random(total) > 0.15
+    region_vals = rng.integers(0, 1000, total).astype(np.float64)
+
+    r_lo, r_hi = int(starts[s_lo]), int(starts[s_hi])
+    first, last, cut, off, base = _slice_plan([chunk, chunk], r_lo, r_hi)
+    assert (first, last) == (0, 1) and off > 0 and r_lo - base > 0
+    lo, hi = r_lo - base, r_hi - base
+    table = SeriesTable(key=(), tags=(), starts=starts, codes=np.zeros((len(lengths), 0), np.int32))
+    n_series, s_pad = s_hi - s_lo, 32
+    bounds = _run_bounds(_Span(table, s_lo, s_hi, r_lo, r_hi, 0), s_pad, base, hi)
+    assert bounds[0] == lo and bounds[n_series] == hi and (bounds[n_series:] == hi).all()
+
+    def cut_out(plane):
+        return np.asarray(_logical_slice(
+            (jnp.asarray(plane[:chunk]), jnp.asarray(plane[chunk:])), cut, np.int32(off)
+        ))
+
+    rows = np.arange(cut[0])
+    sid = np.clip(cut_out(region_sid) - s_lo, 0, s_pad - 1).astype(np.int32)
+    ts, vals = cut_out(region_ts), cut_out(region_vals)
+    valid = cut_out(region_valid) & (rows >= lo) & (rows < hi)
+    start, step, range_, n_steps = 120_000, 60_000, 180_000, 8
+    stats, present = range_windows_dyn(
+        jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), jnp.asarray(valid), bounds,
+        start=start, step=step, range_=range_, n_steps=n_steps, k=3,
+    )
+    mine = slice(r_lo, r_hi)
+    in_table = region_valid[mine]
+    want = _loop(region_sid[mine] - s_lo, region_ts[mine], region_vals[mine], region_vals[mine],
+                 in_table, start, step, range_, n_steps, n_steps, s_pad)
+    for f in dataclasses.fields(WindowStats):
+        np.testing.assert_array_equal(np.asarray(getattr(stats, f.name)), want[f.name], f.name)
+    np.testing.assert_array_equal(
+        np.asarray(present), np.isin(np.arange(s_pad), region_sid[mine][in_table] - s_lo)
+    )
+    assert np.asarray(present)[:n_series].any() and not np.asarray(present)[n_series:].any()

@@ -89,19 +89,19 @@ def test_rate_windows_compile(one_chip):
     program (the strip itself compiles in the test above)."""
     from greptimedb_tpu.ops.rate import extrapolated_rate_dyn, range_windows_dyn
 
-    def rate(sid, ts, v, valid, start, step, range_):
-        stats = range_windows_dyn(
-            sid, ts, v + 1.0, valid, start=start, step=step, range_=range_,
-            n_steps=128, k=8, num_series=4096, raw_values=v,
+    def rate(sid, ts, v, valid, bounds, start, step, range_):
+        stats, present = range_windows_dyn(
+            sid, ts, v + 1.0, valid, bounds, start=start, step=step,
+            range_=range_, n_steps=128, k=8, raw_values=v,
         )
-        return extrapolated_rate_dyn(stats, start, step, range_, 128, "rate")
+        return extrapolated_rate_dyn(stats, start, step, range_, 128, "rate"), present
 
     scalar = _rows(one_chip, jnp.int64, ())
     _compile(
         "range_windows_dyn + extrapolated_rate_dyn, 2^20 rows", rate,
         _rows(one_chip, jnp.int32), _rows(one_chip, jnp.int64),
         _rows(one_chip, jnp.float64), _rows(one_chip, jnp.bool_),
-        scalar, scalar, scalar,
+        _rows(one_chip, jnp.int32, (4097,)), scalar, scalar, scalar,
     )
 
 
@@ -158,7 +158,8 @@ def test_logical_table_program_compiles(one_chip, chunks, cut, agg):
     """The whole TQL tile program over a metric-engine logical table, as
     `prom-metric-engine-range` runs it: a 2^21-row slice cut on the device
     out of 2^22-row chunks at a dynamic offset, series by `run_ordinals`,
-    a matcher's bool and a group id per series as dynamic inputs."""
+    a matcher's bool, a group id and the row bounds per series as dynamic
+    inputs."""
     from greptimedb_tpu.query.promql import tile_exec
 
     rows = 1 << 22
@@ -168,6 +169,7 @@ def test_logical_table_program_compiles(one_chip, chunks, cut, agg):
         return tuple(_rows(one_chip, dtype, (rows,)) for _ in range(chunks))
 
     at = {k: _rows(one_chip, jnp.int32, ()) for k in ("sid0", "off", "lo", "hi")}
+    at["bounds"] = _rows(one_chip, jnp.int32, (4096 + 1,))
     src = ((planes(jnp.int32),), planes(jnp.int64), planes(jnp.float64), None,
            planes(jnp.bool_), at)
     dyn = {
@@ -201,6 +203,7 @@ def test_million_series_panel_program_compiles_and_fits(one_chip):
         return tuple(_rows(one_chip, dtype, (chunk,)) for _ in range(4))
 
     at = {k: _rows(one_chip, jnp.int32, ()) for k in ("sid0", "off", "lo", "hi")}
+    at["bounds"] = _rows(one_chip, jnp.int32, (s_pad + 1,))
     src = ((planes(jnp.int32),), planes(jnp.int64), planes(jnp.float64), None,
            planes(jnp.bool_), at)
     dyn = {

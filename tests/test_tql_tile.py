@@ -327,6 +327,50 @@ def test_cold_serves_legacy_and_schedules_build():
         db.close()
 
 
+@pytest.mark.parametrize(
+    "func", ["rate", "increase", "count_over_time", "last_over_time", "sum_over_time"]
+)
+def test_legacy_scan_with_host_made_bounds_equals_the_tile_path(func, monkeypatch):
+    """The legacy scan (`engine._range_from_samples`) hands the window
+    kernel run bounds made on the host from its dense (sid, ts)-sorted
+    samples, the tile path reads them off the series table: over the same
+    samples (counters without resets, where the strip cannot differ, and a
+    matcher that drops a series between the others) what is found by
+    position answers bit for bit.  `sum_over_time` adds by segment, over
+    the padded plane in another order than over the dense samples (the
+    module docstring's ulp-level exception): within 1e-12."""
+    from greptimedb_tpu.ops import rate as rate_ops
+
+    made = []
+
+    def spy(series, num_series):
+        made.append(num_series)
+        return real(series, num_series)
+
+    real = rate_ops.sorted_run_bounds
+    monkeypatch.setattr(rate_ops, "sorted_run_bounds", spy)
+    db = _db()
+    try:
+        _load_counter(db, np.random.default_rng(41), hosts=5, ticks=40, resets=False)
+        q = f"TQL EVAL (60, 540, '30s') {func}(tq{{host!='h2'}}[2m])"
+        _warm(db, q)
+        d0, b0 = m.TQL_TILE_DISPATCHES.get(), m.TQL_TILE_RUN_BOUNDS.get()
+        made.clear()
+        w = db.sql_one(q)
+        assert m.TQL_TILE_DISPATCHES.get() == d0 + 1
+        assert m.TQL_TILE_RUN_BOUNDS.get() == b0 + 1 and made == []
+        l = _legacy(db, q)
+        assert made == [4]  # the legacy scan's four series
+        assert m.TQL_TILE_DISPATCHES.get() == d0 + 1
+        assert len(_rows(w)) > 0
+        if func == "sum_over_time":
+            _assert_rows_close(_rows(w), _rows(l))
+        else:
+            assert _rows(w) == _rows(l)
+    finally:
+        db.close()
+
+
 def test_tile_off_is_legacy_bit_for_bit():
     db = _db(tile=False)
     try:
@@ -487,6 +531,7 @@ def test_range_windows_ns_scale_timestamps():
         extrapolated_rate_dyn,
         range_windows,
         range_windows_dyn,
+        sorted_run_bounds,
     )
 
     rng = np.random.default_rng(41)
@@ -499,9 +544,9 @@ def test_range_windows_ns_scale_timestamps():
         start=base + 120_000, end=base + 600_000, step=30_000, range_=120_000
     )
     valid = jnp.ones(len(vals), bool)
+    bounds = sorted_run_bounds(sid, n_series)
     stats = range_windows(
-        jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), valid,
-        spec, num_series=n_series,
+        jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), valid, spec, bounds,
     )
     rate_v, defined = extrapolated_rate(stats, spec, "rate")
     rate_v = np.asarray(rate_v)
@@ -526,11 +571,11 @@ def test_range_windows_ns_scale_timestamps():
     got = rate_v.reshape(n_series, -1)[0, w]
     np.testing.assert_allclose(got, want, rtol=1e-12)
     # the dynamic-spec form (the tile program's path) is bit-identical
-    stats_d = range_windows_dyn(
-        jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), valid,
+    stats_d, _present = range_windows_dyn(
+        jnp.asarray(sid), jnp.asarray(ts), jnp.asarray(vals), valid, bounds,
         start=np.int64(spec.start), step=np.int64(spec.step),
         range_=np.int64(spec.range_), n_steps=spec.num_steps,
-        k=spec.windows_per_sample, num_series=n_series,
+        k=spec.windows_per_sample,
     )
     rate_d, defined_d = extrapolated_rate_dyn(
         stats_d, np.int64(spec.start), np.int64(spec.step),
