@@ -102,15 +102,13 @@ def time_bucket(ts: jnp.ndarray, origin: int, interval: int) -> jnp.ndarray:
 
 # ---- series ordinals ---------------------------------------------------------
 #
-# A source whose series are a strict subset of the dictionary (a region of
-# a table partitioned on its leading key tag) holds that tag's codes with
-# gaps the partition rule left: under HASH (hostname) consecutive hosts of
-# one region lie 2-9 codes apart, a block's gids then span up to
-# 9 x n_buckets slots and the blocked kernels' span guard fails for the
-# whole source.  Sources are (pk, ts) sorted, so equal codes are one run:
-# stage 1 groups by the run's ORDINAL in the source (consecutive series
-# exactly one apart whatever the rule left), and the [G] states are
-# carried back to the table-wide code space before anything merges.
+# Sources are (pk, ts) sorted, so a series is one run of rows.  Stage 1 can
+# group by the run's ORDINAL in the source (consecutive series exactly one
+# apart) where the requested tags' codes would not keep a block's gids
+# inside the blocked kernels' span: a wider key's subset or reorder, and a
+# region of a table partitioned on its leading key tag (under HASH
+# (hostname) consecutive hosts of one region lie 2-9 codes apart).  The
+# states then fold to the requested groups (`fold_series_states`).
 
 
 @jax.named_scope("series_ordinals")
@@ -134,83 +132,6 @@ def run_ordinals(keys, lo, hi) -> jnp.ndarray:
         lambda a, b: (a[0] + b[0],), (start.astype(jnp.int32),), (0,)
     )
     return seen - 1
-
-
-@jax.named_scope("series_ordinals")
-def series_ordinals(
-    codes: jnp.ndarray, valid: jnp.ndarray, card: int
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Ordinals of the runs of equal `codes` in one source, and the way
-    back: (ordinal [n] int32 in [0, card), slot_of_code [card] int32,
-    ok scalar bool).
-
-    A row's ordinal is the number of code changes before it: one compare
-    with the previous row and one int32 prefix sum.  The pad tail (the
-    rows after the last valid one, whatever they hold) continues the last
-    run.  `slot_of_code[c]` is the ordinal of code c's run, -1 where the
-    source holds no such run: the code of ordinal k is read at the first
-    row of run k (one search of `card` keys over the non-decreasing
-    ordinals), and a `card`-element scatter inverts it, so runs need not
-    ascend by code.  `ok` is False where ordinals cannot stand for codes:
-    more runs than `card`, or a code in two runs (a source that is not
-    sorted by this tag, e.g. a memtable tail in arrival order); the
-    caller then keeps the codes.  Runs of a code outside [0, card) get no
-    slot: their rows are out of range for the caller's mask as they were."""
-    from .rate import _first_greater, prefix_scan
-
-    n = codes.shape[0]
-    c = codes.astype(jnp.int32)
-    rows = jnp.arange(n, dtype=jnp.int32)
-    last = jnp.max(jnp.where(valid, rows, -1))
-    prev = jnp.concatenate([c[:1], c[:-1]])
-    start = (rows <= last) & ((rows == 0) | (c != prev))
-    (seen,) = prefix_scan(
-        lambda a, b: (a[0] + b[0],), (start.astype(jnp.int32),), (0,)
-    )
-    n_runs = seen[-1]
-    ks = jnp.arange(card, dtype=jnp.int32)
-    first = _first_greater(
-        seen, jnp.zeros(card, jnp.int32), jnp.full(card, n, jnp.int32),
-        ks, n.bit_length(),
-    )  # first row with ordinal + 1 > k
-    code_of = jnp.take(c, first, mode="clip")
-    held = (ks < n_runs) & (code_of >= 0) & (code_of < card)
-    slot_of_code = jnp.full(card, -1, jnp.int32).at[
-        jnp.where(held, code_of, card)
-    ].max(ks, mode="drop")
-    ok = (n_runs <= card) & (jnp.sum(slot_of_code >= 0) == jnp.sum(held))
-    return jnp.clip(seen - 1, 0, card - 1), slot_of_code, ok
-
-
-@jax.named_scope("ordinals_to_codes")
-def ordinal_states_to_codes(
-    state: AggState, slot_of_code: jnp.ndarray, ok: jnp.ndarray
-) -> AggState:
-    """Carry a stage-1 state whose LEADING gid component is a series
-    ordinal (`series_ordinals`) back to the table-wide code space: row c of
-    the [card, G / card] view is the row of code c's ordinal, the
-    aggregate's identity where the source holds no such code.  One gather
-    of G elements per field, against a scan of the source's rows.  Where
-    `ok` is False stage 1 grouped by codes and the state passes as is."""
-    card = slot_of_code.shape[0]
-    absent = (slot_of_code < 0)[:, None]
-    src = jnp.maximum(slot_of_code, 0)
-
-    def carry(arr, identity=lambda dtype: 0):
-        if arr is None:
-            return None
-        rows = jnp.take(arr.reshape(card, -1), src, axis=0)
-        rows = jnp.where(absent, jnp.asarray(identity(arr.dtype), arr.dtype), rows)
-        return jnp.where(ok, rows.reshape(arr.shape), arr)
-
-    return AggState(
-        sums=carry(state.sums),
-        counts=carry(state.counts),
-        mins=carry(state.mins, lambda dtype: jnp.finfo(dtype).max),
-        maxs=carry(state.maxs, lambda dtype: jnp.finfo(dtype).min),
-        last_ts=carry(state.last_ts, lambda dtype: jnp.iinfo(dtype).min),
-        last_val=carry(state.last_val),
-    )
 
 
 # ---- hash group-by ----------------------------------------------------------
@@ -645,8 +566,9 @@ def segment_aggregate(
     * **blocked kernel** — when every BLOCK_ROWS block's MASKED rows span
       fewer than BLOCK_SPAN distinct group ids (the engine's (pk, ts) sort
       guarantees clustering whenever the group keys follow primary-key
-      order — the planner composes hierarchical (pk x bucket) group ids
-      precisely so this holds, see `reduce_state_axes` — and selective
+      order — a plan grouped by a subset of a wider key composes its ids
+      over the source's series ordinals so this holds, see
+      `fold_series_states` — and selective
       filters make sparse blocks trivially narrow), each block reduces
       into a tiny dense [SPAN] accumulator via compare-broadcast sums
       (VPU-friendly, no scatter), and only the [blocks, SPAN] partials hit
@@ -661,7 +583,7 @@ def segment_aggregate(
     on v5e: 4.7 s at 2^16, 66 s at 2^20 — it alone was the round-2 bench
     compile blowup), while blocked+scatter compile in ~3 s flat at any
     shape.  The layouts it served are now handled statically by
-    hierarchical grouping.
+    grouping stage 1 by series (`fold_series_states`).
 
     `gids` may be raw in-range ids (preferred; pass `mask` for filtering)
     or legacy overflow-encoded ids (those fail the in-range guard and take
@@ -987,60 +909,58 @@ def _segment_blocked_last(
     return state
 
 
-def reduce_state_axes(
-    state: AggState,
-    layout_cards: tuple[int, ...],
-    keep_axes: tuple[int, ...],
+@jax.named_scope("series_ordinals")
+def series_rows(marks: jnp.ndarray, n: int) -> jnp.ndarray:
+    """Ordinal [n] int32 of each row's series in a (series, ts) sorted
+    source whose series k opens at row `marks[k]`: `marks` ascends from 0,
+    and its entries at n or past (the padding of a fixed-size table) mark
+    nothing.  The series' row bounds come with the request, off the
+    region's `SeriesTable`: no key plane is read and nothing is searched,
+    one scatter of the marks and one int32 prefix sum.  The pad tail
+    continues the last series: the caller's valid mask drops it."""
+    from .rate import prefix_scan
+
+    hist = jnp.zeros(n, jnp.int32).at[marks].add(1, mode="drop")
+    (seen,) = prefix_scan(lambda a, b: (a[0] + b[0],), (hist,), (0,))
+    return seen - 1
+
+
+@jax.named_scope("series_fold")
+def fold_series_states(
+    state: AggState, series_group: jnp.ndarray, num_groups: int,
+    n_buckets: int,
 ) -> AggState:
-    """Hierarchical grouping, stage 2: fold a [prod(layout_cards)] state
-    down to the requested group space.
-
-    Stage 1 aggregates at a FINER granularity than the query asked for —
-    the group id is composed over a primary-key prefix plus the time
-    bucket, which is the one layout the engine's (pk, ts) sort makes
-    blocked-kernel-friendly per source (`_segment_blocked`).  This fold
-    then reduces away the pk axes the query did not group by and permutes
-    the kept axes into the query's requested order — all on device, before
-    any host transfer.  Equivalent CPU-side shape: the reference's partial
-    aggregate per series merged at the frontend
-    (query/src/dist_plan/commutativity.rs step aggregates); here both
-    stages live in one compiled program.
-
-    Valid for sum/count/min/max/avg states (elementwise monoids commute
-    with the reshape-reduce).  LAST needs an argmax-merge to DROP an axis
-    and is excluded by the planner from real folds — but a pure axis
-    permutation (group keys are a reordering of the pk, e.g. GROUP BY b, a
-    over pk (a, b)) only relabels groups, so LAST transposes fine."""
-    drop = tuple(i for i in range(len(layout_cards)) if i not in keep_axes)
-    if state.last_ts is not None and drop:
-        raise ValueError("reduce_state_axes cannot drop axes of LAST states")
-    if not drop and keep_axes == tuple(range(len(layout_cards))):
-        return state
+    """Stage 2 of a plan that groups by series (`DistGroupByPlan.
+    series_slots`): fold a [S * B] state (series-major, bucket minor) to
+    the [G * B] the query asked for, through `series_group` [S] int32, the
+    group code of each series (-1 for a padding slot: dropped).  One
+    segment reduction per field, on the device, before any readback: sums
+    and counts add, mins and maxs take the extreme.  LAST states only
+    permute (each series its own group, the planner's rule): they are
+    placed, never folded."""
+    s = series_group.shape[0]
+    ids = jnp.where(series_group < 0, num_groups, series_group)
 
     def fold(arr, op):
-        a = arr.reshape(layout_cards)
-        if drop:
-            a = op(a, axis=drop)
-        # permute remaining axes into requested order
-        remaining = [i for i in range(len(layout_cards)) if i in keep_axes]
-        perm = [remaining.index(i) for i in keep_axes]
-        if perm != list(range(len(perm))):
-            a = jnp.transpose(a, perm)
-        return a.reshape(-1)
+        if arr is None:
+            return None
+        return op(arr.reshape(s, n_buckets), ids, num_segments=num_groups).reshape(-1)
 
-    out = AggState()
-    if state.sums is not None:
-        out.sums = fold(state.sums, jnp.sum)
-    if state.counts is not None:
-        out.counts = fold(state.counts, jnp.sum)
-    if state.mins is not None:
-        out.mins = fold(state.mins, jnp.min)
-    if state.maxs is not None:
-        out.maxs = fold(state.maxs, jnp.max)
-    if state.last_ts is not None:  # drop == (): permutation only
-        out.last_ts = fold(state.last_ts, None)
-        out.last_val = fold(state.last_val, None)
-    return out
+    def place(arr, identity):
+        if arr is None:
+            return None
+        out = jnp.full((num_groups, n_buckets), identity, arr.dtype)
+        return out.at[ids].set(arr.reshape(s, n_buckets), mode="drop").reshape(-1)
+
+    return AggState(
+        sums=fold(state.sums, jax.ops.segment_sum),
+        counts=fold(state.counts, jax.ops.segment_sum),
+        mins=fold(state.mins, jax.ops.segment_min),
+        maxs=fold(state.maxs, jax.ops.segment_max),
+        last_ts=None if state.last_ts is None
+        else place(state.last_ts, jnp.iinfo(state.last_ts.dtype).min),
+        last_val=place(state.last_val, 0),
+    )
 
 
 def merge_states(a: AggState, b: AggState) -> AggState:
@@ -1073,7 +993,7 @@ def pack_f64_bits(x: jnp.ndarray) -> jnp.ndarray:
     search over exact power-of-two scalings, the mantissa from two exact
     floor splits, the sign from the float32 cast (sign-preserving for
     every input, and a 32-bit bitcast lowers fine).  Everything but the
-    float64 compares/multiplies is int32.  int32 words bitcast to bytes
+    float64 compares/multiplies is int32.  int32 words bitcast to uint32
     fine, so f64 rows can join the one flat result buffer and the whole
     compact readback ships as a SINGLE device_get.
 

@@ -43,20 +43,26 @@ from ..ops.aggregate import (
     _FAST_MIN_ROWS,
     AggState,
     finalize,
+    fold_series_states,
     hash_group_slots,
     limb_segment_sums,
-    ordinal_states_to_codes,
     psum_states,
     quantize_limbs,
     raw_group_ids,
     segment_aggregate,
-    series_ordinals,
+    series_rows,
     time_bucket,
 )
 from ..ops.tiles import TileBatch, padded_size, tiles_from_table
 from .mesh import REGION_AXIS
 
 COUNT_STAR = "__count_star"  # pseudo-column for count(*)
+# a series plan's per-source inputs, carried among the source's columns
+# (see compute_partial_states)
+SERIES_MARKS, SERIES_ROWS, SERIES_GROUP = (
+    "__series_marks", "__series_rows", "__series_group",
+)
+SERIES_KEYS = (SERIES_MARKS, SERIES_ROWS, SERIES_GROUP)
 
 # SQL agg func -> kernel agg name
 _FUNC_TO_KERNEL = {
@@ -91,13 +97,21 @@ class DistGroupByPlan:
     # (SQL: NULL never satisfies a predicate); the table-based path
     # pre-filters on the host so this only matters for the tile path
     filter_null_cols: tuple[str, ...] = ()
-    # Hierarchical grouping (ops/aggregate.py reduce_state_axes): when the
-    # requested group keys are not a primary-key prefix in pk order, the
-    # group id is composed over this pk prefix instead (+ bucket last), the
-    # blocked kernel aggregates at that finer layout-clustered granularity,
-    # and the state is folded down to `group_tags` on device.
-    layout_tags: tuple[str, ...] | None = None
-    layout_cards: tuple[int, ...] = ()
+    # Series grouping (ops/aggregate.py fold_series_states): where the
+    # requested group keys are not the leading columns of a wider primary
+    # key, or lead a key the table is partitioned on (a region holds every
+    # n-th code of that tag), stage 1 groups by the source's SERIES
+    # ORDINAL (one run of the (pk, ts) sorted rows per series) x bucket
+    # over `series_slots` padded series, and the state folds down to
+    # `group_tags` on device
+    # through a [series_slots] series -> group-code table.  The stage-1
+    # space is the series present, never the product of the key's tag
+    # cardinalities.  0 = the gid is composed over `group_tags` directly.
+    series_slots: int = 0
+    # A series plan over the regions of a table partitioned on its leading
+    # key tag, where a region's series stand in for that tag's table-wide
+    # codes (counted by TILE_ORDINAL_GIDS); it changes no program.
+    partitioned: bool = False
     # Time-major execution: sources are gathered through a ts-ascending
     # permutation before aggregation, making `gid = bucket` globally
     # non-decreasing for ANY bucket interval (bucket-only group-bys like
@@ -118,14 +132,6 @@ class DistGroupByPlan:
     # so group spaces far past max_groups stay executable.
     agg_strategy: str = "sort"
     hash_slots: int = 0
-    # Stage 1 groups by the source's own series ORDINAL in place of the
-    # gid's leading tag code (ops/aggregate.series_ordinals), and every
-    # state is carried back to code space where `fold` sits.  Set by the
-    # planner where a source holds a strict subset of that tag's
-    # dictionary (a region of a table partitioned on its leading key tag:
-    # the codes a region holds lie apart, and a block's gids past
-    # `block_span`); elsewhere ordinals equal codes and it stays unset.
-    lead_ordinals: bool = False
 
     @property
     def num_groups(self) -> int:
@@ -139,15 +145,12 @@ class DistGroupByPlan:
 
     @property
     def internal_groups(self) -> int:
-        """Stage-1 group-space size (= num_groups unless hierarchical)."""
-        if self.layout_tags is None:
+        """Stage-1 group-space size (= num_groups unless series-grouped)."""
+        if not self.series_slots:
             return self.num_groups
-        g = 1
-        for c in self.layout_cards:
-            g *= c
-        if self.bucket_col is not None:
-            g *= self.n_buckets
-        return g
+        return self.series_slots * (
+            self.n_buckets if self.bucket_col is not None else 1
+        )
 
     def value_cols(self) -> list[str]:
         out = []
@@ -267,10 +270,13 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
     quantized planes per column (dict col -> (limbs, scale)); missing
     columns quantize in-program from their f64 plane.
 
-    With `plan.lead_ordinals` the gid's leading component is the row's
-    series ordinal in THIS source, not the tag's table-wide code, and
-    every state leaves here carried back to code space (where `fold`
-    sits), so merges, collectives and finalize see code-space states.
+    With `plan.series_slots` the gid is the row's series ordinal in THIS
+    source x bucket, and every state leaves here folded to the group
+    space (`fold_series_states`).  The source carries its series among
+    its columns, as `SERIES_GROUP` (the [series_slots] group code of each
+    of its series, -1 past them) and either `SERIES_MARKS` (the row at
+    which each series opens, for (pk, ts) sorted planes) or `SERIES_ROWS`
+    (each row's ordinal, for a memtable tail in arrival order).
 
     With `plan.agg_strategy == "hash"` the caller must pass `hash_table`
     (the [hash_slots] int64 key table threaded across this query's
@@ -282,6 +288,9 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
     place (sum-merged across sources) so the executor can fall back to
     the dense path instead of ever returning a wrong result."""
     acc = jnp.float32 if plan.acc_dtype == "float32" else jnp.float64
+    series = {k: columns[k] for k in SERIES_KEYS if k in columns}
+    if series:
+        columns = {k: v for k, v in columns.items() if k not in series}
     if perm is not None:
         columns = {k: v[perm] for k, v in columns.items()}
         valid = valid[perm]
@@ -299,9 +308,18 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
             mask = mask & nulls[c]
 
     components: list[tuple[jnp.ndarray, int]] = []
-    if plan.layout_tags is not None:
-        for tag, card in zip(plan.layout_tags, plan.layout_cards):
-            components.append((columns[tag], card))
+    internal = plan.internal_groups
+    n_per = plan.n_buckets if plan.bucket_col is not None else 1
+    if plan.series_slots:
+        # the source's own series table sizes its stage 1 (a memtable
+        # tail may hold more series than the planes' padded count)
+        slots = series[SERIES_GROUP].shape[0]
+        if SERIES_ROWS in series:
+            sid = series[SERIES_ROWS]
+        else:
+            sid = series_rows(series[SERIES_MARKS], valid.shape[0])
+        components.append((sid, slots))
+        internal = slots * n_per
     else:
         for tag, card in zip(plan.group_tags, plan.tag_cards):
             components.append((columns[tag], card))
@@ -312,21 +330,6 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
         components.append((b, plan.n_buckets))
     is_hash = plan.agg_strategy == "hash"
     overflow = None
-    to_codes = None
-    if plan.lead_ordinals:  # a sort plan whose gid leads with a tag
-        lead, card = components[0]
-        ordinal, slot_of_code, by_ordinal = series_ordinals(lead, valid, card)
-        # a code outside the dictionary stays out of range, as raw_group_ids
-        # finds it from the code; where the ordinals cannot stand for the
-        # codes (a source not sorted by this tag) the codes stay
-        mask = mask & (lead >= 0) & (lead < card)
-        components[0] = (
-            jnp.where(by_ordinal, ordinal, lead.astype(jnp.int32)), card
-        )
-
-        def to_codes(state: AggState) -> AggState:
-            return ordinal_states_to_codes(state, slot_of_code, by_ordinal)
-
     if is_hash:
         if hash_table is None:
             raise ValueError("hash agg strategy requires the threaded hash_table")
@@ -340,7 +343,7 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
         mask = active
         n_internal = plan.hash_slots
     else:
-        n_internal = plan.internal_groups
+        n_internal = internal
         # raw in-range ids + mask (NOT overflow-encoded): keeps scan-order
         # sortedness intact so segment_aggregate's block kernel can engage.
         # Tail padding rows (valid=False) get the max id so they don't break
@@ -360,22 +363,13 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
     # per-column null mask for SQL NULL semantics (sum over an all-null
     # group is NULL, not 0).  last_value keeps the per-column path (needs
     # the ts-ordered two-pass kernel).
-    from ..ops.aggregate import reduce_state_axes, segment_aggregate_multi
+    from ..ops.aggregate import segment_aggregate_multi
 
-    if plan.layout_tags is not None:
-        fold_cards = plan.layout_cards + (
-            (plan.n_buckets,) if plan.bucket_col is not None else ()
-        )
-        keep_axes = tuple(plan.layout_tags.index(t) for t in plan.group_tags) + (
-            (len(plan.layout_tags),) if plan.bucket_col is not None else ()
-        )
-
+    if plan.series_slots:
         def fold(state: AggState) -> AggState:
-            if to_codes is not None:
-                state = to_codes(state)
-            return reduce_state_axes(state, fold_cards, keep_axes)
-    elif to_codes is not None:
-        fold = to_codes
+            return fold_series_states(
+                state, series[SERIES_GROUP], plan.num_groups // n_per, n_per
+            )
     else:
         def fold(state: AggState) -> AggState:
             return state
@@ -397,8 +391,8 @@ def compute_partial_states(plan: DistGroupByPlan, columns, valid, nulls, dyn=Non
     limb_batch: list[tuple[str, bool]] = []  # (col, counted)
     for col, aggs in per_col_aggs.items():
         if "last" in aggs:
-            # LAST has no reshape-reduce fold; the planner never builds a
-            # hierarchical plan with last_value
+            # LAST states are placed, never folded: the planner builds a
+            # series plan with last_value only where each series is a group
             key = tuple(sorted(aggs | {"count"}))
             col_mask = mask & nulls[col] if col in nulls else mask
             if col not in nulls:

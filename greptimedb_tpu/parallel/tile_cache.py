@@ -29,8 +29,10 @@ fetch regardless of scale.
 Layout strategy (what makes the hot kernel scatter-free):
   * group keys that are a primary-key prefix (in pk order) ride the
     engine's (pk, ts) sort directly;
-  * other tag subsets aggregate hierarchically at a pk-prefix granularity
-    and fold down on device (ops/aggregate.py `reduce_state_axes`);
+  * other tag subsets aggregate by the sorted rows' SERIES (one run per
+    series, row bounds off the region's `SeriesTable`) x bucket and fold
+    down on device (ops/aggregate.py `fold_series_states`): the stage-1
+    space is the series present, whatever the key's width;
   * bucket-only group-bys (TSBS single-groupby, groupby-orderby-limit) go
     time-major: rows are gathered through a cached ts-ascending
     permutation, making `gid = bucket` sorted for ANY interval.
@@ -101,6 +103,10 @@ from .batcher import (
 )
 from .executor import (
     COUNT_STAR,
+    SERIES_GROUP,
+    SERIES_KEYS,
+    SERIES_MARKS,
+    SERIES_ROWS,
     DistGroupByPlan,
     GroupByResult,
     _FUNC_TO_KERNEL,
@@ -121,6 +127,28 @@ TILE_CHUNK_ROWS = 1 << 24
 # its own ceiling).  Margin below 2^63 keeps every intermediate
 # `gid * card + c` in range too.
 _HASH_GID_LIMIT = 1 << 62
+
+# ---- declines ----------------------------------------------------------------
+# Why the tile path gave the statement back (TileExecutor.execute returns
+# None and the engine hands it on): the first reason noted since the
+# call began, read by the engine for the `query.tpu` span's `declined`
+# and for `query.legacy_fallback = false`.
+_DECLINE = threading.local()
+
+
+def _decline(reason: str):
+    """Note `reason` for the statement in flight (the first one noted
+    wins: it is the deepest check) and return None, the decline."""
+    if getattr(_DECLINE, "reason", None) is None:
+        _DECLINE.reason = reason
+    return None
+
+
+def last_decline() -> str | None:
+    """The reason the current thread's last SQL tile call declined, None
+    where it answered."""
+    return getattr(_DECLINE, "reason", None)
+
 
 # ---- flow-maintenance attribution ------------------------------------------
 # Dirty-window flow recompute (flow/dataflow.py) drives its per-window
@@ -458,6 +486,23 @@ class SeriesTable:
                 self.memo.pop(next(iter(self.memo)))
             self.memo[key] = make()
         return self.memo[key]
+
+    def prefix(self, j: int) -> "SeriesTable":
+        """The series of the key's first `j` columns: runs of this table's
+        series whose first `j` codes are equal (kept in the memo)."""
+
+        def make():
+            lead = self.codes[:, :j]
+            first = np.flatnonzero(
+                np.concatenate([[True], (lead[1:] != lead[:-1]).any(axis=1)])
+            ) if len(lead) else np.zeros(0, np.int64)
+            return SeriesTable(
+                key=self.key + (j,), tags=self.tags[:j],
+                starts=np.append(self.starts[first], self.starts[-1]),
+                codes=lead[first],
+            )
+
+        return self.remember(("prefix", j), make)
 
     def code_range(self, tag: str, code: int) -> tuple[int, int]:
         """Series [s_lo, s_hi) whose LEADING key column holds `code`."""
@@ -2912,6 +2957,23 @@ class TileCacheManager:
             return first, end, int((kept[end] - kept[first]).sum())
         return first, end, int((end - first).sum())
 
+    def window_positions(
+        self, entry: _SuperTiles, window: tuple[int, int], ts_name: str,
+        dedup: bool, rows: np.ndarray,
+    ) -> np.ndarray:
+        """For rows of the sorted planes, the row of `window`'s tile at or
+        after which each falls: how many of the tile's rows (the window's
+        rows, less the dedup losers) lie before it."""
+        first, end, _n = self._window_ranges(entry, window, ts_name, dedup)
+        kept = entry.keep_prefix if dedup else None
+        held = (kept[end] - kept[first]) if dedup else (end - first)
+        before = np.concatenate([[0], np.cumsum(held)])
+        run = np.clip(np.searchsorted(first, rows, side="right") - 1, 0, None)
+        inside = np.clip(rows, first[run], end[run])
+        return before[run] + (
+            (kept[inside] - kept[first[run]]) if dedup else (inside - first[run])
+        )
+
     @staticmethod
     def _window_sources(wt: dict, need_cols: set[str], limb_cols: set[str]):
         n_chunks = len(wt["valid"])
@@ -3395,7 +3457,7 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
     O(rows_out) readback contract.  Compact results skip the f32/uint8
     byte packing (they are small; f64 keeps them bit-identical to the
     host path on the same aggregates) and their f64 rows join the SAME
-    flat byte buffer as arithmetically-composed IEEE bit pairs
+    flat word buffer as arithmetically-composed IEEE bit pairs
     (ops/aggregate.pack_f64_bits), so the whole compact result —
     lastpoint included — is ONE device_get of one array (each extra
     fetched array is another device->host crossing).
@@ -3405,7 +3467,7 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
     across ALL sources), every state row is [hash_slots]-sized, and the
     fetch ships (buf, accs64, table_keys) — the host decodes slot ->
     group key from the table, so the dense [G] space never exists on
-    device OR on the wire.  An overflow byte rides the flat buffer like
+    device OR on the wire.  An overflow word rides the flat buffer like
     the limb verdict: 1 means some row never found a slot and the caller
     must rerun on the dense path (never a wrong result).
 
@@ -3441,7 +3503,7 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
     )
     int_dtype = jnp.int32 if (needs_exact_counts or not pack_bytes) else jnp.uint8
     # columns whose sums carry a quantization-error bound (limb mode):
-    # the program appends a one-byte verdict — 1 iff every group's bound
+    # the program appends a one-word verdict — 1 iff every group's bound
     # is within _LIMB_VERDICT_RTOL of |sum| — and the caller reruns in
     # exact f64 on 0
     limb_err_cols = (
@@ -3548,15 +3610,15 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
 
         def as_int(row):
             if int_dtype == jnp.uint8:
-                # gating-only rows (consumed as `> 0`): pack to 1 bit/group
-                # (np.unpackbits order: index 0 = MSB)
+                # gating-only rows (consumed as `> 0`): pack to 1 bit/group,
+                # 32 to a word whose little-endian bytes are np.packbits'
+                # (group 8j + k of a word is bit 7 - k of its byte j)
                 g = row.shape[0]
-                gp = -(-g // 8) * 8
-                bits = (
-                    jnp.pad(row > 0, (0, gp - g)).reshape(gp // 8, 8)
-                    * jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], jnp.uint8)
-                )
-                return jnp.sum(bits, axis=1, dtype=jnp.uint8)
+                gp = -(-g // 32) * 32
+                k = jnp.arange(32, dtype=jnp.uint32)
+                weight = jnp.left_shift(jnp.uint32(1), k // 8 * 8 + 7 - k % 8)
+                bits = jnp.pad(row > 0, (0, gp - g)).reshape(gp // 32, 32)
+                return jnp.sum(bits * weight, axis=1, dtype=jnp.uint32)
             return row.astype(jnp.int32)
 
         parts = [
@@ -3582,15 +3644,19 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
                 parts.append(pack_f64_bits(jnp.stack(
                     [pick(outs[col][agg]) for col, agg in acc64_layout]
                 )))
-        # ONE flat byte buffer for the 8/32-bit rows: every array of a
-        # jax.device_get is its own device->host crossing, so ints + f32
-        # rows bitcast to bytes and concatenate.  f64 rows CANNOT join it — the TPU x64 rewrite has
-        # no lowering for 64-bit bitcast-convert — so they ride as a
-        # second (usually empty) array in the same device_get.
+        # ONE flat buffer of 32-bit words for the int and f32 rows: every
+        # array of a jax.device_get is its own device->host crossing, so
+        # the rows bitcast to uint32 and concatenate; the host reads the
+        # words' bytes.  Words, not bytes: a bitcast to uint8 compiles for
+        # a v5e in ~0.25 ms an element (a [10, 57344] float32 stack took
+        # 200 s), one to a type of the same width in none.  f64 rows
+        # CANNOT join it — the TPU x64 rewrite has no lowering for 64-bit
+        # bitcast-convert — so they ride as a second (usually empty)
+        # array in the same device_get.
         flat = [
             p.reshape(-1)
-            if p.dtype == jnp.uint8
-            else jax.lax.bitcast_convert_type(p, jnp.uint8).reshape(-1)
+            if p.dtype == jnp.uint32
+            else jax.lax.bitcast_convert_type(p, jnp.uint32).reshape(-1)
             for p in parts
         ]
         if limb_err_cols:
@@ -3601,12 +3667,12 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
                 ok = ok & jnp.all(
                     err <= jnp.maximum(jnp.abs(s) * _LIMB_VERDICT_RTOL, 1e-12)
                 )
-            flat.append(ok.astype(jnp.uint8).reshape(1))
+            flat.append(ok.astype(jnp.uint32).reshape(1))
         if is_hash:
-            # trailing verdict byte, like the limb bound: 0 = clean,
+            # trailing verdict word, like the limb bound: 0 = clean,
             # 1 = some row never placed -> caller reruns dense
             flat.append(
-                (merged["__hash_overflow"].counts > 0).astype(jnp.uint8).reshape(1)
+                (merged["__hash_overflow"].counts > 0).astype(jnp.uint32).reshape(1)
             )
         buf = jnp.concatenate(flat) if len(flat) > 1 else flat[0]
         if spec is not None:
@@ -3638,7 +3704,9 @@ def _tile_program(plan: DistGroupByPlan, nullable_cols: tuple[str, ...], spec=No
         if not _in_fused_build():
             # builder (ghost) dispatches stay out of the per-query counter
             metrics.TPU_DEVICE_DISPATCHES.inc()
-            if plan.lead_ordinals:
+            if plan.series_slots:
+                metrics.TILE_SERIES_GIDS.inc()
+            if plan.partitioned:
                 metrics.TILE_ORDINAL_GIDS.inc()
         if _in_flow_maintenance():
             metrics.FLOW_DEVICE_DISPATCH_TOTAL.inc()
@@ -4197,7 +4265,9 @@ def _mesh_run(plan, nullable_cols, mesh, staged, pdyn, hv, program):
     # not double-count against the single-chip dispatch that follows
     if not _in_fused_build():
         metrics.TPU_DEVICE_DISPATCHES.inc()
-        if plan.lead_ordinals:
+        if plan.series_slots:
+            metrics.TILE_SERIES_GIDS.inc()
+        if plan.partitioned:
             metrics.TILE_ORDINAL_GIDS.inc()
     if _in_flow_maintenance():
         metrics.FLOW_DEVICE_DISPATCH_TOTAL.inc()
@@ -4261,6 +4331,18 @@ class TileExecutor:
 
     # -- public entry --------------------------------------------------------
     def execute(self, lowering, schema, time_bounds, ctx: TileContext):
+        ghost = _in_fused_build()
+        if not ghost:
+            _DECLINE.reason = None
+        out = self._execute(lowering, schema, time_bounds, ctx)
+        if out is None and not ghost:
+            # every statement the tile path gives back is counted, with
+            # the check that declined it
+            _DECLINE.reason = _DECLINE.reason or "the tile path does not apply"
+            metrics.TILE_DECLINED.inc()
+        return out
+
+    def _execute(self, lowering, schema, time_bounds, ctx: TileContext):
         t0 = time.perf_counter()
         # device-health reaction point: drop device planes when a
         # quarantine/heal moved the generation, and bail to the scan path
@@ -4271,7 +4353,7 @@ class TileExecutor:
         sup = device_health.SUPERVISOR
         if sup.enabled and sup.all_quarantined(len(self.cache.devices)):
             flight_recorder.flag_next("device_all_quarantined")
-            return None
+            return _decline("every device quarantined")
         fp = None
         bc = self.cache.batch_config
         batching = (
@@ -4877,24 +4959,24 @@ class TileExecutor:
             or any(f[0] == ts_name for f in scan.filters)
         )
         use_ts = ts_name if (needs_ts and ts_name) else None
-        # hierarchical layouts compose gids over a pk prefix: those tag
-        # codes must be tiled even when not grouped or filtered on
+        # a plan that groups by series reads each series' key codes off
+        # the region's series table, and a memtable tail's off its own
+        # rows: the series key is encoded, though no key plane is uploaded
         pk = [c.name for c in schema.tag_columns()]
-        layout_probe = _choose_layout(pk, tag_cols, lowering.bucket is not None)
+        series_key = _series_key(
+            pk, tag_cols, lowering.bucket is not None, _partitioned(ctx, pk)
+        )
         needs_last = any(f == "last_value" for f, _ in lowering.agg_specs)
-        if needs_last and (
-            (layout_probe is not None and set(tag_cols) != set(layout_probe))
-            or (lowering.bucket is not None and not tag_cols)
-        ):
-            # LAST states cannot fold away a pk axis (only permute) and
-            # have no time-major variant — bail BEFORE pinning/encoding
-            return None
-        extra_tag_cols = []
-        if layout_probe is not None:
-            extra_tag_cols = [
-                t for t in layout_probe
-                if t not in tag_cols and t not in filter_tag_cols
-            ]
+        if needs_last and series_key is not None and set(tag_cols) != set(series_key):
+            # LAST states cannot fold series together (only permute) —
+            # bail BEFORE pinning/encoding
+            return _decline("last_value over groups of several series")
+        if needs_last and lowering.bucket is not None and not tag_cols:
+            return _decline("last_value has no time-major variant")
+        extra_tag_cols = [
+            t for t in series_key or ()
+            if t not in tag_cols and t not in filter_tag_cols
+        ]
         all_tag_cols = tag_cols + filter_tag_cols + extra_tag_cols
 
         # 1. snapshot + safety gate, pinning every region until dispatch
@@ -4910,14 +4992,14 @@ class TileExecutor:
             # fieldwise (last_non_null) merging is not a per-row no-op even
             # over disjoint sources when the memtable holds partial-null
             # versions — the authoritative scan path owns this mode
-            return None
+            return _decline("last_non_null merge mode")
         pinned_regions: list[Region] = []
         with ctx.dictionary.table_lock:
             try:
                 return self._locked_execute(
                     lowering, schema, scan, ctx, time_bounds, pinned_regions,
                     ts_name, tag_names, tag_cols, all_tag_cols, value_cols, use_ts,
-                    layout_probe,
+                    series_key,
                 )
             finally:
                 for region in pinned_regions:
@@ -4926,7 +5008,7 @@ class TileExecutor:
     def _locked_execute(
         self, lowering, schema, scan, ctx, time_bounds, pinned_regions,
         ts_name, tag_names, tag_cols, all_tag_cols, value_cols, use_ts,
-        layout_probe,
+        series_key,
     ):
         # Eligibility is judged on the sources that INTERSECT the query's
         # time window: the super-tile spans every file, but rows outside
@@ -4960,7 +5042,8 @@ class TileExecutor:
                 if not in_window(*meta.time_range):
                     continue
                 if meta.num_deletes != 0:
-                    return None  # tombstones (or unknown) -> dedup needed
+                    # tombstones (or unknown) -> dedup needed
+                    return _decline("tombstones in the window")
                 file_ranges.append(meta.time_range)
             for mem in mems:
                 mem_table = mem.scan(None, dedup=not ctx.append_mode)
@@ -4981,7 +5064,7 @@ class TileExecutor:
                             pc.fill_null(pc.cast(op_rows[OP_COL], pa.int64()), 0)
                         ).as_py()
                     ):
-                        return None  # tombstones inside the window
+                        return _decline("tombstones in the window")
                     mem_table = mem_table.drop_columns([OP_COL])
                 if ts_name and ts_name in mem_table.column_names:
                     ts_i = pc.cast(mem_table[ts_name], pa.int64())
@@ -5005,18 +5088,19 @@ class TileExecutor:
                 # puts each pk in exactly one region.
                 if mem_ranges and not _disjoint(mem_ranges + file_ranges):
                     if not _disjoint(mem_ranges):
-                        return None
+                        return _decline("memtables overlap in time")
                     for mr in mem_ranges:
                         if any(
                             fr[1] >= mr[0] and fr[0] <= mr[1]
                             for fr in file_ranges
                         ):
-                            return None
+                            return _decline("a memtable overlaps the files in time")
                 if not _disjoint(file_ranges):
                     dedup_regions.add(region.region_id)
             region_sources.append((region, all_files, mem_tables))
         if not any(fs or ms for _r, fs, ms in region_sources):
-            return None  # empty table: let the normal path shape output
+            # empty table: let the normal path shape output
+            return _decline("empty table")
 
         # 2. phase A — every dictionary mutation happens BEFORE the plan
         # is built: memtable values first (cheap), then per-file host
@@ -5042,7 +5126,7 @@ class TileExecutor:
         time_major_probe = (
             lowering.bucket is not None
             and not lowering.group_tags
-            and layout_probe is None  # same probe _try_execute computed
+            and series_key is None  # same probe _try_execute computed
         )
         # agg-strategy probe runs BEFORE limb decisions: a hash plan
         # accumulates exact f64, so its value columns must keep their f64
@@ -5050,6 +5134,13 @@ class TileExecutor:
         agg_probe = self._choose_agg_strategy(
             lowering, schema, scan, ctx, tag_cols, time_bounds
         )
+        # a series plan reads its groups off the series tables: of the tag
+        # planes, only those its filters compare reach the device
+        if series_key is not None and agg_probe is None:
+            device_tag_cols = [t for t in all_tag_cols if t in filter_col_names]
+        else:
+            series_key = None
+            device_tag_cols = all_tag_cols
         limb_skip_upload: set[str] = set()
         if (
             self.config_acc_dtype() == "limb"
@@ -5121,7 +5212,7 @@ class TileExecutor:
                     m.num_rows for m in metas_i if in_window(*m.time_range)
                 )
                 per_row = 1 + (8 if use_ts else 0)
-                per_row += 4 * len(set(all_tag_cols))
+                per_row += 4 * len(set(device_tag_cols))
                 per_row += 8 * len(device_value_cols)
                 per_row += 8 * len(limb_est)
                 est_dev += padded_size(rows_i) * per_row
@@ -5182,7 +5273,7 @@ class TileExecutor:
                 # uploads — the cold-serve router below may answer from
                 # host and skip the (link-dominated) plane uploads
                 entry, excluded = self.cache.super_tiles(
-                    region, ctx.dictionary, metas, all_tag_cols,
+                    region, ctx.dictionary, metas, device_tag_cols,
                     ts_name or use_ts,
                     device_value_cols if big else value_cols,
                     pinned_ids, pk, device_upload=False,
@@ -5191,24 +5282,40 @@ class TileExecutor:
                 # queries whose window its rows could affect
                 for meta in excluded:
                     if in_window(*meta.time_range):
-                        return None
+                        return _decline("a file in the window cannot join the planes")
                 if entry is not None:
                     super_entries.append(entry)
                     slots.append(entry)
             for mt in mem_tables:
                 slots.append((region, mt))
         if not slots:
-            return None  # nothing in-window to aggregate on device
+            return _decline("nothing in the window")
 
-        # 3. the static plan (cards AFTER all dictionary updates) plus
+        # 3. phase B — dictionary is final for this query: repair stale
+        # device tiles with one gather; a series plan reads its series off
+        # the repaired sorted host codes
+        self.cache.repair_super(super_entries, ctx.dictionary, all_tag_cols)
+        series_tables: dict[int, SeriesTable] = {}
+        for entry in super_entries if series_key is not None else ():
+            # the table of the whole key (shared with the PromQL path); a
+            # prefix's series are runs of its series
+            table = self.cache.series_table(entry, ctx.dictionary, pk)
+            if table is None:
+                return _decline("series table: the sorted host planes are gone")
+            if len(series_key) < len(pk):
+                table = table.prefix(len(series_key))
+            series_tables[entry.region_id] = table
+
+        # 4. the static plan (cards AFTER all dictionary updates) plus
         # its runtime-dynamic parameters (filter literals, bucket
         # geometry) — changing a literal or window reuses the compile
         built = self._build_plan(
             lowering, schema, scan, ctx, tag_cols, time_bounds, use_ts,
             agg_probe=agg_probe,
+            n_series=max((len(t) for t in series_tables.values()), default=1),
         )
         if built is None:
-            return None
+            return _decline("no tile plan for the statement")
         plan, dyn_host, fspec = built
         if plan.agg_strategy == "hash":
             # the dense [G] space never materializes — only the slot
@@ -5218,13 +5325,16 @@ class TileExecutor:
             pass
         else:
             if plan.num_groups > self.config.max_groups * 64:
-                return None  # group space too large for dense [G] states
+                # group space too large for dense [G] states
+                return _decline(
+                    f"max_groups: {plan.num_groups} groups over "
+                    f"{self.config.max_groups * 64}"
+                )
             if plan.internal_groups > self.config.max_internal_groups:
-                return None
-
-        # 4. phase B — dictionary is final for this query: repair stale
-        # device tiles with one gather, build perms, encode memtail
-        self.cache.repair_super(super_entries, ctx.dictionary, all_tag_cols)
+                return _decline(
+                    f"max_internal_groups: {plan.internal_groups} stage-1 "
+                    f"groups over {self.config.max_internal_groups}"
+                )
 
         # 4.5 host fast path: a highly selective pk-equality query (TSBS
         # single-groupby / cpu-max-all / high-cpu-1 shapes) binary-searches
@@ -5263,7 +5373,7 @@ class TileExecutor:
                 # (the cpu-max-all-8 contention fix needs WARM planes)
                 manifest = PlaneManifest(
                     table_key=ctx.table_key,
-                    tag_cols=tuple(all_tag_cols),
+                    tag_cols=tuple(device_tag_cols),
                     ts_col=use_ts,
                     value_cols=tuple(value_cols),
                     limb_cols=tuple(self._limb_sum_cols(plan)),
@@ -5318,7 +5428,7 @@ class TileExecutor:
                     win_manifest = (int(window[0]), int(window[1]))
                 manifest = PlaneManifest(
                     table_key=ctx.table_key,
-                    tag_cols=tuple(all_tag_cols),
+                    tag_cols=tuple(device_tag_cols),
                     ts_col=use_ts,
                     value_cols=tuple(dict.fromkeys(
                         list(device_value_cols)
@@ -5365,6 +5475,7 @@ class TileExecutor:
             super_entries
             and plan.agg_strategy != "hash"  # hash partials thread the
             # key table; shape-only precompile doesn't model it
+            and not plan.series_slots  # nor a series plan's inputs
             and self.cache._tile_opt("pipelined_build", True)
             and passes.enabled("pipelined_build", self.config)
             # it compiles the single-chip partial program, which a mesh
@@ -5395,13 +5506,13 @@ class TileExecutor:
                 max(sum(m.num_rows for m in metas), 1)
             ) >= _LIMB_MIN_ROWS
             entry, _excluded = self.cache.super_tiles(
-                region, ctx.dictionary, metas, all_tag_cols,
+                region, ctx.dictionary, metas, device_tag_cols,
                 ts_name or use_ts,
                 device_value_cols if big else value_cols,
                 pinned_ids, pk,
             )
             if entry is None:
-                return None
+                return _decline("no planes for a region")
 
         device_sources = []
         limb_need = self._limb_sum_cols(plan)
@@ -5417,7 +5528,8 @@ class TileExecutor:
                             "keep plane unavailable: merge scan owns dedup"
                             if dp_enabled else "pass disabled",
                         )
-                        return None  # host planes evicted: scan path owns it
+                        # host planes evicted: scan path owns it
+                        return _decline("dedup keep plane unavailable")
                     passes.note(
                         "dedup_plane", True,
                         "overlapping-SST LWW dedup lowered to a device keep "
@@ -5451,6 +5563,20 @@ class TileExecutor:
                             "in-window rows gathered into a compact tile",
                             region=s.region_id, sources=len(wsrc),
                         )
+                        if plan.series_slots:
+                            # the tile's rows are the window's rows of the
+                            # sorted planes: its series open where theirs do
+                            held = series_tables[s.region_id]
+                            series = self._series_inputs(
+                                held, plan, [src[1] for src in wsrc],
+                                starts=self.cache.window_positions(
+                                    s, window, use_ts, dedup, held.starts
+                                ),
+                            )
+                            wsrc = [
+                                ({**cols, **extra}, *rest)
+                                for (cols, *rest), extra in zip(wsrc, series)
+                            ]
                         device_sources.extend(wsrc)
                         continue
                     passes.note(
@@ -5468,13 +5594,13 @@ class TileExecutor:
                         max(sum(m.num_rows for m in metas_d), 1)
                     ) >= _LIMB_MIN_ROWS
                     up, _excluded = self.cache.super_tiles(
-                        region_d, ctx.dictionary, metas_d, all_tag_cols,
+                        region_d, ctx.dictionary, metas_d, device_tag_cols,
                         ts_name or use_ts,
                         device_value_cols if big else value_cols,
                         pinned_ids, pk,
                     )
                     if up is None:
-                        return None
+                        return _decline("no planes for a region")
                     # a tag plane uploaded HERE (its device copy released by
                     # release_unneeded, or never made) comes from the
                     # persisted codes at their stored epoch, after phase
@@ -5486,7 +5612,7 @@ class TileExecutor:
                         # live object (and re-derive its dedup plane)
                         s = up
                         if dedup and not self.cache.ensure_dedup_keep(s):
-                            return None
+                            return _decline("dedup keep plane unavailable")
                 if s.nbytes - _window_tile_bytes(s) > self.cache.budget // 2:
                     # one-entry deployments: make room for THIS query's
                     # planes by dropping the entry's own unused columns
@@ -5519,13 +5645,17 @@ class TileExecutor:
                 if any(
                     c not in limbs and c not in s.cols for c in limb_need
                 ):
-                    return None
+                    return _decline("a limb column has no device plane")
+                series = (
+                    self._series_inputs(series_tables[s.region_id], plan, valid)
+                    if plan.series_slots else [{}] * len(valid)
+                )
                 # one jit source per chunk: bounded per-dispatch temporaries
                 # (see _SuperTiles.cols), merged on device like any source
                 for i in range(len(valid)):
                     device_sources.append(
                         (
-                            {k: v[i] for k, v in cols.items()},
+                            {**{k: v[i] for k, v in cols.items()}, **series[i]},
                             valid[i],
                             {k: v[i] for k, v in nulls.items()},
                             None,
@@ -5534,11 +5664,13 @@ class TileExecutor:
                     )
             else:
                 src = self._encode_mem(
-                    ctx.dictionary, s[1], all_tag_cols, use_ts, value_cols
+                    ctx.dictionary, s[1], all_tag_cols, use_ts, value_cols,
+                    series_plan=plan if plan.series_slots else None,
+                    series_key=series_key,
                 )
                 if src is None:
-                    return None
-                need_cols = self._plan_cols(plan)
+                    return _decline("the memtable tail cannot be encoded")
+                need_cols = self._plan_cols(plan) | set(SERIES_KEYS)
                 cols, valid, nulls = src
                 device_sources.append(
                     (
@@ -5569,6 +5701,11 @@ class TileExecutor:
             "bucket_origin": np.int64(dyn_host["bucket_origin"]),
             "bucket_interval": np.int64(dyn_host["bucket_interval"]),
             "having_values": tuple(dyn_host["having_values"]),
+            # the series stage 1 holds (a memtable tail's included)
+            "series": sum(len(t) for t in series_tables.values()) + sum(
+                int(np.sum(np.asarray(src[0][SERIES_GROUP]) >= 0))
+                for src in device_sources if SERIES_ROWS in src[0]
+            ),
         }
         ndev = len(self.cache.devices)
         placed = ndev > 1 and passes.enabled("chunk_placement", self.config)
@@ -5647,6 +5784,8 @@ class TileExecutor:
                     dyn_host, fspec,
                 ),
             )
+        if plan.series_slots and not _in_fused_build():
+            metrics.TILE_STAGE1_SERIES.inc(dyn["series"])
         for attempt_plan in attempts:
             program, int_layout, acc32_layout, acc64_layout, int_dtype = (
                 _tile_program_cached(attempt_plan, nullable_cols, fspec)
@@ -5721,7 +5860,7 @@ class TileExecutor:
                 return table
         # reachable only for a hash plan whose slot table overflowed AND
         # whose dense twin exceeds the [G] bounds: the scan path owns it
-        return None
+        return _decline("hash slots overflowed and the dense plan is too large")
 
     def _precompile_async(
         self, plan, fspec, entry, dyn_host, tag_like, ts_name, skip_f64,
@@ -5866,12 +6005,29 @@ class TileExecutor:
                     if mt[name].null_count:
                         null_present.add(name)
 
+        # a series plan sizes stage 1 before any region is built: by the
+        # series its key's dictionaries allow (a region of a table
+        # partitioned on its leading tag holds a subset of them)
+        series_key = _series_key(
+            pk, tag_cols, lowering.bucket is not None, _partitioned(ctx, pk)
+        )
+        n_series = 1
+        for t in series_key or ():
+            n_series *= max(ctx.dictionary.cardinality(t), 1)
         built = self._build_plan(
-            lowering, schema, scan, ctx, tag_cols, time_bounds, use_ts
+            lowering, schema, scan, ctx, tag_cols, time_bounds, use_ts,
+            n_series=n_series,
         )
         if built is None:
             return None
         plan, dyn_host, fspec = built
+        if plan.series_slots and plan.internal_groups > self.config.max_internal_groups:
+            passes.note(
+                "stream_spill", False,
+                "series plan: the key's dictionaries allow more series than "
+                "max_internal_groups; the all-at-once build counts them",
+            )
+            return None
         if tag_cols:
             passes.note(
                 "agg_strategy", False,
@@ -5903,6 +6059,7 @@ class TileExecutor:
         n_regions = sum(1 for _r, m, _t in region_sources if m)
         bail: dict = {}
         counted = False
+        stage1 = {"series": 0}
 
         def make_sources():
             prev: list = [None]
@@ -5956,9 +6113,23 @@ class TileExecutor:
                             k: v for k, v in entry.nulls.items()
                             if k in need_cols
                         }
+                        series = [{}] * len(entry.valid)
+                        if plan.series_slots:
+                            table = self.cache.series_table(
+                                entry, ctx.dictionary, pk
+                            )
+                            if table is not None and len(series_key) < len(pk):
+                                table = table.prefix(len(series_key))
+                            if table is None or len(table) > plan.series_slots:
+                                bail["why"] = "no series table"
+                                return
+                            series = self._series_inputs(
+                                table, plan, entry.valid
+                            )
+                            stage1["series"] += len(table)
                         for i in range(len(entry.valid)):
                             yield (
-                                {k: v[i] for k, v in cols.items()},
+                                {**{k: v[i] for k, v in cols.items()}, **series[i]},
                                 entry.valid[i],
                                 {k: v[i] for k, v in nulls.items()},
                                 None,
@@ -5976,13 +6147,22 @@ class TileExecutor:
                         src = self._encode_mem(
                             ctx.dictionary, mt, all_tag_cols, use_ts,
                             value_cols,
+                            series_plan=plan if plan.series_slots else None,
+                            series_key=series_key,
                         )
                         if src is None:
                             bail["why"] = "memtable encode failed"
                             return
                         mcols, mvalid, mnulls = src
+                        if SERIES_GROUP in mcols:
+                            stage1["series"] += int(
+                                np.sum(np.asarray(mcols[SERIES_GROUP]) >= 0)
+                            )
                         yield (
-                            {k: v for k, v in mcols.items() if k in need_cols},
+                            {
+                                k: v for k, v in mcols.items()
+                                if k in need_cols or k in SERIES_KEYS
+                            },
                             mvalid,
                             {k: v for k, v in mnulls.items() if k in need_cols},
                             None,
@@ -6041,6 +6221,8 @@ class TileExecutor:
                 if not _in_fused_build():
                     metrics.TILE_LOWERED_TOTAL.inc()
                     metrics.AGG_STRATEGY_TOTAL.inc(strategy="sort")
+                    if plan.series_slots:
+                        metrics.TILE_STAGE1_SERIES.inc(stage1["series"])
             table = self._finalize(
                 packed, int_layout, acc32_layout, acc64_layout, int_dtype,
                 attempt_plan, lowering, schema, ctx, dyn_host, fspec,
@@ -6163,9 +6345,11 @@ class TileExecutor:
 
     @staticmethod
     def _plan_cols(plan: DistGroupByPlan) -> set:
-        need = set(plan.group_tags) | {f[0] for f in plan.filters}
-        if plan.layout_tags:
-            need |= set(plan.layout_tags)
+        """The planes a dispatch of `plan` reads: a series plan reads no
+        group tag's (its groups come with its series)."""
+        need = {f[0] for f in plan.filters}
+        if not plan.series_slots:
+            need |= set(plan.group_tags)
         if plan.bucket_col:
             need.add(plan.bucket_col)
         if plan.ts_col:
@@ -6175,9 +6359,72 @@ class TileExecutor:
                 need.add(c)
         return need
 
-    def _encode_mem(self, dictionary, table, tag_cols, ts_col, value_cols):
+    @staticmethod
+    def _series_groups(codes: np.ndarray, tags, plan: DistGroupByPlan) -> np.ndarray:
+        """The group code [S] int32 of each series whose key codes are the
+        rows of `codes` (columns `tags`): `plan.group_tags`' codes composed
+        over `plan.tag_cards` as the gid composes them, -1 where a code is
+        out of range (its rows are out of every group, as a direct gid
+        finds them)."""
+        g = np.zeros(len(codes), np.int64)
+        ok = np.ones(len(codes), bool)
+        for tag, card in zip(plan.group_tags, plan.tag_cards):
+            c = codes[:, list(tags).index(tag)].astype(np.int64)
+            ok &= (c >= 0) & (c < card)
+            g = g * card + np.clip(c, 0, card - 1)
+        return np.where(ok, g, -1).astype(np.int32)
+
+    def _series_inputs(
+        self, table: SeriesTable, plan: DistGroupByPlan, valid, starts=None,
+    ) -> list:
+        """Per chunk of a region's planes (`valid`, the chunk list), the
+        inputs of a series plan's stage 1 (`compute_partial_states`):
+        SERIES_MARKS, the chunk row at which each of its series opens, and
+        SERIES_GROUP, each one's group code, both [plan.series_slots]
+        int32, padded past the chunk's series with its length / -1.  The
+        series' row bounds come with the request off the region's series
+        table: nothing is searched over the planes.  Made once per group
+        space and chunk geometry and kept, on the chunks' devices, in the
+        table's memo.  `starts`: where the series open in a window tile's
+        rows in place of the planes' (a series with no row in the window
+        opens where the next one does: it holds no row), made anew."""
+        sizes = tuple(int(v.shape[0]) for v in valid)
+        devices = tuple(next(iter(v.devices())) for v in valid)
+
+        def make():
+            groups = self._series_groups(table.codes, table.tags, plan)
+            n_series = len(table)
+            opens = table.starts if starts is None else starts
+            out, lo = [], 0
+            for i, n in enumerate(sizes):
+                k0 = max(int(np.searchsorted(opens[:n_series], lo, side="right")) - 1, 0)
+                k1 = int(np.searchsorted(opens[:n_series], lo + n, side="left"))
+                k1 = max(k1, k0)
+                marks = np.full(plan.series_slots, n, np.int32)
+                group = np.full(plan.series_slots, -1, np.int32)
+                marks[: k1 - k0] = np.maximum(opens[k0:k1] - lo, 0)
+                group[: k1 - k0] = groups[k0:k1]
+                out.append({
+                    SERIES_MARKS: jax.device_put(marks, devices[i]),
+                    SERIES_GROUP: jax.device_put(group, devices[i]),
+                })
+                lo += n
+            return out
+
+        if starts is not None:
+            return make()
+        key = ("sql", plan.group_tags, plan.tag_cards, plan.series_slots, sizes, devices)
+        return table.remember(key, make)
+
+    def _encode_mem(
+        self, dictionary, table, tag_cols, ts_col, value_cols,
+        series_plan: DistGroupByPlan | None = None, series_key=None,
+    ):
         """Encode the (small, fresh) memtable tail; same host encode as
-        file tiles (_encode_host_tiles) so the two can never diverge."""
+        file tiles (_encode_host_tiles) so the two can never diverge.
+        With `series_plan` its rows, in arrival order, carry their series
+        over `series_key` as SERIES_ROWS and the series' group codes as
+        SERIES_GROUP (see `compute_partial_states`)."""
         need = list(
             dict.fromkeys(tag_cols + ([ts_col] if ts_col else []) + value_cols)
         )
@@ -6200,6 +6447,15 @@ class TileExecutor:
             buf = np.zeros(pad, bool)
             buf[:n] = arr
             out_nulls[name] = jnp.asarray(buf)
+        if series_plan is not None:
+            keys = np.stack([cols[t] for t in series_key], axis=1)
+            uniq, inv = np.unique(keys, axis=0, return_inverse=True)
+            rows = np.zeros(pad, np.int32)
+            rows[:n] = inv.reshape(-1)
+            group = np.full(_quantize_card(len(uniq)), -1, np.int32)
+            group[: len(uniq)] = self._series_groups(uniq, series_key, series_plan)
+            out_cols[SERIES_ROWS] = jnp.asarray(rows)
+            out_cols[SERIES_GROUP] = jnp.asarray(group)
         v = np.zeros(pad, bool)
         v[:n] = True
         return (out_cols, jnp.asarray(v), out_nulls)
@@ -6346,15 +6602,16 @@ class TileExecutor:
         return None
 
     def _build_plan(self, lowering, schema, scan, ctx, tag_cols, time_bounds, use_ts,
-                    agg_probe=None):
+                    agg_probe=None, n_series=1):
         """Returns (plan, dyn_host): `plan` is the compile-static structure
         (filter literals replaced by placeholders, n_buckets quantized to a
         power of two) and `dyn_host` carries the runtime values — so
         dashboards that vary literals or time windows reuse one compile.
-        Also decides the LAYOUT strategy (direct / hierarchical /
-        time-major) from the primary-key order — see module docstring.
+        Also decides the LAYOUT strategy (direct / series / time-major)
+        from the primary-key order — see module docstring; a series plan
+        holds `n_series`, the most series of one region, padded.
         `agg_probe` (a _choose_agg_strategy result) switches the plan to
-        the hash group-by: no layout fold, no time-major, exact f64
+        the hash group-by: no series fold, no time-major, exact f64
         accumulation, slot table re-sized from the now-final dictionary."""
         d = ctx.dictionary
         bucket_col, interval_native, origin, n_buckets_real, n_buckets = (
@@ -6381,7 +6638,7 @@ class TileExecutor:
             if name in tag_names:
                 f = _encode_tag_filter(d, name, op, value)
                 if f is None:
-                    return None
+                    return _decline("a tag filter has no code-space form")
                 for fname, fop, fval in f:
                     push(fname, fop, fval, np.int32)
             else:
@@ -6404,12 +6661,12 @@ class TileExecutor:
                 if op in ("in", "not in"):
                     vals = [_coerce(v) for v in value]
                     if any(v is None for v in vals):
-                        return None
+                        return _decline("a value filter literal is not numeric")
                     value = tuple(vals)
                 else:
                     value = _coerce(value)
                     if value is None:
-                        return None
+                        return _decline("a value filter literal is not numeric")
                 dtype = np.int64 if name == ts_name else np.float64
                 push(name, op, value, dtype)
         if scan.time_range is not None and use_ts:
@@ -6427,14 +6684,16 @@ class TileExecutor:
         # layout strategy
         pk = [c.name for c in schema.tag_columns()]
         is_hash = agg_probe is not None and agg_probe.get("strategy") == "hash"
-        layout_tags = (
-            None if is_hash else _choose_layout(pk, tag_cols, bucket_col is not None)
+        partitioned = _partitioned(ctx, pk)
+        series_key = (
+            None if is_hash
+            else _series_key(pk, tag_cols, bucket_col is not None, partitioned)
         )
         time_major = (
             not is_hash
             and bucket_col is not None
             and not tag_cols
-            and layout_tags is None
+            and series_key is None
             and passes.enabled("time_major", self.config)
         )
         if time_major:
@@ -6448,13 +6707,14 @@ class TileExecutor:
                 "time-major disabled or layout claims the sort order",
             )
         if (
-            layout_tags is not None
+            series_key is not None
             and needs_ts_order
-            and set(tag_cols) != set(layout_tags)
+            and set(tag_cols) != set(series_key)
         ):
-            return None  # LAST states only permute, never fold away an axis
+            # LAST states only permute, never fold series together
+            return _decline("last_value over groups of several series")
         if time_major and needs_ts_order:
-            return None
+            return _decline("last_value has no time-major variant")
 
         filter_null_cols = tuple(
             sorted(
@@ -6476,9 +6736,12 @@ class TileExecutor:
         # the cap the runtime guard fails and the scatter path (always
         # correct) takes over.
         est_rows = sum(r.approx_rows() for r in ctx.regions)
-        gid_tags = layout_tags if layout_tags is not None else tag_cols
+        series_slots = _quantize_card(n_series) if series_key is not None else 0
         real_groups = max(n_buckets, 1)
-        for t in gid_tags:
+        if series_key is not None:
+            # a region's rows over its series: the regions split the rows
+            real_groups *= max(n_series, 1) * max(len(ctx.regions), 1)
+        for t in tag_cols if series_key is None else ():
             real_groups *= max(d.cardinality(t), 1)
         if time_major:
             # window rows spread over n_buckets; out-of-window rows are
@@ -6493,19 +6756,6 @@ class TileExecutor:
         block_span = 16
         while block_span < min(span_est, 128):
             block_span <<= 1
-        # a region of a table partitioned on its leading sort tag holds
-        # every n-th code of that tag or so: the span above holds for
-        # consecutive SERIES, so the gid's leading component is the
-        # source's own series ordinal there (DistGroupByPlan.lead_ordinals)
-        lead_ordinals = bool(
-            not is_hash
-            and not time_major
-            and gid_tags
-            and gid_tags[0] == pk[0]
-            and len(ctx.regions) > 1
-            and pk[0] in ctx.partition_columns
-        )
-
         acc_dtype = self.config_acc_dtype()
         hash_slots = 0
         if is_hash:
@@ -6521,7 +6771,8 @@ class TileExecutor:
                 d_prod *= card
                 g_final *= _quantize_card(card)
             if g_final >= _HASH_GID_LIMIT:
-                return None  # int64 gids would wrap: scan path owns it
+                # int64 gids would wrap: scan path owns it
+                return _decline("hash gid range past int64")
             d_est = min(max(est_rows, 1), d_prod * max(n_buckets_real, 1))
             hash_slots = self._size_hash_slots(d_est)
             # hash accumulates exact f64: slot ids defeat both the limb
@@ -6541,15 +6792,12 @@ class TileExecutor:
             acc_dtype=acc_dtype,
             ts_col=use_ts if needs_ts_order else None,
             filter_null_cols=filter_null_cols,
-            layout_tags=None if layout_tags is None else tuple(layout_tags),
-            layout_cards=()
-            if layout_tags is None
-            else tuple(_quantize_card(d.cardinality(t)) for t in layout_tags),
+            series_slots=series_slots,
+            partitioned=partitioned and series_key is not None,
             time_major=time_major,
             block_span=block_span,
             agg_strategy="hash" if is_hash else "sort",
             hash_slots=hash_slots,
-            lead_ordinals=lead_ordinals,
         )
         dyn_host = {
             "filter_values": filter_vals,
@@ -7727,8 +7975,12 @@ class TileExecutor:
             )
         traces0 = _MEGA_STATS["traces"]
         metrics.TPU_DEVICE_DISPATCHES.inc()
-        if any(cd.key[0].lead_ordinals for cd in cds):
+        if any(cd.key[0].series_slots for cd in cds):
+            metrics.TILE_SERIES_GIDS.inc()
+        if any(cd.key[0].partitioned for cd in cds):
             metrics.TILE_ORDINAL_GIDS.inc()
+        for cd in cds:
+            metrics.TILE_STAGE1_SERIES.inc(cd.dyn.get("series", 0))
         with tracing.span("tile.fused_dispatch", members=len(cds)) as disp:
             packed_all = device_health.supervised_call(
                 "dispatch", lambda: fused(tuple(inputs))
@@ -7815,8 +8067,10 @@ class TileExecutor:
             g = plan.hash_slots
         else:
             g = plan.num_groups
+        # the words' bytes (little-endian on host and device alike)
+        buf = np.ascontiguousarray(buf).view(np.uint8)
         bit_packed = int_dtype == jnp.uint8
-        int_row = -(-g // 8) if bit_packed else g
+        int_row = -(-g // 32) * 4 if bit_packed else g
         ni = len(int_layout)
         off = ni * int_row * (1 if bit_packed else 4)
         ints = np.frombuffer(
@@ -8042,28 +8296,43 @@ def _np_filter(mask: np.ndarray, col: np.ndarray, op: str, val) -> np.ndarray:
     return np.zeros_like(mask)
 
 
-def _choose_layout(
-    pk: list[str], group_tags: list[str], has_bucket: bool
-) -> list[str] | None:
-    """Pick the hierarchical gid composition, or None when the requested
-    groups already follow the storage sort order (direct layout) or when a
-    time-major permutation serves better (bucket-only group-by).
+def _partitioned(ctx, pk: list[str]) -> bool:
+    """Whether the table's regions split it on its leading key tag, so
+    that a region holds every n-th code of that tag or so."""
+    return bool(pk) and len(ctx.regions) > 1 and pk[0] in ctx.partition_columns
 
-    Sources are sorted by (pk..., ts); a gid composed over a pk PREFIX in
-    pk order (+ bucket last, which follows ts) is non-decreasing per
-    source, which is what the blocked kernel wants."""
+
+def _series_key(
+    pk: list[str], group_tags: list[str], has_bucket: bool, partitioned: bool,
+) -> list[str] | None:
+    """The key whose series stage 1 groups by (`DistGroupByPlan.
+    series_slots`), or None where the gid is composed over the requested
+    tags' own codes.  Series are runs of the (pk, ts) sorted rows
+    whatever the key's width; groups are runs only where the requested
+    tags are the key's leading columns in key order.  Elsewhere — a
+    bucketed group-by on a strict subset of the key or on the key in
+    another order (the whole key, since the bucket follows ts), an
+    unbucketed one off the key's prefix (the shortest prefix that holds
+    the tags) — the gid is (series, bucket) and the state folds down to
+    the groups on device.  Where the groups are runs but the table is
+    `partitioned` on the key's leading tag, a region's codes of that tag
+    lie apart and its blocks' gids past the blocked kernels' span: stage
+    1 groups by the series of the requested tags themselves.  A
+    bucket-only group-by goes time-major, a scalar one is one group."""
     if not all(t in pk for t in group_tags):
         return None  # non-pk group tag: no layout claim (scatter handles)
     if has_bucket:
         if not group_tags:
             return None  # bucket-only: time-major path instead
         if list(group_tags) == pk:
-            return None  # direct: (full pk, bucket) rides the sort
-        return pk  # aggregate at (full pk, bucket), fold down
+            # direct: (full pk, bucket) rides the sort
+            return pk if partitioned else None
+        return pk  # aggregate at (series, bucket), fold down
     if not group_tags:
         return None  # scalar aggregate: single group
     if list(group_tags) == pk[: len(group_tags)]:
-        return None  # direct: pk prefix in pk order
+        # direct: pk prefix in pk order
+        return list(group_tags) if partitioned else None
     j = 1 + max(pk.index(t) for t in group_tags)
     return pk[:j]
 

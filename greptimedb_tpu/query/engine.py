@@ -174,6 +174,12 @@ class QueryEngine:
                     if table is not None:
                         return table
                     backend = "cpu"
+                    if not self.config.legacy_fallback:
+                        from .promql.tile_exec import LegacyFallbackDisabled
+
+                        raise LegacyFallbackDisabled(
+                            tpu.declined, "query.legacy_fallback"
+                        )
                 if (
                     lowering is not None
                     and self._partial_agg is not None

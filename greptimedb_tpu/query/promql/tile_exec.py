@@ -136,12 +136,13 @@ class _Ineligible(Exception):
 
 
 class LegacyFallbackDisabled(UnsupportedError):
-    """`tql.legacy_fallback = false` and the statement has no answer from
-    the device: the message names what sent it towards the legacy scan."""
+    """`tql.legacy_fallback = false` (a SQL statement: `query.legacy_fallback`)
+    and the statement has no answer from the device: the message names
+    what sent it towards the legacy scan."""
 
-    def __init__(self, reason: str):
+    def __init__(self, reason: str, setting: str = "tql.legacy_fallback"):
         super().__init__(
-            f"tql.legacy_fallback = false: no answer from the device tiles ({reason})"
+            f"{setting} = false: no answer from the device tiles ({reason})"
         )
 
 

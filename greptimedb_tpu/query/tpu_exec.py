@@ -188,14 +188,18 @@ class TpuExecutor:
         self.acc_dtype = acc_dtype
         self.tile_executor = tile_executor
         self.tile_context_provider = tile_context_provider
+        # why the last `try_tile` found no answer on the tile path
+        self.declined: str | None = None
 
     def try_tile(self, lowering: Lowering, schema: Schema, time_bounds) -> pa.Table | None:
         """HBM super-tile path only: the standalone hot path.  Returns the
         finished result table, or None when the tile executor doesn't
-        apply (caller then weighs dist-state shipping vs the mesh path)."""
+        apply (caller then weighs dist-state shipping vs the mesh path);
+        `self.declined` then says why."""
         from .analyze import stage
 
         scan = lowering.scan
+        self.declined = "no tile context for the table"
         if self.tile_executor is None or self.tile_context_provider is None:
             return None
         ctx = self.tile_context_provider(scan)
@@ -218,6 +222,14 @@ class TpuExecutor:
                 lowering, schema, lambda: time_bounds(), ctx
             )
             info["hit"] = table is not None
+            if table is None:
+                from ..parallel.tile_cache import last_decline
+                from ..utils import tracing
+
+                self.declined = last_decline()
+                root = tracing.current_span()
+                if root is not None:
+                    root.attributes["declined"] = self.declined
             if (
                 table is not None
                 and rbl is not None

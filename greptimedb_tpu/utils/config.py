@@ -147,9 +147,10 @@ class QueryConfig:
     backend: str = "tpu"
     tile_rows: int = 1 << 20
     max_groups: int = 1 << 16
-    # stage-1 group-space cap for hierarchical (pk x bucket) aggregation
-    # (ops/aggregate.py reduce_state_axes); dense [G] states at 8 bytes make
-    # 2^24 = 128 MB per tracked aggregate — fine in HBM, folded before fetch
+    # stage-1 group-space cap: a series plan's padded series x buckets
+    # (ops/aggregate.py fold_series_states) and the hash strategy's slot
+    # table; dense [G] states at 8 bytes make 2^24 = 128 MB per tracked
+    # aggregate — fine in HBM, folded before fetch
     max_internal_groups: int = 1 << 24
     # Cost-based backend routing: lowerable plans whose post-prune row
     # estimate falls below this stay on the host CPU path — a device
@@ -244,6 +245,11 @@ class QueryConfig:
     # to this percentile of recent latencies (hedge_delay_ms stays the
     # floor) — the "hedge after the p95" recipe.
     hedge_percentile: float = 0.95
+    # False: a SQL statement the tile path declines fails, naming the
+    # check that declined it, where the legacy TpuExecutor scan would
+    # answer it with no sign (the SQL twin of `tql.legacy_fallback`;
+    # every decline moves greptime_tile_declined_total either way).
+    legacy_fallback: bool = True
 
 
 @dataclasses.dataclass

@@ -266,7 +266,10 @@ TILE_PERSIST_WRITES = REGISTRY.counter("greptime_tile_persist_writes_total", "Su
 TILE_WINDOW_BUILDS = REGISTRY.counter("greptime_tile_window_builds_total", "Compact window tiles gathered from sorted encodes")
 TILE_WINDOW_COUNTED = REGISTRY.counter("greptime_tile_window_counted_total", "Window-tile probes whose row count came from the run bounds of the sorted ts plane (less the builds: probes that declined without touching the plane)")
 TILE_WINDOW_RESIDENT_SCANS = REGISTRY.counter("greptime_tile_window_resident_scans_total", "Counted window-tile probes that declined because the region's full planes were on the device and their masked scan was cheaper than the tile's host build")
-TILE_ORDINAL_GIDS = REGISTRY.counter("greptime_tile_ordinal_gids_total", "Tile dispatches whose plan groups by the source's own series ordinals in place of the leading tag's table-wide codes (a region of a table partitioned on that tag)")
+TILE_ORDINAL_GIDS = REGISTRY.counter("greptime_tile_ordinal_gids_total", "Tile dispatches whose plan groups by the source's own series ordinals in place of the leading tag's table-wide codes (a series plan over the regions of a table partitioned on that tag)")
+TILE_SERIES_GIDS = REGISTRY.counter("greptime_tile_series_gids_total", "Tile dispatches whose stage 1 groups by the series ordinals of a multi-tag key (x bucket), folded to the requested groups on the device")
+TILE_STAGE1_SERIES = REGISTRY.counter("greptime_tile_stage1_series_total", "Series that the stage 1 of a series-grouped SQL statement holds, before padding, once a statement")
+TILE_DECLINED = REGISTRY.counter("greptime_tile_declined_total", "SQL statements the tile path gave back to the engine (the reason is the query.tpu span's `declined`)")
 TILE_HOST_FAST_PATH = REGISTRY.counter("greptime_tile_host_fast_path_total", "Selective queries served from the sorted host encode cache")
 TILE_STREAM_QUERIES = REGISTRY.counter("greptime_tile_stream_total", "Queries whose working set exceeded the HBM budget, executed region-streamed")
 TILE_DELTA_MERGES = REGISTRY.counter(

@@ -32,7 +32,7 @@ SHAPES = ("double-groupby-1", "lastpoint", "groupby-orderby-limit")
 MESH_COUNTERS = (
     "TILE_MESH_DISPATCHES", "TILE_MESH_INELIGIBLE", "TILE_MESH_DEGRADED",
     "TPU_DEVICE_DISPATCHES", "TILE_WINDOW_BUILDS", "TILE_WINDOW_COUNTED", "TILE_WINDOW_RESIDENT_SCANS",
-    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TILE_ORDINAL_GIDS",
+    "TPU_FALLBACK_TOTAL", "TPU_ROUTED_TO_CPU", "TILE_SERIES_GIDS", "TILE_ORDINAL_GIDS",
     "TPU_COMPILE_CACHE_HITS", "TPU_COMPILE_CACHE_MISSES",
 )
 
@@ -152,8 +152,9 @@ def test_planes_not_on_the_device_at_the_decision_still_get_their_window_tile(fl
         for rid, entry in cache._super.items():
             base = region_device_index(rid, CHIPS)
             assert len(entry.window_tiles) == (1 if fleet.half else 0)
-            # a region served by its tile never uploads its planes
-            assert ("hostname" in entry.cols) == (not fleet.half)
+            # a region served by its tile never uploads its planes (the
+            # groups come off its series table: `ts` is a plane it reads)
+            assert ("ts" in entry.cols) == (not fleet.half)
             for wt in entry.window_tiles.values():
                 assert wt["rows"] == entry.num_rows // 2 and len(wt["valid"]) == 3
                 for chunks in (wt["valid"], *wt["cols"].values()):
@@ -302,21 +303,34 @@ def test_one_region_tables_on_the_same_chip_share_one_chips_budget(tmp_path):
 def test_a_tag_plane_uploaded_again_after_a_release_is_at_the_dictionary_epoch(fleet):
     """Where an entry's planes pass half a chip's budget, a query drops the
     columns it does not read (`release_unneeded`): a `groupby-orderby-limit`
-    drops `hostname`, and the next `lastpoint` uploads it again from the
-    persisted codes, at their stored epoch, after the query's repair pass has
-    run.  It has to be gathered forward there: four regions grew the
-    dictionary in turn, so every region's stored epoch is stale."""
+    drops `hostname`, and the next `lastpoint` that compares `hostname`
+    uploads it again from the persisted codes, at their stored epoch, after
+    the query's repair pass has run (its groups come off the series tables;
+    the plane is its filter's).  It has to be gathered forward there: four
+    regions grew the dictionary in turn, so every region's stored epoch is
+    stale."""
     for shape in SHAPES:
         fleet.warm(shape)
+    # a range no partition rule prunes: every region reads the plane
+    sql = fleet.sql("lastpoint").replace(" GROUP BY", " WHERE hostname >= 'host_1' GROUP BY")
+    fleet.db.config.query.backend = "cpu"
+    try:
+        want = fleet.db.sql_one(sql)
+    finally:
+        fleet.db.config.query.backend = "tpu"
+    assert 0 < want.num_rows < HOSTS
+    fleet.db.sql_one(sql)
+    program.wait_builds(fleet.db)
     cache = fleet.db.query_engine.tile_cache
     for _ in range(2):
         for entry in list(cache._super.values()):
             cache.release_unneeded(entry, {"ts", "usage_user"})
             assert "hostname" not in entry.cols
-        table, moved = fleet.ask("lastpoint")
+        before = {k: getattr(metrics, k).total() for k in MESH_COUNTERS}
+        table = fleet.db.sql_one(sql)
+        moved = {k: getattr(metrics, k).total() - before[k] for k in MESH_COUNTERS}
         assert moved["TILE_MESH_DISPATCHES"] == 1 and not moved["TILE_MESH_DEGRADED"], moved
-        keys_ok, gap = fleet.gap("lastpoint", table)
-        assert keys_ok and gap <= 1e-9
+        assert table.sort_by("hostname").to_pydict() == want.sort_by("hostname").to_pydict()
         assert all("hostname" in e.cols for e in cache._super.values())
 
 
@@ -346,7 +360,7 @@ def rows_close(a, b, rtol):
             assert a[name] == b[name], name
 
 
-# a dispatch of the shape groups by ordinals: its gid leads with `hostname`
+# a dispatch of the shape groups by its regions' series: its gid leads with `hostname`
 ORDINAL = {"double-groupby-1": 1, "lastpoint": 1, "high-cpu-1": 0, "groupby-orderby-limit": 0}
 
 
@@ -358,13 +372,14 @@ def test_a_hash_partitioned_table_answers_as_the_one_region_table_by_ordinals(
     fleet.warm(shape)
     one_region.warm(shape)
     want, moved = one_region.ask(shape)
-    assert moved["TILE_ORDINAL_GIDS"] == 0, "one region holds the whole dictionary"
+    assert moved["TILE_SERIES_GIDS"] == moved["TILE_ORDINAL_GIDS"] == 0, "one region holds the whole dictionary"
     assert not moved["TPU_FALLBACK_TOTAL"] and not moved["TPU_ROUTED_TO_CPU"], moved
     fleet.db.config.tile.mesh_devices = mesh_devices
     try:
         for _ in range(2):
             got, moved = fleet.ask(shape)
-            assert moved["TILE_ORDINAL_GIDS"] == ORDINAL[shape] * moved["TPU_DEVICE_DISPATCHES"], moved
+            assert moved["TILE_SERIES_GIDS"] == ORDINAL[shape] * moved["TPU_DEVICE_DISPATCHES"], moved
+            assert moved["TILE_ORDINAL_GIDS"] == moved["TILE_SERIES_GIDS"], moved
             assert moved["TILE_MESH_DISPATCHES"] == (moved["TPU_DEVICE_DISPATCHES"] if mesh_devices else 0)
             assert not moved["TPU_FALLBACK_TOTAL"] and not moved["TPU_ROUTED_TO_CPU"], moved
             assert not moved["TILE_MESH_DEGRADED"] and not moved["TILE_MESH_INELIGIBLE"], moved
@@ -397,22 +412,25 @@ def plans_of(f, shape, monkeypatch):
 @pytest.mark.parametrize("shape", SHAPES)
 def test_a_one_region_table_keeps_the_parents_plan_and_program(one_region, shape, monkeypatch):
     seen, moved = plans_of(one_region, shape, monkeypatch)
-    assert seen and not any(p.lead_ordinals for p in seen)
+    assert seen and not any(p.series_slots for p in seen)
     # the field at its default is the parent's plan: the same cache key,
     # so the same compiled program, found again by the second request
-    parents = {f.name for f in dataclasses.fields(seen[-1])} - {"lead_ordinals"}
+    parents = {f.name for f in dataclasses.fields(seen[-1])} - {"series_slots", "partitioned"}
     twin = type(seen[-1])(**{k: getattr(seen[-1], k) for k in parents})
     assert twin == seen[-1] and hash(twin) == hash(seen[-1])
     assert moved["TPU_COMPILE_CACHE_HITS"] >= 1 and moved["TPU_COMPILE_CACHE_MISSES"] == 0, moved
-    assert moved["TILE_ORDINAL_GIDS"] == 0 and moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+    assert moved["TILE_SERIES_GIDS"] == moved["TILE_ORDINAL_GIDS"] == 0, moved
+    assert moved["TPU_DEVICE_DISPATCHES"] == 1, moved
 
 
 @pytest.mark.parametrize("shape", SHAPES)
 def test_a_region_of_a_table_partitioned_on_its_leading_tag_plans_ordinals(fleet, shape, monkeypatch):
     seen, moved = plans_of(fleet, shape, monkeypatch)
-    assert seen and {p.lead_ordinals for p in seen} == {bool(ORDINAL[shape])}
+    assert seen and {bool(p.series_slots) for p in seen} == {bool(ORDINAL[shape])}
+    assert {p.partitioned for p in seen} == {bool(ORDINAL[shape])}
     assert all(p.time_major for p in seen) == (shape == "groupby-orderby-limit")
-    assert moved["TILE_ORDINAL_GIDS"] == ORDINAL[shape] and moved["TPU_DEVICE_DISPATCHES"] == 1, moved
+    assert moved["TILE_SERIES_GIDS"] == moved["TILE_ORDINAL_GIDS"] == ORDINAL[shape], moved
+    assert moved["TPU_DEVICE_DISPATCHES"] == 1, moved
     assert moved["TPU_COMPILE_CACHE_MISSES"] == 0, moved
 
 
@@ -439,12 +457,12 @@ def answers(hosts) -> dict:
 
 
 def grouped(db, name):
-    moved = metrics.TILE_ORDINAL_GIDS.total(), metrics.TPU_DEVICE_DISPATCHES.total()
+    moved = metrics.TILE_SERIES_GIDS.total(), metrics.TPU_DEVICE_DISPATCHES.total()
     out = db.sql_one(
         f"SELECT host, max(v) AS hi, count(v) AS n, last_value(v) AS last FROM {name} GROUP BY host ORDER BY host"
     )
     program.wait_builds(db)
-    moved = metrics.TILE_ORDINAL_GIDS.total() - moved[0], metrics.TPU_DEVICE_DISPATCHES.total() - moved[1]
+    moved = metrics.TILE_SERIES_GIDS.total() - moved[0], metrics.TPU_DEVICE_DISPATCHES.total() - moved[1]
     return {h: (hi, n, last) for h, hi, n, last in zip(*out.to_pydict().values())}, moved
 
 
@@ -452,7 +470,7 @@ def grouped(db, name):
 def test_a_dictionary_grown_after_the_planes_were_built_is_repaired_before_the_ordinals(tmp_path, mesh_devices):
     """New hosts that sort before, between and after the old ones move every
     code; the regions' planes are gathered forward to the new epoch, and the
-    ordinals of a region's runs are carried back to the NEW codes."""
+    series of a region's runs fold to the NEW codes."""
     db = program.open_database(
         str(tmp_path), {"query.fallback_to_cpu": False, "tile.mesh_devices": mesh_devices}
     )

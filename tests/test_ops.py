@@ -474,53 +474,76 @@ def test_segment_aggregate_blocked_last(layout):
     np.testing.assert_allclose(np.asarray(state.last_val)[nz], last_val[nz])
 
 
-def test_reduce_state_axes_fold_and_permute():
-    """Hierarchical stage 2: folding a [a, b, bucket] state down to
-    (b, bucket), (bucket,), and the pk-order-violating (b, a) must match
-    numpy reshape-reduce."""
-    from greptimedb_tpu.ops.aggregate import AggState, reduce_state_axes
+def test_fold_series_states_folds_and_places():
+    """Stage 2 of a series plan: a [S x bucket] state folded through its
+    series -> group table must match numpy's per-group reductions, the
+    padding slots (-1) dropped; LAST states of a one-to-one table are
+    placed, each series in its group's row."""
+    from greptimedb_tpu.ops.aggregate import AggState, fold_series_states
 
     rng = np.random.default_rng(5)
-    cards = (4, 3, 5)
-    g = 4 * 3 * 5
-    sums = rng.normal(size=g)
-    counts = rng.integers(0, 9, g).astype(np.int64)
-    mins = rng.normal(size=g)
-    maxs = rng.normal(size=g)
+    s, b, g = 12, 5, 4
+    group = np.array([0, 2, 2, 1, 3, 0, 2, -1, 1, 3, -1, 0], np.int32)
+    sums = rng.normal(size=s * b)
+    counts = rng.integers(0, 9, s * b).astype(np.int64)
+    mins = rng.normal(size=s * b)
+    maxs = rng.normal(size=s * b)
     st = AggState(
         sums=jnp.asarray(sums), counts=jnp.asarray(counts),
         mins=jnp.asarray(mins), maxs=jnp.asarray(maxs),
     )
-    cube = lambda a: a.reshape(cards)
-
-    out = reduce_state_axes(st, cards, keep_axes=(1, 2))  # drop axis 0
-    np.testing.assert_allclose(np.asarray(out.sums), cube(sums).sum(0).reshape(-1))
-    np.testing.assert_array_equal(np.asarray(out.counts), cube(counts).sum(0).reshape(-1))
-    np.testing.assert_allclose(np.asarray(out.mins), cube(mins).min(0).reshape(-1))
-    np.testing.assert_allclose(np.asarray(out.maxs), cube(maxs).max(0).reshape(-1))
-
-    out = reduce_state_axes(st, cards, keep_axes=(2,))  # bucket only
-    np.testing.assert_allclose(np.asarray(out.sums), cube(sums).sum((0, 1)).reshape(-1))
-
-    out = reduce_state_axes(st, cards, keep_axes=(1, 0))  # permuted, drop bucket
-    np.testing.assert_allclose(
-        np.asarray(out.sums), cube(sums).sum(2).transpose(1, 0).reshape(-1)
+    out = fold_series_states(st, jnp.asarray(group), g, b)
+    for k in range(g):
+        rows = group == k
+        np.testing.assert_allclose(
+            np.asarray(out.sums).reshape(g, b)[k], sums.reshape(s, b)[rows].sum(0)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(out.counts).reshape(g, b)[k], counts.reshape(s, b)[rows].sum(0)
+        )
+        np.testing.assert_allclose(
+            np.asarray(out.mins).reshape(g, b)[k], mins.reshape(s, b)[rows].min(0)
+        )
+        np.testing.assert_allclose(
+            np.asarray(out.maxs).reshape(g, b)[k], maxs.reshape(s, b)[rows].max(0)
+        )
+    # a permutation: series k is group perm[k]; two padding slots
+    perm = np.array([3, 0, 2, 1, -1, -1], np.int32)
+    last_ts = rng.integers(0, 1000, 6 * b).astype(np.int64)
+    last_val = rng.normal(size=6 * b)
+    out = fold_series_states(
+        AggState(last_ts=jnp.asarray(last_ts), last_val=jnp.asarray(last_val)),
+        jnp.asarray(perm), g, b,
     )
-
-    identity = reduce_state_axes(st, cards, keep_axes=(0, 1, 2))
-    np.testing.assert_allclose(np.asarray(identity.sums), sums)
+    for k, grp in enumerate(perm[:4]):
+        np.testing.assert_array_equal(
+            np.asarray(out.last_ts).reshape(g, b)[grp], last_ts.reshape(6, b)[k]
+        )
+        np.testing.assert_array_equal(
+            np.asarray(out.last_val).reshape(g, b)[grp], last_val.reshape(6, b)[k]
+        )
 
 
 def test_compute_partial_states_hierarchical_matches_direct():
-    """A plan grouped by a non-prefix pk subset (layout over the full pk)
-    must produce the same states as the direct plan over shuffled data."""
-    from greptimedb_tpu.parallel.executor import DistGroupByPlan, compute_partial_states
+    """A plan grouped by a non-prefix pk subset (stage 1 by the sorted
+    rows' series over the full pk, folded down) must produce the same
+    states as the direct plan, from the series' row bounds (planes) and
+    from each row's series (a memtable tail) alike."""
+    from greptimedb_tpu.parallel.executor import (
+        SERIES_GROUP,
+        SERIES_MARKS,
+        SERIES_ROWS,
+        DistGroupByPlan,
+        compute_partial_states,
+    )
 
     rng = np.random.default_rng(3)
     n = 4096
     a = rng.integers(0, 4, n).astype(np.int32)
     b = rng.integers(0, 8, n).astype(np.int32)
     ts = rng.integers(0, 16_000, n).astype(np.int64)
+    order = np.lexsort((ts, b, a))  # (pk, ts) sorted, as the planes are
+    a, b, ts = a[order], b[order], ts[order]
     v = rng.normal(10, 2, n)
     cols = {
         "a": jnp.asarray(a), "b": jnp.asarray(b),
@@ -532,20 +555,31 @@ def test_compute_partial_states_hierarchical_matches_direct():
         agg_specs=(("avg", "v"), ("max", "v")), acc_dtype="float64",
     )
     direct = DistGroupByPlan(group_tags=("b",), tag_cards=(8,), **common)
-    hier = DistGroupByPlan(
-        group_tags=("b",), tag_cards=(8,),
-        layout_tags=("a", "b"), layout_cards=(4, 8), **common,
+    by_series = DistGroupByPlan(
+        group_tags=("b",), tag_cards=(8,), series_slots=32, **common,
     )
+    key = a * 8 + b
+    starts = np.flatnonzero(np.diff(key, prepend=-1))
+    marks = np.full(32, n, np.int32)
+    marks[: len(starts)] = starts
+    group = np.full(32, -1, np.int32)
+    group[: len(starts)] = b[starts]
+    planes = {**cols, SERIES_MARKS: jnp.asarray(marks), SERIES_GROUP: jnp.asarray(group)}
+    rows = {
+        **cols, SERIES_ROWS: jnp.asarray(np.cumsum(np.diff(key, prepend=-1) != 0) - 1),
+        SERIES_GROUP: jnp.asarray(group),
+    }
     s1 = compute_partial_states(direct, cols, valid, {})
-    s2 = compute_partial_states(hier, cols, valid, {})
-    for k in s1:
-        if s1[k].sums is not None:
-            np.testing.assert_allclose(
-                np.asarray(s1[k].sums), np.asarray(s2[k].sums), rtol=1e-12
-            )
-        np.testing.assert_array_equal(np.asarray(s1[k].counts), np.asarray(s2[k].counts))
-        if s1[k].maxs is not None:
-            np.testing.assert_allclose(np.asarray(s1[k].maxs), np.asarray(s2[k].maxs))
+    for series in (planes, rows):
+        s2 = compute_partial_states(by_series, series, valid, {})
+        for k in s1:
+            if s1[k].sums is not None:
+                np.testing.assert_allclose(
+                    np.asarray(s1[k].sums), np.asarray(s2[k].sums), rtol=1e-12
+                )
+            np.testing.assert_array_equal(np.asarray(s1[k].counts), np.asarray(s2[k].counts))
+            if s1[k].maxs is not None:
+                np.testing.assert_allclose(np.asarray(s1[k].maxs), np.asarray(s2[k].maxs))
 
 
 def test_compute_partial_states_time_major_perm():

@@ -1,8 +1,10 @@
-"""Group ids over a source's own series ordinals (`DistGroupByPlan.lead_ordinals`,
-`ops/aggregate.series_ordinals`): on a plane whose leading tag's codes lie
-apart as `PARTITION BY HASH (hostname) PARTITIONS 4` leaves them, the blocked
-kernels' span guard fails over code gids and holds over ordinal gids, and the
-states carried back to code space are the code-space states, field by field.
+"""Group ids over a source's own series ordinals (a series plan,
+`DistGroupByPlan.series_slots`, whose series are the runs of the grouped
+tag): on a plane whose leading tag's codes lie apart as `PARTITION BY HASH
+(hostname) PARTITIONS 4` leaves them, the blocked kernels' span guard fails
+over code gids and holds over ordinal gids (`ops/aggregate.series_rows`),
+and the states folded to the codes are the code-space states, field by
+field.
 """
 
 import dataclasses
@@ -16,10 +18,15 @@ import pytest
 from greptimedb_tpu.ops.aggregate import (
     BLOCK_ROWS,
     merge_states,
-    series_ordinals,
+    series_rows,
     time_bucket,
 )
-from greptimedb_tpu.parallel.executor import DistGroupByPlan, compute_partial_states
+from greptimedb_tpu.parallel.executor import (
+    SERIES_GROUP,
+    SERIES_MARKS,
+    DistGroupByPlan,
+    compute_partial_states,
+)
 
 CARD, BUCKETS, SPAN, HOUR = 4096, 14, 32, 3_600_000
 PER_SERIES = 4320  # a 12 h window of 10 s ticks
@@ -63,6 +70,24 @@ def plan(aggs, **kw):
     )
     base.update(kw)
     return DistGroupByPlan(**base)
+
+
+SLOTS = 64  # padded series a source
+
+
+def with_series(cols, valid):
+    """The source's series as its region's series table hands them: the
+    row at which each run of equal `hostname` codes among the valid rows
+    opens (`SERIES_MARKS`, the plane's length past them) and the code each
+    run groups to (`SERIES_GROUP`, -1 for a code outside the dictionary)."""
+    code = np.asarray(cols["hostname"])
+    n = int(np.asarray(valid).sum())
+    opens = np.flatnonzero(np.diff(code[:n], prepend=code[:1] - 1)) if n else np.zeros(0, int)
+    marks = np.full(SLOTS, code.shape[0], np.int32)
+    marks[: len(opens)] = opens
+    group = np.full(SLOTS, -1, np.int32)
+    group[: len(opens)] = np.where((code[opens] >= 0) & (code[opens] < CARD), code[opens], -1)
+    return {**cols, SERIES_MARKS: jnp.asarray(marks), SERIES_GROUP: jnp.asarray(group)}, valid
 
 
 def states_of(p, sources, nulls=None, count_cols=None):
@@ -116,13 +141,13 @@ def test_hash_gapped_codes_fail_the_guard_by_code_and_hold_it_by_ordinal():
     bucket = np.asarray(time_bucket(cols["ts"], 0, HOUR))
     assert bucket.max() < BUCKETS
     assert not span_ok(np.asarray(cols["hostname"]) * 16 + bucket, valid)
-    ordinal, slot_of_code, ok = series_ordinals(cols["hostname"], valid, CARD)
-    assert bool(ok)
+    series = with_series(*src)
+    ordinal = series_rows(series[0][SERIES_MARKS], valid.shape[0])
     assert span_ok(np.asarray(ordinal) * 16 + bucket, valid)
-    assert np.array_equal(np.flatnonzero(np.asarray(slot_of_code) >= 0), codes)
+    assert np.array_equal(np.asarray(series[0][SERIES_GROUP])[: len(codes)], codes)
     aggs = DOUBLE_GROUPBY + (("max", "usage_user"), ("count", "usage_idle"))
     by_code = states_of(plan(aggs), [src])  # the guard fails: `scatter`
-    by_ordinal = states_of(plan(aggs, lead_ordinals=True), [src])
+    by_ordinal = states_of(plan(aggs, series_slots=SLOTS), [series])
     assert_states_equal(by_code, by_ordinal)
     assert int(np.asarray(by_ordinal["__presence"].counts).sum()) == len(codes) * PER_SERIES
 
@@ -164,7 +189,8 @@ def runs_not_ascending():
 
 
 def a_code_in_two_runs():
-    # not sorted by the tag (a memtable tail in arrival order): the codes stay
+    # not sorted by the tag (a memtable tail in arrival order): two series
+    # of one code fold to its group
     return [plane(np.array([40, 44, 40, 49, 44], np.int32), per=20_000)], DOUBLE_GROUPBY, None
 
 
@@ -197,39 +223,62 @@ def test_ordinal_states_carried_back_equal_the_code_space_states(case):
         kw = dict(bucket_col=None, n_buckets=1, block_span=16)
     count_cols = ("usage_user",) if nulls is not None else ()
     by_code = states_of(plan(aggs, **kw), sources, nulls, count_cols)
-    by_ordinal = states_of(plan(aggs, lead_ordinals=True, **kw), sources, nulls, count_cols)
+    by_ordinal = states_of(
+        plan(aggs, series_slots=SLOTS, **kw), [with_series(*src) for src in sources], nulls,
+        count_cols,
+    )
     assert_states_equal(by_code, by_ordinal)
     assert int(np.asarray(by_code["__presence"].counts).sum()) > 0
 
 
 @pytest.mark.parametrize("case", [a_code_in_two_runs, runs_not_ascending], ids=["two-runs", "one-run"])
 def test_ordinals_stand_for_codes_only_where_a_code_is_one_run(case):
+    """A row's ordinal is its run's; the runs fold to their codes whatever
+    their order, two runs of one code into the one group."""
     (cols, valid), = case()[0]
-    _ordinal, slot_of_code, ok = series_ordinals(cols["hostname"], valid, CARD)
-    assert bool(ok) == (case is runs_not_ascending)
-    if bool(ok):
-        held = np.asarray(slot_of_code)
-        assert [int(held[c]) for c in (700, 30, 31, 2900, 8, 1500)] == [0, 1, 2, 3, 4, 5]
-        assert (held >= 0).sum() == 6
+    series, _valid = with_series(cols, valid)
+    ordinal = np.asarray(series_rows(series[SERIES_MARKS], valid.shape[0]))
+    group = np.asarray(series[SERIES_GROUP])
+    n = int(np.asarray(valid).sum())
+    assert np.array_equal(group[ordinal[:n]], np.asarray(cols["hostname"])[:n])
+    runs = [700, 30, 31, 2900, 8, 1500] if case is runs_not_ascending else [40, 44, 40, 49, 44]
+    assert group[: len(runs)].tolist() == runs and (group >= 0).sum() == len(runs)
+    by_host = plan(DOUBLE_GROUPBY, series_slots=SLOTS, bucket_col=None, n_buckets=1, block_span=16)
+    presence = np.asarray(states_of(by_host, [(series, valid)])["__presence"].counts)
+    per = n // len(runs)
+    assert {int(c): int(presence[c]) for c in np.flatnonzero(presence)} == {
+        c: per * runs.count(c) for c in runs
+    }
 
 
-def test_a_hierarchical_plan_folds_after_the_carry():
-    """`layout_tags` (hostname, rack) folded down to GROUP BY rack: the lead
-    axis is carried back to codes before `reduce_state_axes` drops it."""
+def test_a_series_plan_folds_a_gapped_region_to_its_groups():
+    """Key (hostname, rack) on a region whose hostname codes lie apart,
+    grouped by rack: stage 1 by the region's series (row bounds as the
+    series table gives them, not the codes) folds to the same rack states
+    as a plan composed over rack's own codes."""
+    from greptimedb_tpu.parallel.executor import SERIES_GROUP, SERIES_MARKS
+
     codes = region_codes(2)[:24]
     cols, valid = plane(codes)
     cols["rack"] = (cols["hostname"] % 4).astype(jnp.int32)
     p = plan(
         (("sum", "usage_user"), ("max", "usage_idle")), group_tags=("rack",), tag_cards=(4,),
-        layout_tags=("hostname", "rack"), layout_cards=(CARD, 4), acc_dtype="float64",
-        block_span=128,
+        acc_dtype="float64", block_span=128,
     )
     by_code = states_of(p, [(cols, valid)])
-    by_ordinal = states_of(dataclasses.replace(p, lead_ordinals=True), [(cols, valid)])
+    n = int(valid.shape[0])
+    marks = np.full(32, n, np.int32)
+    marks[: len(codes)] = np.arange(len(codes)) * PER_SERIES
+    group = np.full(32, -1, np.int32)
+    group[: len(codes)] = codes % 4
+    series = {**cols, SERIES_MARKS: jnp.asarray(marks), SERIES_GROUP: jnp.asarray(group)}
+    by_series = states_of(
+        dataclasses.replace(p, series_slots=32, block_span=SPAN), [(series, valid)]
+    )
     present = np.asarray(by_code["__presence"].counts) > 0
     assert present.shape == (4 * 16,) and present.sum() in (36, 48)  # 12 buckets a rack
     for key in by_code:
         for f in ("sums", "counts", "maxs"):
-            a, b = getattr(by_code[key], f), getattr(by_ordinal[key], f)
+            a, b = getattr(by_code[key], f), getattr(by_series[key], f)
             if a is not None:
                 np.testing.assert_allclose(np.asarray(a)[present], np.asarray(b)[present], rtol=1e-12)

@@ -60,10 +60,13 @@ def test_streamed_matches_cpu_and_bounds_hbm(db):
     hosts, ts, vals = _load_partitioned(db)
     _force_stream(db)
     n_stream0 = metrics.TILE_STREAM_QUERIES.get()
+    series0 = metrics.TILE_STAGE1_SERIES.get()
     t1 = db.sql_one(Q)
     assert metrics.TILE_STREAM_QUERIES.get() == n_stream0 + 1, (
         "working set above budget must take the streamed path"
     )
+    # partitioned on `host`: each region's stage 1 groups by its own hosts
+    assert metrics.TILE_STAGE1_SERIES.get() == series0 + 16
     # per-region latency samples were recorded (one per region with files)
     assert len(tc.LAST_STREAM_CHUNK_MS) == 4
     # after the query every region's planes were released: resident device

@@ -420,10 +420,13 @@ def test_window_probe_counts_the_rows_a_delta_added(tmp_path, monkeypatch):
         # (after a delta the family's fused build probes the window too)
         assert metrics.TILE_WINDOW_COUNTED.get() == counted + len(probes) >= counted + 2
         assert not entry.window_tiles
+        # (GROUP BY host over the key (host, region) groups by series: the
+        # region's series table is charged beside the planes it is read from)
         assert entry.host_nbytes == (
             entry.order.nbytes + entry.file_row_offsets.nbytes
             + sum(a.nbytes for a in entry.sorted_host.values())
             + entry.ts_run_starts.nbytes
+            + (entry.series_table.nbytes if entry.series_table is not None else 0)
         )
     finally:
         db.close()
