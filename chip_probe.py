@@ -158,8 +158,9 @@ def main(seed: int):
 
     def rate_program(sid, ts, v, valid, start, nsteps):
         """What the tile program runs for `rate`: every statistic it reads
-        is found by row position (two searches over the carried keys, seven
-        gathers of S*W), no segment reduction at all."""
+        is found by row position (two searches over the carried keys, the
+        carried planes read at two positions a cell), no segment reduction
+        at all."""
         st = windows(sid, ts, R.strip_counter_resets_segmented(sid, v, valid),
                      valid, start, nsteps, raw=v, reduce=())
         vals, defined = R.extrapolated_rate_dyn(
@@ -170,9 +171,10 @@ def main(seed: int):
     args = (d_sid, d_ts, d_val, d_valid, start, nsteps)
     timed("rate program: strip + range_windows_dyn + extrapolated_rate_dyn",
           jax.jit(rate_program), *args, reps=3, scatters=0)
-    timed("window rows: carry scan + runs from the bounds + two searches + counts",
-          lambda *a: R._window_rows(*a, n_steps=W_PAD),
-          d_ts, d_valid, d_bounds, start, np.int64(STEP_MS), np.int64(RANGE_MS), nsteps)
+    timed("window cells: carry scan + runs from the bounds + two searches + reads",
+          lambda *a: R._window_cells(*a, n_steps=W_PAD),
+          d_ts, d_val, None, d_valid, d_bounds, start, np.int64(STEP_MS),
+          np.int64(RANGE_MS), nsteps)
     cells = jax.block_until_ready(
         (jnp.arange(S_PAD * W_PAD, dtype=jnp.int32) * 7) % (SERIES * SAMPLES)
     )

@@ -12,8 +12,9 @@ series' run bounds beside them, so the rows of a (series, window) cell
 are one contiguous run of the plane: two binary searches inside the
 series' run find it, and the statistics `rate` / `increase` / `delta` /
 `count_over_time` / `last_over_time` / `timestamp()` read (count, first and
-last timestamp and value) are a difference of row counts and gathers at the
-run's ends — selections, no arithmetic, no scatter.  Only sum / min / max
+last timestamp and value) are a difference of row counts and reads of
+carried planes at the run's first row and its last — selections, no
+arithmetic, no scatter.  Only sum / min / max
 (`avg/sum/min/max_over_time`) still assign every sample to the K eval
 windows that can contain it (K = ceil(range/step), static from the query)
 and reduce by segment.  Counter resets are removed up front by a per-series
@@ -125,7 +126,7 @@ def strip_counter_resets_segmented(
     """`strip_counter_resets` for PADDED tile planes: invalid rows (pad
     rows, dedup losers, rows outside the fetch range) may sit BETWEEN a
     series' samples, so "previous sample" means the previous VALID row of
-    the same series.  It is a CARRY, as `_window_rows` carries its keys:
+    the same series.  It is a CARRY, as `_window_cells` carries its keys:
     one prefix scan brings the last valid row's series id and value
     forward over every row, and the carry shifted one place is the
     previous valid row's (series id -1: there is none; valid rows' series
@@ -189,8 +190,10 @@ class WindowStats:
 
 
 # The statistics a caller may ask `range_windows*` to reduce by segment;
-# the other six fields of `WindowStats` are selections, found by position.
+# the other six fields of `WindowStats` are `count` and these selections,
+# found by position.
 REDUCTIONS = ("sum", "min", "max")
+_SELECTED = ("first_ts", "last_ts", "first_val", "first_raw", "last_val")
 
 
 _READS = {
@@ -262,12 +265,26 @@ def sorted_run_bounds(series, num_series: int) -> np.ndarray:
     ).astype(np.int32)
 
 
+# A grid spanning fewer milliseconds than this, (n_steps - 1) * step +
+# range, searches and reads 32-bit keys off its left edge (`_window_cells`).
+_KEY_STOP = int(np.iinfo(np.int32).max)
+
+
+def wide_grid(n_steps, step, range_):
+    """Whether the grid of `n_steps` (the static, padded count) windows at
+    `step` and `range_` ms spans 2**31 - 1 ms or more, so that
+    `range_windows_dyn` searches and reads the 64-bit timestamps, not
+    32-bit keys.  Python ints and traced scalars alike."""
+    return (n_steps - 1) * step + range_ >= _KEY_STOP
+
+
 @functools.partial(jax.jit, static_argnames=("n_steps",))
-def _window_rows(ts, valid, bounds, start, step, range_, n_steps_actual,
-                 *, n_steps: int):
-    """Row positions of every (series, window) cell: (count, first_row,
-    last_row), each [num_series, n_steps] int32, the rows meaningful where
-    count > 0; and [num_series] bools, which series hold a valid row.
+def _window_cells(ts, values, raw, valid, bounds, start, step, range_,
+                  n_steps_actual, *, n_steps: int):
+    """The selections of every (series, window) cell: (count, first_ts,
+    last_ts, first_val, first_raw, last_val), each [num_series * n_steps]
+    in [S*W] order and meaningful where count > 0 (`raw` None: first_raw
+    is first_val); and [num_series] bools, which series hold a valid row.
     `bounds` is the [num_series + 1] int32 run bounds: series s is rows
     [bounds[s], bounds[s + 1]).  See `range_windows_dyn` for the order it
     needs."""
@@ -276,27 +293,28 @@ def _window_rows(ts, valid, bounds, start, step, range_, n_steps_actual,
     rows = jnp.arange(n, dtype=jnp.int32)
 
     # Over every row, the last VALID row at or before it: its index, its
-    # ts, and how many valid rows the plane holds up to here; and, read
-    # back to front, the nearest valid row at or after it.  Whatever an
-    # invalid row holds (a pad row's zeros, a dedup loser, a series the
-    # matcher masked) is never read: the carried keys are non-decreasing
-    # within a run because the valid rows' are.
+    # ts and value, and how many valid rows the plane holds up to here;
+    # and, read back to front, the nearest valid row at or after it.
+    # Whatever an invalid row holds (a pad row's zeros, a dedup loser, a
+    # series the matcher masked) is never read: the carried keys are
+    # non-decreasing within a run because the valid rows' are.
     def carry(a, b):
         take = b[0] >= 0
         return (
             jnp.where(take, b[0], a[0]), jnp.where(take, b[1], a[1]),
-            a[2] + b[2], jnp.maximum(a[3], b[3]),
+            jnp.where(take, b[2], a[2]), a[3] + b[3], jnp.maximum(a[4], b[4]),
         )
 
-    last_valid, ts_c, n_valid, back = prefix_scan(
+    last_valid, ts_c, v_c, n_valid, back = prefix_scan(
         carry,
         (
             jnp.where(valid, rows, -1),
-            jnp.where(valid, ts.astype(jnp.int64), tsmin),
+            jnp.where(valid, ts, tsmin),
+            jnp.where(valid, values, 0),
             valid.astype(jnp.int32),
             jnp.where(valid[::-1], rows, -1),
         ),
-        (-1, tsmin, 0, -1),
+        (-1, tsmin, 0, 0, -1),
     )
     next_valid = (n - 1 - back)[::-1]  # n past the last valid row
 
@@ -329,24 +347,42 @@ def _window_rows(ts, valid, bounds, start, step, range_, n_steps_actual,
     edge = start - range_
     d = jnp.stack([w * step, w * step + range_])
     run = jnp.broadcast_to(lo, (2, n_steps, num_series))
-    stop = jnp.iinfo(jnp.int32).max
+    # A cell reads its rows at two positions, [2, W, S] as the search lays
+    # them out: a, which is a valid row where the cell holds one (the plain
+    # planes and the carried ones agree there, and n_valid[a - 1] is
+    # n_valid[a] - 1), and b - 1, whose carries are the window's last
+    # sample's.  Inside a window a row's 32-bit key is exactly ts - edge,
+    # so a timestamp is read as one word, beside n_valid in ONE gather of
+    # the two stacked along the major axis (a [N, 2] plane would pad its
+    # minor axis to 128 lanes).
+    ends = jnp.array([0, 1], jnp.int32)[:, None, None]
 
     def narrow():
-        key = jnp.where(last_valid >= 0, jnp.clip(ts_c - edge, -1, stop), -1)
-        return _first_greater(
-            key.astype(jnp.int32), run, hi, d.astype(jnp.int32), rounds
-        )
+        key = jnp.where(last_valid >= 0, jnp.clip(ts_c - edge, -1, _KEY_STOP), -1)
+        key = key.astype(jnp.int32)
+        found = _first_greater(key, run, hi, d.astype(jnp.int32), rounds)
+        words = jnp.take(
+            jnp.stack([n_valid, key]), (found - ends).reshape(-1), axis=1, mode="clip"
+        ).reshape((2,) + found.shape)
+        return found, words[0], edge + words[1].astype(jnp.int64)
 
     def wide():
-        return _first_greater(ts_c, run, hi, edge + d, rounds)
+        found = _first_greater(ts_c, run, hi, edge + d, rounds)
+        pos = found - ends
+        return found, jnp.take(n_valid, pos, mode="clip"), jnp.take(ts_c, pos, mode="clip")
 
-    found = jax.lax.cond((n_steps - 1) * step + range_ < stop, narrow, wide)
-    before = jnp.where(
-        found > 0, jnp.take(n_valid, found - 1, mode="clip"), 0
-    )
-    count = jnp.where(w < n_steps_actual, before[1] - before[0], 0)
-    last_row = jnp.take(last_valid, found[1] - 1, mode="clip")
-    return count.T, found[0].T, last_row.T, present
+    found, nv, ts_at = jax.lax.cond(wide_grid(n_steps, step, range_), wide, narrow)
+    a, b = found
+    count = jnp.where((w < n_steps_actual) & (a < b), nv[1] - nv[0] + 1, 0)
+    v_at = jnp.take(v_c, found - ends, mode="clip")
+    raw_at = v_at[0] if raw is None else jnp.take(raw, a, mode="clip")
+
+    def cells(x):  # [W, S] -> [S*W]
+        return x.T.reshape(-1)
+
+    return tuple(map(cells, (
+        count, ts_at[0], ts_at[1], v_at[0], raw_at, v_at[1],
+    ))), present
 
 
 @jax.named_scope("range_windows")
@@ -391,7 +427,7 @@ def range_windows_dyn(
     non-decreasing among them, so the rows of a cell are one run [a, b)
     of the plane; `series` holds s on them (the reductions group by it).
     Invalid rows may sit anywhere and hold anything: the kernel carries
-    the last valid row's key over them (`_window_rows`), so a pad row's
+    the last valid row's key over them (`_window_cells`), so a pad row's
     zeros at the tail or a masked series' rows in the middle break
     nothing.  Valid rows outside every run are in no cell.  What each
     caller hands it:
@@ -411,9 +447,12 @@ def range_windows_dyn(
 
     **By position.**  count, first_ts, last_ts, first_val, last_val and
     first_raw are selections: two searches over the carried keys inside
-    the series' run find [a, b), a difference of valid-row counts is
-    `count`, and five gathers read the first and the last row.  No arithmetic touches a value, so
-    the six are bit-equal to a loop over (series, window).  Of duplicate
+    the series' run find [a, b), and the planes carried over every row
+    (the last valid row's ts and value, the count of valid rows) are read
+    at a and at b - 1 (`_window_cells`): the difference of the counts is
+    `count`, the rest the first and the last sample.  No arithmetic
+    touches a value, so the six are bit-equal to a loop over (series,
+    window).  Of duplicate
     (series, ts) among valid rows (an append-mode table the dedup plane
     does not cover; Prometheus data has none) the FIRST in plane order is
     the window's first sample and the LAST its last, as the reference's
@@ -450,32 +489,17 @@ def range_windows_dyn(
     if ts.shape[0] == 0:  # a subquery whose every point is NaN
         return empty, jnp.zeros(num_series, bool)
 
-    count, first_row, last_row, present = _window_rows(
-        ts, valid, run_bounds, start, step, range_, n_steps_actual,
-        n_steps=n_steps,
-    )
-    count, first_row, last_row = (
-        x.reshape(num_groups) for x in (count, first_row, last_row)
-    )
-    live = count > 0
-
-    def at(plane, row, fills):
-        return jnp.where(live, jnp.take(plane, row, mode="clip"), fills)
-
     v = values.astype(acc_dtype)
     ts = ts.astype(jnp.int64)
-    first_val = at(v, first_row, empty.first_val)
-    stats = dataclasses.replace(
-        empty,
-        count=count,
-        first_ts=at(ts, first_row, empty.first_ts),
-        last_ts=at(ts, last_row, empty.last_ts),
-        first_val=first_val,
-        first_raw=first_val if raw_values is None else at(
-            raw_values.astype(acc_dtype), first_row, empty.first_raw
-        ),
-        last_val=at(v, last_row, empty.last_val),
+    cells, present = _window_cells(
+        ts, v, None if raw_values is None else raw_values.astype(acc_dtype),
+        valid, run_bounds, start, step, range_, n_steps_actual, n_steps=n_steps,
     )
+    live = cells[0] > 0
+    stats = dataclasses.replace(empty, count=cells[0], **{
+        name: jnp.where(live, x, getattr(empty, name))
+        for name, x in zip(_SELECTED, cells[1:])
+    })
     if not reduce:
         return stats, present
 

@@ -92,6 +92,7 @@ from ...ops.rate import (
     range_windows_dyn,
     reductions_for,
     strip_counter_resets_segmented,
+    wide_grid,
 )
 from ...utils import flight_recorder, metrics
 from ...utils import tracing
@@ -889,6 +890,8 @@ class TqlTileExecutor:
                 metrics.TQL_TILE_LOGICAL_DISPATCHES.inc()
             if reductions_for(func):
                 metrics.TQL_TILE_SEGMENT_STATS.inc()
+            if wide_grid(w_pad, step, range_ms):
+                metrics.TQL_TILE_WIDE_GRIDS.inc()
         passes.note(
             "tql_tile", True,
             f"warm: {func} over {len(sources)} region(s) served from "

@@ -423,6 +423,12 @@ TQL_TILE_RUN_BOUNDS = REGISTRY.counter(
     "handed, read off the region's series table (the kernel searches no "
     "series' run over the plane)",
 )
+TQL_TILE_WIDE_GRIDS = REGISTRY.counter(
+    "greptime_tql_tile_wide_grids_total",
+    "TQL tile dispatches whose grid spans 2^31 - 1 ms or more ((padded "
+    "steps - 1) x step + range), so the window kernel searches and reads "
+    "64-bit timestamps where a narrower grid reads 32-bit keys",
+)
 TQL_TILE_SERIES = REGISTRY.counter(
     "greptime_tql_tile_series_total",
     "Series a TQL tile dispatch evaluates (the source's series that exist, "

@@ -8,6 +8,7 @@ import dataclasses
 import re
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -223,6 +224,37 @@ def _case(name):
         vals = np.concatenate([np.zeros(head), vals, np.zeros(tail)])
         valid = np.concatenate([np.zeros(head, bool), valid, np.zeros(tail, bool)])
         grid = (60_000, 30_000, 90_000, 8, 8, 3, 5)
+    elif name == "last_valid_row_then_invalid_rows_up_to_b":
+        # of every five rows two valid: a window's last sample is followed
+        # by invalid rows up to the next valid one past t_w, so b - 1 is
+        # invalid and the carries answer for the last sample
+        sid, ts, vals = _plane(rng, 5, 40, 10_000, 0)
+        valid = np.arange(len(ts)) % 5 < 2
+        grid = (80_000, 50_000, 30_000, 8, 8, 3, 5)
+    elif name == "empty_window_at_the_run_end":
+        # series that end early, their rows cut (not masked): a window
+        # past a series' last sample has a = b = the run's end, where the
+        # next series' first row lies
+        sid, ts, vals = _plane(rng, 4, 30, 10_000, 0)
+        keep = ts <= np.array([300_000, 100_000, 200_000, 50_000])[sid]
+        sid, ts, vals = sid[keep], ts[keep], vals[keep]
+        valid = np.ones(len(ts), bool)
+        grid = (60_000, 30_000, 60_000, 9, 9, 2, 4)
+    elif name == "run_ending_in_invalid_rows_before_the_next_series":
+        # each series' last three rows invalid, the next series' first row
+        # valid right after them
+        sid, ts, vals = _plane(rng, 5, 30, 10_000, 0)
+        valid = np.arange(len(ts)) % 30 < 27
+        grid = (100_000, 40_000, 90_000, 8, 8, 3, 5)
+    elif name == "million_series_shape_small":
+        # prom-1m-series-rules' shape: ten rows a series at a 60 s scrape,
+        # rate[5m] from 5 min on, six steps padded to eight, the series
+        # space and the rows padded to powers of two
+        sid, ts, vals = _plane(rng, 100, 10, 60_000, 0)
+        valid = np.ones(len(ts), bool)
+        bounds = _bounds(sid, 128, 2048)
+        sid, ts, vals, valid = _pad_tail(sid, ts, vals, valid, 2048 - len(sid))
+        grid = (300_000, 60_000, 300_000, 8, 6, 8, 128)
     else:
         raise KeyError(name)
     if bounds is None:
@@ -237,7 +269,19 @@ CASES = [
     "span_one_under_the_32_bit_stop", "span_at_the_32_bit_stop", "one_row", "no_row", "no_valid_row",
     "invalid_rows_between_a_series_samples_hold_anything", "series_with_no_row_in_the_fetch",
     "pad_series_past_the_data", "pad_tail_after_a_valid_last_row", "logical_slice_off_and_lo",
+    "last_valid_row_then_invalid_rows_up_to_b", "empty_window_at_the_run_end",
+    "run_ending_in_invalid_rows_before_the_next_series", "million_series_shape_small",
 ]
+
+
+def _cells(sid, ts, valid, grid):
+    """(series, window, rows) of every cell of the real grid: the cell's
+    valid rows in plane order."""
+    start, step, range_, n_steps, n_actual, _k, n_series = grid
+    for s in range(n_series):
+        for w in range(min(n_steps, n_actual)):
+            t_w = start + w * step
+            yield s, w, np.nonzero(valid & (sid == s) & (ts > t_w - range_) & (ts <= t_w))[0]
 
 
 @pytest.mark.parametrize("name", CASES)
@@ -272,6 +316,20 @@ def test_range_windows_equal_a_loop_over_series_and_windows(name):
         assert (want["count"] == 0).any() and (want["count"] == 1).any()
     if name == "series_with_no_row_in_the_fetch":
         assert not np.asarray(present)[[0, 3, 5]].any()
+    cells = list(_cells(sid, ts, valid, grid))
+    if name == "last_valid_row_then_invalid_rows_up_to_b":
+        # a cell whose last sample's next row is invalid, and its series
+        # has a valid row after it: b - 1 lies past the last sample
+        assert any(
+            len(r) and not valid[r[-1] + 1] and (valid & (sid == s))[r[-1] + 1:].any()
+            for s, _w, r in cells
+        )
+    if name == "empty_window_at_the_run_end":
+        # an empty cell of a present series, past its last sample
+        assert any(
+            not len(r) and ts[valid & (sid == s)].max() <= start + w * step - range_
+            for s, w, r in cells
+        )
 
 
 def test_without_a_raw_plane_first_raw_is_first_val_and_unasked_reductions_keep_their_fill():
@@ -639,6 +697,86 @@ def test_the_loop_walk_finds_a_search_over_the_plane():
         )
 
     assert _loops(jax.make_jaxpr(search)(jnp.zeros(n, jnp.int32)).jaxpr) == [("scan",)]
+
+
+def _gathers(jaxpr, tainted=(), under=()):
+    """Every gather of a jaxpr and its sub-jaxprs as (path, shape, dtype,
+    indexed by a gather): `path` the loop primitives and `cond` branches
+    ("cond0", "cond1", …) that hold it, the last whether its indices are
+    made of another gather's output by plain operations, not through a
+    loop (a search's positions count as the loop's).  `tainted`: which of
+    the jaxpr's inputs are such outputs."""
+    found = []
+    bad = {v for v, t in zip(jaxpr.invars, tainted) if t}
+
+    def is_bad(v):
+        return not isinstance(v, jax.extend.core.Literal) and v in bad
+
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        ins = [is_bad(v) for v in eqn.invars]
+        outs = [False] * len(eqn.outvars)
+        if name == "gather":
+            aval = eqn.outvars[0].aval
+            found.append((under, aval.shape, str(aval.dtype), ins[1]))
+            outs = [True]
+        elif name in ("while", "scan"):
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found += _gathers(sub, (), under + (name,))[0]
+        elif name == "cond":
+            for i, branch in enumerate(eqn.params["branches"]):
+                got, out = _gathers(branch.jaxpr, ins[1:], under + (f"cond{i}",))
+                found += got
+                outs = [a or b for a, b in zip(outs, out)]
+        else:
+            subs = list(jax.core.jaxprs_in_params(eqn.params))
+            if len(subs) == 1 and len(subs[0].invars) == len(eqn.invars):
+                got, outs = _gathers(subs[0], ins, under)  # a jit: follow it
+                found += got
+            else:
+                for sub in subs:
+                    found += _gathers(sub, [any(ins)] * len(sub.invars), under)[0]
+                outs = [any(ins)] * len(eqn.outvars)
+        bad |= {v for v, t in zip(eqn.outvars, outs) if t}
+    return found, [is_bad(v) for v in jaxpr.outvars]
+
+
+def test_a_cell_reads_carried_planes_at_the_searched_positions():
+    """Outside the window search's loops, the panel program's gathers of
+    [S*W] elements or more read at the search's own positions, none at
+    indices another gather made, and only the 64-bit branch of the
+    search's `cond` reads an int64 plane.  The program until the carried
+    value came held seven such gathers: `before` (n_valid at a - 1 and
+    b - 1), `last_row` (last_valid at b - 1) and five statistics (ts at the
+    first and the last row, the value at both, the raw value at the
+    first), two of them at `last_row`'s indices and both ts reads int64.
+    Now five: the stacked words (n_valid, 32-bit key) at a and b - 1 in
+    the 32-bit branch, n_valid and the 64-bit ts there in the other, the
+    carried value at both positions, the raw value at a."""
+    s_pad, w_pad = 256, 8
+    gathers, _ = _gathers(_panel_program_jaxpr(s_pad=s_pad, w_pad=w_pad))
+    big = [
+        g for g in gathers
+        if np.prod(g[1]) >= s_pad * w_pad and not {"while", "scan"} & set(g[0])
+    ]
+    assert [g for g in big if g[3]] == []
+    (wide,) = {g[0][-2] for g in gathers if g[0][-1:] == ("while",) and g[2] == "int64"}
+    assert [g[0] for g in big if g[2] == "int64"] == [(wide,)]
+    assert len(big) == 5
+
+
+def test_the_gather_walk_finds_a_read_through_another_gather():
+    """What the program held until the value was carried: the last sample
+    read at a row that a gather of `last_valid` found."""
+    n, cells = 1 << 12, 256
+
+    def last_ts(ts, last_valid, pos):
+        return jnp.take(ts, jnp.take(last_valid, pos - 1, mode="clip"), mode="clip")
+
+    gathers, _ = _gathers(jax.make_jaxpr(last_ts)(
+        jnp.zeros(n, jnp.int64), jnp.zeros(n, jnp.int32), jnp.zeros(cells, jnp.int32)
+    ).jaxpr)
+    assert [(g[1], g[3]) for g in gathers] == [((cells,), False), ((cells,), True)]
 
 
 def test_a_logical_slice_reads_its_series_at_the_bounds_made_for_it():

@@ -187,8 +187,10 @@ def test_million_series_panel_program_compiles_and_fits(one_chip):
     of 2^20 series and 2^24 rows in four 2^22-row chunks, 6 steps padded to
     8, a `sum by` fold into 16 groups.  What it holds beside its inputs, by
     the compiler's memory analysis (the number `tile_exec._program_bytes`
-    reads before a dispatch), is 2.17 GB with the window search's series on
-    the minor axis (5.34 GB with its 8 steps there, padded to 128 lanes)."""
+    reads before a dispatch), is 1.90 GB with the window search's series on
+    the minor axis and a cell read at two positions of carried planes
+    (2.17 GB while it gathered five statistics at its first and last row;
+    5.34 GB with the 8 steps on the minor axis, padded to 128 lanes)."""
     from greptimedb_tpu.query.promql import tile_exec
 
     chunk, s_pad, w_pad, g_pad = 1 << 22, 1 << 20, 8, 16

@@ -691,3 +691,51 @@ def test_sql_scalar_rate_delta_over_elapsed():
         np.testing.assert_allclose(got2[3], 1.0 / 1000.0)
     finally:
         db.close()
+
+
+@pytest.mark.parametrize("range_ms", [2646, 2647])
+def test_a_grid_past_31_bits_reads_64_bit_timestamps(range_ms):
+    """`TQL_TILE_WIDE_GRIDS` counts a dispatch whose grid, (padded steps
+    - 1) x step + range, spans 2^31 - 1 ms or more: there the window
+    kernel searches and reads 64-bit timestamps, below it 32-bit keys
+    (the `span_at_the_32_bit_stop` grid of `tests/test_rate_windows.py`,
+    samples on and beside every window's edges).  Both answer as the
+    legacy scan; the grids of `prom-1m-series-rules` (a rule's instant
+    and a 6-step panel of rate[5m]) never move it."""
+    step = 715_827_000
+    start = 1_700_000_000_000
+    edges = [start + w * step for w in range(4)]
+    ts = sorted({
+        t + d for t in edges for d in (-range_ms - 1, -range_ms, -range_ms + 1, -1, 0, 1)
+    })
+    db = _db()
+    try:
+        db.sql(
+            "CREATE TABLE tq (host STRING, greptime_value DOUBLE,"
+            " ts TIMESTAMP(3) TIME INDEX, PRIMARY KEY (host))"
+        )
+        db.sql("INSERT INTO tq VALUES " + ",".join(
+            f"('h{h}', {float(i * (h + 1))}, {t})" for h in range(3) for i, t in enumerate(ts)
+        ))
+        db.sql("ADMIN flush_table('tq')")
+        end_s = (edges[-1]) // 1000
+        q = (f"TQL EVAL ({start // 1000}, {end_s}, '{step // 1000}s') "
+             f"rate(tq[{range_ms}ms])")
+        _warm(db, q)
+        d0, w0 = m.TQL_TILE_DISPATCHES.get(), m.TQL_TILE_WIDE_GRIDS.get()
+        got = db.sql_one(q)
+        assert m.TQL_TILE_DISPATCHES.get() == d0 + 1
+        assert m.TQL_TILE_WIDE_GRIDS.get() == w0 + (range_ms == 2647)
+        assert len(_rows(got)) == 12
+        _assert_rows_close(_rows(got), _rows(_legacy(db, q)))
+
+        t = start // 1000 + 300
+        for grid in (f"({t}, {t}, '1m')", f"({t}, {t + 300}, '60s')"):
+            cell = f"TQL EVAL {grid} rate(tq[5m])"
+            _warm(db, cell)
+            d0, w0 = m.TQL_TILE_DISPATCHES.get(), m.TQL_TILE_WIDE_GRIDS.get()
+            db.sql_one(cell)
+            assert m.TQL_TILE_DISPATCHES.get() == d0 + 1
+            assert m.TQL_TILE_WIDE_GRIDS.get() == w0
+    finally:
+        db.close()
